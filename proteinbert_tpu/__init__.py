@@ -29,18 +29,3 @@ Package map (≈ reference layer map, SURVEY.md §1):
 """
 
 __version__ = "0.1.0"
-
-import jax as _jax
-
-if not _jax.config.jax_threefry_partitionable:
-    # The framework's core contracts — on-device corruption whose stream
-    # is identical sharded and unsharded (sharded train_step ==
-    # single-device train_step, tests/test_parallel.py), byte-identical
-    # checkpoint resume across mesh shapes — require the partitionable
-    # threefry lowering. jax >= 0.5 defaults it on; jax 0.4.x defaults
-    # it OFF, which both changes the random stream and breaks
-    # sharded-vs-single-device parity. Pin the new-jax default at
-    # package import, before any RNG use, so the stream is one thing
-    # everywhere. (Not inside make_mesh: flipping the flag mid-process
-    # would split the stream between pre- and post-mesh phases.)
-    _jax.config.update("jax_threefry_partitionable", True)

@@ -189,6 +189,19 @@ def _attention_body(
     (S, G) output in x's dtype."""
     dtype = x.dtype
     inv_scale = 1.0 / jnp.sqrt(jnp.asarray(key_dim, jnp.float32))
+    S = g.shape[0]
+    # The mask stays fp32 until its compare: the v5e VPU has no bf16
+    # comparison.
+    oh32 = oh.astype(jnp.float32)
+    live = oh32 > 0
+    if zero_empty:
+        # Empty segment slots are zeroed through their softmax WEIGHTS,
+        # in the (L, S) layout the scores already have: a (1, S) row
+        # broadcasts down the sublanes, where zeroing the (S, G) output
+        # instead would need the row turned into an (S, 1) column — a
+        # relayout Mosaic refuses for a mask vector.
+        seg_exists = (jnp.sum(oh32, axis=0, keepdims=True)
+                      > 0).astype(jnp.float32)
 
     heads = []
     for h in range(num_heads):
@@ -207,11 +220,19 @@ def _attention_body(
 
         # (L, S) scores: position l's score against segment s's query —
         # A·Bᵀ on the MXU; the one-hot applies as-is, no transposes.
-        scores = lax.dot_general(
-            k_h, q_h, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * inv_scale
-        scores = jnp.where(oh > 0, scores, jnp.float32(-1e30))
+        if S == 1:
+            # One query row is a matrix-VECTOR product; written out as
+            # the fp32 multiply + lane reduction it is, because Mosaic's
+            # own rewrite of that case refuses bf16 operands.
+            scores = jnp.sum(
+                k_h.astype(jnp.float32) * q_h.astype(jnp.float32),
+                axis=1, keepdims=True)
+        else:
+            scores = lax.dot_general(
+                k_h, q_h, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        scores = jnp.where(live, scores * inv_scale, jnp.float32(-1e30))
         # Masked softmax over L (axis 0): -1e30 entries underflow to
         # exact +0.0 after the max shift, so cross-segment V rows
         # contribute exact zeros to the weighted sum (bit-identity,
@@ -220,7 +241,10 @@ def _attention_body(
         # entry zeroes those segments below.
         m = jnp.max(scores, axis=0, keepdims=True)
         e = jnp.exp(scores - m)
-        w = (e / jnp.sum(e, axis=0, keepdims=True)).astype(dtype)
+        w = e / jnp.sum(e, axis=0, keepdims=True)
+        if zero_empty:
+            w = w * seg_exists
+        w = w.astype(dtype)
         # (S, v) = weightsᵀ · V — Aᵀ·B on the MXU.
         heads.append(lax.dot_general(
             w, v_h, (((0,), (0,)), ((), ())),
@@ -244,11 +268,6 @@ def _attention_body(
         part = lax.dot_general(out_h, sel, (((1,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32)
         out = part if out is None else out + part  # (S, G) fp32
-    if zero_empty:
-        seg_exists = jnp.sum(oh.astype(jnp.float32), axis=0,
-                             keepdims=True) > 0  # (1, S)
-        out = jnp.where(seg_exists.reshape(-1, 1), out,
-                        jnp.float32(0.0))
     return out.astype(dtype)
 
 
@@ -391,7 +410,8 @@ def fused_packed_attention(
     global_: jax.Array,
     segment_ids: jax.Array,
     real_mask: Optional[jax.Array] = None,
-    interpret: Optional[bool] = None,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     """Per-segment global attention over a packed row — the dispatch
     that closes the attention leg of ROADMAP item 3: on supported
@@ -424,8 +444,6 @@ def fused_packed_attention(
     if reason is None:
         note_attention_path("pallas", "packed", shape_key)
         oh = _segment_one_hot(segment_ids, S, local.dtype, real_mask)
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         if quantized:
             # Inference-only int8 path: in-kernel dequant, no VJP
             # (quantized params carry no gradient contract).
@@ -445,7 +463,8 @@ def fused_global_attention(
     local: jax.Array,
     global_: jax.Array,
     pad_mask: Optional[jax.Array] = None,
-    interpret: Optional[bool] = None,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     """DENSE (unpacked) global attention through the same kernel: the
     (B, G) global track is an S=1 segment set and the pad mask a
@@ -475,8 +494,6 @@ def fused_global_attention(
             oh = jnp.ones((B, L, 1), local.dtype)
         else:
             oh = pad_mask[..., None].astype(local.dtype)
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         if quantized:
             out = _pallas_attention_forward(params, local,
                                             global_[:, None, :], oh,

@@ -68,7 +68,7 @@ VMEM) and the measured kernel is 0.88-1.03x XLA. Every preset
 therefore trains on the XLA path with remat_policy="convs"; the kernel
 remains an opt-in (`model.use_pallas`) validated for correctness —
 including the Mosaic-only resident-order semantics — by
-tests/tpu_kernel_child.py on real hardware, and is the reference
+chip_smoke.py (phase `kernels`) on real hardware, and is the reference
 implementation for fused-local-track schedules at sharded
 (seq-parallel) shapes.
 """
@@ -146,6 +146,18 @@ def force_reference_requested() -> bool:
     silently force the slow path."""
     return os.environ.get(FORCE_REFERENCE_ENV, "").strip().lower() not in (
         "", "0", "false")
+
+
+def pallas_interpret() -> bool:
+    """Whether a Pallas request runs under the interpreter in this
+    process — the ONE place that reads the backend. True only on the
+    CPU backend, where the interpreter is the only way a TPU kernel can
+    run (the tests and the CPU rehearsals); on any other backend it is
+    False, so the request compiles for the device or raises. The
+    kernels' callers (models/proteinbert.block_apply,
+    parallel/seq_parallel.seq_parallel_apply) ask once per trace and
+    pass the answer down; no kernel entry looks at the backend."""
+    return jax.default_backend() == "cpu"
 
 
 def register_path_observer(cb: Callable[[str, str], None]) -> None:

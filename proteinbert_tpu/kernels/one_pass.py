@@ -451,7 +451,8 @@ def fused_onepass_segments(
     segment_ids: jax.Array,
     real_mask: Optional[jax.Array] = None,
     narrow_dilation: int = 1, wide_dilation: int = 5,
-    interpret: Optional[bool] = None,
+    *,
+    interpret: bool,
 ) -> Tuple[jax.Array, jax.Array]:
     """Whole packed trunk layer — local track AND per-segment global
     attention — as one dispatch (the ISSUE 16 tentpole). On supported
@@ -494,8 +495,6 @@ def fused_onepass_segments(
         seg_oh = _segment_one_hot(segment_ids, S, x.dtype)
         real = (jnp.ones((B, L, 1), x.dtype) if real_mask is None
                 else real_mask[..., None].astype(x.dtype))
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         if quantized:
             # Inference-only int8 path: in-kernel dequant, no VJP.
             return _pallas_onepass_forward(
@@ -507,11 +506,9 @@ def fused_onepass_segments(
             seg_oh, real, narrow_dilation, wide_dilation, True, True,
             interpret)
     note_onepass_path("reference", reason, shape_key)
-    interp = (jax.default_backend() != "tpu" if interpret is None
-              else interpret)
     local = fused_local_track_segments(
         track_params, x, broadcast_seg, segment_ids,
-        narrow_dilation, wide_dilation, interp)
+        narrow_dilation, wide_dilation, interpret)
     attn = fused_packed_attention(
         attn_params, local, global_seg, segment_ids,
         real_mask=real_mask, interpret=interpret)
@@ -523,7 +520,8 @@ def fused_onepass_dense(
     broadcast: jax.Array, global_: jax.Array,
     pad_mask: Optional[jax.Array] = None,
     narrow_dilation: int = 1, wide_dilation: int = 5,
-    interpret: Optional[bool] = None,
+    *,
+    interpret: bool,
 ) -> Tuple[jax.Array, jax.Array]:
     """DENSE (unpacked) trunk layer through the same one-pass program:
     the (B, G) global track is an S=1 segment set, `broadcast` (B, C)
@@ -559,8 +557,6 @@ def fused_onepass_dense(
         else:
             oh = pad_mask[..., None].astype(x.dtype)
         real = jnp.ones((B, L, 1), x.dtype)
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         if quantized:
             local, attn = _pallas_onepass_forward(
                 track_params, attn_params, x, broadcast[:, None, :],
@@ -575,8 +571,6 @@ def fused_onepass_dense(
     note_onepass_path("reference", reason, shape_key)
     # Two-kernel dense composition — the model's pre-one-pass dispatch,
     # fused_kernel_path_total accounting included.
-    interp = (jax.default_backend() != "tpu" if interpret is None
-              else interpret)
     tp = dequant_params(track_params) if quantized else track_params
     track_key = (B, L, C, str(jnp.dtype(x.dtype)))
     if forced:
@@ -586,7 +580,7 @@ def fused_onepass_dense(
     elif pallas_supported(C, L, x.dtype, nt, wt, wide_dilation):
         note_kernel_path("pallas", "dense", track_key)
         local = fused_local_track(tp, x, broadcast,
-                                  narrow_dilation, wide_dilation, interp)
+                                  narrow_dilation, wide_dilation, interpret)
     else:
         note_kernel_path("reference", "unsupported_shape", track_key)
         local = local_track_reference(tp, x, broadcast,

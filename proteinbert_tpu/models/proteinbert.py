@@ -146,10 +146,10 @@ def block_apply(
     # track with the OLD global track, exactly like the split path.
     if cfg.use_pallas:
         from proteinbert_tpu.kernels import (
-            fused_onepass_dense, fused_onepass_segments,
+            fused_onepass_dense, fused_onepass_segments, pallas_interpret,
         )
 
-        interp = jax.default_backend() != "tpu"
+        interp = pallas_interpret()
         if packed:
             # pad_mask is the REAL-token mask: for training packs it
             # equals segment_ids > 0 (segments hold no pad); the ragged

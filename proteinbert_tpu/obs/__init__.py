@@ -17,8 +17,7 @@ Four cooperating pieces behind one `Telemetry` facade:
 
 Consumers: `pbt diagnose` (obs/diagnose.py), `tools/validate_events.py`,
 `tools/trace_attribution.py` (span dumps share the device-trace
-format), `tools/tpu_watch.py` and `bench.py` (note events on the same
-stream). docs/observability.md documents the schema and conventions.
+format), and `bench.py` (note events on the same stream). docs/observability.md documents the schema and conventions.
 
 Overhead contract: `NULL` (the default when no telemetry is passed) is
 a do-nothing facade — `emit` returns None, `span` is a shared

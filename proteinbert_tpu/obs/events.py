@@ -51,7 +51,7 @@ EVENT_FIELDS: Dict[str, Dict[str, Any]] = {
     "nan_halt": {"step": int, "metrics": dict},
     # Terminal record; outcome in OUTCOMES, perf is StepTimer.summary().
     "run_end": {"outcome": str, "perf": dict},
-    # Generic annotated event for tools (tpu_watch, bench) that share
+    # Generic annotated event for tools (bench, the drills) that share
     # the stream format without being training runs.
     "note": {"source": str},
     # ---- online serving lifecycle (proteinbert_tpu/serve/) ----

@@ -1,7 +1,7 @@
 from proteinbert_tpu.parallel.mesh import make_mesh, mesh_for_devices
 from proteinbert_tpu.parallel.sharding import (
-    batch_sharding, serve_batch_sharding, state_sharding,
-    shard_train_state,
+    batch_sharding, pin_state_sharding, serve_batch_sharding,
+    state_sharding, shard_train_state,
 )
 from proteinbert_tpu.parallel.halo import (
     halo_exchange, conv1d_halo, seq_parallel_conv1d,
@@ -20,8 +20,8 @@ from proteinbert_tpu.parallel.zero import (
 
 __all__ = [
     "make_mesh", "mesh_for_devices",
-    "batch_sharding", "serve_batch_sharding", "state_sharding",
-    "shard_train_state",
+    "batch_sharding", "pin_state_sharding", "serve_batch_sharding",
+    "state_sharding", "shard_train_state",
     "halo_exchange", "conv1d_halo", "seq_parallel_conv1d",
     "make_seq_parallel_train_step", "seq_parallel_apply",
     "sharded_global_attention", "maybe_initialize_distributed",

@@ -106,7 +106,7 @@ def seq_parallel_conv1d(
     fn = partial(
         conv1d_halo, dilation=dilation, axis_name="seq", axis_size=n_seq
     )
-    from proteinbert_tpu.parallel.mesh import shard_map
+    from jax import shard_map
 
     return shard_map(
         lambda p, xb: fn(p, xb),

@@ -30,20 +30,15 @@ from jax.sharding import Mesh
 from proteinbert_tpu.configs import MeshConfig
 
 
-# Version-compat shard_map — moved to utils/compat.py (one home for the
-# jax 0.4.x shims, alongside request_cpu_devices); re-exported here for
-# the existing importers (seq_parallel, halo, tests).
-from proteinbert_tpu.utils.compat import shard_map  # noqa: F401
-
-
 def make_mesh(
     cfg: MeshConfig, devices: Optional[Sequence[jax.Device]] = None
 ) -> Mesh:
     """Build the (data, fsdp, model, seq) mesh from available devices.
 
-    Uses jax.experimental.mesh_utils device ordering on real TPU slices so
-    mesh-adjacent devices are ICI-adjacent; falls back to a plain reshape
-    on CPU/virtual platforms.
+    On a TPU the device order comes from jax.experimental.mesh_utils, so
+    mesh-adjacent devices are ICI-adjacent — and a topology the helpers
+    refuse is raised, never replaced by an arbitrary order. CPU/virtual
+    platforms have no topology: a plain reshape.
     """
     if devices is None:
         devices = jax.devices()
@@ -53,6 +48,8 @@ def make_mesh(
             f"mesh {cfg.shape} wants {cfg.num_devices} devices, have {n}"
         )
     if devices[0].platform == "tpu":
+        from jax.experimental import mesh_utils
+
         n_slices = len({getattr(d, "slice_index", 0) for d in devices})
         if n_slices > 1:
             # Multi-slice pod: the slower DCN hop must carry only the
@@ -64,29 +61,13 @@ def make_mesh(
                     f"mesh data axis {cfg.data} must be a multiple of the "
                     f"{n_slices} slices so DCN carries only data "
                     "parallelism")
-            from jax.experimental import mesh_utils
-
             per_slice = (cfg.data // n_slices, cfg.fsdp, cfg.model, cfg.seq)
-            try:
-                dev_array = mesh_utils.create_hybrid_device_mesh(
-                    per_slice, (n_slices, 1, 1, 1), devices=devices)
-                return Mesh(dev_array, cfg.axis_names)
-            except Exception:  # pragma: no cover - picky topology helpers:
-                # a reshape mesh is suboptimal (DCN placement not
-                # guaranteed) but runs; don't crash training at startup.
-                import logging
-
-                logging.getLogger(__name__).warning(
-                    "create_hybrid_device_mesh failed for %s over %d "
-                    "slices; falling back to reshape ordering",
-                    cfg.shape, n_slices)
-        try:
-            from jax.experimental import mesh_utils
-
-            dev_array = mesh_utils.create_device_mesh(cfg.shape, devices=devices)
-            return Mesh(dev_array, cfg.axis_names)
-        except Exception:  # pragma: no cover - topology helpers can be picky
-            pass
+            dev_array = mesh_utils.create_hybrid_device_mesh(
+                per_slice, (n_slices, 1, 1, 1), devices=devices)
+        else:
+            dev_array = mesh_utils.create_device_mesh(cfg.shape,
+                                                      devices=devices)
+        return Mesh(dev_array, cfg.axis_names)
     dev_array = np.asarray(devices).reshape(cfg.shape)
     return Mesh(dev_array, cfg.axis_names)
 

@@ -304,7 +304,7 @@ def make_quant_zero_train_step(mesh: Mesh, cfg: PretrainConfig,
     from proteinbert_tpu.train.schedule import (
         effective_lr, make_optimizer, needs_loss_value,
     )
-    from proteinbert_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     payload = payload or cfg.parallel.grad_reduce_dtype
     joint = check_quant_mesh(mesh, payload, cfg.data.batch_size)

@@ -42,6 +42,7 @@ from proteinbert_tpu.data.vocab import PAD_ID
 from proteinbert_tpu.kernels.fused_block import (
     fused_local_track_valid,
     local_track_valid_reference,
+    pallas_interpret,
     pallas_supported,
     track_halo,
 )
@@ -196,10 +197,10 @@ def seq_parallel_apply(
     models/proteinbert.apply; use when cfg.use_pallas needs to run under
     sequence parallelism (see module docstring)."""
     axis_size = mesh.shape[_SEQ_AXIS]
-    interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret()
     fn = partial(_shard_forward, cfg=cfg, axis_size=axis_size,
                  interpret=interpret)
-    from proteinbert_tpu.parallel.mesh import shard_map
+    from jax import shard_map
 
     return shard_map(
         fn,

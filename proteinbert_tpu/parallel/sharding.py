@@ -223,3 +223,20 @@ def shard_train_state(state: Any, mesh: Mesh,
     shardings = state_sharding(mesh, jax.eval_shape(lambda: state),
                                zero_update=zero_update)
     return jax.device_put(state, shardings)
+
+
+def pin_state_sharding(step, state, static_argnums=()):
+    """`step(state, batch, ...) -> (new_state, metrics)`, jitted so that
+    the new state keeps `state`'s layout leaf for leaf (and the old
+    state is donated to it).
+
+    Left to itself the partitioner may hand a leaf back in another
+    layout than the storage rules chose — on fsdp meshes it does, for
+    the replicated embedding table and its Adam moments. The next call
+    then sees new input shardings and is traced and COMPILED a second
+    time, and the state no longer sits where `state_sharding` put it.
+    The trainer wraps every sharded step in this; one executable, one
+    layout."""
+    pinned = jax.tree.map(lambda a: a.sharding, state)
+    return jax.jit(step, static_argnums=static_argnums, donate_argnums=0,
+                   out_shardings=(pinned, None))

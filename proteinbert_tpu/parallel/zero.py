@@ -58,11 +58,11 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from proteinbert_tpu.configs import OptimizerConfig, PretrainConfig
 from proteinbert_tpu.parallel.sharding import param_spec, zero_update_spec
-from proteinbert_tpu.utils.compat import shard_map
 
 ZERO_AXES = ("data", "fsdp")
 

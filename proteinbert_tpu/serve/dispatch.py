@@ -50,15 +50,11 @@ KINDS = ("embed", "predict_go", "predict_residues")
 
 
 def _device_hbm_bytes() -> Optional[int]:
-    """The accelerator's per-device memory budget in bytes, when the
-    backend reports one (TPU/GPU memory_stats); None when it doesn't
-    (CPU) — candidate HBM pricing then only refuses against an
-    explicit budget."""
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:  # noqa: BLE001 — backend-optional API; absence
-        # of a budget must never break candidate loading.
-        return None
+    """The accelerator's per-device memory budget in bytes
+    (memory_stats()["bytes_limit"]). The CPU backend reports no stats
+    (None) — candidate HBM pricing then only refuses against an
+    explicit budget; an accelerator that fails to report raises."""
+    stats = jax.local_devices()[0].memory_stats()
     if isinstance(stats, dict):
         limit = stats.get("bytes_limit")
         if isinstance(limit, int) and limit > 0:

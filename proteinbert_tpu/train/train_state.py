@@ -19,7 +19,6 @@ the reference never had (SURVEY C18).
 
 from __future__ import annotations
 
-import os
 from functools import partial
 from typing import Any, Dict, Tuple
 
@@ -27,19 +26,6 @@ import flax.struct
 import jax
 import jax.numpy as jnp
 import optax
-
-# Buffer-donation opt-out, honored by every donating step in the
-# framework (train_step here, finetune_step, the explicit seq-parallel
-# step). On jax 0.4.x, executables DESERIALIZED from the persistent
-# compilation cache mis-handle donated buffers on the CPU backend —
-# observed as both segfaults and silently dropped parameter updates;
-# without donation the same warm-cache runs are bit-correct
-# (tests/conftest.py documents the repro). The test harness therefore
-# sets PBT_DISABLE_DONATION=1 and keeps the compile cache: donation is
-# worthless on CPU smoke shapes but vital for HBM headroom on TPU, so
-# it stays on by default. Read at import time — it must be set before
-# the first `proteinbert_tpu` import to take effect.
-DONATE_STATE = () if os.environ.get("PBT_DISABLE_DONATION") else (0,)
 
 from proteinbert_tpu.configs import PretrainConfig
 from proteinbert_tpu.models import proteinbert
@@ -51,6 +37,12 @@ from proteinbert_tpu.train.loss import (
 from proteinbert_tpu.train.schedule import (
     effective_lr, make_optimizer, needs_loss_value, plateau_uses_eval,
 )
+
+# Every step that takes a train state donates it (train_step here,
+# finetune_step, the ZeRO-1, quantized and explicit seq-parallel steps):
+# the update happens in the state's own buffers, which is the HBM
+# headroom a 16 GB chip needs at the presets' batch sizes.
+DONATE_STATE = (0,)
 
 
 @flax.struct.dataclass
@@ -167,22 +159,6 @@ def plateau_observation(cfg_opt, metrics: Dict[str, jax.Array],
 
 
 @jax.jit
-def copy_pytree(tree):
-    """Jitted identity copy of a pytree — fresh XLA-produced buffers.
-
-    Two consumers, one jit cache entry: snapshot_train_state (below)
-    uses it to decouple a checkpoint snapshot from the donated live
-    buffers, and Checkpointer.restore uses it to canonicalize
-    orbax-restored arrays — on jax 0.4.37's CPU backend, restored
-    arrays fed straight into a DONATING jitted step whose executable
-    was DESERIALIZED from the persistent compilation cache segfault
-    (minimal repro: orbax restore + donate_argnums + warm
-    jax_compilation_cache_dir; remove any one, no crash). The copy
-    re-materializes leaves as ordinary XLA outputs, which cached
-    executables donate safely — device_put/host round-trips do NOT."""
-    return jax.tree.map(jnp.copy, tree)
-
-
 def snapshot_train_state(state: TrainState) -> TrainState:
     """On-device copy of the whole state pytree, dispatched asynchronously.
 
@@ -197,17 +173,23 @@ def snapshot_train_state(state: TrainState) -> TrainState:
     only, and the copy itself is device-side memcpy ordered BEFORE the
     next train step on the stream. The staged saver then device_gets the
     copy from a worker thread while training keeps dispatching."""
-    return copy_pytree(state)
+    return jax.tree.map(jnp.copy, state)
 
 
 def create_train_state(key: jax.Array, cfg: PretrainConfig) -> TrainState:
     k_init, k_state = jax.random.split(key)
     params = proteinbert.init(k_init, cfg.model)
     tx = make_optimizer(cfg.optimizer)
+    # optax initialises some scalars (reduce_on_plateau's best/avg value)
+    # from Python numbers, i.e. weakly typed; the first step returns them
+    # strongly typed, and the SECOND step would then be traced and
+    # compiled all over again. Fix the types at birth: one executable.
+    opt_state = jax.tree.map(lambda x: jnp.asarray(x, dtype=x.dtype),
+                             tx.init(params))
     return TrainState(
         step=jnp.zeros((), jnp.int32),
         params=params,
-        opt_state=tx.init(params),
+        opt_state=opt_state,
         key=k_state,
     )
 

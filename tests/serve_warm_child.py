@@ -1,5 +1,6 @@
 """Child process for the compile-cache warm-boot test (ISSUE 11
-satellite): arm the persistent compilation cache at argv[1], boot a
+satellite): arm the persistent compilation cache where
+JAX_COMPILATION_CACHE_DIR says (the one way a run places it), boot a
 tiny serve Server (warming two request kinds), and print one JSON line
 {"warmup_seconds", "executables"}. Run twice against the SAME fresh
 cache dir by tests/test_fleet.py: the first boot compiles cold, the
@@ -19,14 +20,12 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ.setdefault("PBT_DISABLE_DONATION", "1")
 
 
 def main() -> int:
-    cache_dir = sys.argv[1]
     from proteinbert_tpu.utils.compat import configure_compile_cache
 
-    configure_compile_cache(cache_dir)
+    configure_compile_cache()
 
     import jax
 

@@ -50,7 +50,7 @@ def _seg_rows(*rows):
 
 @jax.jit
 def _fused(params, x, g, seg):
-    return ka.fused_packed_attention(params, x, g, seg)
+    return ka.fused_packed_attention(params, x, g, seg, interpret=True)
 
 
 @jax.jit
@@ -60,7 +60,8 @@ def _ref(params, x, g, seg):
 
 @jax.jit
 def _fused_masked(params, x, g, seg, real):
-    return ka.fused_packed_attention(params, x, g, seg, real_mask=real)
+    return ka.fused_packed_attention(params, x, g, seg, real_mask=real,
+                                     interpret=True)
 
 
 @jax.jit
@@ -127,7 +128,7 @@ def test_dense_parity_and_all_pad_row(attn_inputs):
     pad = jnp.asarray(pad)
     before = dict(ka.ATTN_PATH_TOTAL)
     got = jax.jit(lambda p, xx, gg, m: ka.fused_global_attention(
-        p, xx, gg, m))(params, x, g2, pad)
+        p, xx, gg, m, interpret=True))(params, x, g2, pad)
     assert (ka.ATTN_PATH_TOTAL.get(("pallas", "dense"), 0)
             > before.get(("pallas", "dense"), 0))
     want = jax.jit(lambda p, xx, gg, m: global_attention_apply(
@@ -144,7 +145,8 @@ def test_gradient_parity(attn_inputs):
     seg = _seg_rows([(1, 100), (2, 80)], [(1, L)])
 
     def loss_fused(p, xx, gg):
-        return jnp.sum(ka.fused_packed_attention(p, xx, gg, seg) ** 2)
+        return jnp.sum(ka.fused_packed_attention(
+            p, xx, gg, seg, interpret=True) ** 2)
 
     def loss_ref(p, xx, gg):
         return jnp.sum(
@@ -183,7 +185,8 @@ def test_bf16_parity(attn_inputs):
     params, x, g = attn_inputs
     seg = _seg_rows([(1, 200)], [(1, 64), (2, 190)])
     got = ka.fused_packed_attention(
-        params, x.astype(jnp.bfloat16), g.astype(jnp.bfloat16), seg
+        params, x.astype(jnp.bfloat16), g.astype(jnp.bfloat16), seg,
+        interpret=True,
     ).astype(jnp.float32)
     want = packed_global_attention_apply(
         params, x.astype(jnp.bfloat16), g.astype(jnp.bfloat16), seg
@@ -203,18 +206,18 @@ def test_force_reference_env_override(attn_inputs, monkeypatch):
     seg = _seg_rows([(1, 200)], [(1, L)])
     monkeypatch.setenv(fb.FORCE_REFERENCE_ENV, "0")
     before = dict(ka.ATTN_PATH_TOTAL)
-    _ = ka.fused_packed_attention(params, x, g, seg)
+    _ = ka.fused_packed_attention(params, x, g, seg, interpret=True)
     assert (ka.ATTN_PATH_TOTAL.get(("reference", "forced"), 0)
             == before.get(("reference", "forced"), 0))
     monkeypatch.setenv(fb.FORCE_REFERENCE_ENV, "1")
     before = ka.ATTN_PATH_TOTAL.get(("reference", "forced"), 0)
-    got = ka.fused_packed_attention(params, x, g, seg)
+    got = ka.fused_packed_attention(params, x, g, seg, interpret=True)
     assert ka.ATTN_PATH_TOTAL.get(("reference", "forced"), 0) == before + 1
     want = packed_global_attention_apply(params, x, g, seg)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     # The dense entry honors it too.
     g2 = jnp.zeros((B, G), jnp.float32)
-    got_d = ka.fused_global_attention(params, x, g2)
+    got_d = ka.fused_global_attention(params, x, g2, interpret=True)
     assert ka.ATTN_PATH_TOTAL.get(("reference", "forced"), 0) == before + 2
     np.testing.assert_array_equal(
         np.asarray(got_d),

@@ -1,0 +1,170 @@
+"""Compile the main kernel path for the chip, without the chip.
+
+The TPU's compiler is installed beside the CPU backend and compiles for a
+chip that is described, not attached (the `on-chip-measurement` guide,
+section 2.3). Interpret mode cannot show what it refuses — an operand
+Mosaic cannot lay out, a slice off the tiling, more VMEM than a kernel may
+use, a step that does not fit 16 GB — so each Pallas entry of the main
+path is compiled here with `interpret=False` at the two widths users run
+(`base`: C=512/G=512/H=8, the paper's: C=128/G=512/H=4; L=512, bf16), plus
+the whole `base` train step. Nothing runs: results and times come only
+from `chip_smoke.py` on the chip.
+
+Skipped where the v5e topology cannot be described (no TPU compiler in the
+installation).
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+# The compiler library guards a real chip with a lock file; nothing here
+# touches a chip, and under pytest-xdist several workers describe the
+# topology at once.
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from proteinbert_tpu import kernels as K
+from proteinbert_tpu.configs import ModelConfig, get_preset
+from proteinbert_tpu.kernels import attention, one_pass
+from proteinbert_tpu.models import proteinbert
+
+V5E_HBM_BYTES = 16 * 1024 ** 3
+
+B, L, S = 8, 512, 8
+WIDTHS = {  # name: (C, G, H, key_dim)
+    "base": (512, 512, 8, 64),
+    "paper": (128, 512, 4, 64),
+}
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e chip to compile for. The persistent cache is off
+    around these compiles: an entry written for a described device cannot
+    be read back without the device, and the next compile would warn."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any refusal means no compiler
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _on(chip, tree):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), tree)
+
+
+def _block_shapes(width, quantized=False):
+    C, G, H, kd = WIDTHS[width]
+    cfg = ModelConfig(local_dim=C, global_dim=G, num_heads=H, key_dim=kd,
+                      num_blocks=1, dtype="bfloat16")
+    blk = jax.eval_shape(lambda k: proteinbert.block_init(k, cfg),
+                         jax.random.PRNGKey(0))
+    if quantized:
+        from proteinbert_tpu.parallel.quant import quantize_params
+
+        blk = jax.eval_shape(quantize_params, blk)
+    track = {k: blk[k] for k in ("narrow_conv", "wide_conv", "local_ln1",
+                                 "local_dense", "local_ln2")}
+    return C, G, track, blk["attention"]
+
+
+def _sds(shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _entry(name, width):
+    """(function, abstract args) of one kernel entry at one width."""
+    quantized = name.endswith("_int8")
+    C, G, track, attn = _block_shapes(width, quantized)
+    x, bc, bcs = _sds((B, L, C)), _sds((B, C)), _sds((B, S, C))
+    g, gs = _sds((B, G)), _sds((B, S, G))
+    seg, pad = _sds((B, L), jnp.int32), _sds((B, L), jnp.bool_)
+    if name == "fused_local_track":  # forward AND the custom-VJP backward
+        def f(p, x, b):
+            return jax.value_and_grad(
+                lambda p, x: K.fused_local_track(p, x, b, 1, 5, False)
+                .astype(jnp.float32).sum(), argnums=(0, 1))(p, x)
+        return f, (track, x, bc)
+    if name == "fused_local_track_segments":
+        return (lambda p, x, b, s: K.fused_local_track_segments(
+            p, x, b, s, 1, 5, False)), (track, x, bcs, seg)
+    if name == "fused_global_attention":
+        return (lambda p, x, g, m: attention.fused_global_attention(
+            p, x, g, m, interpret=False)), (attn, x, g, pad)
+    if name == "fused_packed_attention":
+        return (lambda p, x, g, s, m: attention.fused_packed_attention(
+            p, x, g, s, m, interpret=False)), (attn, x, gs, seg, pad)
+    if name.startswith("fused_onepass_dense"):
+        return (lambda tp, ap, x, b, g, m: one_pass.fused_onepass_dense(
+            tp, ap, x, b, g, m, 1, 5, interpret=False)), (
+                track, attn, x, bc, g, pad)
+    if name.startswith("fused_onepass_segments"):
+        return (lambda tp, ap, x, b, g, s, m: one_pass.fused_onepass_segments(
+            tp, ap, x, b, g, s, m, 1, 5, interpret=False)), (
+                track, attn, x, bcs, gs, seg, pad)
+    raise KeyError(name)
+
+
+# The six entries of the main kernel path. The one-pass pair compiles in
+# both arms: fp32-held weights, and int8 leaves dequantized in the kernel.
+ENTRIES = ("fused_local_track", "fused_local_track_segments",
+           "fused_global_attention", "fused_packed_attention",
+           "fused_onepass_dense", "fused_onepass_segments")
+CASES = ([(e, w) for w in WIDTHS for e in ENTRIES]
+         + [(e + "_int8", w) for w in WIDTHS
+            for e in ("fused_onepass_dense", "fused_onepass_segments")])
+
+
+@pytest.mark.parametrize("entry,width", CASES,
+                         ids=[f"{e}-{w}" for e, w in CASES])
+def test_kernel_entry_compiles_for_v5e(chip, entry, width):
+    """`interpret=False` → a Mosaic kernel in the compiled program, or the
+    compiler's own refusal as the failure. At `base` width the one-pass
+    gate defers (its VMEM pricing) and the two-kernel composition is what
+    compiles — two custom calls; at the paper width the one-pass program
+    itself — one."""
+    fn, args = _entry(entry, width)
+    text = jax.jit(fn).lower(*_on(chip, args)).compile().as_text()
+    calls = text.count("tpu_custom_call")
+    if entry.startswith("fused_onepass"):
+        assert calls == (2 if width == "base" else 1), calls
+    else:
+        assert calls >= 1, "no tpu_custom_call in the compiled text"
+
+
+def test_base_train_step_fits_the_chip(chip):
+    """The whole `base` step at the preset's own batch (128 x 512, bf16,
+    remat "convs") compiles for one v5e and its arguments, outputs and
+    temporaries fit the 16 GB of HBM with the donated state aliased."""
+    from proteinbert_tpu.train import create_train_state, train_step
+
+    cfg = get_preset("base")
+    state = jax.eval_shape(
+        lambda: create_train_state(jax.random.PRNGKey(0), cfg))
+    batch = {
+        "tokens": _sds((cfg.data.batch_size, cfg.data.seq_len), jnp.int32),
+        "annotations": _sds((cfg.data.batch_size,
+                             cfg.model.num_annotations), jnp.float32),
+    }
+    compiled = train_step.lower(
+        _on(chip, state), _on(chip, batch), cfg).compile()
+    m = compiled.memory_analysis()
+    need = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert 0 < need < V5E_HBM_BYTES, (need, m)
+    # Donation took: the new state lives in the old one's buffers.
+    assert m.alias_size_in_bytes > 0.9 * m.output_size_in_bytes, m

@@ -64,7 +64,8 @@ def _seg_rows(*rows):
 
 @jax.jit
 def _one(track, attn, x, bc, g, seg):
-    return op.fused_onepass_segments(track, attn, x, bc, g, seg)
+    return op.fused_onepass_segments(track, attn, x, bc, g, seg,
+                                     interpret=True)
 
 
 @jax.jit
@@ -77,7 +78,7 @@ def _two(track, attn, x, bc, g, seg):
 @jax.jit
 def _one_masked(track, attn, x, bc, g, seg, real):
     return op.fused_onepass_segments(track, attn, x, bc, g, seg,
-                                     real_mask=real)
+                                     real_mask=real, interpret=True)
 
 
 @jax.jit
@@ -90,7 +91,8 @@ def _two_masked(track, attn, x, bc, g, seg, real):
 
 @jax.jit
 def _one_dense(track, attn, x, bc, g, pad):
-    return op.fused_onepass_dense(track, attn, x, bc, g, pad_mask=pad)
+    return op.fused_onepass_dense(track, attn, x, bc, g, pad_mask=pad,
+                                  interpret=True)
 
 
 @jax.jit
@@ -206,7 +208,8 @@ def test_gradient_parity(onepass_inputs):
     real = jnp.ones((B, L, 1), jnp.float32)
 
     def loss_one(tp, ap, xx, bb, gg):
-        local, a = op.fused_onepass_segments(tp, ap, xx, bb, gg, seg)
+        local, a = op.fused_onepass_segments(tp, ap, xx, bb, gg, seg,
+                                             interpret=True)
         return jnp.sum(local ** 2) + jnp.sum(a ** 2)
 
     def loss_ref(tp, ap, xx, bb, gg):
@@ -238,7 +241,7 @@ def test_force_reference_env_override_both_entries(onepass_inputs,
 
     before = op.ONEPASS_PATH_TOTAL.get(("reference", "forced"), 0)
     got = jax.jit(lambda tp, ap, xx, bb, gg: op.fused_onepass_segments(
-        tp, ap, xx, bb, gg, seg))(track, attn, x, bc, g)
+        tp, ap, xx, bb, gg, seg, interpret=True))(track, attn, x, bc, g)
     assert op.ONEPASS_PATH_TOTAL.get(("reference", "forced"),
                                      0) == before + 1
     want = jax.jit(lambda tp, ap, xx, bb, gg: (
@@ -252,7 +255,7 @@ def test_force_reference_env_override_both_entries(onepass_inputs,
     bc_d, g_d = bc[:, 0, :], g[:, 0, :]
     before = op.ONEPASS_PATH_TOTAL.get(("reference", "forced"), 0)
     got_d = jax.jit(lambda tp, ap, xx, bb, gg: op.fused_onepass_dense(
-        tp, ap, xx, bb, gg))(track, attn, x, bc_d, g_d)
+        tp, ap, xx, bb, gg, interpret=True))(track, attn, x, bc_d, g_d)
     assert op.ONEPASS_PATH_TOTAL.get(("reference", "forced"),
                                      0) == before + 1
     # `fused_local_track` is the raw kernel (no force check of its

@@ -84,7 +84,7 @@ def main() -> int:
     # ---- gate 1: packed parity + counter coverage --------------------
     before = dict(ka.ATTN_PATH_TOTAL)
     got = jax.jit(lambda p, x, g, s: ka.fused_packed_attention(
-        p, x, g, s))(params, local, gseg, seg)
+        p, x, g, s, interpret=True))(params, local, gseg, seg)
     delta_p = (ka.ATTN_PATH_TOTAL.get(("pallas", "packed"), 0)
                - before.get(("pallas", "packed"), 0))
     delta_s = (ka.ATTN_PATH_TOTAL.get(("reference", "segments"), 0)
@@ -105,7 +105,7 @@ def main() -> int:
     real[1, :100] = True
     real = jnp.asarray(real)
     got_m = ka.fused_packed_attention(params, local, gseg, seg,
-                                      real_mask=real)
+                                      real_mask=real, interpret=True)
     want_m = packed_global_attention_apply(params, local, gseg, seg,
                                            real_mask=real)
     diff_m = float(np.abs(np.asarray(got_m) - np.asarray(want_m)).max())
@@ -118,7 +118,8 @@ def main() -> int:
     pad[1, :] = False
     pad = jnp.asarray(pad)
     before = dict(ka.ATTN_PATH_TOTAL)
-    got_d = ka.fused_global_attention(params, local, g2, pad)
+    got_d = ka.fused_global_attention(params, local, g2, pad,
+                                      interpret=True)
     delta_d = (ka.ATTN_PATH_TOTAL.get(("pallas", "dense"), 0)
                - before.get(("pallas", "dense"), 0))
     want_d = global_attention_apply(params, local, g2, pad)
@@ -129,7 +130,8 @@ def main() -> int:
 
     # ---- gate 3: VJP gradient parity ---------------------------------
     def loss_f(p, x, g):
-        return jnp.sum(ka.fused_packed_attention(p, x, g, seg) ** 2)
+        return jnp.sum(ka.fused_packed_attention(
+            p, x, g, seg, interpret=True) ** 2)
 
     def loss_r(p, x, g):
         return jnp.sum(packed_global_attention_apply(p, x, g, seg) ** 2)
@@ -146,7 +148,7 @@ def main() -> int:
     try:
         before = dict(ka.ATTN_PATH_TOTAL)
         got_fo = jax.jit(lambda p, x, g, s: ka.fused_packed_attention(
-            p, x, g, s))(params, local, gseg, seg)
+            p, x, g, s, interpret=True))(params, local, gseg, seg)
         bumps = (ka.ATTN_PATH_TOTAL.get(("reference", "forced"), 0)
                  - before.get(("reference", "forced"), 0))
         bit = np.array_equal(np.asarray(got_fo), np.asarray(want))

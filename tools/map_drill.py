@@ -61,7 +61,6 @@ sys.path.insert(0, REPO)
 sys.path.insert(1, os.path.dirname(os.path.abspath(__file__)))
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("PBT_DISABLE_DONATION", "1")
 
 SEQ_LEN = 48
 BUCKETS = "[16,32,48]"

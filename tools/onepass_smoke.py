@@ -86,7 +86,8 @@ def main() -> int:
          "guard: (128, 64, 128, 4) fp32 shape has a one-pass plan")
 
     def one(tp, ap, xx, bb, gg, ss):
-        return op.fused_onepass_segments(tp, ap, xx, bb, gg, ss)
+        return op.fused_onepass_segments(tp, ap, xx, bb, gg, ss,
+                                         interpret=True)
 
     def two(tp, ap, xx, bb, gg, ss):
         loc = fb.fused_local_track_segments(tp, xx, bb, ss, 1, 5, True)
@@ -115,7 +116,7 @@ def main() -> int:
     real[1, :100] = True
     real = jnp.asarray(real)
     got_m = op.fused_onepass_segments(track, attn, x, bc, g, seg,
-                                      real_mask=real)
+                                      real_mask=real, interpret=True)
     loc_m = fb.fused_local_track_segments(track, x, bc, seg, 1, 5, True)
     want_m = (loc_m, ka.fused_packed_attention(attn, loc_m, g, seg,
                                                real_mask=real,
@@ -141,7 +142,7 @@ def main() -> int:
     pad = jnp.asarray(pad)
     before = dict(op.ONEPASS_PATH_TOTAL)
     got_d = op.fused_onepass_dense(track, attn, x, bc_d, g_d,
-                                   pad_mask=pad)
+                                   pad_mask=pad, interpret=True)
     delta_d = (op.ONEPASS_PATH_TOTAL.get(("pallas", "dense"), 0)
                - before.get(("pallas", "dense"), 0))
     loc_d = fb.fused_local_track(track, x, bc_d, 1, 5, True)
@@ -161,7 +162,8 @@ def main() -> int:
     ones_real = jnp.ones((B, L, 1), jnp.float32)
 
     def loss_f(tp, ap, xx, bb, gg):
-        lo, at = op.fused_onepass_segments(tp, ap, xx, bb, gg, seg)
+        lo, at = op.fused_onepass_segments(tp, ap, xx, bb, gg, seg,
+                                           interpret=True)
         return jnp.sum(lo ** 2) + jnp.sum(at ** 2)
 
     def loss_r(tp, ap, xx, bb, gg):
@@ -183,7 +185,8 @@ def main() -> int:
         # Fresh lambdas: re-jitting a cached function object would hit
         # the trace cache and skip the trace-time env read.
         got_fo = jax.jit(lambda tp, ap, xx, bb, gg: (
-            op.fused_onepass_segments(tp, ap, xx, bb, gg, seg)))(
+            op.fused_onepass_segments(tp, ap, xx, bb, gg, seg,
+                                      interpret=True)))(
             track, attn, x, bc, g)
         want_fo = jax.jit(lambda tp, ap, xx, bb, gg: (
             lambda loc: (loc, ka.fused_packed_attention(
@@ -203,12 +206,16 @@ def main() -> int:
     # ---- gate 6: int8 in-kernel dequant bit-identity -----------------
     qtrack, qattn = quantize_params(track), quantize_params(attn)
     dtrack, dattn = fb.dequant_params(qtrack), fb.dequant_params(qattn)
-    got_q = op.fused_onepass_segments(qtrack, qattn, x, bc, g, seg)
-    want_q = op.fused_onepass_segments(dtrack, dattn, x, bc, g, seg)
+    got_q = op.fused_onepass_segments(qtrack, qattn, x, bc, g, seg,
+                                      interpret=True)
+    want_q = op.fused_onepass_segments(dtrack, dattn, x, bc, g, seg,
+                                       interpret=True)
     bit_q = all(np.array_equal(np.asarray(a), np.asarray(b))
                 for a, b in zip(got_q, want_q))
-    got_qd = op.fused_onepass_dense(qtrack, qattn, x, bc_d, g_d)
-    want_qd = op.fused_onepass_dense(dtrack, dattn, x, bc_d, g_d)
+    got_qd = op.fused_onepass_dense(qtrack, qattn, x, bc_d, g_d,
+                                    interpret=True)
+    want_qd = op.fused_onepass_dense(dtrack, dattn, x, bc_d, g_d,
+                                     interpret=True)
     bit_qd = all(np.array_equal(np.asarray(a), np.asarray(b))
                  for a, b in zip(got_qd, want_qd))
     gate(bit_q and bit_qd,

@@ -36,11 +36,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("PBT_DISABLE_DONATION", "1")
-flags = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in flags:
-    os.environ["XLA_FLAGS"] = (
-        flags + " --xla_force_host_platform_device_count=8").strip()
 
 INT8_PARAM_BOUND = 1e-3   # docs/distributed.md, quantized reduction
 BF16_PARAM_BOUND = 5e-4

@@ -57,7 +57,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("PBT_DISABLE_DONATION", "1")
 
 SEQ_LEN = 48
 BUCKETS = (24, 48)

@@ -1,0 +1,7 @@
+"""Model: device ms a packed batch under the `local_track` scope of
+`_packed_encode_batch`."""
+from benchmark import span_readers
+
+
+def read(obs):
+    return span_readers.scope_ms(obs, "local_track")

@@ -17,8 +17,9 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from typing import Iterator
+
+from proteinbert_tpu.obs.tracing import span
 
 _SENTINEL = object()
 
@@ -26,12 +27,11 @@ _SENTINEL = object()
 class PrefetchIterator:
     """Iterator view over `source` with `depth` batches produced ahead.
 
-    Starvation accounting: `wait_s` accumulates the wall seconds the
-    CONSUMER spent blocked on an empty queue (i.e. the host input
-    pipeline failed to stay ahead of the device) and `batches` counts
-    deliveries — the two numbers telemetry exports as the
-    `data_wait_seconds` / `data_batches_total` metrics, turning "is the
-    chip starving?" from a data-bench rerun into a per-run gauge."""
+    `batches` counts deliveries (telemetry's `data_batches_total`). How
+    long the CONSUMER sat blocked on an empty queue — the host input
+    pipeline failing to stay ahead of the device — is the consumer's to
+    time: the trainer's `train.data_wait` span around its `next()` is
+    the one clock, and feeds `data_wait_seconds`."""
 
     def __init__(self, source: Iterator, depth: int = 2):
         if depth < 1:
@@ -41,14 +41,20 @@ class PrefetchIterator:
         self._error = None
         self._done = False
         self._source = source
-        self.wait_s = 0.0
         self.batches = 0
         self._thread = threading.Thread(target=self._fill, daemon=True)
         self._thread.start()
 
     def _fill(self):
         try:
-            for item in self._source:
+            source = iter(self._source)
+            while True:
+                # The producer's own time a batch, on the span spine
+                # beside the trainer's `train.data_wait` (obs/tracing).
+                with span("data.produce"):
+                    item = next(source, _SENTINEL)
+                if item is _SENTINEL:
+                    break
                 while not self._stop.is_set():
                     try:
                         self._q.put(item, timeout=0.1)
@@ -81,7 +87,6 @@ class PrefetchIterator:
     def __next__(self):
         if self._done:
             raise StopIteration
-        t0 = time.perf_counter()
         while True:
             try:
                 item = self._q.get(timeout=0.5)
@@ -95,11 +100,9 @@ class PrefetchIterator:
                 # reads as clean end-of-data.
                 if self._stop.is_set() or not self._thread.is_alive():
                     if self._error is not None:
-                        self.wait_s += time.perf_counter() - t0
                         self._raise_pending_error()
                     self._done = True
                     raise StopIteration from None
-        self.wait_s += time.perf_counter() - t0
         if item is _SENTINEL:
             if self._error is not None:
                 self._raise_pending_error()

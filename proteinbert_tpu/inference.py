@@ -126,15 +126,18 @@ def _packed_encode_batch(params, tokens, segment_ids, annotations,
     — the packed executables this builds are fast-path executables,
     counted in fused_kernel_path_total{path=pallas,reason=packed}."""
     pad_mask = tokens != PAD_ID
-    local, global_ = proteinbert.encode(params, tokens, annotations, cfg,
-                                        pad_mask=pad_mask,
-                                        segment_ids=segment_ids)
-    m = _segment_real_mask(tokens, segment_ids,
-                           annotations.shape[1]).astype(jnp.float32)
-    local = local.astype(jnp.float32)
-    local_mean = (jnp.einsum("bsl,blc->bsc", m, local)
-                  / jnp.maximum(m.sum(-1)[..., None], 1.0))
-    return {"local_mean": local_mean, "global": global_.astype(jnp.float32)}
+    with jax.named_scope("encode"):
+        local, global_ = proteinbert.encode(params, tokens, annotations, cfg,
+                                            pad_mask=pad_mask,
+                                            segment_ids=segment_ids)
+    with jax.named_scope("pool"):
+        m = _segment_real_mask(tokens, segment_ids,
+                               annotations.shape[1]).astype(jnp.float32)
+        local = local.astype(jnp.float32)
+        local_mean = (jnp.einsum("bsl,blc->bsc", m, local)
+                      / jnp.maximum(m.sum(-1)[..., None], 1.0))
+        return {"local_mean": local_mean,
+                "global": global_.astype(jnp.float32)}
 
 
 @partial(jax.jit, static_argnames="cfg")

@@ -337,6 +337,7 @@ def _pallas_attention_forward(
     )
     return pl.pallas_call(
         kernel,
+        name="pbt_global_attention",
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, L, C), lambda b: (b, 0, 0),

@@ -815,6 +815,7 @@ def _pallas_forward(
         )
         return pl.pallas_call(
             kernel,
+            name="pbt_local_track",
             grid=grid,
             in_specs=[row_spec, bcast_spec] + [whole(a) for a in inputs[2:]],
             out_specs=pl.BlockSpec((1, tile, C), lambda b, j: (b, j, 0),
@@ -892,6 +893,7 @@ def _pallas_forward(
         out_map = imap(lambda b, c, p, j: (b, j, 0))
     return pl.pallas_call(
         kernel,
+        name="pbt_local_track_tiled",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, tile, C), out_map,
@@ -1145,6 +1147,7 @@ def _pallas_segments_forward(
         )
         return pl.pallas_call(
             kernel,
+            name="pbt_local_track_segments",
             grid=grid,
             in_specs=[row_spec, oh_spec, bcast_spec]
                      + [whole(a) for a in inputs[3:]]
@@ -1231,6 +1234,7 @@ def _pallas_segments_forward(
         out_map = imap(lambda b, c, p, j: (b, j, 0))
     return pl.pallas_call(
         kernel,
+        name="pbt_local_track_segments_tiled",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, tile, C), out_map,

@@ -379,6 +379,7 @@ def _pallas_onepass_forward(
     )
     return pl.pallas_call(
         kernel,
+        name="pbt_onepass",
         grid=(B,),
         in_specs=[
             bmap((1, Lp, C)), bmap((1, Lp, S)), bmap((1, L, 1)),

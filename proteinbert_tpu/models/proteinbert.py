@@ -124,12 +124,18 @@ def block_apply(
     segment — a packed row is numerically a batch of independent
     proteins (tests/test_packing.py asserts bit-level isolation)."""
     packed = segment_ids is not None
-    # Local track (reference modules.py:201-217).
-    broadcast = jax.nn.gelu(dense_apply(params["global_to_local"], global_))
     from proteinbert_tpu.kernels import (
         gather_segment_broadcast, local_track_reference,
         local_track_segment_reference,
     )
+
+    # Local track (reference modules.py:201-217). The scopes
+    # (`local_track`, `attention`, `global_track`) name the compiled
+    # instructions for obs/tracing.program_scopes; the one-pass kernel
+    # runs both tracks as one program and is `onepass`.
+    with jax.named_scope("local_track"):
+        broadcast = jax.nn.gelu(
+            dense_apply(params["global_to_local"], global_))
 
     track_params = {k: params[k] for k in ("narrow_conv", "wide_conv",
                                            "local_ln1", "local_dense",
@@ -150,54 +156,63 @@ def block_apply(
         )
 
         interp = pallas_interpret()
-        if packed:
-            # pad_mask is the REAL-token mask: for training packs it
-            # equals segment_ids > 0 (segments hold no pad); the ragged
-            # serving path packs bucket-quantized spans with <pad>
-            # tails, which are excluded from the attention softmax but
-            # DO participate in the convs (two-kernel semantics).
-            local, attn = fused_onepass_segments(
-                track_params, params["attention"], local, broadcast,
-                global_, segment_ids, real_mask=pad_mask,
-                narrow_dilation=1, wide_dilation=cfg.wide_dilation,
-                interpret=interp,
-            )
-        else:
-            local, attn = fused_onepass_dense(
-                track_params, params["attention"], local, broadcast,
-                global_, pad_mask=pad_mask,
-                narrow_dilation=1, wide_dilation=cfg.wide_dilation,
-                interpret=interp,
-            )
+        with jax.named_scope("onepass"):
+            if packed:
+                # pad_mask is the REAL-token mask: for training packs it
+                # equals segment_ids > 0 (segments hold no pad); the
+                # ragged serving path packs bucket-quantized spans with
+                # <pad> tails, which are excluded from the attention
+                # softmax but DO participate in the convs (two-kernel
+                # semantics).
+                local, attn = fused_onepass_segments(
+                    track_params, params["attention"], local, broadcast,
+                    global_, segment_ids, real_mask=pad_mask,
+                    narrow_dilation=1, wide_dilation=cfg.wide_dilation,
+                    interpret=interp,
+                )
+            else:
+                local, attn = fused_onepass_dense(
+                    track_params, params["attention"], local, broadcast,
+                    global_, pad_mask=pad_mask,
+                    narrow_dilation=1, wide_dilation=cfg.wide_dilation,
+                    interpret=interp,
+                )
     elif packed:
         # Gather each position's own segment's broadcast vector:
         # (B, S, C) → (B, L, C), zero at pad so nothing row-wide
         # leaks into the masked conv taps.
-        local = local_track_segment_reference(
-            track_params, local,
-            gather_segment_broadcast(broadcast, segment_ids),
-            segment_ids, 1, cfg.wide_dilation,
-        )
-        attn = packed_global_attention_apply(
-            params["attention"], local, global_, segment_ids,
-            real_mask=pad_mask)
+        with jax.named_scope("local_track"):
+            local = local_track_segment_reference(
+                track_params, local,
+                gather_segment_broadcast(broadcast, segment_ids),
+                segment_ids, 1, cfg.wide_dilation,
+            )
+        with jax.named_scope("attention"):
+            attn = packed_global_attention_apply(
+                params["attention"], local, global_, segment_ids,
+                real_mask=pad_mask)
     else:
-        local = local_track_reference(
-            track_params, local, broadcast, 1, cfg.wide_dilation
-        )
-        attn = global_attention_apply(
-            params["attention"], local, global_, pad_mask)
+        with jax.named_scope("local_track"):
+            local = local_track_reference(
+                track_params, local, broadcast, 1, cfg.wide_dilation
+            )
+        with jax.named_scope("attention"):
+            attn = global_attention_apply(
+                params["attention"], local, global_, pad_mask)
 
     # Global track (reference modules.py:219-229) — per segment when
     # packed: every dense/LN is feature-last and shape-agnostic over the
     # leading (B, S) axes; `attn` was computed above against the OLD
     # global track.
-    dense1 = jax.nn.gelu(dense_apply(params["global_dense1"], global_))
-    global_ = layer_norm_apply(params["global_ln1"], global_ + dense1 + attn)
-    global_ = layer_norm_apply(
-        params["global_ln2"],
-        global_ + jax.nn.gelu(dense_apply(params["global_dense2"], global_)),
-    )
+    with jax.named_scope("global_track"):
+        dense1 = jax.nn.gelu(dense_apply(params["global_dense1"], global_))
+        global_ = layer_norm_apply(params["global_ln1"],
+                                   global_ + dense1 + attn)
+        global_ = layer_norm_apply(
+            params["global_ln2"],
+            global_ + jax.nn.gelu(
+                dense_apply(params["global_dense2"], global_)),
+        )
     return local, global_
 
 
@@ -269,10 +284,11 @@ def encode(
         pad_mask = (segment_ids > 0 if segment_ids is not None
                     else tokens != PAD_ID)
 
-    local = embedding_apply(params["embedding"], tokens, dtype)
-    global_ = jax.nn.gelu(
-        dense_apply(params["global_in"], annotations.astype(dtype))
-    )
+    with jax.named_scope("embed"):
+        local = embedding_apply(params["embedding"], tokens, dtype)
+        global_ = jax.nn.gelu(
+            dense_apply(params["global_in"], annotations.astype(dtype))
+        )
 
     body = remat_wrap(
         partial(block_apply, cfg=cfg, segment_ids=segment_ids), cfg)
@@ -352,8 +368,11 @@ def apply(
     """
     local, global_ = encode(params, tokens, annotations, cfg, pad_mask,
                             segment_ids)
-    local_logits = dense_apply(params["local_head"], local).astype(jnp.float32)
-    global_logits = dense_apply(params["global_head"], global_).astype(jnp.float32)
+    with jax.named_scope("heads"):
+        local_logits = dense_apply(
+            params["local_head"], local).astype(jnp.float32)
+        global_logits = dense_apply(
+            params["global_head"], global_).astype(jnp.float32)
     return local_logits, global_logits
 
 

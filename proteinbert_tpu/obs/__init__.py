@@ -9,20 +9,24 @@ Four cooperating pieces behind one `Telemetry` facade:
   StepTimer summaries, overlap accounting, ZeRO per-chip state bytes,
   data-pipeline wait time, and host RSS/HBM estimates; exports JSONL
   snapshots and a Prometheus-style textfile;
-- **tracing** — nested host `span()`s that forward to
-  jax.profiler.TraceAnnotation when a device trace is live and dump
-  Perfetto-compatible trace-event JSON;
+- **tracing** — the program's one span spine: nested host `span()`s
+  that are always a jax.profiler.TraceAnnotation, are recorded while a
+  device trace is being captured (or into `Telemetry(spans=True)`'s
+  collector), and dump Perfetto-compatible trace-event JSON; plus the
+  compile counter and the instruction → scope map of the hot programs;
 - **flight** — a bounded ring of the last N event records, dumped to
   `flight_<pid>.json` on SIGTERM / NaN-halt / unhandled exception.
 
 Consumers: `pbt diagnose` (obs/diagnose.py), `tools/validate_events.py`,
-`tools/trace_attribution.py` (span dumps share the device-trace
-format), and `bench.py` (note events on the same stream). docs/observability.md documents the schema and conventions.
+the benchmark's per-layer readers (`benchmark/span_readers.py`), and
+`bench.py` (note events on the same stream). docs/observability.md documents the schema and conventions.
 
 Overhead contract: `NULL` (the default when no telemetry is passed) is
-a do-nothing facade — `emit` returns None, `span` is a shared
-nullcontext, `metrics` is a disabled registry — so instrumented code
-paths cost ~zero when telemetry is off.
+a do-nothing facade — `emit` returns None, `spans` is None, `metrics`
+is a disabled registry — so instrumented code paths cost ~zero when
+telemetry is off. Spans do not go through the facade: call
+`tracing.span` directly (pass `collector=tele.spans` to also keep the
+span in the telemetry's own collector).
 
 No jax import at module level: the whole package must be usable on a
 machine that only holds the artifacts.
@@ -30,7 +34,6 @@ machine that only holds the artifacts.
 
 from __future__ import annotations
 
-import contextlib
 import os
 import threading as _threading
 import time
@@ -54,9 +57,7 @@ from proteinbert_tpu.obs.slo import (
     ExemplarHistogram, ProfileTrigger, SLObjective, SLOEvaluator,
     parse_slo, parse_slos,
 )
-from proteinbert_tpu.obs.tracing import SpanCollector, span
-
-_NULL_CTX = contextlib.nullcontext()
+from proteinbert_tpu.obs.tracing import SpanCollector
 
 
 class Telemetry:
@@ -106,9 +107,6 @@ class Telemetry:
             self.flight.record(rec)
         return rec
 
-    def span(self, name: str, step: Optional[int] = None, **args):
-        return span(name, collector=self.spans, step=step, **args)
-
     def dump_flight(self, reason: str) -> Optional[str]:
         return self.flight.dump(reason)
 
@@ -133,9 +131,6 @@ class _NullTelemetry:
 
     def emit(self, event: str, **fields) -> None:
         return None
-
-    def span(self, name: str, step: Optional[int] = None, **args):
-        return _NULL_CTX
 
     def dump_flight(self, reason: str) -> None:
         return None
@@ -164,6 +159,6 @@ __all__ = [
     "MetricsRegistry", "QuantileWindow",
     "SLObjective", "SLOEvaluator", "ExemplarHistogram", "ProfileTrigger",
     "parse_slo", "parse_slos",
-    "SpanCollector", "span",
+    "SpanCollector",
     "FlightRecorder", "flight_path", "validate_flight_dump",
 ]

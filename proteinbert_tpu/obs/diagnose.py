@@ -531,9 +531,9 @@ def export_fleet_spans(records: List[Dict[str, Any]], collector,
             continue
         t0, t1 = min(ts), max(ts)
         base = zlib.crc32(tid.encode()) & 0x7FFFFFFF
-        collector.add(
+        root = collector.add(
             f"fleet:{c['path'] or '?'}:{c['outcome'] or '?'}",
-            t0, max(t1 - t0, _MIN), 0, tid=base, trace_id=tid,
+            t0, max(t1 - t0, _MIN), tid=base, trace_id=tid,
             retries=c["retries"], status=c["status"])
         for i, a in enumerate(c["attempts"]):
             lane = (base + 1 + (a["attempt"] if isinstance(
@@ -546,16 +546,16 @@ def export_fleet_spans(records: List[Dict[str, Any]], collector,
                 a0, dur = a["t"], _MIN
             else:
                 continue
-            collector.add(
+            attempt = collector.add(
                 f"attempt{a['attempt']}:{a['replica']}:{a['outcome']}",
-                a0, max(dur, _MIN), 0, tid=lane, trace_id=tid,
+                a0, max(dur, _MIN), tid=lane, trace_id=tid,
                 status=a.get("status"))
             cursor = a0
             for stage, sdur in ((s or {}).get("stages") or {}).items():
                 if not isinstance(sdur, (int, float)):
                     continue
-                collector.add(stage, cursor, max(sdur, _MIN), 1,
-                              tid=lane, trace_id=tid)
+                collector.add(stage, cursor, max(sdur, _MIN), tid=lane,
+                              parent=attempt, trace_id=tid)
                 cursor += sdur
             if isinstance(a.get("backoff_s"), (int, float)) \
                     and a["backoff_s"] > 0 \
@@ -563,8 +563,8 @@ def export_fleet_spans(records: List[Dict[str, Any]], collector,
                 # The wait a retry paid AFTER this failed attempt —
                 # rendered on the router lane where the sleep ran.
                 collector.add("backoff", a["t"],
-                              max(a["backoff_s"], _MIN), 1,
-                              tid=base, trace_id=tid)
+                              max(a["backoff_s"], _MIN), tid=base,
+                              parent=root, trace_id=tid)
         n += 1
     return n
 

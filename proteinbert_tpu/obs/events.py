@@ -333,12 +333,15 @@ def _validate_quant_fields(event: str, rec: Dict[str, Any]) -> None:
 def _validate_packed_fields(event: str, rec: Dict[str, Any]) -> None:
     """Optional ragged-packing fields shared by serve_batch and
     serve_request (ISSUE 9): typed when present, absent on older
-    streams and the bucketed path."""
-    seg = rec.get("segments")
-    if seg is not None and (not isinstance(seg, int)
-                            or isinstance(seg, bool) or seg < 0):
-        raise ValueError(f"{event}.segments must be a non-negative int, "
-                         f"got {seg!r}")
+    streams and the bucketed path. `batch` (both paths) is the
+    scheduler's sequence number of the batch, the id its `serve.*`
+    spans carry (obs/tracing)."""
+    for name in ("segments", "batch"):
+        v = rec.get(name)
+        if v is not None and (not isinstance(v, int)
+                              or isinstance(v, bool) or v < 0):
+            raise ValueError(f"{event}.{name} must be a non-negative "
+                             f"int, got {v!r}")
     spr = rec.get("segments_per_row")
     if spr is not None and (isinstance(spr, bool)
                             or not isinstance(spr, (int, float))

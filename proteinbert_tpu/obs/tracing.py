@@ -1,18 +1,32 @@
-"""Host-side span tracing with device-trace forwarding.
+"""The program's one span spine: host spans, the compile spans and the
+map from a compiled program's instructions to the scopes they came from.
 
-`span("name")` times a nested host region. Three sinks, all optional:
+`span("name", batch=7)` times a host region. Called directly it is
+always a `jax.profiler.TraceAnnotation` (`StepTraceAnnotation` with
+`step=`), so whenever anybody captures a device trace the span is on the
+xplane's host plane, on the device trace's own clock, with its ids as
+the event's stats. It is RECORDED, into the process's one bounded
+`SpanCollector` (`recorder()`), only while a profiler session is live at
+entry — "tracing on" means "a device trace is being captured": the
+benchmark's `--trace 1` window, `pbt pretrain --profile-dir`, the SLO
+profile trigger — or into an explicit `collector=` (what
+`Telemetry(spans=True)` hands out). With tracing off a span costs the
+annotation and one flag read. Either way the object the `with` yields
+carries `.start_ns`, `.end_ns` and `.seconds` after exit, so a caller
+that needs the duration (a histogram, a `timings` key) reads the span's
+own and keeps no second clock.
 
-- a SpanCollector accumulates finished spans and dumps them as
-  Perfetto-compatible `{"traceEvents": [...]}` JSON — the SAME format
-  jax.profiler's trace.json.gz uses, so `tools/trace_attribution.py`
-  parses host-span dumps and device traces with one parser;
-- when jax is already imported, the span body also runs under
-  `jax.profiler.TraceAnnotation`, so spans appear on the host lane of a
-  live device trace (and with `step=`, `StepTraceAnnotation` gives the
-  profiler step boundaries for its per-step views);
-- nesting depth is tracked per-thread, so a collector dump renders as a
-  flame graph (perfetto nests by timestamps; depth is kept as an arg
-  for flat consumers).
+A record holds name, start and end (`perf_counter_ns`), thread, its own
+id, the id of the span that enclosed it on that thread, and the ids
+passed in. `SpanCollector.dump` writes Perfetto `traceEvents` JSON on the
+wall clock.
+
+`note_program` keeps the abstract arguments of a hot jitted program at
+its first call; `program_scopes` compiles from them on demand and returns
+`{HLO instruction name: scope path}` from each instruction's
+`metadata={op_name=...}`. Instruction names are what a device trace's
+"XLA Ops" events carry (`fusion.1081`), so the map joins the device trace
+with the `jax.named_scope`s inside the program.
 
 jax is NEVER imported by this module — only used if something else
 already did — so the obs package stays importable on artifact-only
@@ -21,64 +35,99 @@ machines.
 
 from __future__ import annotations
 
-import contextlib
 import gzip
+import itertools
 import json
+import logging
 import os
+import re
 import sys
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
 
-_tls = threading.local()
+logger = logging.getLogger(__name__)
 
+# Records live on perf_counter_ns; dumps and post-hoc `add()` speak wall
+# clock. One offset, read once: both clocks tick at the same rate.
+_WALL_OFFSET_NS = time.time_ns() - time.perf_counter_ns()
 
-def _depth() -> int:
-    return getattr(_tls, "depth", 0)
+_tls = threading.local()        # .open: id of the innermost recorded span
+_ids = itertools.count(1)       # next() is atomic under the GIL
 
 
 class SpanCollector:
     """Bounded buffer of finished spans (oldest dropped past capacity —
-    a long run must not grow host memory without bound)."""
+    a long run must not grow host memory without bound). `dropped`
+    counts what went since the last `clear()`: a sum over the records is
+    whole only while it reads 0."""
 
     def __init__(self, capacity: int = 20000):
         self._spans: deque = deque(maxlen=capacity)
         self._lock = threading.Lock()
-        # getpid() is a real syscall on every add() — measurably slow
+        self.dropped = 0
+        # getpid() is a real syscall on every dump row — measurably slow
         # under sandboxed kernels (~90us observed) — and the pid cannot
         # change under us: collectors are not expected to survive fork.
         self._pid = os.getpid()
 
-    def add(self, name: str, wall_start: float, dur_s: float,
-            depth: int, tid: Optional[int] = None, **args) -> None:
-        """Record one finished span. `tid` defaults to the calling
-        thread; post-hoc emitters (serve request traces, which replay a
-        request's stages after it resolves) pass a synthetic tid so
-        each request renders on its own lane — overlapping requests on
-        one thread id would nest into nonsense."""
+    def _append(self, name: str, start_ns: int, end_ns: int, tid: int,
+                span_id: int, parent: Optional[int], ids: Dict) -> None:
         with self._lock:
+            if len(self._spans) == self._spans.maxlen:
+                self.dropped += 1
             self._spans.append({
-                "ph": "X", "name": name, "pid": self._pid,
-                "tid": threading.get_ident() if tid is None else tid,
-                "ts": round(wall_start * 1e6, 3),   # perfetto: microseconds
-                "dur": round(dur_s * 1e6, 3),
-                "args": {"depth": depth, **args} if (args or depth)
-                        else {"depth": 0},
-            })
+                "name": name, "start_ns": start_ns, "end_ns": end_ns,
+                "tid": tid, "id": span_id, "parent": parent, "ids": ids})
+
+    def add(self, name: str, wall_start: float, dur_s: float,
+            tid: Optional[int] = None, parent: Optional[int] = None,
+            **ids) -> int:
+        """Record one span after the fact, from wall-clock seconds;
+        returns its id (a later `add` names it as `parent`). `tid`
+        defaults to the calling thread; post-hoc emitters (serve request
+        traces, which replay a request's stages after it resolves) pass
+        a synthetic tid so each request renders on its own lane —
+        overlapping requests on one thread id would nest into nonsense."""
+        span_id = next(_ids)
+        start_ns = round(wall_start * 1e9) - _WALL_OFFSET_NS
+        self._append(name, start_ns, start_ns + round(dur_s * 1e9),
+                     threading.get_ident() if tid is None else tid,
+                     span_id, parent, ids)
+        return span_id
 
     def __len__(self) -> int:
         return len(self._spans)
 
-    def to_perfetto(self) -> Dict[str, Any]:
-        meta = [{"ph": "M", "name": "process_name", "pid": self._pid,
-                 "args": {"name": "proteinbert_tpu host spans"}}]
+    def spans(self) -> List[Dict[str, Any]]:
+        """A copy of the records, oldest first."""
         with self._lock:
-            return {"traceEvents": meta + list(self._spans)}
+            return list(self._spans)
+
+    def clear(self) -> None:
+        with self._lock:
+            self._spans.clear()
+            self.dropped = 0
+
+    def to_perfetto(self) -> Dict[str, Any]:
+        events = [{"ph": "M", "name": "process_name", "pid": self._pid,
+                   "args": {"name": "proteinbert_tpu host spans"}}]
+        for s in self.spans():
+            args = {"id": s["id"], **s["ids"]}
+            if s["parent"] is not None:
+                args["parent"] = s["parent"]
+            events.append({
+                "ph": "X", "name": s["name"], "pid": self._pid,
+                "tid": s["tid"],      # perfetto: microseconds
+                "ts": (s["start_ns"] + _WALL_OFFSET_NS) / 1e3,
+                "dur": (s["end_ns"] - s["start_ns"]) / 1e3,
+                "args": args})
+        return {"traceEvents": events}
 
     def dump(self, path: str) -> str:
         """Write trace-event JSON (gzipped when the path ends in .gz) —
-        loadable by ui.perfetto.dev and tools/trace_attribution.py."""
+        loadable by ui.perfetto.dev beside the device trace."""
         data = json.dumps(self.to_perfetto())
         if path.endswith(".gz"):
             with gzip.open(path, "wt") as f:
@@ -89,41 +138,240 @@ class SpanCollector:
         return path
 
 
-def _jax_annotation(name: str, step: Optional[int] = None):
-    """A TraceAnnotation context when jax is live, else a null context.
-    Checked through sys.modules: telemetry must not be the thing that
-    pays the jax import."""
+_RECORDER = SpanCollector()
+
+
+def recorder() -> SpanCollector:
+    """The process's one collector: what was recorded while a profiler
+    session was live."""
+    return _RECORDER
+
+
+# --------------------------------------------------------- the profiler
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_profiler = None                # jax.profiler, once jax is live
+_is_enabled = None              # TraceAnnotation.is_enabled, where it exists
+_lock = threading.Lock()
+
+
+def _arm():
+    """Bind to jax.profiler the first time jax is found imported, and
+    hang the compile listener on jax.monitoring (once: two threads may
+    open their first span together). Checked through sys.modules:
+    telemetry must not be the thing that pays the jax import."""
+    global _profiler, _is_enabled
     jax = sys.modules.get("jax")
     if jax is None:
-        return contextlib.nullcontext()
-    try:
-        if step is not None:
-            return jax.profiler.StepTraceAnnotation(name, step_num=step)
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return contextlib.nullcontext()
+        return None
+    with _lock:
+        if _profiler is None:
+            _is_enabled = getattr(jax.profiler.TraceAnnotation,
+                                  "is_enabled", None)
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_duration)
+            _profiler = jax.profiler
+    return _profiler
 
 
-@contextlib.contextmanager
-def span(name: str, collector: Optional[SpanCollector] = None,
-         step: Optional[int] = None, **args):
-    """Nested host span: times the body, forwards to the jax profiler
-    when available, records into `collector` when given."""
-    depth = _depth()
-    _tls.depth = depth + 1
-    wall0 = time.time()
-    t0 = time.perf_counter()
-    try:
-        with _jax_annotation(name, step):
-            yield
-    finally:
-        _tls.depth = depth
-        if collector is not None:
-            dur = time.perf_counter() - t0
-            if step is not None:
-                args["step"] = step
-            collector.add(name, wall0, dur, depth, **args)
+def _live() -> bool:
+    """True while a profiler session is capturing (never, where the
+    installed jax cannot say)."""
+    return _is_enabled is not None and _is_enabled()
 
-# (A step_span(step, …) convenience wrapper used to live here; nothing
-# referenced it — removed by the ISSUE 15 dead-export sweep. Pass
-# `step=` to span() for StepTraceAnnotation boundaries.)
+
+def _on_duration(event: str, duration_secs: float, **_kw) -> None:
+    """While recording, every backend compile (or load from the
+    persistent cache) is a `jax.compile` span on the compiling thread:
+    which step recompiled."""
+    if event == _COMPILE_EVENT and _live():
+        end = time.perf_counter_ns()
+        _RECORDER._append("jax.compile", end - round(duration_secs * 1e9),
+                          end, threading.get_ident(), next(_ids),
+                          getattr(_tls, "open", None), {})
+
+
+class span:
+    """Nested host span; see the module docstring."""
+
+    __slots__ = ("name", "ids", "start_ns", "end_ns", "_step", "_sink",
+                 "_ann", "_id", "_parent")
+
+    def __init__(self, name: str, collector: Optional[SpanCollector] = None,
+                 step: Optional[int] = None, **ids):
+        self.name = name
+        self.ids = ids          # a record's ids: `step` joins them at exit
+        self._step = step
+        self._sink = collector
+        self._ann = None
+        self.start_ns = self.end_ns = 0
+
+    def __enter__(self) -> "span":
+        prof = _profiler or _arm()
+        if prof is not None:
+            if self._step is not None:
+                ann = prof.StepTraceAnnotation(
+                    self.name, step_num=self._step, **self.ids)
+            else:
+                ann = prof.TraceAnnotation(self.name, **self.ids)
+            ann.__enter__()
+            self._ann = ann
+            if self._sink is None and _live():
+                self._sink = _RECORDER
+        if self._sink is not None:
+            self._id = next(_ids)
+            self._parent = getattr(_tls, "open", None)
+            _tls.open = self._id
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.end_ns = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self._sink is not None:
+            _tls.open = self._parent
+            if self._step is not None:
+                self.ids["step"] = self._step
+            self._sink._append(self.name, self.start_ns, self.end_ns,
+                               threading.get_ident(), self._id,
+                               self._parent, self.ids)
+        return False
+
+    @property
+    def seconds(self) -> float:
+        return (self.end_ns - self.start_ns) * 1e-9
+
+
+# ------------------------------------------- programs and their scopes
+
+_programs: Dict[str, tuple] = {}    # name -> (jitted, args, kwargs)
+_compiling = threading.Lock()       # one around-the-cache compile at a time
+
+
+def note_program(name: str, jitted, args: tuple,
+                 kwargs: Optional[Dict] = None) -> None:
+    """Keep what it takes to compile `jitted` again as it was called:
+    every array argument as its shape, dtype and sharding, everything
+    else (static arguments) as it is. Only the first call under a name
+    costs anything, and that is one `tree.map`."""
+    if name in _programs:
+        return
+    jax = sys.modules["jax"]
+
+    def abstract(x):
+        if not (hasattr(x, "shape") and hasattr(x, "dtype")):
+            return x
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=getattr(x, "sharding", None),
+            weak_type=getattr(getattr(x, "aval", None), "weak_type", False))
+
+    _programs[name] = (jitted, jax.tree.map(abstract, tuple(args)),
+                       jax.tree.map(abstract, dict(kwargs or {})))
+
+
+def noted_programs() -> List[str]:
+    return sorted(_programs)
+
+
+# `%fusion.12 = bf16[...] fusion(...), ..., metadata={op_name="..." ...}`
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+_OPERAND = re.compile(r"[\w\-]+\(%?([A-Za-z_][\w.\-]*)")
+_COMPUTATION = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")
+# Wrappers that say how the compiler got there, not where in the model:
+# the jit frames, a scan's `body` under its `while` (kept), a remat's
+# `checkpoint` (its `rematted_computation` is kept: the recomputation).
+_NOISE = re.compile(r"^(?:jit\(.*\)|pjit|closed_call|checkpoint|body|cond"
+                    r"|core_call|custom_jvp_call|custom_vjp_call(?:_jaxpr)?)$")
+
+
+def scope_path(op_name: str) -> str:
+    """An `op_name` with the wrappers stripped and its last component,
+    the primitive, dropped: `jit(step)/transpose(jvp(forward))/while/body/
+    closed_call/checkpoint/attention/dot_general` ->
+    `transpose(jvp(forward))/while/attention`. `transpose(jvp(...))`
+    stays as the mark of the backward pass."""
+    parts = [p for p in op_name.split("/")[:-1] if not _NOISE.match(p)]
+    return "/".join(parts)
+
+
+def scopes_from_hlo(text: str) -> Dict[str, str]:
+    """{instruction name: scope path} of an HLO module's text, from each
+    instruction's `op_name`. An instruction that carries none takes the
+    scope of the first instruction inside the computation it calls (a
+    layout-only fusion) or else of its first operand (the start / done
+    pair of an asynchronous copy or slice: the scope of what it moves)."""
+    named: Dict[str, str] = {}
+    borrows: Dict[str, str] = {}    # unnamed instruction -> whom to ask
+    first_in: Dict[str, str] = {}   # computation -> its first named scope
+    computation = None
+    for line in text.splitlines():
+        header = _COMPUTATION.match(line)
+        if header and " = " not in line.split("(")[0]:
+            computation = header.group(1)
+            continue
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        op = _OP_NAME.search(line)
+        if op:
+            named[m.group(1)] = scope_path(op.group(1))
+            if computation is not None:
+                first_in.setdefault(computation, named[m.group(1)])
+            continue
+        called = _CALLS.search(line)
+        operand = _OPERAND.search(line.split(" = ", 1)[1])
+        if called:
+            borrows[m.group(1)] = "calls:" + called.group(1)
+        elif operand:
+            borrows[m.group(1)] = operand.group(1)
+    for instruction in borrows:
+        seen, at = set(), instruction
+        while at in borrows and at not in seen:     # a -done asks its -start
+            seen.add(at)
+            at = borrows[at]
+        scope = (first_in.get(at[6:]) if at.startswith("calls:")
+                 else named.get(at))
+        if scope is not None:
+            named[instruction] = scope
+    return named
+
+
+def program_scopes(name: str) -> Optional[Dict[str, str]]:
+    """`scopes_from_hlo` of the program noted under `name`, compiled
+    from its abstract arguments; None where none was noted.
+
+    This one compile goes AROUND the persistent cache. The cache's key
+    leaves the metadata out, so a cache shared with another checkout
+    hands back THAT checkout's executable, names and all (the device
+    trace's own per-operation names are stale the same way); and an
+    entry keyed on the metadata would grow a shared cache by one
+    executable a program a checkout. So it is a real compile every time
+    it is asked for, which is after a traced window, never inside one;
+    its seconds are logged.
+
+    Going around the cache means turning the process-wide
+    `jax_enable_compilation_cache` off and on again: one caller at a time
+    (`_compiling`), and a compile another thread starts meanwhile merely
+    misses the cache too — slower, never another program."""
+    noted = _programs.get(name)
+    if noted is None:
+        return None
+    jax = sys.modules["jax"]
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jitted, args, kwargs = noted
+    with _compiling, span("tracing.program_scopes", program=name) as took:
+        was_on = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()     # the cache memoizes "am I used"
+        try:
+            text = jitted.lower(*args, **kwargs).compile().as_text()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was_on)
+            compilation_cache.reset_cache()
+    logger.info("program_scopes(%s): compiled around the cache in %.1f s",
+                name, took.seconds)
+    return scopes_from_hlo(text)

@@ -44,6 +44,7 @@ from proteinbert_tpu.data.vocab import EOS_ID, PAD_ID, SOS_ID
 from proteinbert_tpu import inference
 from proteinbert_tpu.heads import apply as heads_apply
 from proteinbert_tpu.heads.registry import LoadedHead, UnknownHeadError
+from proteinbert_tpu.obs import tracing
 from proteinbert_tpu.serve.errors import CandidateUnfitError, NoCandidateError
 
 KINDS = ("embed", "predict_go", "predict_residues")
@@ -798,7 +799,8 @@ class BucketDispatcher:
                         annotations: Optional[np.ndarray] = None,
                         timed: bool = True,
                         heads: Optional[Sequence[LoadedHead]] = None,
-                        arm: str = "resident") -> InFlightBatch:
+                        arm: str = "resident",
+                        batch: Optional[int] = None) -> InFlightBatch:
         """Submit one micro-batch and return an `InFlightBatch` as soon
         as the jitted call is enqueued (ISSUE 19). Validation, padding,
         device placement and the model call happen here on the calling
@@ -806,7 +808,10 @@ class BucketDispatcher:
         parity shadow run in the handle's `finalize()`. `arm` selects
         the trunk (ISSUE 20): "resident" is the live arm, "candidate"
         the blue-green shadow arm — both trees are read atomically via
-        `_arm_snapshot`, so a concurrent flip never tears a batch."""
+        `_arm_snapshot`, so a concurrent flip never tears a batch.
+        `batch` is the scheduler's sequence number, stamped on this
+        batch's `serve.place` / `serve.launch` / `serve.fetch` spans
+        (obs/tracing), whose durations are also what `timings` reports."""
         if kind == NEIGHBORS_KIND:
             kind = "embed"  # identical device work, shared executable
         rows, L = tokens.shape
@@ -819,19 +824,20 @@ class BucketDispatcher:
                 "do not agree: predict_task batches carry per-row heads, "
                 "pretrain kinds never do")
         timings: Dict[str, float] = {}
-        t0 = time.perf_counter() if timed else 0.0
-        annotations = inference.check_annotations(annotations, rows, self.cfg)
-        cls = self.batch_class(rows)
+        with tracing.span("serve.place", batch=batch) as placed:
+            annotations = inference.check_annotations(annotations, rows,
+                                                      self.cfg)
+            cls = self.batch_class(rows)
+            if timed:
+                real = int((tokens != PAD_ID).sum())
+                timings["pad_fraction"] = round(1.0 - real / (cls * L), 6)
+            if rows < cls:
+                tokens = np.pad(tokens, ((0, cls - rows), (0, 0)))
+                annotations = np.pad(annotations,
+                                     ((0, cls - rows), (0, 0)))
+            tb, ab = self._place(tokens, annotations)
         if timed:
-            real = int((tokens != PAD_ID).sum())
-            timings["pad_fraction"] = round(1.0 - real / (cls * L), 6)
-        if rows < cls:
-            tokens = np.pad(tokens, ((0, cls - rows), (0, 0)))
-            annotations = np.pad(annotations, ((0, cls - rows), (0, 0)))
-        tb, ab = self._place(tokens, annotations)
-        t1 = time.perf_counter()
-        if timed:
-            timings["prep_s"] = round(t1 - t0, 9)
+            timings["prep_s"] = round(placed.seconds, 9)
         run_params, ref_params = self._arm_snapshot(arm)
         parity_due = (arm == "resident"
                       and self._quant_batch_tick(timings))
@@ -842,45 +848,45 @@ class BucketDispatcher:
             # its own head's output (heads/apply.py). The tails ride
             # in the fetch closure: they are tiny, and the trunk — the
             # device work worth overlapping — is already in flight.
-            trunk_out = self._trunk_fn()(run_params, tb, ab,
-                                         self.cfg.model)
+            with tracing.span("serve.launch", batch=batch):
+                trunk_out = self._trunk_fn()(run_params, tb, ab,
+                                             self.cfg.model)
             self._note_warm(("trunk", L, cls))
 
             def fetch():
-                out = heads_apply.apply_heads(trunk_out, heads)
-                if parity_due:
-                    self._shadow_parity(
-                        out,
-                        lambda: heads_apply.apply_heads(
-                            heads_apply.trunk_batch(ref_params, tb, ab,
-                                                    self.cfg.model),
-                            heads),
-                        timings)
-                return out
+                return heads_apply.apply_heads(trunk_out, heads)
+
+            def reference():
+                return heads_apply.apply_heads(
+                    heads_apply.trunk_batch(ref_params, tb, ab,
+                                            self.cfg.model), heads)
         else:
             fn = self._fn(kind)
-            res = fn(run_params, tb, ab, self.cfg.model)
+            with tracing.span("serve.launch", batch=batch):
+                res = fn(run_params, tb, ab, self.cfg.model)
             self._note_warm((kind, L, cls))
 
             def fetch():
-                out = jax.tree.map(lambda a: np.asarray(a)[:rows], res)
-                if parity_due:
-                    self._shadow_parity(
-                        out,
-                        lambda: jax.tree.map(
-                            lambda a: np.asarray(a)[:rows],
-                            self._fn(kind, quantized=False)(
-                                ref_params, tb, ab, self.cfg.model)),
-                        timings)
-                return out
+                return jax.tree.map(lambda a: np.asarray(a)[:rows], res)
+
+            def reference():
+                return jax.tree.map(
+                    lambda a: np.asarray(a)[:rows],
+                    self._fn(kind, quantized=False)(
+                        ref_params, tb, ab, self.cfg.model))
 
         def finalize_fetch():
-            tf = time.perf_counter()
-            out = fetch()
+            # `serve.fetch`: blocked on the device, then the copy to the
+            # host (and, on a parity tick, the fp32 shadow). The rows
+            # are split per request by the scheduler's seal loop.
+            with tracing.span("serve.fetch", batch=batch) as fetched:
+                out = fetch()
+                if parity_due:
+                    self._shadow_parity(out, reference, timings)
             if timed:
-                now = time.perf_counter()
-                timings["device_s"] = round(now - t1, 9)
-                timings["finalize_s"] = round(now - tf, 9)
+                timings["device_s"] = round(
+                    (fetched.end_ns - placed.end_ns) * 1e-9, 9)
+                timings["finalize_s"] = round(fetched.seconds, 9)
             return out
 
         return InFlightBatch(rows, timings, finalize_fetch)
@@ -1164,7 +1170,9 @@ class RaggedDispatcher(BucketDispatcher):
                                annotations: np.ndarray,
                                riders: Sequence[Tuple[int, int, int, int]],
                                heads=None, timed: bool = True,
-                               arm: str = "resident") -> InFlightBatch:
+                               arm: str = "resident",
+                               batch: Optional[int] = None,
+                               ) -> InFlightBatch:
         """Submit one packed batch through the kind's single warm
         executable; the returned `InFlightBatch.finalize()` fans
         per-segment outputs back out after the host fetch (ISSUE 19).
@@ -1193,78 +1201,83 @@ class RaggedDispatcher(BucketDispatcher):
                 "agree: predict_task batches carry per-rider heads, "
                 "pretrain kinds never do")
         timings: Dict[str, float] = {}
-        t0 = time.perf_counter() if timed else 0.0
+        with tracing.span("serve.place", batch=batch) as placed:
+            if timed:
+                real = int((tokens != PAD_ID).sum())
+                timings["pad_fraction"] = round(1.0 - real / (R * L), 6)
+                timings["segments"] = len(riders)
+                timings["segments_per_row"] = round(len(riders) / R, 4)
+            tb, sb, ab = self._place_packed(tokens, segment_ids,
+                                            annotations)
         if timed:
-            real = int((tokens != PAD_ID).sum())
-            timings["pad_fraction"] = round(1.0 - real / (R * L), 6)
-            timings["segments"] = len(riders)
-            timings["segments_per_row"] = round(len(riders) / R, 4)
-        tb, sb, ab = self._place_packed(tokens, segment_ids, annotations)
-        t1 = time.perf_counter()
-        if timed:
-            timings["prep_s"] = round(t1 - t0, 9)
+            timings["prep_s"] = round(placed.seconds, 9)
         run_params, ref_params = self._arm_snapshot(arm)
         parity_due = (arm == "resident"
                       and self._quant_batch_tick(timings))
 
-        def fan_out(host):
-            fanned = []
-            for row, seg, start, span in riders:
-                if kind == "embed":
-                    fanned.append(
-                        {"global": host["global"][row, seg],
-                         "local_mean": host["local_mean"][row, seg]})
-                elif kind == "predict_go":
-                    fanned.append(host[row, seg])
-                else:  # predict_residues: the span lines up with the
-                    # bucketed (bucket_len, V) output
-                    fanned.append(host[row, start:start + span])
-            return fanned
-
         if heads is not None:
-            trunk_out = self._packed_trunk_fn()(
-                run_params, tb, sb, ab, self.cfg.model)
+            with tracing.span("serve.launch", batch=batch):
+                trunk_out = self._packed_trunk_fn()(
+                    run_params, tb, sb, ab, self.cfg.model)
             self._note_warm(("trunk", L, R))
+            tails = [(h,) + tuple(r) for h, r in zip(heads, riders)]
 
             def fetch():
-                outs = heads_apply.apply_heads_packed(
-                    trunk_out,
-                    [(h,) + tuple(r) for h, r in zip(heads, riders)])
-                if parity_due:
-                    self._shadow_parity(
-                        outs,
-                        lambda: heads_apply.apply_heads_packed(
-                            heads_apply.packed_trunk_batch(
-                                ref_params, tb, sb, ab, self.cfg.model),
-                            [(h,) + tuple(r)
-                             for h, r in zip(heads, riders)]),
-                        timings)
+                return heads_apply.apply_heads_packed(trunk_out, tails)
+
+            def fan_out(outs):  # the tails come back one per rider
                 return outs
+
+            def reference():
+                return heads_apply.apply_heads_packed(
+                    heads_apply.packed_trunk_batch(
+                        ref_params, tb, sb, ab, self.cfg.model), tails)
         else:
-            res = self._packed_fn(kind)(run_params, tb, sb, ab,
-                                        self.cfg.model)
+            fn = self._packed_fn(kind)
+            tracing.note_program(fn.__name__, fn, (
+                run_params, tb, sb, ab, self.cfg.model))
+            with tracing.span("serve.launch", batch=batch):
+                res = fn(run_params, tb, sb, ab, self.cfg.model)
             self._note_warm((kind, L, R))
 
             def fetch():
-                outs = fan_out(jax.tree.map(np.asarray, res))
-                if parity_due:
-                    self._shadow_parity(
-                        outs,
-                        lambda: fan_out(jax.tree.map(
-                            np.asarray,
-                            self._packed_fn(kind, quantized=False)(
-                                ref_params, tb, sb, ab,
-                                self.cfg.model))),
-                        timings)
-                return outs
+                return jax.tree.map(np.asarray, res)
+
+            def fan_out(host):
+                fanned = []
+                for row, seg, start, span_len in riders:
+                    if kind == "embed":
+                        fanned.append(
+                            {"global": host["global"][row, seg],
+                             "local_mean": host["local_mean"][row, seg]})
+                    elif kind == "predict_go":
+                        fanned.append(host[row, seg])
+                    else:  # predict_residues: the span lines up with the
+                        # bucketed (bucket_len, V) output
+                        fanned.append(host[row, start:start + span_len])
+                return fanned
+
+            def reference():
+                return fan_out(jax.tree.map(
+                    np.asarray,
+                    self._packed_fn(kind, quantized=False)(
+                        ref_params, tb, sb, ab, self.cfg.model)))
 
         def finalize_fetch():
-            tf = time.perf_counter()
-            outs = fetch()
+            # `serve.fetch`: blocked on the device, then the copy to the
+            # host. `serve.fan_out`: one output per rider (and, on a
+            # parity tick, the fp32 shadow).
+            with tracing.span("serve.fetch", batch=batch) as fetched:
+                host = fetch()
+            with tracing.span("serve.fan_out", batch=batch) as fanned:
+                outs = fan_out(host)
+                if parity_due:
+                    self._shadow_parity(outs, reference, timings)
             if timed:
-                now = time.perf_counter()
-                timings["device_s"] = round(now - t1, 9)
-                timings["finalize_s"] = round(now - tf, 9)
+                timings["device_s"] = round(
+                    (fanned.end_ns - placed.end_ns) * 1e-9, 9)
+                timings["finalize_s"] = round(
+                    (fanned.end_ns - fetched.start_ns) * 1e-9, 9)
             return outs
 
         return InFlightBatch(len(riders), timings, finalize_fetch)

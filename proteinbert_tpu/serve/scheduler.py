@@ -29,6 +29,18 @@ additionally get per-stage clock marks (ingest / pop / execute) and a
 terminal `complete_observer` callback (outcome ∈ ok/error/expired) the
 Server uses to seal the trace, emit the `serve_request` event, and
 feed the SLO evaluator. All marks use the injected clock.
+
+The batch path is on the span spine (obs/tracing): every dispatched
+batch takes a sequence number, and its spans carry it as `batch=` —
+on this thread `serve.ingest` (requests taken from the queue and placed,
+tagged with the batch then forming; `n=` how many), `serve.assemble`
+(pop to grids), `serve.wait_slot`, then the dispatcher's `serve.place`
+and `serve.launch`; on the completer `serve.retire` around the
+dispatcher's `serve.fetch` / `serve.fan_out` and this module's
+`serve.seal` (the per-rider loop). Riders' `RequestTrace`s and the
+`serve_batch` event carry the same number. The spans' own durations are
+what the histograms and `pipeline_stats()` report: there is no second
+clock on this path.
 """
 
 from __future__ import annotations
@@ -42,6 +54,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from proteinbert_tpu.obs.metrics import Histogram
+from proteinbert_tpu.obs.tracing import span
 from proteinbert_tpu.serve.errors import DeadlineExceededError
 from proteinbert_tpu.serve.queue import Request, RequestQueue
 
@@ -140,6 +153,9 @@ class MicroBatchScheduler:
         # unlocked stats()-path read ISSUE 15's lock rule was built to
         # catch) and read through stats_counts().
         self.batches_total = 0               # guarded-by: _pending_lock
+        # Sequence number of the NEXT batch to dispatch (scheduler
+        # thread only): the `batch=` of its spans and of its riders.
+        self._next_batch = 1
         self.rows_total = 0                  # guarded-by: _pending_lock
         self.expired_total = 0               # guarded-by: _pending_lock
         self._occupancy_g = self.tele.metrics.gauge("serve_batch_occupancy")
@@ -203,7 +219,8 @@ class MicroBatchScheduler:
         items = self.queue.pop_all()
         if not items:
             return
-        with self._pending_lock:
+        with span("serve.ingest", batch=self._next_batch, n=len(items)), \
+                self._pending_lock:
             for req in items:
                 if req.trace is not None:
                     req.trace.mark_ingested(now)
@@ -282,43 +299,46 @@ class MicroBatchScheduler:
         # ":<head_id>" suffix; the dispatcher and events see the base
         # kind (per-row heads travel on the requests themselves).
         kind, bucket_len = key[0].split(":", 1)[0], key[1]
-        with self._pending_lock:
-            group = self._pending.get(key)
-            if not group:  # raced an abort's fail_pending
-                return 0
-            batch: List[Request] = [group.popleft()
-                                    for _ in range(min(self.max_batch,
-                                                       len(group)))]
-            if not group:
-                del self._pending[key]
-        cls = self.dispatcher.batch_class(len(batch))
-        tracing = False
-        timed = self.time_batches
-        for req in batch:
-            self._observe_wait(req, now)
-            if req.trace is not None:
-                tracing = True
-                if req.trace.sampled:
-                    timed = True
-                req.trace.mark_popped(now)
-        tokens = np.stack([r.tokens for r in batch])
-        num_ann = self.dispatcher.cfg.model.num_annotations
-        annotations = np.stack([
-            r.annotations if r.annotations is not None
-            else np.zeros(num_ann, np.float32)
-            for r in batch])
-        ctx = {"rows": len(batch), "batch_class": cls,
-               "bucket_len": bucket_len}
-        # predict_task rows carry their own LoadedHead (resolved at
-        # admission): pass them through so the dispatcher runs the
-        # shared trunk once and each head's cheap tail per group.
-        heads = ([r.head for r in batch]
-                 if batch[0].head is not None else None)
-        extra = {"heads": heads} if heads is not None else {}
-        if heads is not None:
-            ctx["heads"] = sorted({h.head_id for h in heads})
-        self._wait_for_slot()
-        t0 = time.perf_counter()
+        seq = self._next_batch
+        with span("serve.assemble", batch=seq):
+            with self._pending_lock:
+                group = self._pending.get(key)
+                if not group:  # raced an abort's fail_pending
+                    return 0
+                batch: List[Request] = [group.popleft()
+                                        for _ in range(min(self.max_batch,
+                                                           len(group)))]
+                if not group:
+                    del self._pending[key]
+            cls = self.dispatcher.batch_class(len(batch))
+            tracing = False
+            timed = self.time_batches
+            for req in batch:
+                self._observe_wait(req, now)
+                if req.trace is not None:
+                    tracing = True
+                    if req.trace.sampled:
+                        timed = True
+                    req.trace.mark_popped(now)
+            tokens = np.stack([r.tokens for r in batch])
+            num_ann = self.dispatcher.cfg.model.num_annotations
+            annotations = np.stack([
+                r.annotations if r.annotations is not None
+                else np.zeros(num_ann, np.float32)
+                for r in batch])
+            ctx = {"rows": len(batch), "batch_class": cls,
+                   "bucket_len": bucket_len, "batch": seq}
+            # predict_task rows carry their own LoadedHead (resolved at
+            # admission): pass them through so the dispatcher runs the
+            # shared trunk once and each head's cheap tail per group.
+            heads = ([r.head for r in batch]
+                     if batch[0].head is not None else None)
+            extra = {"heads": heads} if heads is not None else {}
+            if heads is not None:
+                ctx["heads"] = sorted({h.head_id for h in heads})
+        self._next_batch = seq + 1
+        with span("serve.wait_slot", batch=seq) as slot:
+            self._wait_for_slot()
         run0 = self.clock()
         try:
             # run_timed_async (BucketDispatcher) returns an in-flight
@@ -335,7 +355,8 @@ class MicroBatchScheduler:
             run_timed = getattr(self.dispatcher, "run_timed", None)
             if run_async is not None:
                 handle = run_async(kind, tokens, annotations,
-                                   timed=bool(tracing and timed), **extra)
+                                   timed=bool(tracing and timed),
+                                   batch=seq, **extra)
             elif run_timed is not None:
                 result, timings = run_timed(kind, tokens, annotations,
                                             timed=bool(tracing and timed),
@@ -350,22 +371,25 @@ class MicroBatchScheduler:
         self._enqueue_inflight({
             "mode": "bucketed", "batch": batch, "handle": handle,
             "ctx": ctx, "kind": kind, "bucket_len": bucket_len,
-            "cls": cls, "run0": run0, "t0": t0})
+            "cls": cls, "run0": run0, "seq": seq,
+            "submit_ns": slot.end_ns})
         return len(batch)
 
-    def _finalize_batch(self, entry: Dict) -> None:
+    def _finalize_batch(self, entry: Dict, retired) -> None:
         """Resolve one in-flight micro-batch: blocking host fetch,
         per-request finalize/fan-out, trace marks, counters, the
         serve_batch event and the terminal complete callback. Runs on
         the completer thread when one is live, else inline right after
         submit. Trace stages: `execute` is submit → fetch-complete
         (run0 → run1) and `finalize` is fetch-complete → sealed, so
-        per-request stages still tile [submit, done]."""
+        per-request stages still tile [submit, done]. `retired` is the
+        `serve.retire` span this runs under: the fetch took from its
+        start to the start of `serve.seal`."""
         batch: List[Request] = entry["batch"]
         ctx, run0 = entry["ctx"], entry["run0"]
         kind, bucket_len, cls = (entry["kind"], entry["bucket_len"],
                                  entry["cls"])
-        tf0 = time.perf_counter()
+        seq = entry["seq"]
         try:
             result, timings = entry["handle"].finalize()
         except Exception as e:  # fail THIS batch, keep serving
@@ -379,38 +403,42 @@ class MicroBatchScheduler:
                     req.trace.mark_run(run0, fail_t)
                     req.trace.mark_batch(
                         bucket_len, cls, len(batch),
-                        pad_fraction=ctx.get("pad_fraction"))
+                        pad_fraction=ctx.get("pad_fraction"), batch=seq)
                 if not req.future.done():
                     req.future.set_exception(e)
                 self._on_complete(req, "error", fail_t, e, ctx)
             return
         ctx.update(timings)
-        dt = time.perf_counter() - entry["t0"]
-        run1 = self.clock()
-        self._batch_h.observe(dt)
-        self._finalize_h.observe(time.perf_counter() - tf0)
-        done_t = self.clock()
-        for i, req in enumerate(batch):
-            if isinstance(result, dict):
-                row = {k: v[i] for k, v in result.items()}
-            else:
-                row = result[i]
-            outcome, err = "ok", None
-            try:
-                self.finalize(req, row)
-            except Exception as e:
-                outcome, err = "error", e
-                if not req.future.done():
-                    req.future.set_exception(e)
-            self._latency(done_t - req.enqueued_at)
-            if req.trace is not None:
-                req.trace.mark_run(run0, run1)
-                req.trace.mark_batch(
-                    bucket_len, cls, len(batch),
-                    pad_fraction=ctx.get("pad_fraction"),
-                    prep_s=ctx.get("prep_s"),
-                    device_s=ctx.get("device_s"))
-            self._on_complete(req, outcome, self.clock(), err, ctx)
+        with span("serve.seal", batch=seq) as sealing:
+            # Submit → fetch complete, and the fetch alone: both end
+            # where the seal starts.
+            dt = (sealing.start_ns - entry["submit_ns"]) * 1e-9
+            run1 = self.clock()
+            self._batch_h.observe(dt)
+            self._finalize_h.observe(
+                (sealing.start_ns - retired.start_ns) * 1e-9)
+            done_t = self.clock()
+            for i, req in enumerate(batch):
+                if isinstance(result, dict):
+                    row = {k: v[i] for k, v in result.items()}
+                else:
+                    row = result[i]
+                outcome, err = "ok", None
+                try:
+                    self.finalize(req, row)
+                except Exception as e:
+                    outcome, err = "error", e
+                    if not req.future.done():
+                        req.future.set_exception(e)
+                self._latency(done_t - req.enqueued_at)
+                if req.trace is not None:
+                    req.trace.mark_run(run0, run1)
+                    req.trace.mark_batch(
+                        bucket_len, cls, len(batch),
+                        pad_fraction=ctx.get("pad_fraction"),
+                        prep_s=ctx.get("prep_s"),
+                        device_s=ctx.get("device_s"), batch=seq)
+                self._on_complete(req, outcome, self.clock(), err, ctx)
         with self._pending_lock:
             self.batches_total += 1
             self.rows_total += len(batch)
@@ -424,7 +452,7 @@ class MicroBatchScheduler:
                        rows=len(batch), batch_class=cls,
                        batch_seconds=round(dt, 6),
                        pad_fraction=ctx.get("pad_fraction"),
-                       heads=ctx.get("heads"), **quant_fields,
+                       heads=ctx.get("heads"), batch=seq, **quant_fields,
                        **self._replica_fields)
 
     # ------------------------------------------------- in-flight window
@@ -475,9 +503,9 @@ class MicroBatchScheduler:
         accounting: finalize wall-seconds spent while ANOTHER batch was
         in the window are overlapped — the device had work the whole
         time the host was fetching/sealing."""
-        t0 = time.perf_counter()
-        self._finalize_batch(entry)
-        fsec = time.perf_counter() - t0
+        with span("serve.retire", batch=entry["seq"]) as retired:
+            self._finalize_batch(entry, retired)
+        fsec = retired.seconds
         with self._inflight_lock:
             overlapped = overlapped or bool(self._inflight)
             self.finalize_seconds_total += fsec
@@ -700,7 +728,8 @@ class PackedBatchScheduler(MicroBatchScheduler):
         items = self.queue.pop_all()
         if not items:
             return
-        with self._pending_lock:
+        with span("serve.ingest", batch=self._next_batch, n=len(items)), \
+                self._pending_lock:
             for req in items:
                 if req.trace is not None:
                     req.trace.mark_ingested(now)
@@ -768,53 +797,56 @@ class PackedBatchScheduler(MicroBatchScheduler):
     def _dispatch(self, key, now: float) -> int:
         kind = key
         R, L, S = self.rows_per_batch, self.seq_len, self.max_segments
-        with self._pending_lock:
-            packer = self._packers.get(kind)
-            if packer is None or len(packer) == 0:  # raced fail_pending
-                return 0
-            rows = packer.pop_rows(R)
-            if len(packer) == 0:
-                del self._packers[kind]
-        num_ann = self.dispatcher.cfg.model.num_annotations
-        tokens = np.zeros((R, L), np.int32)
-        segment_ids = np.zeros((R, L), np.int32)
-        annotations = np.zeros((R, S, num_ann), np.float32)
-        riders: List[Tuple[Request, int, int, int, int]] = []
-        expired: List[Request] = []
-        tracing = False
-        timed = self.time_batches
-        for r, row in enumerate(rows):
-            for s, (req, start, span) in enumerate(row):
-                if req.deadline is not None and now >= req.deadline:
-                    expired.append(req)  # raced in since the last sweep
-                    continue
-                tokens[r, start:start + span] = req.tokens
-                segment_ids[r, start:start + span] = s + 1
-                if req.annotations is not None:
-                    annotations[r, s] = req.annotations
-                riders.append((req, r, s, start, span))
-                self._observe_wait(req, now)
-                if req.trace is not None:
-                    tracing = True
-                    if req.trace.sampled:
-                        timed = True
-                    req.trace.mark_popped(now)
-        self._expire_requests(expired, now)
-        if not riders:
-            return len(expired)
-        batch = [r[0] for r in riders]
-        geom = [(r, s, start, span) for (_, r, s, start, span) in riders]
-        heads = ([req.head for req in batch]
-                 if batch[0].head is not None else None)
-        n_riders = len(riders)
-        ctx = {"rows": R, "batch_class": R, "bucket_len": L,
-               "segments": n_riders,
-               "segments_per_row": round(n_riders / R, 4),
-               "mode": "ragged"}
-        if heads is not None:
-            ctx["heads"] = sorted({h.head_id for h in heads})
-        self._wait_for_slot()
-        t0 = time.perf_counter()
+        seq = self._next_batch
+        with span("serve.assemble", batch=seq):
+            with self._pending_lock:
+                packer = self._packers.get(kind)
+                if packer is None or len(packer) == 0:  # raced fail_pending
+                    return 0
+                rows = packer.pop_rows(R)
+                if len(packer) == 0:
+                    del self._packers[kind]
+            num_ann = self.dispatcher.cfg.model.num_annotations
+            tokens = np.zeros((R, L), np.int32)
+            segment_ids = np.zeros((R, L), np.int32)
+            annotations = np.zeros((R, S, num_ann), np.float32)
+            riders: List[Tuple[Request, int, int, int, int]] = []
+            expired: List[Request] = []
+            tracing = False
+            timed = self.time_batches
+            for r, row in enumerate(rows):
+                for s, (req, start, width) in enumerate(row):
+                    if req.deadline is not None and now >= req.deadline:
+                        expired.append(req)  # raced in since the last sweep
+                        continue
+                    tokens[r, start:start + width] = req.tokens
+                    segment_ids[r, start:start + width] = s + 1
+                    if req.annotations is not None:
+                        annotations[r, s] = req.annotations
+                    riders.append((req, r, s, start, width))
+                    self._observe_wait(req, now)
+                    if req.trace is not None:
+                        tracing = True
+                        if req.trace.sampled:
+                            timed = True
+                        req.trace.mark_popped(now)
+            self._expire_requests(expired, now)
+            if not riders:
+                return len(expired)
+            batch = [r[0] for r in riders]
+            geom = [rider[1:] for rider in riders]
+            heads = ([req.head for req in batch]
+                     if batch[0].head is not None else None)
+            n_riders = len(riders)
+            ctx = {"rows": R, "batch_class": R, "bucket_len": L,
+                   "segments": n_riders,
+                   "segments_per_row": round(n_riders / R, 4),
+                   "mode": "ragged", "batch": seq}
+            if heads is not None:
+                ctx["heads"] = sorted({h.head_id for h in heads})
+        self._next_batch = seq + 1
+        with span("serve.wait_slot", batch=seq) as slot:
+            self._wait_for_slot()
         run0 = self.clock()
         try:
             # Same rule as the bucketed scheduler: untimed batches run
@@ -826,7 +858,8 @@ class PackedBatchScheduler(MicroBatchScheduler):
             if run_async is not None:
                 handle = run_async(kind, tokens, segment_ids,
                                    annotations, geom, heads=heads,
-                                   timed=bool(tracing and timed))
+                                   timed=bool(tracing and timed),
+                                   batch=seq)
             else:
                 outs, timings = self.dispatcher.run_packed_timed(
                     kind, tokens, segment_ids, annotations, geom,
@@ -837,10 +870,10 @@ class PackedBatchScheduler(MicroBatchScheduler):
         self._enqueue_inflight({
             "mode": "ragged", "riders": riders, "handle": handle,
             "ctx": ctx, "kind": kind, "n_riders": n_riders,
-            "run0": run0, "t0": t0})
+            "run0": run0, "seq": seq, "submit_ns": slot.end_ns})
         return n_riders
 
-    def _finalize_batch(self, entry: Dict) -> None:
+    def _finalize_batch(self, entry: Dict, retired) -> None:
         """Packed-batch finalize: host fetch + per-rider fan-out via
         the in-flight handle, then the same marks/counters/event shape
         the pre-pipeline dispatch produced (mode="ragged")."""
@@ -848,7 +881,7 @@ class PackedBatchScheduler(MicroBatchScheduler):
         ctx, run0 = entry["ctx"], entry["run0"]
         kind, n_riders = entry["kind"], entry["n_riders"]
         R, L, S = self.rows_per_batch, self.seq_len, self.max_segments
-        tf0 = time.perf_counter()
+        seq = entry["seq"]
         try:
             outs, timings = entry["handle"].finalize()
         except Exception as e:  # fail THIS batch, keep serving
@@ -858,45 +891,49 @@ class PackedBatchScheduler(MicroBatchScheduler):
             self.tele.emit("note", source="serve", error=str(e),
                            kind=kind, bucket_len=L, mode="ragged")
             fail_t = self.clock()
-            for req, _, _, _, span in riders:
+            for req, _, _, _, width in riders:
                 if req.trace is not None:
                     req.trace.mark_run(run0, fail_t)
                     req.trace.mark_batch(
-                        span, R, R,
+                        width, R, R,
                         pad_fraction=ctx.get("pad_fraction"),
                         segments=n_riders,
                         segments_per_row=ctx["segments_per_row"],
-                        mode="ragged")
+                        mode="ragged", batch=seq)
                 if not req.future.done():
                     req.future.set_exception(e)
                 self._on_complete(req, "error", fail_t, e, ctx)
             return
         ctx.update(timings)
-        dt = time.perf_counter() - entry["t0"]
-        run1 = self.clock()
-        self._batch_h.observe(dt)
-        self._finalize_h.observe(time.perf_counter() - tf0)
-        done_t = self.clock()
-        for (req, _, _, _, span), out in zip(riders, outs):
-            outcome, err = "ok", None
-            try:
-                self.finalize(req, out)
-            except Exception as e:
-                outcome, err = "error", e
-                if not req.future.done():
-                    req.future.set_exception(e)
-            self._latency(done_t - req.enqueued_at)
-            if req.trace is not None:
-                req.trace.mark_run(run0, run1)
-                req.trace.mark_batch(
-                    span, R, R,
-                    pad_fraction=ctx.get("pad_fraction"),
-                    prep_s=ctx.get("prep_s"),
-                    device_s=ctx.get("device_s"),
-                    segments=n_riders,
-                    segments_per_row=ctx["segments_per_row"],
-                    mode="ragged")
-            self._on_complete(req, outcome, self.clock(), err, ctx)
+        with span("serve.seal", batch=seq) as sealing:
+            # Submit → fetch complete, and the fetch alone: both end
+            # where the seal starts.
+            dt = (sealing.start_ns - entry["submit_ns"]) * 1e-9
+            run1 = self.clock()
+            self._batch_h.observe(dt)
+            self._finalize_h.observe(
+                (sealing.start_ns - retired.start_ns) * 1e-9)
+            done_t = self.clock()
+            for (req, _, _, _, width), out in zip(riders, outs):
+                outcome, err = "ok", None
+                try:
+                    self.finalize(req, out)
+                except Exception as e:
+                    outcome, err = "error", e
+                    if not req.future.done():
+                        req.future.set_exception(e)
+                self._latency(done_t - req.enqueued_at)
+                if req.trace is not None:
+                    req.trace.mark_run(run0, run1)
+                    req.trace.mark_batch(
+                        width, R, R,
+                        pad_fraction=ctx.get("pad_fraction"),
+                        prep_s=ctx.get("prep_s"),
+                        device_s=ctx.get("device_s"),
+                        segments=n_riders,
+                        segments_per_row=ctx["segments_per_row"],
+                        mode="ragged", batch=seq)
+                self._on_complete(req, outcome, self.clock(), err, ctx)
         with self._pending_lock:
             self.batches_total += 1
             self.rows_total += n_riders
@@ -915,7 +952,7 @@ class PackedBatchScheduler(MicroBatchScheduler):
                        segments=n_riders,
                        segments_per_row=ctx["segments_per_row"],
                        mode="ragged",
-                       heads=ctx.get("heads"), **quant_fields,
+                       heads=ctx.get("heads"), batch=seq, **quant_fields,
                        **self._replica_fields)
 
     def fail_pending(self, exc: Exception) -> List[Request]:

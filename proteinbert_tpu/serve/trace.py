@@ -76,7 +76,7 @@ class RequestTrace:
         "t_run0", "t_run1", "t_lookup", "t_done",
         "bucket_len", "batch_class", "rows", "pad_fraction",
         "prep_s", "device_s", "cache", "outcome", "error", "head_id",
-        "segments", "segments_per_row", "mode", "quant",
+        "segments", "segments_per_row", "mode", "quant", "batch",
         "trace_id", "parent", "replica_id",
     )
 
@@ -118,6 +118,9 @@ class RequestTrace:
         # Quantized executable arm (ISSUE 12): "int8"/"int8_act" when
         # a quantized executable served this request, None on fp32.
         self.quant: Optional[str] = None
+        # The scheduler's sequence number of the batch this request rode
+        # in: the `batch=` its batch's `serve.*` spans carry (obs/tracing).
+        self.batch: Optional[int] = None
         # Fleet-scope causal context (ISSUE 18): `trace_id` is the
         # router-minted id this request joined via the X-PBT-Trace
         # header (None = self-rooted, standalone server), `parent` the
@@ -174,13 +177,15 @@ class RequestTrace:
                    device_s: Optional[float] = None,
                    segments: Optional[int] = None,
                    segments_per_row: Optional[float] = None,
-                   mode: Optional[str] = None) -> None:
+                   mode: Optional[str] = None,
+                   batch: Optional[int] = None) -> None:
         """Batch-level context, stamped onto every rider of the batch
         (same executable, same padded grid — the attribution is shared
         by construction). On the ragged path `bucket_len` is the
         rider's SPAN (its bucket-quantized length inside the packed
         row), `batch_class` the executable's fixed row count, and
-        `segments`/`segments_per_row`/`mode` describe the packing."""
+        `segments`/`segments_per_row`/`mode` describe the packing.
+        `batch` is the batch's sequence number, shared with its spans."""
         self.bucket_len = bucket_len
         self.batch_class = batch_class
         self.rows = rows
@@ -190,6 +195,7 @@ class RequestTrace:
         self.segments = segments
         self.segments_per_row = segments_per_row
         self.mode = mode
+        self.batch = batch
 
     # ---------------------------------------------------------- finish
 
@@ -280,7 +286,7 @@ class RequestTrace:
         for name in ("bucket_len", "batch_class", "rows", "pad_fraction",
                      "prep_s", "device_s", "error", "head_id",
                      "segments", "segments_per_row", "mode", "quant",
-                     "trace_id", "parent", "replica_id"):
+                     "batch", "trace_id", "parent", "replica_id"):
             v = getattr(self, name)
             if v is not None:
                 fields[name] = v
@@ -304,12 +310,14 @@ class RequestTrace:
             base_args["error"] = self.error
         if self.trace_id is not None:
             base_args["trace_id"] = self.trace_id
-        collector.add("serve.request", self.wall0, self.e2e_s(),
-                      depth=0, tid=tid, **base_args)
+        if self.batch is not None:
+            base_args["batch"] = self.batch
+        parent = collector.add("serve.request", self.wall0, self.e2e_s(),
+                               tid=tid, **base_args)
         for name, t0, t1 in self._segments():
             if t1 - t0 < _MIN_SPAN_S:
                 continue
             collector.add(f"serve.{name}", self.wall0 + (t0 - self.t_submit),
-                          t1 - t0, depth=1, tid=tid,
+                          t1 - t0, tid=tid, parent=parent,
                           request_id=self.request_id,
                           outcome=self.outcome or "ok")

@@ -31,6 +31,7 @@ import optax
 from proteinbert_tpu.configs import FinetuneConfig
 from proteinbert_tpu.data.vocab import PAD_ID
 from proteinbert_tpu.models import finetune as ft_model
+from proteinbert_tpu.obs.tracing import span
 from proteinbert_tpu.train.metrics import DeviceMetricAccumulator
 from proteinbert_tpu.train.schedule import make_optimizer, needs_loss_value
 from proteinbert_tpu.train.train_state import DONATE_STATE, gradient_update
@@ -236,7 +237,7 @@ def finetune(
             (epoch + 1) % cfg.task.eval_every_epochs == 0
             or epoch == cfg.task.epochs - 1
         ):
-            with tele.span("finetune_eval", step=epoch + 1):
+            with span("finetune_eval", tele.spans, step=epoch + 1):
                 em = evaluate(state, eval_batches(), cfg)
             record.update({f"eval_{k}": v for k, v in em.items()})
             tele.emit("eval", step=epoch + 1, metrics=em, kind="finetune")

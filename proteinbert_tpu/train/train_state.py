@@ -8,6 +8,12 @@ jits, shards with a NamedSharding tree, and checkpoints (orbax) as a unit
 — including the RNG key the reference forgets to checkpoint (SURVEY §5
 checkpoint bullet).
 
+The step's stages carry `jax.named_scope`s (`corrupt`, `forward`, `loss`,
+`optimizer`, `step_metrics`; the model adds `embed`, `local_track`,
+`attention`, `global_track`, `heads`): metadata on the compiled
+instructions, read back by `obs/tracing.program_scopes` to give a device
+trace's operations their layer. They change no operation.
+
 `train_step` fuses, on device, everything the reference does across the
 host/device boundary per iteration (reference utils.py:282-319):
 corruption (host DataLoader workers there; `data/corruption.py` here),
@@ -62,8 +68,10 @@ def gradient_update(
     (parallel/seq_parallel.py), ZeRO-1 (parallel/zero.py) and fine-tune
     (train/finetune.py) steps."""
     extra = {"value": loss} if needs_value else {}
-    updates, opt_state = tx.update(grads, opt_state, params, **extra)
-    params = jax.tree.map(lambda p, u: p + u.astype(p.dtype), params, updates)
+    with jax.named_scope("optimizer"):  # clip + Adam + apply
+        updates, opt_state = tx.update(grads, opt_state, params, **extra)
+        params = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
+                              params, updates)
     return params, opt_state
 
 
@@ -79,30 +87,31 @@ def corrupt_for_step(
     noise alone). Returns (next state key, X, Y, W, segment_ids|None);
     a batch carrying "segment_ids" is a PACKED batch (data/packing.py)
     and corrupts segment-aware."""
-    key, step_key = jax.random.split(state.key)
-    if "segment_ids" in batch:
-        seg = batch["segment_ids"]
-        X, Y, W = corrupt_packed_batch(
+    with jax.named_scope("corrupt"):
+        key, step_key = jax.random.split(state.key)
+        if "segment_ids" in batch:
+            seg = batch["segment_ids"]
+            X, Y, W = corrupt_packed_batch(
+                step_key,
+                batch["tokens"],
+                seg,
+                batch["annotations"],
+                token_randomize_prob=cfg.data.token_randomize_prob,
+                annotation_corrupt_prob=cfg.data.annotation_corrupt_prob,
+                annotation_drop_prob=cfg.data.annotation_drop_prob,
+                annotation_add_prob=cfg.data.annotation_add_prob,
+            )
+            return key, X, Y, W, seg
+        X, Y, W = corrupt_batch(
             step_key,
             batch["tokens"],
-            seg,
             batch["annotations"],
             token_randomize_prob=cfg.data.token_randomize_prob,
             annotation_corrupt_prob=cfg.data.annotation_corrupt_prob,
             annotation_drop_prob=cfg.data.annotation_drop_prob,
             annotation_add_prob=cfg.data.annotation_add_prob,
         )
-        return key, X, Y, W, seg
-    X, Y, W = corrupt_batch(
-        step_key,
-        batch["tokens"],
-        batch["annotations"],
-        token_randomize_prob=cfg.data.token_randomize_prob,
-        annotation_corrupt_prob=cfg.data.annotation_corrupt_prob,
-        annotation_drop_prob=cfg.data.annotation_drop_prob,
-        annotation_add_prob=cfg.data.annotation_add_prob,
-    )
-    return key, X, Y, W, None
+        return key, X, Y, W, None
 
 
 def corrupt_forward_grads(
@@ -123,22 +132,26 @@ def corrupt_forward_grads(
     if seg is not None:
 
         def loss_fn(params):
-            local_logits, global_logits = proteinbert.apply(
-                params, X["local"], X["global"], cfg.model,
-                segment_ids=seg,
-            )
-            return packed_pretrain_loss(
-                local_logits, global_logits, Y, W, seg)
+            with jax.named_scope("forward"):
+                local_logits, global_logits = proteinbert.apply(
+                    params, X["local"], X["global"], cfg.model,
+                    segment_ids=seg,
+                )
+            with jax.named_scope("loss"):
+                return packed_pretrain_loss(
+                    local_logits, global_logits, Y, W, seg)
 
         grads, metrics = jax.grad(loss_fn, has_aux=True)(state.params)
         return key, grads, metrics
     pad_mask = W["local"] > 0
 
     def loss_fn(params):
-        local_logits, global_logits = proteinbert.apply(
-            params, X["local"], X["global"], cfg.model, pad_mask
-        )
-        return pretrain_loss(local_logits, global_logits, Y, W)
+        with jax.named_scope("forward"):
+            local_logits, global_logits = proteinbert.apply(
+                params, X["local"], X["global"], cfg.model, pad_mask
+            )
+        with jax.named_scope("loss"):
+            return pretrain_loss(local_logits, global_logits, Y, W)
 
     grads, metrics = jax.grad(loss_fn, has_aux=True)(state.params)
     return key, grads, metrics
@@ -219,8 +232,9 @@ def train_step(
     )
 
     metrics = dict(metrics)
-    metrics["grad_norm"] = optax.global_norm(grads)
-    metrics["lr"] = effective_lr(cfg.optimizer, opt_state, state.step)
+    with jax.named_scope("step_metrics"):
+        metrics["grad_norm"] = optax.global_norm(grads)
+        metrics["lr"] = effective_lr(cfg.optimizer, opt_state, state.step)
     new_state = TrainState(
         step=state.step + 1, params=params, opt_state=opt_state, key=key
     )

@@ -26,6 +26,7 @@ import numpy as np
 
 from proteinbert_tpu.configs import PretrainConfig
 from proteinbert_tpu.obs import as_telemetry
+from proteinbert_tpu.obs.tracing import note_program, span
 from proteinbert_tpu.train import train_state as ts
 from proteinbert_tpu.train.checkpoint import Checkpointer
 from proteinbert_tpu.train.metrics import DeviceMetricAccumulator, StepTimer
@@ -382,6 +383,7 @@ def pretrain(
     early_stopped = False
     diagnostic_saved = False
     ckpt_since_log = False  # a save started since the last log point
+    data_wait_s = 0.0       # the `train.data_wait` spans' seconds, summed
     metrics = None
     # Overlapped boundaries: the checkpoint path needs every shard
     # addressable from this process (device_get assembles the snapshot
@@ -501,282 +503,308 @@ def pretrain(
                    if tele.enabled else None)
     ) as stop:
       for step in range(start_step, cfg.train.max_steps):
-        batch = next(batch_iterator)
-        if fault_stall and step + 1 == fault_stall[0]:
-            # Injected host stall, deliberately NOT discounted from the
-            # timing window — the drill asserts it shows up there.
-            time.sleep(fault_stall[1])
-        if eval_keyed_plateau:
-            state, metrics = plateau_step(state, put(batch), last_eval_loss)
-        else:
-            state, metrics = step_fn(state, put(batch), cfg)
-        timer.update()
-        # An overlap-dispatched eval bracket lands HERE — after this
-        # step's dispatch, so its metrics fetch runs with the train
-        # step already queued behind the eval on the device stream.
-        resolve_pending_eval()
-        if step - start_step + 1 == timer.warmup_steps:
-            # Guaranteed drain at the warmup boundary: t0 was just
-            # anchored at host ENQUEUE time, with the compile/warmup
-            # backlog still executing remotely. sync()'s re-anchor
-            # branch moves t0 past that backlog — without this, a run
-            # with log_every=0 and no eval/checkpoint cadence charges
-            # compile time to the timed window, deflating perf.
-            drain_and_sync()
+        # One span an iteration: every instant of the loop lies inside a
+        # `train.*` span, so an idle gap of the device takes one's name.
+        with span("train.step", step=step + 1):
+            with span("train.data_wait") as waited:
+                batch = next(batch_iterator)
+            data_wait_s += waited.seconds
+            if fault_stall and step + 1 == fault_stall[0]:
+                # Injected host stall, deliberately NOT discounted from the
+                # timing window — the drill asserts it shows up there.
+                time.sleep(fault_stall[1])
+            with span("train.put"):
+                batch = put(batch)
+            if step == start_step and not eval_keyed_plateau:
+                # What the device trace's operations are joined to the
+                # scopes by (obs/tracing.program_scopes), kept once.
+                note_program(step_fn.__name__, step_fn, (state, batch, cfg))
+            with span("train.dispatch"):
+                if eval_keyed_plateau:
+                    state, metrics = plateau_step(state, batch, last_eval_loss)
+                else:
+                    state, metrics = step_fn(state, batch, cfg)
+            timer.update()
+            # An overlap-dispatched eval bracket lands HERE — after this
+            # step's dispatch, so its metrics fetch runs with the train
+            # step already queued behind the eval on the device stream.
+            resolve_pending_eval()
+            if step - start_step + 1 == timer.warmup_steps:
+                # Guaranteed drain at the warmup boundary: t0 was just
+                # anchored at host ENQUEUE time, with the compile/warmup
+                # backlog still executing remotely. sync()'s re-anchor
+                # branch moves t0 past that backlog — without this, a run
+                # with log_every=0 and no eval/checkpoint cadence charges
+                # compile time to the timed window, deflating perf.
+                drain_and_sync()
 
-        if step == start_step:
-            # One-time HBM report once the step (incl. compile-time
-            # buffers) is resident — the first thing to look at when a
-            # bigger batch OOMs. CPU backends report no stats; silent.
-            # Dispatch is async, so force the step to completion first
-            # via a scalar fetch.
-            from proteinbert_tpu.utils.profiling import device_memory_report
-
-            float(metrics["loss"])
-            stats = next((s for s in device_memory_report().values()
-                          if "bytes_in_use" in s), None)
-            if stats:
-                logger.info(
-                    "HBM after first step: %.2f GB in use (peak %.2f) "
-                    "of %.2f GB",
-                    stats["bytes_in_use"] / 1e9,
-                    stats.get("peak_bytes_in_use", 0) / 1e9,
-                    stats.get("bytes_limit", 0) / 1e9,
+            if step == start_step:
+                # One-time HBM report once the step (incl. compile-time
+                # buffers) is resident — the first thing to look at when a
+                # bigger batch OOMs. CPU backends report no stats; silent.
+                # Dispatch is async, so force the step to completion first
+                # via a scalar fetch.
+                from proteinbert_tpu.utils.profiling import (
+                    device_memory_report,
                 )
-                for k in ("bytes_in_use", "peak_bytes_in_use",
-                          "bytes_limit"):
-                    if k in stats:
-                        tele.metrics.gauge(f"hbm_{k}").set(stats[k])
 
-        if cfg.train.log_every and (step + 1) % cfg.train.log_every == 0:
-            # ONE device_get for the whole metrics dict (per-key float()
-            # paid ~10 device→host roundtrips per log point).
-            m = {k: float(v) for k, v in jax.device_get(metrics).items()}
-            # That fetch drained the async dispatch queue through this
-            # step — fold the wait into the timing window, else
-            # summary() reports host enqueue rate.
-            timer.sync()
-            if cfg.train.on_nan != "off" and not check_finite(
-                m, step + 1, mode="quiet"
-            ):
-                # Preserve the state BEFORE halting so the blow-up is
-                # debuggable (reference: no failure handling at all,
-                # SURVEY §5). Saved to a SIBLING directory, once: the
-                # NaN state must never become the checkpoint a restart
-                # resumes from, nor churn the retention window.
-                if checkpointer is not None and not diagnostic_saved:
-                    diag = Checkpointer(
-                        checkpointer.directory + "-diagnostic",
-                        max_to_keep=1, async_save=False)
-                    diag.save(step + 1, state,
-                              {**data_state_for(step + 1),
-                               "non_finite": True})
-                    diag.close()
-                    diagnostic_saved = True
-                    logger.warning("non-finite state preserved in %s",
-                                   checkpointer.directory + "-diagnostic")
-                tele.emit("nan_halt", step=step + 1, metrics=m,
-                          mode=cfg.train.on_nan)
-                if cfg.train.on_nan == "halt":
-                    # About to raise: a staged snapshot mid-fetch is the
-                    # newest durable state a requeued run could resume
-                    # from — flush it before dying (best-effort; the
-                    # NaN stays the reported cause).
-                    flush_inflight_checkpoint(checkpointer,
-                                              "non-finite halt")
-                    tele.emit("run_end", step=step + 1, outcome="nan_halt",
-                              perf=timer.summary())
-                    tele.dump_flight("nan_halt")
-                # Raises in halt mode; logs the warning in warn mode.
-                check_finite(m, step + 1, mode=cfg.train.on_nan)
-            harvest_staged()  # completed overlap lands in this record
-            m.update(timer.summary())
-            if checkpointer is not None:
-                # Attribution flag, not a metric: 1.0 when a checkpoint
-                # save overlapped this log window — still writing now OR
-                # started since the last log point (the latch catches a
-                # save that started AND finished inside the window,
-                # which a point sample at the log instant would miss).
-                m["ckpt_in_flight"] = float(checkpointer.in_flight()
-                                            or ckpt_since_log)
-                ckpt_since_log = False
-            history.append({"step": step + 1, **m})
-            if tele.enabled:
-                # All telemetry sits at log cadence — the per-step hot
-                # path stays untouched (overhead <1% of a log interval,
-                # ~0 of a step).
-                extra = {}
-                reg = tele.metrics
-                if prefetch_it is not None:
-                    extra["data_wait_s"] = round(prefetch_it.wait_s, 4)
-                    reg.gauge("data_wait_seconds").set(prefetch_it.wait_s)
-                    reg.gauge("data_batches_total").set(prefetch_it.batches)
-                try:
-                    import resource
-                    import sys as _sys
+                float(metrics["loss"])
+                stats = next((s for s in device_memory_report().values()
+                              if "bytes_in_use" in s), None)
+                if stats:
+                    logger.info(
+                        "HBM after first step: %.2f GB in use (peak %.2f) "
+                        "of %.2f GB",
+                        stats["bytes_in_use"] / 1e9,
+                        stats.get("peak_bytes_in_use", 0) / 1e9,
+                        stats.get("bytes_limit", 0) / 1e9,
+                    )
+                    for k in ("bytes_in_use", "peak_bytes_in_use",
+                              "bytes_limit"):
+                        if k in stats:
+                            tele.metrics.gauge(f"hbm_{k}").set(stats[k])
 
-                    # ru_maxrss: kilobytes on Linux, BYTES on macOS.
-                    rss = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
-                    rss *= 1 if _sys.platform == "darwin" else 1024
-                    extra["host_max_rss_bytes"] = rss
-                    reg.gauge("host_max_rss_bytes").set(rss)
-                except Exception:
-                    pass  # non-POSIX host: RSS gauge just absent
-                tele.emit("step", step=step + 1, metrics=m, **extra)
-                reg.counter("steps_total").inc(cfg.train.log_every)
-                reg.set_many(m)  # loss/acc + StepTimer summary as gauges
-            logger.info(
-                "step %d loss %.4f (local %.4f global %.4f) acc %.3f %s",
-                step + 1, m["loss"], m["local_loss"], m["global_loss"],
-                m["local_acc"],
-                (f"{m['residues_per_sec_per_chip']:.0f} res/s/chip "
-                 f"MFU {m['mfu']:.3f} on {n_chips}x {device_kind}"
-                 # The since-last-log rate tells a live operator
-                 # "currently slow" apart from "was slow once" — the
-                 # cumulative MFU alone re-reports an old stall forever.
-                 + (f" (window {m['window_mfu']:.3f})"
-                    if "window_mfu" in m else "")) if "mfu" in m else "",
-            )
-            if log_fn is not None:
-                log_fn(step + 1, m)
+            if cfg.train.log_every and (step + 1) % cfg.train.log_every == 0:
+                # ONE device_get for the whole metrics dict (per-key float()
+                # paid ~10 device→host roundtrips per log point).
+                with span("train.log_fetch"):
+                    m = {k: float(v)
+                         for k, v in jax.device_get(metrics).items()}
+                # That fetch drained the async dispatch queue through this
+                # step — fold the wait into the timing window, else
+                # summary() reports host enqueue rate.
+                timer.sync()
+                if cfg.train.on_nan != "off" and not check_finite(
+                    m, step + 1, mode="quiet"
+                ):
+                    # Preserve the state BEFORE halting so the blow-up is
+                    # debuggable (reference: no failure handling at all,
+                    # SURVEY §5). Saved to a SIBLING directory, once: the
+                    # NaN state must never become the checkpoint a restart
+                    # resumes from, nor churn the retention window.
+                    if checkpointer is not None and not diagnostic_saved:
+                        diag = Checkpointer(
+                            checkpointer.directory + "-diagnostic",
+                            max_to_keep=1, async_save=False)
+                        diag.save(step + 1, state,
+                                  {**data_state_for(step + 1),
+                                   "non_finite": True})
+                        diag.close()
+                        diagnostic_saved = True
+                        logger.warning("non-finite state preserved in %s",
+                                       checkpointer.directory + "-diagnostic")
+                    tele.emit("nan_halt", step=step + 1, metrics=m,
+                              mode=cfg.train.on_nan)
+                    if cfg.train.on_nan == "halt":
+                        # About to raise: a staged snapshot mid-fetch is the
+                        # newest durable state a requeued run could resume
+                        # from — flush it before dying (best-effort; the
+                        # NaN stays the reported cause).
+                        flush_inflight_checkpoint(checkpointer,
+                                                  "non-finite halt")
+                        tele.emit("run_end", step=step + 1, outcome="nan_halt",
+                                  perf=timer.summary())
+                        tele.dump_flight("nan_halt")
+                    # Raises in halt mode; logs the warning in warn mode.
+                    check_finite(m, step + 1, mode=cfg.train.on_nan)
+                harvest_staged()  # completed overlap lands in this record
+                m.update(timer.summary())
+                if checkpointer is not None:
+                    # Attribution flag, not a metric: 1.0 when a checkpoint
+                    # save overlapped this log window — still writing now OR
+                    # started since the last log point (the latch catches a
+                    # save that started AND finished inside the window,
+                    # which a point sample at the log instant would miss).
+                    m["ckpt_in_flight"] = float(checkpointer.in_flight()
+                                                or ckpt_since_log)
+                    ckpt_since_log = False
+                history.append({"step": step + 1, **m})
+                if tele.enabled:
+                    # All telemetry sits at log cadence — the per-step hot
+                    # path stays untouched (overhead <1% of a log interval,
+                    # ~0 of a step).
+                    extra = {}
+                    reg = tele.metrics
+                    if prefetch_it is not None:
+                        extra["data_wait_s"] = round(data_wait_s, 4)
+                        reg.gauge("data_wait_seconds").set(data_wait_s)
+                        reg.gauge("data_batches_total").set(
+                            prefetch_it.batches)
+                    try:
+                        import resource
+                        import sys as _sys
 
-        if stop.requested:
-            # Preemption (SIGTERM) / operator interrupt: checkpoint at the
-            # completed step and exit cleanly; resume picks up exactly here.
-            drain_and_sync()
-            saved = False
-            if checkpointer is not None:
-                # An in-flight staged snapshot must land BEFORE the
-                # exit-75 requeue — best-effort, so a stager failure
-                # cannot turn a clean preemption into a crash.
-                flush_inflight_checkpoint(
-                    checkpointer, "preemption (SIGTERM/SIGINT)")
-                saved = checked_save(step + 1, state)
-                checkpointer.wait()
-            logger.warning("preempted at step %d: %s, exiting", step + 1,
-                           "state saved" if saved else "state NOT saved")
-            tele.emit("requeue", step=step + 1,
-                      reason=f"signal_{stop.signum}", saved=saved)
-            # Second, fuller dump (the signal-time one fired mid-step):
-            # now the flush/save outcome and the requeue record are in
-            # the ring — the picture a post-mortem actually wants.
-            tele.dump_flight(f"signal_{stop.signum}")
-            preempted = True
-            break
-
-        if (
-            eval_batches is not None
-            and cfg.train.eval_every
-            and (step + 1) % cfg.train.eval_every == 0
-        ):
-            # Drain BEFORE starting the eval bracket: otherwise the
-            # eval's first device fetch waits out the enqueued train
-            # steps and discount() below subtracts that real step time
-            # from the window, inflating throughput/MFU. (The overlap
-            # path needs the drain too — after it, the eval batches are
-            # the ONLY queued device work, so the deferred resolve-time
-            # fetch waits out eval compute alone and discounting it
-            # cannot swallow real step time.)
-            drain_and_sync()
-            t_eval = time.perf_counter()
-            if fault_eval_stall:
-                # Injected INSIDE the discounted bracket: the drill
-                # asserts this does NOT surface as a slow window.
-                time.sleep(fault_eval_stall)
-            if overlap_eval:
-                # Overlapped bracket: dispatch every eval batch (host
-                # prep + enqueue — discounted) and defer the metrics
-                # fetch until after the next train step's dispatch; the
-                # eval_step dispatches capture the boundary state's
-                # buffers BEFORE the next (donating) train step reuses
-                # them, so the results are exact. History/log records
-                # and the eval-stream bookkeeping happen at resolve
-                # time — identical values, one step later in the
-                # stream. Keying stays by the 1-based boundary step, so
-                # `evaluate --like-step` reproduces it either way.
-                handle = dispatch_eval(
-                    state, eval_batches(), put, cfg,
-                    eval_base_key(cfg, step + 1), drain_every=0)
-                timer.discount(time.perf_counter() - t_eval)
-                pending_eval = (step + 1, handle)
-            else:
-                # Key the eval by the 1-based step recorded in history,
-                # so `evaluate --like-step <history step>` reproduces it.
-                with tele.span("eval_bracket", step=step + 1):
-                    em = _evaluate(state, eval_batches(), put, cfg, step + 1)
-                timer.discount(time.perf_counter() - t_eval)
-                history.append({"step": step + 1, **em})
-                tele.emit("eval", step=step + 1, metrics=em)
+                        # ru_maxrss: kilobytes on Linux, BYTES on macOS.
+                        rss = resource.getrusage(
+                            resource.RUSAGE_SELF).ru_maxrss
+                        rss *= 1 if _sys.platform == "darwin" else 1024
+                        extra["host_max_rss_bytes"] = rss
+                        reg.gauge("host_max_rss_bytes").set(rss)
+                    except Exception:
+                        pass  # non-POSIX host: RSS gauge just absent
+                    tele.emit("step", step=step + 1, metrics=m, **extra)
+                    reg.counter("steps_total").inc(cfg.train.log_every)
+                    reg.set_many(m)  # loss/acc + StepTimer summary as gauges
                 logger.info(
-                    "step %d eval loss %.4f (local %.4f global %.4f) "
-                    "acc %.3f",
-                    step + 1, em["eval_loss"], em["eval_local_loss"],
-                    em["eval_global_loss"], em["eval_local_acc"],
+                    "step %d loss %.4f (local %.4f global %.4f) acc %.3f %s",
+                    step + 1, m["loss"], m["local_loss"], m["global_loss"],
+                    m["local_acc"],
+                    (f"{m['residues_per_sec_per_chip']:.0f} res/s/chip "
+                     f"MFU {m['mfu']:.3f} on {n_chips}x {device_kind}"
+                     # The since-last-log rate tells a live operator
+                     # "currently slow" apart from "was slow once" — the
+                     # cumulative MFU alone re-reports an old stall forever.
+                     + (f" (window {m['window_mfu']:.3f})"
+                        if "window_mfu" in m else "")) if "mfu" in m else "",
                 )
                 if log_fn is not None:
-                    log_fn(step + 1, em)
-                last_eval_loss = np.float32(em["eval_loss"])
-                if em["eval_loss"] < best_eval_loss - cfg.train.early_stop_min_delta:
-                    best_eval_loss = em["eval_loss"]
-                    stalled_evals = 0
-                else:
-                    stalled_evals += 1
-                    if (cfg.train.early_stop_patience
-                            and stalled_evals >= cfg.train.early_stop_patience):
-                        # The regime shift the r3 sustained run exposed:
-                        # eval rising while train loss falls. Checkpoint
-                        # the state and stop — continuing only overfits
-                        # further.
-                        drain_and_sync()
-                        if checkpointer is not None:
-                            checked_save(step + 1, state)
-                            checkpointer.wait()
-                        logger.warning(
-                            "early stop at step %d: eval_loss has not "
-                            "improved for %d consecutive evals (best %.4f)",
-                            step + 1, stalled_evals, best_eval_loss)
-                        early_stopped = True
-                        break
+                    log_fn(step + 1, m)
 
-        if (
-            checkpointer is not None
-            and cfg.checkpoint.every_steps
-            and (step + 1) % cfg.checkpoint.every_steps == 0
-        ):
-            if overlap_ckpt:
-                # Overlapped boundary: no drain, no stop-the-world.
-                # The on-device snapshot captures this step's state
-                # before the next (donating) train step can reuse its
-                # buffers; the stager thread runs the device→host fetch
-                # + orbax write behind the train steps the loop keeps
-                # dispatching. The eval stream must be current FIRST —
-                # a same-step overlapped eval is still pending and its
-                # values belong in this boundary's data_state (resume
-                # must restore them byte-identically).
-                resolve_pending_eval()
-                with tele.span("ckpt_boundary_staged", step=step + 1):
-                    flush_staged_overlap()  # backpressure: one stage in flight
-                    snap = ts.snapshot_train_state(state)
-                    checkpointer.save_staged(step + 1, snap,
-                                             data_state_for(step + 1))
-                ckpt_since_log = True
-                # Deliberately NOT discounted: the snapshot dispatch +
-                # thread handoff are the boundary's only in-window cost
-                # (~ms). The hidden fetch+write seconds are credited to
-                # the overlap account when the stage lands
-                # (harvest/flush), so summary() reports them as
-                # overlapped rather than vanishing.
-            else:
-                # Drain first (so the save's state reads don't swallow
-                # real step time), then discount the save itself — host
-                # serialization is not training time and must not
-                # deflate the window when a later sync() extends it.
+            if stop.requested:
+                # Preemption (SIGTERM) / operator interrupt: checkpoint at
+                # the completed step and exit cleanly; resume picks up
+                # exactly here.
                 drain_and_sync()
-                t_save = time.perf_counter()
-                with tele.span("ckpt_boundary_sync", step=step + 1):
-                    checked_save(step + 1, state)
-                ckpt_since_log = True
-                timer.discount(time.perf_counter() - t_save)
+                saved = False
+                if checkpointer is not None:
+                    # An in-flight staged snapshot must land BEFORE the
+                    # exit-75 requeue — best-effort, so a stager failure
+                    # cannot turn a clean preemption into a crash.
+                    flush_inflight_checkpoint(
+                        checkpointer, "preemption (SIGTERM/SIGINT)")
+                    saved = checked_save(step + 1, state)
+                    checkpointer.wait()
+                logger.warning("preempted at step %d: %s, exiting", step + 1,
+                               "state saved" if saved else "state NOT saved")
+                tele.emit("requeue", step=step + 1,
+                          reason=f"signal_{stop.signum}", saved=saved)
+                # Second, fuller dump (the signal-time one fired mid-step):
+                # now the flush/save outcome and the requeue record are in
+                # the ring — the picture a post-mortem actually wants.
+                tele.dump_flight(f"signal_{stop.signum}")
+                preempted = True
+                break
+
+            if (
+                eval_batches is not None
+                and cfg.train.eval_every
+                and (step + 1) % cfg.train.eval_every == 0
+            ):
+                # Drain BEFORE starting the eval bracket: otherwise the
+                # eval's first device fetch waits out the enqueued train
+                # steps and discount() below subtracts that real step time
+                # from the window, inflating throughput/MFU. (The overlap
+                # path needs the drain too — after it, the eval batches are
+                # the ONLY queued device work, so the deferred resolve-time
+                # fetch waits out eval compute alone and discounting it
+                # cannot swallow real step time.)
+                drain_and_sync()
+                t_eval = time.perf_counter()
+                if fault_eval_stall:
+                    # Injected INSIDE the discounted bracket: the drill
+                    # asserts this does NOT surface as a slow window.
+                    time.sleep(fault_eval_stall)
+                if overlap_eval:
+                    # Overlapped bracket: dispatch every eval batch (host
+                    # prep + enqueue — discounted) and defer the metrics
+                    # fetch until after the next train step's dispatch; the
+                    # eval_step dispatches capture the boundary state's
+                    # buffers BEFORE the next (donating) train step reuses
+                    # them, so the results are exact. History/log records
+                    # and the eval-stream bookkeeping happen at resolve
+                    # time — identical values, one step later in the
+                    # stream. Keying stays by the 1-based boundary step, so
+                    # `evaluate --like-step` reproduces it either way.
+                    handle = dispatch_eval(
+                        state, eval_batches(), put, cfg,
+                        eval_base_key(cfg, step + 1), drain_every=0)
+                    timer.discount(time.perf_counter() - t_eval)
+                    pending_eval = (step + 1, handle)
+                else:
+                    # Key the eval by the 1-based step recorded in history,
+                    # so `evaluate --like-step <history step>` reproduces it.
+                    with span("eval_bracket", tele.spans, step=step + 1):
+                        em = _evaluate(state, eval_batches(), put, cfg,
+                                       step + 1)
+                    timer.discount(time.perf_counter() - t_eval)
+                    history.append({"step": step + 1, **em})
+                    tele.emit("eval", step=step + 1, metrics=em)
+                    logger.info(
+                        "step %d eval loss %.4f (local %.4f global %.4f) "
+                        "acc %.3f",
+                        step + 1, em["eval_loss"], em["eval_local_loss"],
+                        em["eval_global_loss"], em["eval_local_acc"],
+                    )
+                    if log_fn is not None:
+                        log_fn(step + 1, em)
+                    last_eval_loss = np.float32(em["eval_loss"])
+                    if (em["eval_loss"] < best_eval_loss
+                            - cfg.train.early_stop_min_delta):
+                        best_eval_loss = em["eval_loss"]
+                        stalled_evals = 0
+                    else:
+                        stalled_evals += 1
+                        if (cfg.train.early_stop_patience
+                                and stalled_evals
+                                >= cfg.train.early_stop_patience):
+                            # The regime shift the r3 sustained run exposed:
+                            # eval rising while train loss falls. Checkpoint
+                            # the state and stop — continuing only overfits
+                            # further.
+                            drain_and_sync()
+                            if checkpointer is not None:
+                                checked_save(step + 1, state)
+                                checkpointer.wait()
+                            logger.warning(
+                                "early stop at step %d: eval_loss has not "
+                                "improved for %d consecutive evals "
+                                "(best %.4f)",
+                                step + 1, stalled_evals, best_eval_loss)
+                            early_stopped = True
+                            break
+
+            if (
+                checkpointer is not None
+                and cfg.checkpoint.every_steps
+                and (step + 1) % cfg.checkpoint.every_steps == 0
+            ):
+                if overlap_ckpt:
+                    # Overlapped boundary: no drain, no stop-the-world.
+                    # The on-device snapshot captures this step's state
+                    # before the next (donating) train step can reuse its
+                    # buffers; the stager thread runs the device→host fetch
+                    # + orbax write behind the train steps the loop keeps
+                    # dispatching. The eval stream must be current FIRST —
+                    # a same-step overlapped eval is still pending and its
+                    # values belong in this boundary's data_state (resume
+                    # must restore them byte-identically).
+                    resolve_pending_eval()
+                    with span("ckpt_boundary_staged", tele.spans,
+                              step=step + 1):
+                        # backpressure: one stage in flight
+                        flush_staged_overlap()
+                        snap = ts.snapshot_train_state(state)
+                        checkpointer.save_staged(step + 1, snap,
+                                                 data_state_for(step + 1))
+                    ckpt_since_log = True
+                    # Deliberately NOT discounted: the snapshot dispatch +
+                    # thread handoff are the boundary's only in-window cost
+                    # (~ms). The hidden fetch+write seconds are credited to
+                    # the overlap account when the stage lands
+                    # (harvest/flush), so summary() reports them as
+                    # overlapped rather than vanishing.
+                else:
+                    # Drain first (so the save's state reads don't swallow
+                    # real step time), then discount the save itself — host
+                    # serialization is not training time and must not
+                    # deflate the window when a later sync() extends it.
+                    drain_and_sync()
+                    t_save = time.perf_counter()
+                    with span("ckpt_boundary_sync", tele.spans,
+                              step=step + 1):
+                        checked_save(step + 1, state)
+                    ckpt_since_log = True
+                    timer.discount(time.perf_counter() - t_save)
 
     # An eval dispatched at the final step resolves here — before the
     # final save's data_state is built.

@@ -12,6 +12,8 @@ on TPU (per-op time lives on device, invisible to host timers).
 from __future__ import annotations
 
 import contextlib
+import logging
+import os
 import time
 from typing import Dict, Optional
 
@@ -106,15 +108,44 @@ class BoundaryStallMeter:
 @contextlib.contextmanager
 def device_trace(log_dir: str, host_profile: bool = False):
     """Capture a jax.profiler trace (XLA ops, HBM, fusion view) to
-    `log_dir`; open with TensorBoard or ui.perfetto.dev."""
+    `log_dir`; open with TensorBoard or ui.perfetto.dev.
+
+    While the capture runs the program's span spine records
+    (obs/tracing). On exit two files land beside the xplane:
+    `host_spans.json.gz`, the capture's host spans as Perfetto
+    trace-event JSON, and `program_scopes.json`, for every hot jitted
+    program noted during the capture the map from its compiled
+    instructions' names (what the trace's "XLA Ops" carry) to the
+    `jax.named_scope`s they came from."""
+    import glob
+    import json
+
     import jax
 
+    from proteinbert_tpu.obs import tracing
+
+    tracing.recorder().clear()
     jax.profiler.start_trace(log_dir, create_perfetto_trace=host_profile)
     try:
         yield
     finally:
         jax.profiler.stop_trace()
-        log(f"device trace written to {log_dir}")
+        try:
+            planes = sorted(glob.glob(os.path.join(
+                log_dir, "plugins", "profile", "*", "*.xplane.pb")),
+                key=os.path.getmtime)
+            beside = os.path.dirname(planes[-1]) if planes else log_dir
+            tracing.recorder().dump(os.path.join(beside, "host_spans.json.gz"))
+            with open(os.path.join(beside, "program_scopes.json"), "w") as f:
+                json.dump({name: tracing.program_scopes(name)
+                           for name in tracing.noted_programs()}, f)
+            log(f"device trace, host spans and program scopes written to "
+                f"{beside}")
+        except Exception as e:
+            # The xplane is on disk; a failed extra (the scope map is a
+            # compile) must not mask whatever the body itself raised.
+            log(f"device trace written to {log_dir}; host spans / program "
+                f"scopes NOT written: {e!r}", level=logging.WARNING)
 
 
 def monitor_memory(threshold_bytes: int = 100 * 1024 ** 2,

@@ -144,6 +144,32 @@ def test_kernel_entry_compiles_for_v5e(chip, entry, width):
         assert calls == (2 if width == "base" else 1), calls
     else:
         assert calls >= 1, "no tpu_custom_call in the compiled text"
+    # Each pallas_call's `name=` is the compiled instruction's name (what
+    # a device trace's operations carry), so a cell with `use_pallas`
+    # finds its kernels in `device_ops` by name.
+    for name in _kernel_names(entry, width):
+        assert f"%{name}." in text or f"{name})/pallas_call" in text, name
+
+
+def _kernel_names(entry, width):
+    local = ("pbt_local_track_segments" if "segments" in entry
+             or "packed" in entry else "pbt_local_track")
+    if entry.startswith("fused_onepass"):
+        return ((local, "pbt_global_attention") if width == "base"
+                else ("pbt_onepass",))
+    return ("pbt_global_attention",) if "attention" in entry else (local,)
+
+
+@pytest.mark.parametrize("entry,name", [
+    ("fused_local_track", "pbt_local_track_tiled"),
+    ("fused_local_track_segments", "pbt_local_track_segments_tiled")])
+def test_channel_tiled_kernels_are_named(entry, name, monkeypatch):
+    """Above MAX_PALLAS_DIM the local track runs the channel-tiled
+    kernels, which no cell above compiles: their names are read from the
+    jaxpr (no chip, nothing compiled)."""
+    monkeypatch.setitem(WIDTHS, "wide", (1024, 1024, 16, 64))
+    fn, args = _entry(entry, "wide")
+    assert f"name={name}" in str(jax.make_jaxpr(fn)(*args))
 
 
 def test_base_train_step_fits_the_chip(chip):

@@ -2,7 +2,6 @@
 metrics registry, span tracing, flight recorder, diagnose, and the
 trainer wiring end-to-end on a CPU mesh."""
 
-import importlib.util
 import json
 import os
 import subprocess
@@ -177,13 +176,12 @@ def test_disabled_registry_is_a_noop():
 def test_null_facade_is_inert_and_is_the_default():
     # obs.NULL is the do-nothing telemetry every instrumented call
     # site runs through when none is configured: emit returns None,
-    # span is a shared nullcontext, the registry is disabled, and
+    # there is no span collector, the registry is disabled, and
     # as_telemetry(None) hands back exactly this object.
     assert obs.as_telemetry(None) is obs.NULL
     assert obs.NULL.enabled is False
     assert obs.NULL.emit("step", step=1, metrics={}) is None
-    with obs.NULL.span("anything"):
-        pass
+    assert obs.NULL.spans is None
     assert obs.NULL.dump_flight("reason") is None
     obs.NULL.metrics.counter("c").inc()
     assert obs.NULL.metrics.snapshot() == {
@@ -209,48 +207,14 @@ def test_profiler_shim_keeps_api_and_feeds_registry():
     assert reg.snapshot()["histograms"]["etl"]["count"] == 2
 
 
-# ------------------------------------------------------------ tracing
-
-def test_span_collector_dump_feeds_trace_attribution(tmp_path):
-    col = obs.SpanCollector()
-    with obs.span("outer", collector=col):
-        with obs.span("inner", collector=col, step=3):
-            pass
-    assert len(col) == 2
-    names = {s["name"]: s for s in col.to_perfetto()["traceEvents"]
-             if s["ph"] == "X"}
-    assert names["inner"]["args"]["depth"] == 1
-    assert names["inner"]["args"]["step"] == 3
-    path = col.dump(str(tmp_path / "spans.trace.json"))
-    # One format: the device-trace attribution tool parses a span dump.
-    spec = importlib.util.spec_from_file_location(
-        "trace_attribution", os.path.join(REPO, "tools",
-                                          "trace_attribution.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    per_op = mod.parse_trace(path)
-    assert set(per_op) == {"outer", "inner"}
-    # Nested spans attribute SELF time: a 10s parent enclosing an 8s
-    # child reports 2s + 8s, never 18s of double-counted wall.
-    nested = tmp_path / "nested.trace.json"
-    nested.write_text(json.dumps({"traceEvents": [
-        {"ph": "X", "name": "parent", "pid": 1, "tid": 1,
-         "ts": 0, "dur": 10_000_000, "args": {"depth": 0}},
-        {"ph": "X", "name": "child", "pid": 1, "tid": 1,
-         "ts": 1_000_000, "dur": 8_000_000, "args": {"depth": 1}},
-    ]}))
-    per = mod.parse_trace(str(nested))
-    assert per["child"] == 8_000_000
-    assert per["parent"] == 2_000_000
-
-
-def test_prefetch_exposes_wait_accounting():
+def test_prefetch_counts_deliveries_and_keeps_no_clock_of_its_own():
     from proteinbert_tpu.data.prefetch import prefetch
 
     it = prefetch(iter([{"a": 1}] * 5), depth=2)
     assert sum(1 for _ in it) == 5
     assert it.batches == 5
-    assert it.wait_s >= 0.0
+    # the wait is the consumer's `train.data_wait` span (tests/test_tracing)
+    assert not hasattr(it, "wait_s")
 
 
 # ------------------------------------------------------------- flight

@@ -97,6 +97,9 @@ NEGATIVE_CASES = [
         {"v": 1, "event": "serve_request", "seq": 0, "t": 0.0,
          "kind": "embed", "outcome": "ok", "request_id": "r1",
          "stages": {}, "mode": "packed"},  # not a serve mode
+        {"v": 1, "event": "serve_request", "seq": 0, "t": 0.0,
+         "kind": "embed", "outcome": "ok", "request_id": "r1",
+         "stages": {}, "batch": "7"},  # the batch's sequence number: int
         # elastic topology (ISSUE 11): reshard + fleet events.
         {"v": 1, "event": "reshard", "seq": 0, "t": 0.0,
          "step": 1, "target_mesh": {"data": 4}},  # missing wire_bytes

@@ -1284,14 +1284,16 @@ def _serve_ragged_ab(Server, params, cfg, seqs, max_batch, max_wait_s,
             f"(max |diff| {parity_dense['max_abs_diff']:.2e})")
 
     stats = {m: servers[m].stats() for m in servers}
-    # O(kinds) executable gate: one warm kind ("embed") must mean ONE
-    # ragged executable — deterministic, so gated (unlike wall-clock) —
-    # for BOTH ladders (the dense ladder must cost zero executables).
+    # O(kinds x row classes) executable gate: one warm kind ("embed")
+    # must mean one ragged executable a row class (at most four) —
+    # deterministic, so gated (unlike wall-clock) — for BOTH ladders
+    # (the dense ladder must cost zero executables).
     for name in ("ragged", "ragged_dense"):
-        if stats[name]["executables"] > 1:
+        classes = len(servers[name].dispatcher.batch_classes)
+        if stats[name]["executables"] > classes:
             failures.append(
                 f"{name} executable count {stats[name]['executables']} "
-                "> O(kinds)=1 for the single warmed kind")
+                f"> {classes} row classes for the single warmed kind")
     # ---- fused fast-path coverage gate (ISSUE 10 acceptance) ---------
     path_delta = {k: _fb.PATH_TOTAL.get(k, 0) - path_before.get(k, 0)
                   for k in set(_fb.PATH_TOTAL) | set(path_before)

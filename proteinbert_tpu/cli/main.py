@@ -1301,8 +1301,9 @@ def cmd_serve(args) -> int:
         log(f"serving {len(head_ids)} registered head(s) over the "
             f"shared trunk: {', '.join(head_ids)}")
     if args.serve_mode == "ragged":
-        log(f"ragged packed serving: one ({args.max_batch}, "
-            f"{cfg.data.seq_len}) executable per request kind; spans "
+        log(f"ragged packed serving: one (rows, {cfg.data.seq_len}) "
+            f"executable per request kind and row class "
+            f"{list(server.dispatcher.batch_classes)}; spans "
             f"quantized to buckets={list(server.dispatcher.buckets)}, "
             f"up to {args.pack_max_segments} requests per row")
     else:
@@ -2278,11 +2279,12 @@ def build_parser() -> argparse.ArgumentParser:
                     choices=["bucketed", "ragged"],
                     help="bucketed: one warm executable per "
                          "(bucket, batch class); ragged: pack "
-                         "heterogeneous requests into fixed-shape "
-                         "(max_batch, seq_len) rows — one executable "
-                         "per request kind, outputs matching bucketed "
-                         "within jitted tolerance (docs/serving.md, "
-                         "ragged batching)")
+                         "heterogeneous requests into (rows, seq_len) "
+                         "batches — one executable per request kind "
+                         "and row class (max_batch, /2, /4, /8), "
+                         "outputs matching bucketed within jitted "
+                         "tolerance (docs/serving.md, ragged "
+                         "batching)")
     sv.add_argument("--pack-max-segments", type=int, default=8,
                     help="ragged mode: max requests packed into one "
                          "row (a batch carries up to max_batch x this "
@@ -2290,7 +2292,8 @@ def build_parser() -> argparse.ArgumentParser:
     sv.add_argument("--max-batch", type=int, default=8,
                     help="micro-batch size cap (dispatch when a "
                          "(kind, bucket) group reaches it); in ragged "
-                         "mode, the packed ROW count per executable")
+                         "mode, the packed ROW count of the largest "
+                         "batch")
     sv.add_argument("--max-wait-ms", type=float, default=10.0,
                     help="max queueing delay before an under-full "
                          "batch dispatches anyway")

@@ -31,6 +31,7 @@ the same jitted kernels the same padded shapes.
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -125,6 +126,25 @@ def default_batch_classes(max_batch: int, multiple: int = 1) -> Tuple[int, ...]:
         c *= 2
     classes.append(max_batch)
     return tuple(classes)
+
+
+MAX_ROW_CLASSES = 4
+
+
+def default_row_classes(rows_per_batch: int, multiple: int = 1) -> Tuple[int, ...]:
+    """The packed executable's row classes, ascending: R, R/2, R/4, R/8,
+    each kept only while it is a whole number of rows that the mesh's
+    data*fsdp extent `multiple` divides: 512 → (64, 128, 256, 512);
+    6 → (3, 6); (8, multiple=2) → (2, 4, 8). Never more than
+    MAX_ROW_CLASSES: every class is one more executable per request
+    kind to warm and to hold. `rows_per_batch` itself has to split over
+    the replicas, as in `default_batch_classes`."""
+    default_batch_classes(rows_per_batch, multiple)  # the same refusals
+    classes = [rows_per_batch]
+    while (len(classes) < MAX_ROW_CLASSES and classes[-1] % 2 == 0
+           and (classes[-1] // 2) % multiple == 0):
+        classes.append(classes[-1] // 2)
+    return tuple(reversed(classes))
 
 
 class InFlightBatch:
@@ -1023,11 +1043,20 @@ class BucketDispatcher:
 
 
 class RaggedDispatcher(BucketDispatcher):
-    """Ragged PACKED dispatch (ISSUE 9 tentpole): ONE warm executable
-    per request kind at the fixed shape (rows_per_batch, seq_len),
-    consuming the training-side packed representation {tokens,
-    segment_ids, annotations} (data/packing.py) instead of a
-    (bucket_len, batch_class) ladder.
+    """Ragged PACKED dispatch (ISSUE 9 tentpole): warm executables per
+    request kind at the shapes (row class, seq_len), consuming the
+    training-side packed representation {tokens, segment_ids,
+    annotations} (data/packing.py) instead of a (bucket_len,
+    batch_class) ladder.
+
+    Row classes (ISSUE 25): `rows_per_batch` is the LARGEST batch, not
+    the only one. `batch_classes` is the short ladder R, R/2, R/4, R/8
+    (`default_row_classes`: whole numbers the mesh's data*fsdp extent
+    divides, at most four), and `PackedBatchScheduler` runs an
+    under-full batch at the class that fits its open rows instead of
+    padding every dispatch to R rows. Nothing in the packed programs
+    depends on the row count: the local track is linear in rows and
+    attention is per row.
 
     Requests are packed at BUCKET-QUANTIZED spans: a request's span is
     its `bucket_len` (same ladder as the bucketed dispatcher), its
@@ -1046,14 +1075,14 @@ class RaggedDispatcher(BucketDispatcher):
 
     Unlike the bucketed ladder, the bucket set here costs NO
     executables — it is purely a span-quantization rule (the compiled
-    shape is always (rows_per_batch, seq_len)), so a deployment that
-    prefers density over bucketed-parity can run a much denser ladder
-    for free (docs/serving.md, ragged batching).
+    width is always seq_len), so a deployment that prefers density over
+    bucketed-parity can run a much denser ladder for free
+    (docs/serving.md, ragged batching).
 
-    Executable count: O(request kinds) + one shared packed trunk for
-    predict_task + per-head-structure tails, versus the bucketed
-    |buckets| x |classes| x kinds zoo — tracked by the same
-    `serve_executable_count` gauge.
+    Executable count: O(request kinds x at most 4 row classes) + the
+    shared packed trunk for predict_task at each class + per-head-
+    structure tails, versus the bucketed |buckets| x |classes| x kinds
+    zoo — tracked by the same `serve_executable_count` gauge.
     """
 
     def __init__(
@@ -1083,12 +1112,17 @@ class RaggedDispatcher(BucketDispatcher):
         # Mesh support (ISSUE 11 satellite, PR 8 residual): packed rows
         # shard over the joint ('data','fsdp') batch axis exactly like
         # bucketed micro-batches (serve_batch_sharding — segment_ids
-        # shard like the tokens they annotate). The single batch class
-        # (rows_per_batch,) must split evenly across the replicas; the
-        # parent ctor enforces that and builds self._shardings.
+        # shard like the tokens they annotate). rows_per_batch must
+        # split evenly across the replicas (the parent ctor enforces it
+        # and builds self._shardings); a smaller class that does not is
+        # left out of the ladder.
+        divisor = 1
+        if mesh is not None:
+            divisor = mesh.shape.get("data", 1) * mesh.shape.get("fsdp", 1)
         super().__init__(params, cfg, buckets=buckets,
                          max_batch=rows_per_batch,
-                         batch_classes=(rows_per_batch,), mesh=mesh,
+                         batch_classes=default_row_classes(
+                             rows_per_batch, divisor), mesh=mesh,
                          metrics=metrics, quant=quant,
                          quant_parity_every=quant_parity_every)
         self.rows_per_batch = int(rows_per_batch)
@@ -1173,12 +1207,13 @@ class RaggedDispatcher(BucketDispatcher):
                                arm: str = "resident",
                                batch: Optional[int] = None,
                                ) -> InFlightBatch:
-        """Submit one packed batch through the kind's single warm
-        executable; the returned `InFlightBatch.finalize()` fans
+        """Submit one packed batch through the kind's warm executable
+        of its row class; the returned `InFlightBatch.finalize()` fans
         per-segment outputs back out after the host fetch (ISSUE 19).
 
-        tokens/segment_ids are (rows_per_batch, seq_len), annotations
-        (rows_per_batch, max_segments, A). `riders` carries one
+        tokens/segment_ids are (c, seq_len) with c one of
+        `batch_classes`, annotations (c, max_segments, A). `riders`
+        carries one
         (row, segment_index, start, span) per request, row-major, with
         segment_index 0-based; for `predict_task`, `heads` is the
         aligned per-rider LoadedHead list. Returns (per-rider outputs
@@ -1190,10 +1225,10 @@ class RaggedDispatcher(BucketDispatcher):
         if kind == NEIGHBORS_KIND:
             kind = "embed"  # identical device work, shared executable
         R, L = tokens.shape
-        if (R, L) != (self.rows_per_batch, self.cfg.data.seq_len):
+        if R not in self.batch_classes or L != self.cfg.data.seq_len:
             raise ValueError(
-                f"packed tokens shape {(R, L)} != the compiled "
-                f"({self.rows_per_batch}, {self.cfg.data.seq_len})")
+                f"packed tokens shape {(R, L)} is none of the compiled "
+                f"({self.batch_classes}, {self.cfg.data.seq_len})")
         if (kind == TASK_KIND) != (heads is not None):
             raise ValueError(
                 f"kind {kind!r} and "
@@ -1215,8 +1250,11 @@ class RaggedDispatcher(BucketDispatcher):
         parity_due = (arm == "resident"
                       and self._quant_batch_tick(timings))
 
+        # riders are row-major: the last one sits in the last real row
+        rows = riders[-1][0] + 1 if len(riders) else 0
         if heads is not None:
-            with tracing.span("serve.launch", batch=batch):
+            with tracing.span("serve.launch", batch=batch, rows=rows,
+                              cls=R):
                 trunk_out = self._packed_trunk_fn()(
                     run_params, tb, sb, ab, self.cfg.model)
             self._note_warm(("trunk", L, R))
@@ -1236,7 +1274,8 @@ class RaggedDispatcher(BucketDispatcher):
             fn = self._packed_fn(kind)
             tracing.note_program(fn.__name__, fn, (
                 run_params, tb, sb, ab, self.cfg.model))
-            with tracing.span("serve.launch", batch=batch):
+            with tracing.span("serve.launch", batch=batch, rows=rows,
+                              cls=R):
                 res = fn(run_params, tb, sb, ab, self.cfg.model)
             self._note_warm((kind, L, R))
 
@@ -1284,10 +1323,12 @@ class RaggedDispatcher(BucketDispatcher):
 
     # ------------------------------------------------------------- warmup
 
-    def _dummy_packed(self):
-        """One syntactically valid packed batch (a minimal-span segment
-        per row) — content is irrelevant to the compile."""
-        R, L = self.rows_per_batch, self.cfg.data.seq_len
+    def _dummy_packed(self, rows: Optional[int] = None):
+        """One syntactically valid packed batch of `rows` rows (default
+        rows_per_batch; a minimal-span segment per row) — content is
+        irrelevant to the compile."""
+        R = self.rows_per_batch if rows is None else rows
+        L = self.cfg.data.seq_len
         span = self.buckets[0]
         tokens = np.full((R, L), PAD_ID, np.int32)
         tokens[:, 0] = SOS_ID
@@ -1300,22 +1341,24 @@ class RaggedDispatcher(BucketDispatcher):
         return tokens, seg, ann, riders
 
     def warm_candidate(self) -> float:
-        """Pre-run the candidate arm over the warm PACKED executables —
-        same zero-new-compiles contract as the bucketed override (the
-        packed fns are shape-keyed too). Returns wall seconds."""
+        """Pre-run the candidate arm over the warm PACKED executables,
+        each on a dummy of its own row class — same zero-new-compiles
+        contract as the bucketed override (the packed fns are
+        shape-keyed too). Returns wall seconds."""
         with self._warm_lock:
-            keys = sorted(self._warm)
+            keys = sorted(self._warm, key=lambda k: (k[2], k[0]))
         run_params, _ = self._arm_snapshot("candidate")
-        tokens, seg, ann, _riders = self._dummy_packed()
-        tb, sb, ab = self._place_packed(tokens, seg, ann)
         t0 = time.perf_counter()
         self._warming = True
         try:
-            for kind, _L, _R in keys:
-                fn = (self._packed_trunk_fn() if kind == "trunk"
-                      else self._packed_fn(kind))
-                jax.block_until_ready(
-                    fn(run_params, tb, sb, ab, self.cfg.model))
+            for cls, group in itertools.groupby(keys, key=lambda k: k[2]):
+                tokens, seg, ann, _riders = self._dummy_packed(cls)
+                placed = self._place_packed(tokens, seg, ann)
+                for kind, _L, _cls in group:
+                    fn = (self._packed_trunk_fn() if kind == "trunk"
+                          else self._packed_fn(kind))
+                    jax.block_until_ready(
+                        fn(run_params, *placed, self.cfg.model))
         finally:
             self._warming = False
         return time.perf_counter() - t0
@@ -1339,34 +1382,39 @@ class RaggedDispatcher(BucketDispatcher):
         return outs
 
     def warmup(self, kinds: Sequence[str] = ("embed",)) -> int:
-        """Pre-compile the ONE packed executable per kind (plus the
-        shared packed trunk + per-head tails when heads are in play);
-        returns how many were warmed. Compare with the bucketed
-        dispatcher's |kinds| x |buckets| x |classes| — this is the
-        executable-zoo collapse the `serve_executable_count` gauge
-        measures."""
+        """Pre-compile the packed executable of every row class for
+        every kind (plus the shared packed trunk + per-head tails when
+        heads are in play); returns how many were warmed. Compare with
+        the bucketed dispatcher's |kinds| x |buckets| x |classes| —
+        this is the executable-zoo collapse the
+        `serve_executable_count` gauge measures. Largest class first:
+        the first call under a program's name is the one
+        `tracing.note_program` keeps, and the full batch is the shape a
+        saturated server runs."""
         t_warm = time.perf_counter()
         n = 0
         kinds = tuple(kinds)
-        R, L = self.rows_per_batch, self.cfg.data.seq_len
-        tokens, seg, ann, riders = self._dummy_packed()
+        L = self.cfg.data.seq_len
+        for kind in kinds:
+            if kind != TASK_KIND and kind not in KINDS:
+                raise ValueError(f"unknown request kind {kind!r}; "
+                                 f"have {KINDS + (TASK_KIND,)}")
         self._warming = True
         try:
-            for kind in kinds:
-                if kind == TASK_KIND:
+            for cls in reversed(self.batch_classes):
+                with self._warm_lock:
+                    cold = [k for k in kinds if k != TASK_KIND
+                            and (k, L, cls) not in self._warm]
+                if not cold:
                     continue
-                if kind not in KINDS:
-                    raise ValueError(f"unknown request kind {kind!r}; "
-                                     f"have {KINDS + (TASK_KIND,)}")
-                if (kind, L, R) in self._warm:
-                    continue
-                if self._compile_hist is not None:
+                tokens, seg, ann, riders = self._dummy_packed(cls)
+                for kind in cold:
                     t0 = time.perf_counter()
                     self.run_packed(kind, tokens, seg, ann, riders)
-                    self._compile_hist.observe(time.perf_counter() - t0)
-                else:
-                    self.run_packed(kind, tokens, seg, ann, riders)
-                n += 1
+                    if self._compile_hist is not None:
+                        self._compile_hist.observe(
+                            time.perf_counter() - t0)
+                    n += 1
             if TASK_KIND in kinds or self.heads:
                 n += self._warmup_task()
         finally:
@@ -1375,59 +1423,58 @@ class RaggedDispatcher(BucketDispatcher):
         return n
 
     def _warmup_task(self) -> int:
-        """Warm the shared PACKED trunk (once — one shape total) and
-        every registered head's packed tail; returns new trunk
-        executables (0 or 1)."""
+        """Warm the shared PACKED trunk at every row class and every
+        registered head's packed tail on each; returns new trunk
+        executables."""
         report = self.warmup_report
         with self._heads_lock:
             heads = list(self.heads.values())
-        R, L = self.rows_per_batch, self.cfg.data.seq_len
-        tokens, seg, ann, _ = self._dummy_packed()
-        tb, sb, ab = self._place_packed(tokens, seg, ann)
-        with self._warm_lock:
-            new = ("trunk", L, R) not in self._warm
-        t0 = time.perf_counter()
-        trunk_out = self._packed_trunk_fn()(self._run_params(), tb, sb,
-                                            ab, self.cfg.model)
-        jax.block_until_ready(trunk_out)
-        dt = time.perf_counter() - t0
+        L = self.cfg.data.seq_len
         n = 0
-        if new:
-            self._note_warm(("trunk", L, R))
-            report["trunk_executables"] += 1
-            report["trunk_s"] = round(report["trunk_s"] + dt, 6)
-            if self._compile_hist is not None:
-                self._compile_hist.observe(dt)
-            n = 1
-        for head in heads:
+        for cls in reversed(self.batch_classes):
+            tokens, seg, ann, _ = self._dummy_packed(cls)
+            tb, sb, ab = self._place_packed(tokens, seg, ann)
+            with self._warm_lock:
+                new = ("trunk", L, cls) not in self._warm
             t0 = time.perf_counter()
-            jax.block_until_ready(heads_apply.packed_head_batch(
-                head.params, trunk_out["local"], trunk_out["global"],
-                trunk_out["seg_mask"], head.task.kind))
-            report["heads"][head.head_id] = round(
-                report["heads"].get(head.head_id, 0.0)
-                + time.perf_counter() - t0, 6)
+            trunk_out = self._packed_trunk_fn()(
+                self._run_params(), tb, sb, ab, self.cfg.model)
+            jax.block_until_ready(trunk_out)
+            dt = time.perf_counter() - t0
+            if new:
+                self._note_warm(("trunk", L, cls))
+                report["trunk_executables"] += 1
+                report["trunk_s"] = round(report["trunk_s"] + dt, 6)
+                if self._compile_hist is not None:
+                    self._compile_hist.observe(dt)
+                n += 1
+            for head in heads:
+                t0 = time.perf_counter()
+                jax.block_until_ready(heads_apply.packed_head_batch(
+                    head.params, trunk_out["local"], trunk_out["global"],
+                    trunk_out["seg_mask"], head.task.kind))
+                report["heads"][head.head_id] = round(
+                    report["heads"].get(head.head_id, 0.0)
+                    + time.perf_counter() - t0, 6)
         return n
 
     def warm_head(self, head: LoadedHead) -> float:
-        """Compile one head's PACKED tail against the (single) packed
+        """Compile one head's PACKED tail against every warm packed
         trunk shape on zero dummies — no trunk execution, the same
         control-plane/data-plane separation as the bucketed
         `warm_head`. The trunk never compiles here."""
         with self._warm_lock:
-            has_trunk = any(k[0] == "trunk" for k in self._warm)
-        if not has_trunk:
-            self.warmup_report["heads"][head.head_id] = 0.0
-            return 0.0
+            classes = sorted(k[2] for k in self._warm if k[0] == "trunk")
         dtype = jnp.dtype(self.cfg.model.dtype)
-        R, L, S = (self.rows_per_batch, self.cfg.data.seq_len,
-                   self.max_segments)
-        local = jnp.zeros((R, L, self.cfg.model.local_dim), dtype)
-        global_ = jnp.zeros((R, S, self.cfg.model.global_dim), dtype)
-        seg_mask = jnp.zeros((R, S, L), bool)
-        t0 = time.perf_counter()
-        jax.block_until_ready(heads_apply.packed_head_batch(
-            head.params, local, global_, seg_mask, head.task.kind))
-        total = time.perf_counter() - t0
+        L, S = self.cfg.data.seq_len, self.max_segments
+        total = 0.0
+        for cls in classes:
+            local = jnp.zeros((cls, L, self.cfg.model.local_dim), dtype)
+            global_ = jnp.zeros((cls, S, self.cfg.model.global_dim), dtype)
+            seg_mask = jnp.zeros((cls, S, L), bool)
+            t0 = time.perf_counter()
+            jax.block_until_ready(heads_apply.packed_head_batch(
+                head.params, local, global_, seg_mask, head.task.kind))
+            total += time.perf_counter() - t0
         self.warmup_report["heads"][head.head_id] = round(total, 6)
         return total

@@ -34,8 +34,10 @@ The batch path is on the span spine (obs/tracing): every dispatched
 batch takes a sequence number, and its spans carry it as `batch=` —
 on this thread `serve.ingest` (requests taken from the queue and placed,
 tagged with the batch then forming; `n=` how many), `serve.assemble`
-(pop to grids), `serve.wait_slot`, then the dispatcher's `serve.place`
-and `serve.launch`; on the completer `serve.retire` around the
+(pop to grids; a packed batch's carries `rows=` popped and `cls=` the
+row class run), `serve.wait_slot`, then the dispatcher's `serve.place`
+and `serve.launch` (`rows=`, `cls=` again); on the completer
+`serve.retire` around the
 dispatcher's `serve.fetch` / `serve.fan_out` and this module's
 `serve.seal` (the per-rider loop). Riders' `RequestTrace`s and the
 `serve_batch` event carry the same number. The spans' own durations are
@@ -158,6 +160,11 @@ class MicroBatchScheduler:
         self._next_batch = 1
         self.rows_total = 0                  # guarded-by: _pending_lock
         self.expired_total = 0               # guarded-by: _pending_lock
+        # {batch class: batches run}, and the positions those batches
+        # computed (class x batch length each): what the device was
+        # asked for, padding rows and all.
+        self.batch_class_counts: Dict[int, int] = {}  # guarded-by: _pending_lock
+        self.batched_positions = 0           # guarded-by: _pending_lock
         self._occupancy_g = self.tele.metrics.gauge("serve_batch_occupancy")
         self._rows_h = self.tele.metrics.histogram("serve_batch_rows")
         self._batch_h = self.tele.metrics.histogram("serve_batch_seconds")
@@ -214,6 +221,21 @@ class MicroBatchScheduler:
         with self._pending_lock:
             return (self.batches_total, self.rows_total,
                     self.expired_total)
+
+    def class_counts(self) -> Tuple[Dict[int, int], int]:
+        """(batches run by batch class, positions they computed), read
+        under the lock `stats_counts()` reads under."""
+        with self._pending_lock:
+            return dict(self.batch_class_counts), self.batched_positions
+
+    def _count_batch(self, rows: int, cls: int, length: int) -> None:
+        """One batch answered: its requests, its class, its positions."""
+        with self._pending_lock:
+            self.batches_total += 1
+            self.rows_total += rows
+            self.batch_class_counts[cls] = \
+                self.batch_class_counts.get(cls, 0) + 1
+            self.batched_positions += cls * length
 
     def _ingest(self, now: float) -> None:
         items = self.queue.pop_all()
@@ -439,9 +461,7 @@ class MicroBatchScheduler:
                         prep_s=ctx.get("prep_s"),
                         device_s=ctx.get("device_s"), batch=seq)
                 self._on_complete(req, outcome, self.clock(), err, ctx)
-        with self._pending_lock:
-            self.batches_total += 1
-            self.rows_total += len(batch)
+        self._count_batch(len(batch), cls, bucket_len)
         self._occupancy_g.set(len(batch) / cls)
         self._rows_h.observe(len(batch))
         # Quant fields ride only when the arm set them: the documented
@@ -652,10 +672,11 @@ class PackedBatchScheduler(MicroBatchScheduler):
     each request into an open packed row for its KIND via the same
     first-fit residual-capacity rule as `data/packing.PackPlanner`
     (`data/packing.OnlinePacker`), at the request's bucket-quantized
-    span. One dispatch runs `rows_per_batch` rows through the kind's
-    single fixed-shape executable (`serve/dispatch.RaggedDispatcher`)
-    — so every length mix shares one compiled shape, and a batch
-    carries up to rows_per_batch x max_segments requests.
+    span. One dispatch runs at most `rows_per_batch` rows through the
+    kind's executable of one ROW CLASS
+    (`serve/dispatch.RaggedDispatcher.batch_classes`: R, R/2, R/4,
+    R/8) — so every length mix shares the few compiled shapes, and a
+    batch carries up to rows_per_batch x max_segments requests.
 
     Dispatch policy (the same two-knob contract as the bucketed
     scheduler, per KIND):
@@ -665,10 +686,18 @@ class PackedBatchScheduler(MicroBatchScheduler):
       extra row is the open frontier, so the popped rows have already
       been topped off by first-fit);
     - otherwise a kind dispatches when the oldest request in ANY of
-      its open rows has waited `max_wait_s` (latency bound), padding
-      the executable's row count with empty rows;
-    - when the queue is closed (drain), remaining rows flush oldest
-      kind first.
+      its open rows has waited `max_wait_s` (latency bound), or when
+      the queue is closed (drain, oldest kind first): with n open rows
+      it runs the LARGEST class c <= n on the c oldest rows and leaves
+      the newer rows open for the next dispatch (`row_class`). Only
+      under the smallest class is a batch padded with empty rows.
+
+    Rounding DOWN is what keeps the class a function of the offered
+    load and not of the past: a batch never runs padding rows except
+    under the smallest class, so a backlog always drains at the
+    device's full rate and the class decays to what the arrivals need.
+    Rounding up has a fixed point at every class (the arrivals of one
+    long batch round up to the same long batch again).
 
     Deadlines: expiry sweeps open rows every poll (an expired request
     is REMOVED from its row — its span stays dead space, costing
@@ -709,6 +738,10 @@ class PackedBatchScheduler(MicroBatchScheduler):
 
         self._packer_cls = OnlinePacker
         self.rows_per_batch = int(rows_per_batch)
+        # The dispatcher's warm row classes, ascending; one that knows
+        # of none (a stub) runs every batch at rows_per_batch.
+        self.row_classes = tuple(sorted(getattr(
+            dispatcher, "batch_classes", (self.rows_per_batch,))))
         self.max_segments = int(max_segments)
         self.seq_len = int(dispatcher.cfg.data.seq_len)
         # kind -> OnlinePacker of open rows (payloads are Requests).
@@ -723,6 +756,12 @@ class PackedBatchScheduler(MicroBatchScheduler):
         class's per-request semantics — not physical packed rows)."""
         with self._pending_lock:
             return sum(p.total_items() for p in self._packers.values())
+
+    def row_class(self, open_rows: int) -> int:
+        """The class a dispatch of `open_rows` open rows runs at: the
+        largest that the rows fill, else the smallest."""
+        return max((c for c in self.row_classes if c <= open_rows),
+                   default=self.row_classes[0])
 
     def _ingest(self, now: float) -> None:
         items = self.queue.pop_all()
@@ -796,16 +835,20 @@ class PackedBatchScheduler(MicroBatchScheduler):
 
     def _dispatch(self, key, now: float) -> int:
         kind = key
-        R, L, S = self.rows_per_batch, self.seq_len, self.max_segments
+        L, S = self.seq_len, self.max_segments
         seq = self._next_batch
-        with span("serve.assemble", batch=seq):
+        with span("serve.assemble", batch=seq) as assembling:
             with self._pending_lock:
                 packer = self._packers.get(kind)
                 if packer is None or len(packer) == 0:  # raced fail_pending
                     return 0
+                # More than R open rows pop R (the largest class); fewer
+                # pop the largest class they fill, or all of them.
+                R = self.row_class(len(packer))
                 rows = packer.pop_rows(R)
                 if len(packer) == 0:
                     del self._packers[kind]
+            assembling.ids.update(rows=len(rows), cls=R)
             num_ann = self.dispatcher.cfg.model.num_annotations
             tokens = np.zeros((R, L), np.int32)
             segment_ids = np.zeros((R, L), np.int32)
@@ -838,7 +881,7 @@ class PackedBatchScheduler(MicroBatchScheduler):
             heads = ([req.head for req in batch]
                      if batch[0].head is not None else None)
             n_riders = len(riders)
-            ctx = {"rows": R, "batch_class": R, "bucket_len": L,
+            ctx = {"rows": len(rows), "batch_class": R, "bucket_len": L,
                    "segments": n_riders,
                    "segments_per_row": round(n_riders / R, 4),
                    "mode": "ragged", "batch": seq}
@@ -880,7 +923,8 @@ class PackedBatchScheduler(MicroBatchScheduler):
         riders = entry["riders"]
         ctx, run0 = entry["ctx"], entry["run0"]
         kind, n_riders = entry["kind"], entry["n_riders"]
-        R, L, S = self.rows_per_batch, self.seq_len, self.max_segments
+        L, S = self.seq_len, self.max_segments
+        R, n_rows = ctx["batch_class"], ctx["rows"]
         seq = entry["seq"]
         try:
             outs, timings = entry["handle"].finalize()
@@ -895,7 +939,7 @@ class PackedBatchScheduler(MicroBatchScheduler):
                 if req.trace is not None:
                     req.trace.mark_run(run0, fail_t)
                     req.trace.mark_batch(
-                        width, R, R,
+                        width, R, n_rows,
                         pad_fraction=ctx.get("pad_fraction"),
                         segments=n_riders,
                         segments_per_row=ctx["segments_per_row"],
@@ -926,7 +970,7 @@ class PackedBatchScheduler(MicroBatchScheduler):
                 if req.trace is not None:
                     req.trace.mark_run(run0, run1)
                     req.trace.mark_batch(
-                        width, R, R,
+                        width, R, n_rows,
                         pad_fraction=ctx.get("pad_fraction"),
                         prep_s=ctx.get("prep_s"),
                         device_s=ctx.get("device_s"),
@@ -934,9 +978,7 @@ class PackedBatchScheduler(MicroBatchScheduler):
                         segments_per_row=ctx["segments_per_row"],
                         mode="ragged", batch=seq)
                 self._on_complete(req, outcome, self.clock(), err, ctx)
-        with self._pending_lock:
-            self.batches_total += 1
-            self.rows_total += n_riders
+        self._count_batch(n_riders, R, L)
         # Occupancy for a packed grid is token occupancy (1 - pad
         # fraction) when the batch was timed, else segment-slot fill.
         pad = ctx.get("pad_fraction")
@@ -946,7 +988,7 @@ class PackedBatchScheduler(MicroBatchScheduler):
         quant_fields = {k: ctx[k] for k in ("quant", "quant_parity_max")
                         if ctx.get(k) is not None}
         self.tele.emit("serve_batch", kind=kind, bucket_len=L,
-                       rows=R, batch_class=R,
+                       rows=n_rows, batch_class=R,
                        batch_seconds=round(dt, 6),
                        pad_fraction=pad,
                        segments=n_riders,

@@ -186,11 +186,12 @@ class Server:
             # Ragged packed serving (ISSUE 9): heterogeneous requests
             # PACK into fixed-shape (max_batch, seq_len) rows at their
             # bucket-quantized spans — one warm executable per request
-            # kind, outputs matching the bucketed dispatcher's within
-            # the documented jitted tolerance (docs/serving.md).
-            # `max_batch` means packed ROWS per executable here; a
-            # batch carries up to max_batch * pack_max_segments
-            # requests.
+            # kind and row class, outputs matching the bucketed
+            # dispatcher's within the documented jitted tolerance
+            # (docs/serving.md). `max_batch` means the packed ROWS of
+            # the LARGEST batch here (it carries up to max_batch *
+            # pack_max_segments requests); an under-full batch runs at
+            # the row class that fits it: max_batch, /2, /4, /8.
             if partition_heads:
                 raise ValueError(
                     "partition_heads is a bucketed-mode baseline knob; "
@@ -198,8 +199,9 @@ class Server:
                     "trunk by construction")
             if batch_classes is not None:
                 raise ValueError(
-                    "batch_classes is meaningless in ragged mode — the "
-                    "executable shape is fixed at (max_batch, seq_len)")
+                    "batch_classes is a bucketed-mode knob — the ragged "
+                    "row classes are derived from max_batch "
+                    "(dispatch.default_row_classes)")
             self.dispatcher = RaggedDispatcher(
                 params, cfg, buckets=buckets, rows_per_batch=max_batch,
                 max_segments=pack_max_segments, mesh=mesh,
@@ -615,10 +617,12 @@ class Server:
             [seq], self.cfg.data.seq_len, on_overflow="count")[0]
         if self.serve_mode == "ragged":
             # One real rider in row 0 of an otherwise-dummy packed
-            # grid; the other rows compute but fan out to nobody.
+            # grid of the smallest row class; the other rows compute
+            # but fan out to nobody.
             from proteinbert_tpu.data.vocab import PAD_ID
 
-            tok, seg, ann, _ = self.dispatcher._dummy_packed()
+            tok, seg, ann, _ = self.dispatcher._dummy_packed(
+                self.dispatcher.batch_classes[0])
             tok[0, :] = PAD_ID
             tok[0, :bucket_len] = tokens[:bucket_len]
             seg[0, :] = 0
@@ -1098,6 +1102,7 @@ class Server:
         # lock-discipline rule), so an unlocked field read here could
         # see a torn batches/rows pair mid-dispatch.
         batches, rows, expired = self.scheduler.stats_counts()
+        class_counts, positions = self.scheduler.class_counts()
         out = {
             "completed": self.completed_total,
             **mirrors,
@@ -1134,6 +1139,13 @@ class Server:
             "heads": len(self.dispatcher.heads),
             "batches": batches,
             "batched_rows": rows,
+            # Batches run by batch class (ragged: row class) and the
+            # positions they computed, class x length each: the share
+            # of batches under the largest class is how often a smaller
+            # executable was enough, and real residues over
+            # batched_positions is the fill the device really ran at.
+            "batch_class_counts": class_counts,
+            "batched_positions": positions,
             "queue_depth": len(self.queue),
             "evicted": self.queue.evicted_total,
             "expired": expired,

@@ -11,12 +11,17 @@ Two tiers, mirroring tests/test_serve.py:
   matches the bucketed dispatcher's on identical traffic within the
   documented jitted ≤1e-5 tolerance (PR 7 split-parity precedent;
   bucket-quantized spans make the two programs compute the same math —
-  serve/dispatch.RaggedDispatcher module doc) — plus the O(kinds)
-  executable-count collapse, packed telemetry fields round-tripping the
-  schema validator, `pbt diagnose --serve` surfacing, and the
-  fused-kernel fallback counter satellite.
+  serve/dispatch.RaggedDispatcher module doc) — plus the
+  O(kinds x row classes) executable-count collapse, packed telemetry
+  fields round-tripping the schema validator, `pbt diagnose --serve`
+  surfacing, and the fused-kernel fallback counter satellite.
+- **row classes** (ISSUE 25): the class an under-full dispatch runs at,
+  the rows it leaves open, its decay from a backlog (no hysteresis), and
+  on the real dispatcher the same answer at every class, every class
+  warm after `warmup()`, and classes a mesh cannot split left out.
 """
 
+import dataclasses
 import logging
 import threading
 import time
@@ -36,10 +41,12 @@ from proteinbert_tpu.configs import (
 from proteinbert_tpu.data.vocab import ALPHABET
 from proteinbert_tpu.heads.registry import LoadedHead
 from proteinbert_tpu.models import finetune as ft_model
+from proteinbert_tpu.obs import tracing
 from proteinbert_tpu.serve import (
     DeadlineExceededError, PackedBatchScheduler, RaggedDispatcher,
     Request, RequestQueue, Server, ServerClosedError,
 )
+from proteinbert_tpu.serve.dispatch import default_row_classes
 from proteinbert_tpu.train import create_train_state
 
 SEQ_LEN = 48
@@ -87,12 +94,17 @@ class FakeClock:
 class StubRaggedDispatcher:
     """Records packed batches; returns one token-of-proof per rider."""
 
-    def __init__(self, seq_len=SEQ_LEN, num_ann=4):
+    def __init__(self, seq_len=SEQ_LEN, num_ann=4, batch_classes=None,
+                 device=None):
         self.cfg = SimpleNamespace(
             data=SimpleNamespace(seq_len=seq_len),
             model=SimpleNamespace(num_annotations=num_ann))
         self.calls = []
         self.fail_with = None
+        if batch_classes is not None:   # else: a stub that knows of none
+            self.batch_classes = tuple(batch_classes)
+        # (fake clock, seconds a row): the device a batch keeps busy
+        self.device = device
 
     def run_packed(self, kind, tokens, segment_ids, annotations, riders,
                    heads=None):
@@ -102,6 +114,9 @@ class StubRaggedDispatcher:
             "kind": kind, "tokens": tokens.copy(),
             "segment_ids": segment_ids.copy(),
             "riders": [tuple(r) for r in riders]})
+        if self.device is not None:
+            clock, row_seconds = self.device
+            clock.advance(tokens.shape[0] * row_seconds)
         return [("ok", kind) + tuple(r) for r in riders]
 
     def run_packed_timed(self, kind, tokens, segment_ids, annotations,
@@ -129,8 +144,8 @@ def _req(kind="embed", seq="MKT", span=16, clock=None, deadline=None):
 
 
 def _sched(dispatcher=None, rows=2, max_wait=0.01, clock=None,
-           max_segments=4, **kw):
-    q = RequestQueue(max_depth=64)
+           max_segments=4, depth=64, **kw):
+    q = RequestQueue(max_depth=depth)
     done = []
 
     def finalize(req, row):  # the Server's _finalize resolves futures
@@ -263,6 +278,240 @@ class TestPackedFormation:
         assert run() == run()
 
 
+# --------------------------------------------------------- row classes
+
+CLASSES = (2, 4, 8, 16)
+
+
+def _row_sched(clock, max_wait=0.01):
+    """R = 16 over the ladder (2, 4, 8, 16); `_row` requests fill a row
+    each, so open rows are requests."""
+    disp = StubRaggedDispatcher(batch_classes=CLASSES)
+    q, sched, done = _sched(disp, rows=16, max_wait=max_wait, clock=clock,
+                            depth=4096)
+    return disp, q, sched, done
+
+
+def _row(clock, seq="r"):
+    return _req(seq=seq, span=SEQ_LEN, clock=clock)
+
+
+class TestRowClasses:
+    """ISSUE 25: an under-full packed batch runs at the largest class
+    its open rows fill, the newer rows stay open, and only under the
+    smallest class is a batch padded."""
+
+    @pytest.mark.parametrize("rows,multiple,want", [
+        (512, 1, (64, 128, 256, 512)),
+        (16, 1, (2, 4, 8, 16)),
+        (4, 1, (1, 2, 4)),
+        (6, 1, (3, 6)),
+        (3, 1, (3,)),
+        (1, 1, (1,)),
+        (8, 2, (2, 4, 8)),
+        (12, 4, (12,)),
+        (512, 4, (64, 128, 256, 512)),
+    ])
+    def test_the_derived_ladder(self, rows, multiple, want):
+        assert default_row_classes(rows, multiple) == want
+
+    @pytest.mark.parametrize("n,cls,left", [
+        (1, 2, 0),        # under the smallest class: run it, padded
+        (2, 2, 0),        # at a class
+        (3, 2, 1),        # between: round DOWN, the newest stays open
+        (4, 4, 0),
+        (7, 4, 3),
+        (8, 8, 0),
+        (15, 8, 7),
+        (16, 16, 0),      # the largest class, full
+    ])
+    def test_class_chosen_for_open_rows(self, n, cls, left):
+        clock = FakeClock()
+        disp, q, sched, done = _row_sched(clock)
+        reqs = [_row(clock, seq=str(i)) for i in range(n)]
+        for r in reqs:
+            q.push(r)
+        assert sched.poll(clock()) == 0       # nothing overdue yet
+        clock.advance(0.02)
+        popped = min(n, cls)
+        assert sched.poll(clock()) == popped
+        (call,) = disp.calls
+        assert call["tokens"].shape == (cls, SEQ_LEN)
+        # the OLDEST rows ride, in order; the newer ones stay open
+        assert [r[0] for r in call["riders"]] == list(range(popped))
+        assert all(r.future.done() for r in reqs[:popped])
+        assert not any(r.future.done() for r in reqs[popped:])
+        assert sched.pending_rows() == left
+        # rows past the popped ones are padding: all zeros
+        assert not call["tokens"][popped:].any()
+        assert sched.class_counts() == ({cls: 1}, cls * SEQ_LEN)
+
+    @pytest.mark.parametrize("n", [17, 32, 49])
+    def test_more_than_R_open_rows_always_runs_R(self, n):
+        clock = FakeClock()
+        disp, q, sched, done = _row_sched(clock, max_wait=60.0)
+        for i in range(n):
+            q.push(_row(clock, seq=str(i)))
+        left = n
+        while left > 16:                      # no wait: throughput bound
+            assert sched.poll(clock()) == 16
+            assert disp.calls[-1]["tokens"].shape == (16, SEQ_LEN)
+            left -= 16
+        assert sched.pending_rows() == left
+        assert sched.poll(clock()) == 0       # at most R and not overdue
+
+    def test_a_stub_without_classes_runs_rows_per_batch(self):
+        clock = FakeClock()
+        disp = StubRaggedDispatcher()
+        q, sched, done = _sched(disp, rows=4, clock=clock)
+        assert sched.row_classes == (4,)
+        q.push(_row(clock))
+        clock.advance(0.02)
+        assert sched.poll(clock()) == 1
+        assert disp.calls[0]["tokens"].shape == (4, SEQ_LEN)
+
+    def test_drain_flushes_by_classes(self):
+        clock = FakeClock()
+        disp, q, sched, done = _row_sched(clock, max_wait=60.0)
+        for i in range(13):
+            q.push(_row(clock, seq=str(i)))
+        q.close()
+        ran = []
+        while sched.poll(clock()):
+            ran.append(disp.calls[-1]["tokens"].shape[0])
+        assert ran == [8, 4, 2]               # 13 = 8 + 4 + 1 (padded to 2)
+        assert len(done) == 13
+        assert sched.class_counts() == ({8: 1, 4: 1, 2: 1}, 14 * SEQ_LEN)
+
+    @staticmethod
+    def _simulate(backlog, every_ms, ms=1500.0, max_wait_ms=20.0,
+                  other_kind_at_ms=None):
+        """R = 64 over (8, 16, 32, 64) on a serial device that takes
+        1 ms a row, padding rows too (so full batches drain one row a
+        ms), a row arriving every `every_ms`, `backlog` rows open at the
+        start; at `other_kind_at_ms` one `logits` request arrives among
+        the embeds. Returns [(kind, class run, rows popped, when
+        dispatched)]."""
+        clock = FakeClock(0.0)
+        disp = StubRaggedDispatcher(batch_classes=(8, 16, 32, 64),
+                                    device=(clock, 1e-3))
+        q, sched, done = _sched(disp, rows=64, clock=clock, depth=4096,
+                                max_wait=max_wait_ms * 1e-3)
+        for i in range(backlog):
+            q.push(_row(clock, seq=f"b{i}"))
+        ran, k = [], 0
+        while clock() < ms * 1e-3:
+            while k * every_ms * 1e-3 <= clock():     # what has arrived
+                r = _row(clock, seq=str(k))
+                r.enqueued_at = k * every_ms * 1e-3
+                q.push(r)
+                k += 1
+            if other_kind_at_ms is not None \
+                    and clock() >= other_kind_at_ms * 1e-3:
+                q.push(_req(kind="logits", span=SEQ_LEN, clock=clock))
+                other_kind_at_ms = None
+            at = clock()
+            popped = sched.poll(at)                   # the device's time
+            if popped:                                # passes inside it
+                call = disp.calls[-1]
+                ran.append((call["kind"], call["tokens"].shape[0],
+                            popped, at))
+            else:
+                clock.advance(0.25e-3)
+        return ran
+
+    def test_no_hysteresis_from_a_full_backlog(self):
+        """Arrivals at 0.8 x the rate a full batch drains: from a
+        64-row backlog the class decays to the class an empty start
+        settles at, and stays."""
+        cold = [c for _, c, _, _ in self._simulate(backlog=0, every_ms=1.25)]
+        hot = [c for _, c, _, _ in self._simulate(backlog=64, every_ms=1.25)]
+        assert hot[0] == 64 and cold[0] == 16
+        assert set(cold) == {16}              # 20 ms of arrivals: 16 rows
+        settled = hot.index(16)
+        assert 0 < settled < 12
+        # down through the classes, never back up
+        assert all(a >= b for a, b in zip(hot, hot[1:]))
+        assert sorted(set(hot), reverse=True) == [64, 32, 16]
+        assert hot[-40:] == cold[-40:] == [16] * 40
+
+    def test_overload_climbs_to_full_batches_by_itself(self):
+        """Arrivals at 1.1 x the rate the device drains: no class keeps
+        up, the open rows grow by a tenth a batch, the class climbs with
+        them, and once more than R are open every batch is R rows. No
+        batch on the way runs a padding row."""
+        ran = self._simulate(backlog=0, every_ms=1 / 1.1, ms=2500.0)
+        classes = [c for _, c, _, _ in ran]
+        assert all(n == c for _, c, n, _ in ran)      # never padded
+        assert all(a <= b for a, b in zip(classes, classes[1:]))
+        assert sorted(set(classes)) == [16, 32, 64]
+        assert classes[-10:] == [64] * 10
+
+    def test_a_minority_kind_is_not_held_by_a_busy_one(self):
+        """One `logits` request among embeds arriving at 0.8 x the
+        device's rate rides within max_wait and the batch then on the
+        device, alone in the smallest class: another kind's load never
+        holds it, nor pads it to that kind's batch."""
+        ran = self._simulate(backlog=0, every_ms=1.25, ms=600.0,
+                             other_kind_at_ms=300.0)
+        ((kind, cls, popped, at),) = [r for r in ran if r[0] != "embed"]
+        assert (kind, cls, popped) == ("logits", 8, 1)
+        assert 0.300 + 0.020 <= at <= 0.300 + 0.020 + 0.016 + 1e-3
+        # ... and the embeds go on at their class on both sides of it
+        assert {c for k, c, _, _ in ran if k == "embed"} == {16}
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_bursts_run_no_padding_above_the_smallest_class(self, seed):
+        """Rows arriving in bursts of any size (a stalled client's
+        catch-up): every batch is filled by the rows it pops, except one
+        under the smallest class."""
+        rng = np.random.default_rng(seed)
+        clock = FakeClock()
+        disp, q, sched, done = _row_sched(clock)
+        pushed = 0
+        for _ in range(60):
+            for _ in range(int(rng.integers(0, 40))):
+                q.push(_row(clock))
+                pushed += 1
+            clock.advance(0.0125)
+            while sched.poll(clock()):
+                pass
+        clock.advance(0.0125)
+        while sched.poll(clock()):
+            pass
+        assert len(done) == pushed
+        for call in disp.calls:
+            cls = call["tokens"].shape[0]
+            assert len(call["riders"]) == cls or cls == CLASSES[0]
+        classes, positions = sched.class_counts()
+        assert sum(classes.values()) == len(disp.calls)
+        assert positions == SEQ_LEN * sum(c * n for c, n in classes.items())
+
+    def test_spans_and_events_carry_rows_and_class(self):
+        clock = FakeClock()
+        seen = []
+
+        from proteinbert_tpu.obs import Telemetry
+
+        class Capture(Telemetry):
+            def emit(self, event, **fields):
+                seen.append((event, fields))
+
+        disp = StubRaggedDispatcher(batch_classes=CLASSES)
+        q, sched, done = _sched(disp, rows=16, clock=clock, depth=4096,
+                                telemetry=Capture(metrics=False))
+        for i in range(5):
+            q.push(_row(clock, seq=str(i)))
+        clock.advance(0.02)
+        assert sched.poll(clock()) == 4
+        (batch,) = [f for e, f in seen if e == "serve_batch"]
+        assert (batch["rows"], batch["batch_class"]) == (4, 4)
+        clock.advance(0.02)
+        assert sched.poll(clock()) == 1
+        batch = [f for e, f in seen if e == "serve_batch"][-1]
+        assert (batch["rows"], batch["batch_class"]) == (1, 2)
+
+
 # ------------------------------------------------------- end to end
 
 def _drain_poll(srv, futs):
@@ -297,8 +546,12 @@ class TestRaggedParity:
                                        atol=1e-5, rtol=1e-5)
             np.testing.assert_allclose(x["local_mean"], y["local_mean"],
                                        atol=1e-5, rtol=1e-5)
-        # O(kinds): one packed executable for the one kind served.
-        assert rs["executables"] == 1
+        # O(kinds x row classes): the one kind served, at the classes
+        # of max_batch=4 that were run, and never a shape per bucket.
+        assert set(rs["batch_class_counts"]) <= {1, 2, 4}
+        assert rs["executables"] == len(rs["batch_class_counts"]) <= 3
+        assert rs["batched_positions"] == SEQ_LEN * sum(
+            c * n for c, n in rs["batch_class_counts"].items())
         assert bs["executables"] > rs["executables"]
         assert rs["serve_mode"] == "ragged"
 
@@ -332,8 +585,9 @@ class TestRaggedParity:
         for i, (x, y) in enumerate(zip(b, r)):
             assert x.shape == y.shape, i
             np.testing.assert_allclose(x, y, atol=1e-5, rtol=1e-5)
-        # One shared packed trunk; tails don't count as trunk shapes.
-        assert rs["executables"] == 1
+        # One shared packed trunk a row class run; tails don't count
+        # as trunk shapes.
+        assert rs["executables"] == len(rs["batch_class_counts"]) <= 3
 
     def test_ragged_cache_short_circuits(self, trunk):
         params, cfg = trunk
@@ -405,7 +659,7 @@ class TestRaggedTelemetry:
         for b in batches:
             assert b["mode"] == "ragged"
             assert b["bucket_len"] == SEQ_LEN
-            assert b["rows"] == 2
+            assert 1 <= b["rows"] <= b["batch_class"] <= 2
             assert 1 <= b["segments"] <= 2 * 8
             assert 0.0 <= b["pad_fraction"] <= 1.0
         reqs = [r for r in recs if r["event"] == "serve_request"]
@@ -422,10 +676,11 @@ class TestRaggedTelemetry:
         assert summary["batches"]["modes"] == {"ragged": len(batches)}
         assert summary["batches"]["segments"] == len(seqs)
         assert summary["batches"]["mean_segments_per_row"] > 0
-        assert summary["executables"]["count"] == 1
+        # one warm kind x the row classes (1, 2) of max_batch=2
+        assert summary["executables"]["count"] == 2
         assert summary["executables"]["serve_mode"] == "ragged"
         text = render_serve(summary)
-        assert "packed:" in text and "executables: 1 warm" in text
+        assert "packed:" in text and "executables: 2 warm" in text
         # pad_wasted attribution (the ragged lever) present
         assert any("pad_wasted" in k
                    for k in summary["stage_attribution"])
@@ -440,9 +695,10 @@ class TestRaggedTelemetry:
                      serve_mode="ragged", telemetry=tele)
         srv.start()
         m = tele.metrics
-        assert m.gauge("serve_executable_count").value == 2  # O(kinds)
+        # O(kinds x row classes): 2 kinds x the classes (1, 2)
+        assert m.gauge("serve_executable_count").value == 4
         assert m.gauge("serve_warmup_seconds_total").value > 0
-        assert srv.stats()["executables"] == 2
+        assert srv.stats()["executables"] == 4
         srv.drain(timeout=10)
 
 
@@ -582,7 +838,7 @@ class TestFusedPathCounter:
         before = dict(op.ONEPASS_PATH_TOTAL)
         fb_before = dict(fb.PATH_TOTAL)
         attn_before = dict(ka.ATTN_PATH_TOTAL)
-        assert disp.warmup(("embed",)) == 1
+        assert disp.warmup(("embed",)) == 2      # row classes (1, 2)
         delta = {k: op.ONEPASS_PATH_TOTAL.get(k, 0) - before.get(k, 0)
                  for k in op.ONEPASS_PATH_TOTAL}
         assert delta.get(("pallas", "packed"), 0) >= 1
@@ -615,7 +871,10 @@ class TestRaggedMesh:
                                        atol=1e-5, rtol=1e-5)
             np.testing.assert_allclose(x["local_mean"], y["local_mean"],
                                        atol=1e-5, rtol=1e-5)
-        assert rs["executables"] == 1  # sharding adds no executables
+        # sharding adds no executables: the classes (2, 4) that two
+        # replicas split, as many of them as were run
+        assert set(rs["batch_class_counts"]) <= {2, 4}
+        assert rs["executables"] == len(rs["batch_class_counts"])
 
     def test_ragged_mesh_sharded_placement(self, trunk):
         from proteinbert_tpu.parallel import mesh_for_devices
@@ -687,9 +946,198 @@ class TestRaggedDispatcherContracts:
         with pytest.raises(ValueError, match="partition_heads"):
             Server(params, cfg, serve_mode="ragged",
                    partition_heads=True)
+        # the ragged row classes are derived from max_batch, not passed
         with pytest.raises(ValueError, match="batch_classes"):
             Server(params, cfg, serve_mode="ragged",
                    batch_classes=(2, 4))
+        srv = Server(params, cfg, serve_mode="ragged", max_batch=4,
+                     warm_kinds=())
+        assert srv.dispatcher.batch_classes == (1, 2, 4)
+        assert srv.scheduler.row_classes == (1, 2, 4)
+        srv.drain(timeout=10)
+
+
+@pytest.fixture
+def session(tmp_path):
+    """A live CPU profiler session: what switches the recording of
+    `jax.compile` spans on (tests/test_tracing.py)."""
+    tracing.recorder().clear()
+    jax.profiler.start_trace(str(tmp_path / "profile"))
+    yield tracing.recorder()
+    jax.profiler.stop_trace()
+    tracing.recorder().clear()
+
+
+def _compiles(recorder) -> int:
+    return sum(s["name"] == "jax.compile" for s in recorder.spans())
+
+
+@pytest.fixture(scope="module")
+def ladder(trunk):
+    params, cfg = trunk
+    return RaggedDispatcher(params, cfg, rows_per_batch=8, max_segments=4)
+
+
+def _alone(disp, cls, seq):
+    """`seq` alone in row 0 of an otherwise empty batch of class `cls`."""
+    span = disp.bucket_len(len(seq))
+    tokens = inference._tokenize_masked([seq], SEQ_LEN,
+                                        on_overflow="count")[0]
+    tok = np.zeros((cls, SEQ_LEN), np.int32)
+    seg = np.zeros((cls, SEQ_LEN), np.int32)
+    ann = np.zeros((cls, 4, MODEL.num_annotations), np.float32)
+    tok[0, :span] = tokens[:span]
+    seg[0, :span] = 1
+    return tok, seg, ann, [(0, 0, 0, span)]
+
+
+class TestRowClassesOnTheDispatcher:
+    """ISSUE 25 on the real dispatcher at tiny widths."""
+
+    def test_the_ladder_of_eight_rows(self, ladder):
+        assert ladder.batch_classes == (1, 2, 4, 8)
+
+    @pytest.mark.parametrize("kind", ["embed", "predict_go",
+                                      "predict_residues"])
+    @pytest.mark.parametrize("cls", [1, 2, 4])
+    def test_one_request_alone_reads_the_same_in_every_class(
+            self, ladder, seqs, kind, cls):
+        seq = max(seqs, key=len)
+        (full,) = ladder.run_packed(kind, *_alone(ladder, 8, seq))
+        (got,) = ladder.run_packed(kind, *_alone(ladder, cls, seq))
+        for x, y in zip(jax.tree.leaves(full), jax.tree.leaves(got)):
+            assert x.shape == y.shape
+            np.testing.assert_allclose(x, y, atol=1e-5, rtol=1e-5)
+
+    def test_a_shape_outside_the_ladder_is_refused(self, ladder, seqs):
+        with pytest.raises(ValueError, match="none of the compiled"):
+            ladder.run_packed("embed", *_alone(ladder, 3, seqs[0]))
+
+    def test_warmup_warms_every_class_and_nothing_compiles_after(
+            self, trunk, seqs, session):
+        params, cfg = trunk
+        # a sequence length of its own: nothing here is in jit's cache
+        cfg = dataclasses.replace(cfg, data=dataclasses.replace(
+            cfg.data, seq_len=40, buckets=(16, 40)))
+        disp = RaggedDispatcher(params, cfg, rows_per_batch=4,
+                                max_segments=4)
+        with tracing.span("arms.the.listener"):
+            start = _compiles(session)
+        assert disp.warmup(("embed",)) == 3       # classes (1, 2, 4)
+        warm = _compiles(session)
+        assert warm >= start + 3
+        assert disp.executable_count == 3
+        assert {k[2] for k in disp._warm} == {1, 2, 4}
+        assert disp.warmup(("embed",)) == 0       # all warm already
+        for cls in disp.batch_classes:
+            tokens, seg, ann, riders = disp._dummy_packed(cls)
+            outs = disp.run_packed("embed", tokens, seg, ann, riders)
+            assert len(outs) == cls
+        assert _compiles(session) == warm
+
+    def test_the_packed_trunk_and_tails_warm_at_every_class(self, trunk):
+        params, cfg = trunk
+        disp = RaggedDispatcher(params, cfg, rows_per_batch=4,
+                                max_segments=4)
+        task = TaskConfig(kind="sequence_classification", num_outputs=3)
+        head = LoadedHead("hw", "hw", task, ft_model.head_init(
+            jax.random.PRNGKey(3), MODEL, task), {})
+        disp.add_head(head)
+        assert disp.warmup(("predict_task",)) == 3
+        assert disp.trunk_executable_count == 3
+        assert disp.warmup_report["trunk_executables"] == 3
+        assert disp.warmup_report["heads"]["hw"] > 0
+        # a hot-added head's tail compiles against every warm class,
+        # and never the trunk
+        other = LoadedHead("hx", "hx", task, ft_model.head_init(
+            jax.random.PRNGKey(4), MODEL, task), {})
+        assert disp.add_head(other, warm=True) > 0
+        assert disp.trunk_executable_count == 3
+
+    def test_the_candidate_arm_is_warmed_at_every_warm_class(
+            self, trunk, monkeypatch):
+        params, cfg = trunk
+        disp = RaggedDispatcher(params, cfg, rows_per_batch=4,
+                                max_segments=4)
+        disp.warmup(("embed", "predict_go"))
+        disp.load_candidate(jax.tree.map(lambda x: x * 1.01, params))
+        seen = []
+        real = disp._packed_fn
+
+        def spy(kind, quantized=None):
+            fn = real(kind, quantized)
+
+            def run(p, tb, sb, ab, m):
+                seen.append((kind, tb.shape[0], ab.shape[0]))
+                return fn(p, tb, sb, ab, m)
+
+            return run
+
+        monkeypatch.setattr(disp, "_packed_fn", spy)
+        assert disp.warm_candidate() > 0
+        assert sorted(seen) == sorted(
+            (k, c, c) for k in ("embed", "predict_go") for c in (1, 2, 4))
+
+    def test_classes_a_mesh_cannot_split_are_left_out(self, trunk):
+        from proteinbert_tpu.parallel import mesh_for_devices
+
+        params, cfg = trunk
+        disp = RaggedDispatcher(params, cfg, rows_per_batch=4,
+                                mesh=mesh_for_devices(2))
+        assert disp.batch_classes == (2, 4)       # 1 dropped, no error
+        disp = RaggedDispatcher(params, cfg, rows_per_batch=6,
+                                mesh=mesh_for_devices(2))
+        assert disp.batch_classes == (6,)         # 3 is odd
+        # rows_per_batch itself has to split, as before
+        with pytest.raises(ValueError, match="not divisible"):
+            RaggedDispatcher(params, cfg, rows_per_batch=3,
+                             mesh=mesh_for_devices(2))
+
+    def test_a_shadow_request_rides_the_smallest_class(self, trunk, seqs):
+        params, cfg = trunk
+        srv = Server(params, cfg, max_batch=4, max_wait_s=60.0,
+                     cache_size=0, warm_kinds=("embed",),
+                     serve_mode="ragged")
+        srv.dispatcher.load_candidate(params)
+        srv.dispatcher.warm_candidate()
+        shapes = []
+        real = srv.dispatcher.run_packed_candidate
+
+        def spy(kind, tokens, *a, **kw):
+            shapes.append(tokens.shape)
+            return real(kind, tokens, *a, **kw)
+
+        srv.dispatcher.run_packed_candidate = spy
+        got = srv.shadow_submit("embed", seqs[0])
+        assert shapes == [(1, SEQ_LEN)]
+        fut = srv.submit("embed", seqs[0])
+        (live,) = _drain_poll(srv, [fut])
+        np.testing.assert_allclose(got["global"], live["global"],
+                                   atol=1e-5, rtol=1e-5)
+        srv.drain(timeout=10)
+
+
+def test_stats_read_the_share_of_small_batches_and_the_real_fill(
+        trunk, seqs):
+    """`Server.stats()`: `batch_class_counts` gives the share of batches
+    run under the largest class, and real residues over
+    `batched_positions` the fill the device really ran at."""
+    params, cfg = trunk
+    srv = Server(params, cfg, max_batch=4, max_wait_s=60.0, cache_size=0,
+                 warm_kinds=("embed",), serve_mode="ragged")
+    before = srv.stats()
+    assert before["batch_class_counts"] == {}
+    assert before["batched_positions"] == 0
+    # one short request: one row, the smallest class
+    _drain_poll(srv, [srv.submit("embed", seqs[0])])
+    stats = srv.stats()
+    assert stats["batch_class_counts"] == {1: 1}
+    assert stats["batched_positions"] == SEQ_LEN
+    below = sum(n for c, n in stats["batch_class_counts"].items()
+                if c < srv.dispatcher.rows_per_batch)
+    assert below / stats["batches"] == 1.0
+    assert 0 < len(seqs[0]) / stats["batched_positions"] <= 1
+    srv.drain(timeout=10)
 
 
 class TestNeighborsRideOnePass:

@@ -415,6 +415,10 @@ def test_one_ragged_batch_emits_the_eight_spans_under_one_batch(session):
         assert len(named[name]) == 1, (name, len(named.get(name, ())))
     (batch,) = {s["ids"]["batch"] for n in SERVE_SPANS for s in named[n]}
     assert named["serve.ingest"][0]["ids"]["n"] == 3
+    # three short requests share one packed row: the smallest row class
+    for name in ("serve.assemble", "serve.launch"):
+        ids = named[name][0]["ids"]
+        assert (ids["rows"], ids["cls"]) == (1, 1), name
     order = [named[n][0] for n in SERVE_SPANS]
     assert all(a["end_ns"] <= b["start_ns"] for a, b in zip(order, order[1:]))
     retire = named["serve.retire"][0]

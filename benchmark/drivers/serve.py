@@ -16,7 +16,7 @@ import time
 
 import numpy as np
 
-from benchmark import compare, flops, traffic
+from benchmark import compare, flops, readers, span_readers, traffic
 from benchmark.program import model_sizes, program_config
 from benchmark.reference import proteinbert_f32 as ref
 from benchmark.device import memory_peak_bytes
@@ -128,6 +128,27 @@ def _capture_telemetry():
 
 
 def run(run, devices):
+    """One run of the cell: the window, then the comparison of a sample
+    of its answers with the plain reference."""
+    out, sample = measure(run, devices)
+    t_ref = time.perf_counter()
+    reference = reference_answers(
+        run.seed, sample["seqs"], sample["ladder"], model_sizes(run.config),
+        rows=run.workload["reference_rows"],
+        bf16_operands=run.config["dtype"] == "bfloat16")
+    print(f"reference: {len(reference)} answers in "
+          f"{time.perf_counter() - t_ref:.1f} s")
+    gaps = compare.embedding_checks(sample["served"], reference)
+    out["checks"] = [(name, gaps[name], run.workload["limits"][name])
+                     for name in sorted(gaps)]
+    return out
+
+
+def measure(run, devices):
+    """Set-up and the measured window, with the server closed and its
+    state freed on return: (the run's result without its checks, the
+    sample of the window's answers the reference is held against). The
+    knee sweep (`benchmark.find_knee`) stops here."""
     import jax
 
     from proteinbert_tpu.models import proteinbert
@@ -138,8 +159,7 @@ def run(run, devices):
     m = model_sizes(run.config)
     ladder = list(cfg.data.buckets)
     opts = dict(wl["server"])
-    rows, segments, seq_len = (opts["max_batch"], opts["pack_max_segments"],
-                               cfg.data.seq_len)
+    segments, seq_len = opts["pack_max_segments"], cfg.data.seq_len
 
     n_blocks = traffic.blocks_for(mix, run.seconds)
     seqs, lengths = traffic.sequences(mix, n_blocks, run.seed)
@@ -153,6 +173,7 @@ def run(run, devices):
                     telemetry=tele, trace_sample_rate=1.0 if run.trace else None,
                     **opts)
     server.start()
+    ladder_rows = sorted(int(c) for c in server.dispatcher.batch_classes)
     try:
         booted = server.stats()["batched_rows"]
         for f in [server.submit("embed", s) for s in warm_seqs]:
@@ -202,6 +223,7 @@ def run(run, devices):
     e2e = {}
     if wl["judged"] == "latency":
         failed = int(n - ok.sum())
+        # the tail of ALL the requests due in the window, stalls and all
         e2e["embed_latency_p95_ms"] = float(np.percentile(latency, 95) * 1e3)
     else:
         failed = int(errors)
@@ -215,15 +237,8 @@ def run(run, devices):
     pick = set(rng.choice(pool, min(SAMPLE - 1, len(pool)), replace=False).tolist())
     pick.add(int(pool[np.argmax(lengths_n[pool])]))
     pick = sorted(pick)
-    served = [load.futures[i].result() for i in pick]
-    del server, params
-    t_ref = time.perf_counter()
-    reference = reference_answers(
-        run.seed, [seqs[i] for i in pick], ladder, m, rows=wl["reference_rows"],
-        bf16_operands=run.config["dtype"] == "bfloat16")
-    print(f"reference: {len(pick)} answers in {time.perf_counter() - t_ref:.1f} s")
-    gaps = compare.embedding_checks(served, reference)
-    checks = [(name, gaps[name], wl["limits"][name]) for name in sorted(gaps)]
+    sample = {"seqs": [seqs[i] for i in pick], "ladder": ladder,
+              "served": [load.futures[i].result() for i in pick]}
 
     # The batches the window counted, and the residues they answered:
     # a batch is counted with its riders, and riders are answered in the
@@ -232,28 +247,90 @@ def run(run, devices):
     riders = after["batched_rows"] - before["batched_rows"]
     first = sorted(load.done, key=load.done.get)[:riders]
     residues_in_batches = int(sum(lengths_n[i] for i in first if ok[i]))
+    # Batches by row class and the positions they really computed: the
+    # difference of the same two reads.
+    class_counts = {
+        int(c): int(k) - int(before["batch_class_counts"].get(c, 0))
+        for c, k in after["batch_class_counts"].items()}
+    class_counts = {c: k for c, k in sorted(class_counts.items()) if k}
+    obs = {
+        "program": "_packed_encode_batch",
+        "batches": batches,
+        "residues_in_batches": residues_in_batches,
+        "batch_class_counts": class_counts,
+        "batched_positions": int(after["batched_positions"]
+                                 - before["batched_positions"]),
+        "requests_in_window": int(in_window.sum()),
+        "residues_in_window": int(lengths_n[in_window].sum()),
+        "latency_s": latency,
+        "due_s": due_n,
+        "seconds": run.seconds,
+        "late_s": np.asarray(load.sent) - due_n[:len(load.sent)],
+        "stages": stages,
+        # What one batch of each row class needs, from shapes alone,
+        # and what it takes to compile that class's executable again
+        # for its scope map (after the window, like `program_scopes`).
+        "classes": {
+            cls: {"flops": flops.forward_flops(m, cls, seq_len, segments,
+                                               heads=False),
+                  "min_bytes": flops.embed_min_bytes(m, cls, seq_len,
+                                                     segments)}
+            for cls in ladder_rows},
+        "class_program": lambda cls: cell_program(wl, run.config, rows=cls),
+    }
     _print_pace(np.sort(done_at[in_window]), load, due_n, run.seconds)
+    _print_window(n, obs, run)
     return {
         "e2e": e2e,
         "attempted": n,
         "failed": failed,
-        "checks": checks,
+        "checks": [],
         "memory_peak_bytes": int(memory_peak),
-        "obs": {
-            "program": "_packed_encode_batch",
-            "batches": batches,
-            "residues_in_batches": residues_in_batches,
-            "requests_in_window": int(in_window.sum()),
-            "residues_in_window": int(lengths_n[in_window].sum()),
-            "positions_per_batch": rows * seq_len,
-            "latency_s": latency,
-            "late_s": np.asarray(load.sent) - due_n[:len(load.sent)],
-            "stages": stages,
-            "call_flops": flops.forward_flops(m, rows, seq_len, segments,
-                                              heads=False),
-            "call_min_bytes": flops.embed_min_bytes(m, rows, seq_len, segments),
-        },
-    }
+        "obs": obs,
+    }, sample
+
+
+def _print_window(attempted, obs, run):
+    """The lines a reader's number can be checked against by hand: what
+    was left when the window closed (a backlog that grows is past the
+    knee), the batches by row class as `Server.stats()` counted them
+    with the real fill, and, traced, the same classes as the trace has
+    them (they may differ by the window's edges only) with each one's
+    mean device time."""
+    counts, positions = obs["batch_class_counts"], obs["batched_positions"]
+    fill = 100.0 * obs["residues_in_batches"] / positions if positions else 0.0
+    print(f"window: {attempted} requests due, {obs['requests_in_window']} "
+          f"answered inside it, {attempted - obs['requests_in_window']} left "
+          f"at close; batches by row class (Server.stats) {counts}, "
+          f"{obs['residues_in_batches']} residues in {positions} positions "
+          f"= fill {fill:.2f} %")
+    growth = readers.backlog_growth_per_s(obs["latency_s"], obs["due_s"],
+                                          obs["seconds"])
+    print(f"backlog: growing by {growth:.1f} requests/s (median of the "
+          f"window's last quarter less the third's, over a quarter)")
+    per_interval = readers.interval_p95s_ms(obs["latency_s"], obs["due_s"],
+                                            obs["seconds"])
+    if len(per_interval):
+        print(f"p95 of each whole {readers.INTERVAL_S} s, ms: "
+              + " ".join(f"{v:.0f}" for v in per_interval)
+              + f"; median {np.median(per_interval):.2f}, whole window "
+              f"{readers.latency_p95_ms(obs):.2f}")
+    if run.trace_summary is None:
+        return
+    got = span_readers.class_runs(dict(obs, trace=run.trace_summary))
+    if got is None:
+        print("classes in the trace: not read (no device plane, no `cls=` on the launches, "
+              "or the join of runs to launches is not sound)")
+        return
+    by_class = {}
+    for cls, seconds in got:
+        by_class.setdefault(cls, []).append(seconds)
+    told = sum(len(t) for cls, t in by_class.items() if cls is not None)
+    print(f"classes in the trace: {told} of {len(got)} runs classified; "
+          + "; ".join(
+              f"{cls if cls is not None else 'unknown'} rows x {len(t)} runs, "
+              f"mean {1e3 * float(np.mean(t)):.3f} ms"
+              for cls, t in sorted(by_class.items(), key=lambda kv: kv[0] or 0)))
 
 
 def _print_pace(answered, load, due, seconds):
@@ -282,9 +359,10 @@ def _aborted(future) -> bool:
     return isinstance(future.exception(), ServerClosedError)
 
 
-def cell_program(workload: dict, config: dict):
+def cell_program(workload: dict, config: dict, rows=None):
     """(jitted function, abstract arguments, static keyword arguments) of
-    the program the window times, for `benchmark.rehearse`."""
+    the program the window times at its largest row class, or at `rows`:
+    for `benchmark.rehearse` and for the scope map of each class."""
     import jax
     import jax.numpy as jnp
 
@@ -292,7 +370,7 @@ def cell_program(workload: dict, config: dict):
     from proteinbert_tpu.models import proteinbert
 
     cfg = program_config(config, workload["overrides"])
-    rows = workload["server"]["max_batch"]
+    rows = rows or workload["server"]["max_batch"]
     segments = workload["server"]["pack_max_segments"]
     params = jax.eval_shape(
         lambda k: proteinbert.init(k, cfg.model), jax.random.PRNGKey(0))

@@ -1,2 +1,3 @@
-"""Kernels / XLA ops: roofline bound of one packed embed batch over its device time."""
-from benchmark.readers import program_roofline_pct as read  # noqa: F401
+"""Kernels / XLA ops: roofline bound of every packed embed batch of the traced
+window at its OWN row class, summed, over the device time of the same runs."""
+from benchmark.readers import packed_roofline_pct as read  # noqa: F401

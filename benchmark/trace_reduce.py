@@ -119,16 +119,29 @@ def program_name(event_name: str) -> str:
     return name[4:] if name.startswith("jit_") else name
 
 
+def executable_id(event_name: str) -> str:
+    """'jit_train_step(1234567)' -> '1234567': one id per compiled
+    executable, so two shapes of one jitted function are told apart."""
+    return event_name.partition("(")[2].partition(")")[0]
+
+
+def program_runs(plane: dict, program: str) -> list:
+    """[(start ns, end ns, executable id), ...] of the runs of one
+    program on this chip, in the order they ran."""
+    return sorted((s, s + d, executable_id(name))
+                  for name, s, d in _line(plane, MODULES_LINE)
+                  if program_name(name) == program)
+
+
 def program_gaps_s(plane: dict, program: str) -> np.ndarray:
     """Seconds the chip sat idle between one run of `program` and the
     next run of it: from the end of the busy time in between."""
-    runs = sorted((s, s + d) for name, s, d in _line(plane, MODULES_LINE)
-                  if program_name(name) == program)
+    runs = program_runs(plane, program)
     if len(runs) < 2:
         return np.zeros(0)
     busy = busy_intervals(plane)
     gaps = []
-    for (_, e0), (s1, _) in zip(runs, runs[1:]):
+    for (_, e0, _), (s1, _, _) in zip(runs, runs[1:]):
         inside = busy[(busy[:, 1] > e0) & (busy[:, 0] < s1)]
         covered = np.clip(inside[:, 1], e0, s1) - np.clip(inside[:, 0], e0, s1)
         gaps.append(max(0, (s1 - e0) - int(covered.sum())) * 1e-9)

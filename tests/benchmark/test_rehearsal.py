@@ -14,15 +14,19 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 CELLS = ["pretrain-base-dense", "serve-base-sat", "pretrain-large-dense",
          "serve-base-steady"]
+HELD_OUT = {"serve-base-steady"}     # benchmark/held_out/: files kept, not judged
 LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 
 
-def _manifest():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        return json.load(f)
+def _manifest(held_out=False):
+    from benchmark.run import load_manifest
+
+    return load_manifest(held_out)
 
 
 def _run(cell, *extra, seconds="1"):
+    if cell in HELD_OUT:
+        extra = (*extra, "--held-out")
     return subprocess.run(
         [sys.executable, "-m", "benchmark.run", "--workload", cell,
          "--seed", "3000000019", "--seconds", seconds, *extra],
@@ -41,7 +45,7 @@ def test_rehearsal_prints_the_contracts_line(cell, trace):
     assert line["attempted"] > 0
     assert line["device"]["platform"] == "cpu" and line["rehearsal"] is True
     assert {"kind", "count", "memory_peak_bytes"} <= set(line["device"])
-    manifest = _manifest()
+    manifest = _manifest(cell in HELD_OUT)
     if trace == "0":
         named = {m["name"] for m in manifest["end_to_end"]
                  if "workloads" not in m or cell in m["workloads"]}
@@ -80,8 +84,25 @@ def test_a_directory_with_only_the_benchmark_is_an_error(tmp_path):
     assert not any(ln.startswith("{") for ln in done.stdout.splitlines())
 
 
-def test_manifest_names_files_that_exist():
-    manifest = _manifest()
+def test_a_held_out_cell_runs_only_where_it_is_asked_for():
+    """`serve-base-steady` is out of BENCHMARK.json (PERF.md section 7);
+    its files stay, and `held_out/<cell>.json` holds the entries that
+    bring it back: the driver's command does not know the cell."""
+    names = {w["name"] for w in _manifest()["workloads"]}
+    assert not names & HELD_OUT
+    assert HELD_OUT <= {w["name"] for w in _manifest(True)["workloads"]}
+    done = subprocess.run(
+        [sys.executable, "-m", "benchmark.run", "--workload", "serve-base-steady",
+         "--seed", "1", "--seconds", "1", "--trace", "0", "--rehearse"],
+        cwd=ROOT, capture_output=True, text=True, timeout=300,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert done.returncode != 0 and "no workload named" in done.stderr
+    assert not any(ln.startswith("{") for ln in done.stdout.splitlines())
+
+
+@pytest.mark.parametrize("held_out", [False, True])
+def test_manifest_names_files_that_exist(held_out):
+    manifest = _manifest(held_out)
     for c in manifest["configs"]:
         assert os.path.exists(os.path.join(ROOT, c["file"]))
     for w in manifest["workloads"]:

@@ -183,7 +183,9 @@ def test_readers_return_nothing_where_there_is_nothing_to_read():
                    readers.device_idle_pct, readers.peak_hbm_gib,
                    readers.mfu_pct, readers.program_roofline_pct,
                    readers.queue_wait_ms, readers.batch_fill_pct,
-                   readers.latency_p95_ms, readers.generator_late_ms):
+                   readers.latency_p95_ms, readers.generator_late_ms,
+                   readers.serve_mfu_pct, readers.packed_roofline_pct,
+                   readers.stalled_seconds, readers.typical_p95_ms):
         assert reader(empty) is None
 
 
@@ -226,7 +228,7 @@ def test_leaf_dir_gaps_see_a_direction_the_norms_do_not():
 def test_batch_fill_counts_the_residues_of_the_batches_counted():
     from benchmark import readers
 
-    obs = {"batches": 4, "positions_per_batch": 1000, "residues_in_batches": 2600,
+    obs = {"batches": 4, "batched_positions": 4000, "residues_in_batches": 2600,
            "residues_in_window": 9999}
     assert readers.batch_fill_pct(obs) == pytest.approx(65.0)
 

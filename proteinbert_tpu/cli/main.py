@@ -206,7 +206,17 @@ def cmd_pretrain(args) -> int:
 
     cfg = _build_config(args)
 
-    if args.data is not None:
+    from proteinbert_tpu.configs import DecoderConfig
+
+    if isinstance(cfg.model, DecoderConfig):
+        if args.data is not None:
+            raise SystemExit(
+                "the decoder presets train on synthetic token documents: "
+                "--data reads the protein HDF5 layout only")
+        ds = _synthetic_documents(cfg, n_min=256)
+        log("pretraining the causal decoder on synthetic token documents "
+            "(a residue of this model is a token)")
+    elif args.data is not None:
         ds = HDF5PretrainingDataset(
             args.data, cfg.data.seq_len, crop_seed=cfg.train.seed + 1)
         n_ann = ds.num_annotations
@@ -346,8 +356,9 @@ def cmd_pretrain(args) -> int:
     perf = out["perf"]
     if perf:
         log(f"done: {perf.get('residues_per_sec_per_chip', 0):.0f} "
-            f"residues/s/chip, MFU {perf.get('mfu', 0):.3f} on "
-            f"{jax.device_count()}x {jax.devices()[0].device_kind}")
+            "residues/s/chip, "
+            + (f"MFU {perf['mfu']:.3f} " if "mfu" in perf else "")  # none: decoder
+            + f"on {jax.device_count()}x {jax.devices()[0].device_kind}")
     if args.history_json:
         with open(args.history_json, "w") as f:
             json.dump(out["history"], f, indent=2)
@@ -581,6 +592,22 @@ def _synthetic_dataset(cfg, n_min: int):
         max(4 * cfg.data.batch_size, n_min), rng,
         num_annotations=cfg.model.num_annotations)
     return InMemoryPretrainingDataset(seqs, ann, cfg.data.seq_len)
+
+
+def _synthetic_documents(cfg, n_min: int):
+    """Synthetic token documents for the decoder presets (ids uniform
+    over the vocabulary slice, lengths log-normal up to a row)."""
+    import numpy as np
+
+    from proteinbert_tpu.data.dataset import TokenDocumentDataset
+    from proteinbert_tpu.data.synthetic import make_random_documents
+
+    rng = np.random.default_rng(cfg.train.seed)
+    L = cfg.data.seq_len
+    docs = make_random_documents(
+        max(16 * cfg.data.batch_size, n_min), rng, cfg.model.vocab_size,
+        median=min(1200.0, L / 4), min_len=min(32, L), max_len=L)
+    return TokenDocumentDataset(docs, L)
 
 
 def _pretrain_run_config(pretrained: str, preset: str, overrides):
@@ -2008,7 +2035,8 @@ def build_parser() -> argparse.ArgumentParser:
 
     def add_train_args(sp, default_preset="base"):
         sp.add_argument("--preset", default=default_preset,
-                        choices=["tiny", "base", "long", "large"])
+                        choices=["tiny", "base", "long", "large",
+                                 "glm47flash_ep8"])
         sp.add_argument("--data", type=existing_file,
                         help="HDF5 dataset from create-h5 (default: synthetic)")
         sp.add_argument("--max-steps", type=int)

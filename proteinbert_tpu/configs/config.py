@@ -11,7 +11,7 @@ BASELINE.json.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Optional, Tuple, Union
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,6 +66,63 @@ class ModelConfig:
         # reference modules.py:119: value_dim = global_dim // num_heads
         assert self.global_dim % self.num_heads == 0
         return self.global_dim // self.num_heads
+
+
+@dataclasses.dataclass(frozen=True)
+class DecoderConfig:
+    """Architecture of the causal expert decoder (`models/glm_moe.py`;
+    GLM-4.7-Flash, `model_type: glm4_moe_lite`): latent attention, one
+    leading dense layer then expert layers (routed + one shared expert,
+    sigmoid router balanced by a bias that no gradient trains), and one
+    multi-token-prediction module. Key names follow the published
+    config.json; the defaults are its values.
+
+    Three fields describe THIS CHIP'S SHARE of an expert-parallel group
+    rather than the model: `experts_held` of the `n_routed_experts` the
+    router scores (ids `expert_offset` ..), and `vocab_size` rows of the
+    embedding and the head (ids are drawn from the slice). The router
+    keeps its published width; what the absent experts would add is
+    left out (models/glm_moe.py)."""
+
+    vocab_size: int = 154_880
+    hidden_size: int = 2048
+    num_hidden_layers: int = 47         # leading dense layers included
+    first_k_dense_replace: int = 1
+    intermediate_size: int = 10_240     # the dense layers' SwiGLU width
+    moe_intermediate_size: int = 1536   # each expert's SwiGLU width
+    n_routed_experts: int = 64          # the router's width
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 4
+    routed_scaling_factor: float = 1.8
+    norm_topk_prob: bool = True
+    experts_held: int = 64              # routed experts this chip holds
+    expert_offset: int = 0              # id of the first one held
+    num_attention_heads: int = 20
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 192
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 256
+    rope_theta: float = 1e6
+    rms_norm_eps: float = 1e-5
+    num_nextn_predict_layers: int = 1
+    mtp_loss_weight: float = 0.3        # lambda (assumed; not in config.json)
+    bias_update_speed: float = 1e-3     # gamma (assumed)
+    init_std: float = 0.02              # (assumed)
+    dtype: str = "bfloat16"
+    param_dtype: str = "float32"
+    attention_block: int = 512          # queries (and keys, on a TPU: the flash
+                                        # kernel's tile) per block of attention
+    expert_block: int = 512             # rows per block of the grouped products
+    loss_chunk: int = 1024              # positions per chunk of the head loss
+
+    @property
+    def num_moe_layers(self) -> int:
+        return self.num_hidden_layers - self.first_k_dense_replace
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -367,7 +424,8 @@ class FinetuneConfig:
 
 @dataclasses.dataclass(frozen=True)
 class PretrainConfig:
-    model: ModelConfig = dataclasses.field(default_factory=ModelConfig)
+    model: Union[ModelConfig, DecoderConfig] = dataclasses.field(
+        default_factory=ModelConfig)
     data: DataConfig = dataclasses.field(default_factory=DataConfig)
     optimizer: OptimizerConfig = dataclasses.field(default_factory=OptimizerConfig)
     mesh: MeshConfig = dataclasses.field(default_factory=MeshConfig)
@@ -435,11 +493,49 @@ def _large() -> PretrainConfig:
     )
 
 
+def _glm47flash_ep8() -> PretrainConfig:
+    # GLM-4.7-Flash (huggingface.co/zai-org/GLM-4.7-Flash config.json) as
+    # ONE chip of an eight-chip expert-parallel layer group holds it:
+    # every width as published; 8 of the 64 routed experts and 19,360 of
+    # the 154,880 vocabulary rows; the dense layer, 4 expert layers and
+    # the prediction module (further layers lie on further chips as
+    # pipeline stages). 706.5 M parameters, 10.53 GiB of state here.
+    return PretrainConfig(
+        model=DecoderConfig(vocab_size=19_360, num_hidden_layers=5,
+                            experts_held=8),
+        data=DataConfig(seq_len=8192, batch_size=2, packing=True,
+                        pack_max_segments=16),
+        optimizer=OptimizerConfig(warmup_steps=10_000, total_steps=2_000_000),
+        train=TrainConfig(max_steps=2_000_000),
+    )
+
+
+def _glm_tiny() -> PretrainConfig:
+    # The decoder at CPU-test size: 2 dense-or-expert layers + the
+    # prediction module, 8 experts top-2 (all held), vocabulary 512.
+    return PretrainConfig(
+        model=DecoderConfig(
+            vocab_size=512, hidden_size=64, num_hidden_layers=2,
+            intermediate_size=128, moe_intermediate_size=32,
+            n_routed_experts=8, experts_held=8, num_experts_per_tok=2,
+            num_attention_heads=4, q_lora_rank=32, kv_lora_rank=24,
+            qk_nope_head_dim=12, qk_rope_head_dim=4, v_head_dim=16,
+            dtype="float32", attention_block=16, expert_block=8,
+            loss_chunk=32),
+        data=DataConfig(seq_len=64, batch_size=2, packing=True,
+                        pack_max_segments=4),
+        optimizer=OptimizerConfig(warmup_steps=50, total_steps=250),
+        train=TrainConfig(max_steps=250),
+    )
+
+
 PRESETS = {
     "tiny": _tiny,
     "base": _base,
     "long": _long,
     "large": _large,
+    "glm47flash_ep8": _glm47flash_ep8,
+    "glm_tiny": _glm_tiny,
 }
 
 
@@ -467,7 +563,10 @@ def _build(cls, data: dict):
             # default (f.type is a string under PEP 563 annotations).
             default = (f.default_factory() if f.default_factory
                        is not dataclasses.MISSING else f.default)
-            kwargs[f.name] = _build(type(default), v)
+            node = type(default)
+            if node is ModelConfig and "n_routed_experts" in v:
+                node = DecoderConfig    # `model` has two types: told by keys
+            kwargs[f.name] = _build(node, v)
         elif isinstance(v, list):
             kwargs[f.name] = tuple(v)  # configs must stay hashable
         else:

@@ -122,6 +122,39 @@ class InMemoryPretrainingDataset:
         return {"tokens": tokens, "annotations": self.annotations[idx]}
 
 
+class TokenDocumentDataset:
+    """Documents of token ids for the causal decoder (models/glm_moe.py):
+    no alphabet, no special tokens, no annotations. Every id of the
+    vocabulary is a real token, 0 included, so a row's length is kept
+    beside it rather than read off its padding. `get_batch` gives one
+    document a row, {"tokens", "segment_ids"} (n, seq_len) int32 with
+    segment 1 over the document and 0 past its end: what the dense
+    iterator feeds as it is, and what the packed iterator
+    (data/packing.py) packs into rows of several documents through the
+    same `PackPlanner` as proteins. A document longer than `seq_len`
+    keeps its head."""
+
+    def __init__(self, documents: Sequence[np.ndarray], seq_len: int):
+        self.seq_len = seq_len
+        self.lengths = np.array([min(len(d), seq_len) for d in documents],
+                                np.int64)
+        self.tokens = np.zeros((len(documents), seq_len), np.int32)
+        for i, (doc, n) in enumerate(zip(documents, self.lengths)):
+            self.tokens[i, :n] = np.asarray(doc[:n], np.int32)
+
+    def row_lengths(self) -> np.ndarray:
+        return self.lengths
+
+    def __len__(self) -> int:
+        return len(self.tokens)
+
+    def get_batch(self, idx: np.ndarray, epoch: int = 0) -> Dict[str, np.ndarray]:
+        idx = np.asarray(idx)
+        return {"tokens": self.tokens[idx],
+                "segment_ids": (np.arange(self.seq_len)[None, :]
+                                < self.lengths[idx][:, None]).astype(np.int32)}
+
+
 class HDF5PretrainingDataset:
     """Working lazy HDF5 reader (fixes reference data_processing.py:186-333).
 

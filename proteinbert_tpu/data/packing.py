@@ -231,6 +231,27 @@ def pack_rows(
             "annotations": annotations}
 
 
+def pack_token_rows(
+    fetched_tokens: np.ndarray,
+    fetched_lengths: np.ndarray,
+    groups: List[List[int]],
+    seq_len: int,
+) -> Dict[str, np.ndarray]:
+    """`pack_rows` for documents of token ids
+    (data/dataset.TokenDocumentDataset): a row's length comes with it
+    (id 0 is a real token), and the batch has no annotations."""
+    tokens = np.zeros((len(groups), seq_len), dtype=np.int32)
+    segment_ids = np.zeros((len(groups), seq_len), dtype=np.int32)
+    for i, group in enumerate(groups):
+        cursor = 0
+        for s, pos in enumerate(group):
+            n = min(int(fetched_lengths[pos]), seq_len - cursor)
+            tokens[i, cursor:cursor + n] = fetched_tokens[pos, :n]
+            segment_ids[i, cursor:cursor + n] = s + 1
+            cursor += n
+    return {"tokens": tokens, "segment_ids": segment_ids}
+
+
 def pad_fraction(tokens: np.ndarray) -> float:
     """Fraction of pad positions in a (B, L) token batch."""
     return float((tokens == PAD_ID).mean())
@@ -301,10 +322,16 @@ def make_packed_iterator(
             positions.append(list(range(pos, pos + len(g))))
             pos += len(g)
         data = fetch(np.asarray(flat, dtype=np.int64), epoch)
-        batch = pack_rows(data["tokens"], data["annotations"], positions,
-                          seq_len, max_segments)
+        if "annotations" in data:
+            batch = pack_rows(data["tokens"], data["annotations"], positions,
+                              seq_len, max_segments)
+        else:   # documents of token ids: the length is the segment's
+            batch = pack_token_rows(data["tokens"],
+                                    (data["segment_ids"] > 0).sum(axis=1),
+                                    positions, seq_len)
         if metrics is not None:
-            gauge.set(pad_fraction(batch["tokens"]))
+            gauge.set(pad_fraction(batch["tokens"]) if "annotations" in batch
+                      else float((batch["segment_ids"] == 0).mean()))
             counter_seg.inc(len(flat))
             counter_rows.inc(len(mine))
         return batch

@@ -157,3 +157,13 @@ def make_task_batches(
     from proteinbert_tpu.data.finetune_data import batch_task_data
 
     return batch_task_data(tokens, labels, batch_size)
+
+
+def make_random_documents(n: int, rng: np.random.Generator, vocab_size: int,
+                          median: float = 1200.0, sigma: float = 1.0,
+                          min_len: int = 32, max_len: int = 8192):
+    """`n` documents of uniform random token ids with log-normal lengths:
+    what `pbt pretrain` trains the causal decoder on when no data is given."""
+    lengths = np.clip(np.rint(median * np.exp(sigma * rng.standard_normal(n))),
+                      min_len, max_len).astype(np.int64)
+    return [rng.integers(0, vocab_size, k).astype(np.int32) for k in lengths]

@@ -32,6 +32,7 @@ are bias-free like the reference's raw `randn` parameter matrices
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Dict, Optional
 
 import jax
@@ -159,3 +160,83 @@ def packed_global_attention_apply(
                     jnp.zeros((), dtype))
     b, s, h, vd = out.shape
     return out.reshape(b, s, h * vd)
+
+
+# --------------------------------------- token-to-token attention (glm_moe)
+
+def causal_segment_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, segment_ids: jax.Array,
+    scale: float, block: int,
+) -> jax.Array:
+    """Causal softmax attention inside each segment of a packed row.
+
+    q, k: (B, L, H, d), v: (B, L, H, dv), segment_ids: (B, L). Position i
+    attends to j <= i with segment_ids[j] == segment_ids[i]; pad positions
+    (segment 0) see only each other, so no row of scores is ever empty.
+    Queries go in blocks of `block`, each against the keys up to its own
+    end (the keys after it are never multiplied), each block's scores in
+    float32 and recomputed in the backward pass rather than kept:
+    (B, H, block, L) at a time, never (B, H, L, L)."""
+    L = q.shape[1]
+    outs = []
+    for start in range(0, L, block):
+        end = min(start + block, L)
+        outs.append(_attention_block(
+            q[:, start:end], k[:, :end], v[:, :end],
+            segment_ids[:, start:end], segment_ids[:, :end], start, scale))
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+
+
+@partial(jax.checkpoint, static_argnums=(5, 6))
+def _attention_block(q, k, v, seg_q, seg_k, q_start, scale):
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                        preferred_element_type=jnp.float32) * scale
+    qi = q_start + jnp.arange(q.shape[1])
+    ki = jnp.arange(k.shape[1])
+    mask = ((ki[None, :] <= qi[:, None])[None]
+            & (seg_q[:, :, None] == seg_k[:, None, :]))
+    scores = jnp.where(mask[:, None], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
+
+
+def flash_tiles_fit(seq_len: int, block: int, head_dim: int,
+                    v_head_dim: int) -> bool:
+    """Whether the shipped flash kernel's tiles take these sizes."""
+    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+
+    b = min(block, seq_len)
+    return not (b % fa.MIN_BLOCK_SIZE or seq_len % b
+                or head_dim % 128 or v_head_dim % 128)
+
+
+def flash_segment_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, segment_ids: jax.Array,
+    scale: float, block: int,
+) -> jax.Array:
+    """`causal_segment_attention` by the Pallas TPU flash-attention kernel
+    that ships with jax (`jax.experimental.pallas.ops.tpu.flash_attention`,
+    forward and both backward kernels): online softmax over blocks of
+    keys in float32, nothing of size L x L ever in HBM. Same arguments
+    and result; `block` is the tile of queries and of keys. Sizes the
+    tiles do not take are an error that names them, never another path."""
+    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+
+    L = q.shape[1]
+    if not flash_tiles_fit(L, block, q.shape[-1], v.shape[-1]):
+        raise ValueError(
+            f"flash attention takes rows of a multiple of the block (itself a "
+            f"multiple of {fa.MIN_BLOCK_SIZE}) and head sizes that are "
+            f"multiples of 128; got rows of {L}, block {block}, heads of "
+            f"{q.shape[-1]} / {v.shape[-1]}")
+    b = min(block, L)
+    sizes = fa.BlockSizes(
+        block_q=b, block_k_major=b, block_k=b, block_b=1,
+        block_q_major_dkv=b, block_k_major_dkv=b, block_k_dkv=b, block_q_dkv=b,
+        block_k_major_dq=b, block_k_dq=b, block_q_dq=b)
+    heads_first = lambda a: a.transpose(0, 2, 1, 3)  # noqa: E731
+    out = fa.flash_attention(
+        heads_first(q), heads_first(k), heads_first(v),
+        segment_ids=fa.SegmentIds(q=segment_ids, kv=segment_ids),
+        causal=True, sm_scale=scale, block_sizes=sizes)
+    return heads_first(out)

@@ -109,3 +109,45 @@ def embedding_apply(params: Params, ids: jax.Array, dtype: Optional[jnp.dtype] =
     if dtype is not None:
         table = table.astype(dtype)
     return jnp.take(table, ids, axis=0)
+
+
+# ------------------------------------------------- decoder layers (glm_moe)
+
+def rms_norm_apply(scale: jax.Array, x: jax.Array, eps: float = 1e-5) -> jax.Array:
+    """RMSNorm over the last axis, statistics in float32."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (y * scale).astype(x.dtype)
+
+
+def segment_positions(segment_ids: jax.Array) -> jax.Array:
+    """(B, L) position of every token inside its own segment: the count
+    restarts wherever the segment id changes (packed rows, data/packing.py)."""
+    idx = jnp.arange(segment_ids.shape[-1], dtype=jnp.int32)
+    starts = jnp.concatenate(
+        [jnp.ones_like(segment_ids[..., :1], bool),
+         segment_ids[..., 1:] != segment_ids[..., :-1]], axis=-1)
+    return idx - lax.cummax(jnp.where(starts, idx, 0), axis=segment_ids.ndim - 1)
+
+
+def rotary_apply(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """Rotary position embedding over ALL of the last axis, half-split
+    pairing (dimension j turns with dimension j + d/2). x: (B, L, ..., d),
+    positions: (B, L). Angles in float32."""
+    d = x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angles = positions.astype(jnp.float32)[..., None] * inv_freq    # (B, L, d/2)
+    angles = angles.reshape(angles.shape[:2] + (1,) * (x.ndim - 3) + (d // 2,))
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., : d // 2], x32[..., d // 2:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def swiglu_apply(params: Params, x: jax.Array) -> jax.Array:
+    """W_down(silu(W_gate x) * W_up x), no bias."""
+    dt = x.dtype
+    gate = x @ params["gate"].astype(dt)
+    up = x @ params["up"].astype(dt)
+    return (jax.nn.silu(gate) * up) @ params["down"].astype(dt)

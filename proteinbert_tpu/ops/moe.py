@@ -1,0 +1,237 @@
+"""Sparse-expert layer: a sigmoid router over ALL experts, and grouped
+products over the experts THIS CHIP HOLDS, with no dropped assignment.
+
+The router keeps its published width (`n_routed_experts`) and its experts
+per token whatever share of the experts lives here; the chip computes the
+part of the layer's result that its own experts give for the tokens
+routed to them, and what the absent experts would add is left out (one
+chip of an expert-parallel group, without its exchange).
+
+    s       = sigmoid(x W_r)                 float32, all experts
+    chosen  = top-k of s + b                 b: balance bias, no gradient
+    weights = s[chosen] / (sum + 1e-20) * routed_scaling_factor
+    y       = sum over chosen experts HELD HERE of weight * Expert_e(x)
+
+Stages, each under its `jax.named_scope`:
+
+- `moe_router`: the scores, the choice, the weights.
+- `moe_dispatch`: the (token, slot) assignments to held experts sorted by
+  expert, cut into blocks of `block` rows of ONE expert each (the last
+  block of an expert is part full); inside the loop, the gather of a
+  block's token rows and the scatter-add of its weighted result.
+- `moe_experts`: the grouped products: a loop over the blocks that hold
+  anything, each block three products against its expert's matrices.
+  The trip count is the number of blocks the step's routing filled, so
+  the work follows the assignments that really fell here, and every one
+  of them is taken whatever the imbalance: the block table is sized for
+  every token choosing held experts in all its slots.
+
+A loop with a data-dependent trip count has no automatic transpose, so
+`expert_ffn` carries its own backward pass: the same loop, each block
+recomputing its hidden activations and adding its share of the
+gradients of the inputs, the weights and the experts' matrices.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+from typing import Dict, NamedTuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+
+class Plan(NamedTuple):
+    """The step's assignments to held experts, by block."""
+
+    order: jax.Array        # (A + block,) assignment ids sorted by held expert
+    block_expert: jax.Array  # (max_blocks,) local expert id of each block
+    block_start: jax.Array  # (max_blocks,) first sorted position of the block
+    block_rows: jax.Array   # (max_blocks,) rows of the block that are real
+    n_blocks: jax.Array     # () blocks that hold anything
+    held_counts: jax.Array  # (experts_held,) tokens per held expert
+    dropped: jax.Array      # () assignments to held experts in no block
+
+
+def route(x, router_kernel, bias, top_k: int, scaling: float,
+          norm_topk: bool = True):
+    """x: (T, D) -> (ids (T, k) int32, weights (T, k) float32). The
+    product runs in float32 at full precision, as published; the bias
+    moves the choice only."""
+    with jax.named_scope("moe_router"):
+        logits = jnp.dot(x.astype(jnp.float32),
+                         router_kernel.astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits)
+        _, ids = lax.top_k(scores + lax.stop_gradient(bias), top_k)
+        weights = jnp.take_along_axis(scores, ids, axis=-1)
+        if norm_topk:
+            weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+        return ids.astype(jnp.int32), weights * scaling
+
+
+def max_blocks(tokens: int, top_k: int, experts_held: int, block: int) -> int:
+    """Blocks enough for EVERY token to choose held experts in every
+    slot it can: nothing is ever dropped for want of room."""
+    most = tokens * min(top_k, experts_held)
+    return -(-most // block) + experts_held
+
+
+def plan_dispatch(ids, experts_held: int, expert_offset: int,
+                  block: int) -> Plan:
+    with jax.named_scope("moe_dispatch"):
+        T, K = ids.shape
+        local = ids.reshape(-1) - expert_offset
+        held = (local >= 0) & (local < experts_held)
+        key = jnp.where(held, local, experts_held)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        counts = jnp.zeros((experts_held + 1,), jnp.int32).at[key].add(1)
+        counts = counts[:experts_held]
+        starts = jnp.cumsum(counts) - counts
+        per_expert = (counts + block - 1) // block
+        ends = jnp.cumsum(per_expert)
+        n = max_blocks(T, K, experts_held, block)
+        i = jnp.arange(n, dtype=jnp.int32)
+        e = jnp.minimum(jnp.searchsorted(ends, i, side="right"),
+                        experts_held - 1).astype(jnp.int32)
+        j = i - (ends[e] - per_expert[e])
+        rows = jnp.where(i < ends[-1],
+                         jnp.clip(counts[e] - j * block, 0, block), 0)
+        return Plan(
+            order=jnp.concatenate([order, jnp.zeros((block,), jnp.int32)]),
+            block_expert=e, block_start=starts[e] + j * block,
+            block_rows=rows.astype(jnp.int32),
+            n_blocks=jnp.minimum(ends[-1], n).astype(jnp.int32),
+            held_counts=counts,
+            dropped=counts.sum() - rows.sum())
+
+
+def _block_rows(plan: Plan, i, top_k: int, tokens: int, block: int):
+    """(local expert, assignment ids, token ids, valid) of block i; the
+    rows past the block's real ones point at spare rows of their own."""
+    with jax.named_scope("moe_dispatch"):
+        spare = jnp.arange(block, dtype=jnp.int32)
+        a = lax.dynamic_slice(plan.order, (plan.block_start[i],), (block,))
+        valid = spare < plan.block_rows[i]
+        tok = jnp.where(valid, a // top_k, tokens + spare)
+        return plan.block_expert[i], a, tok, valid
+
+
+def _hidden(xb, gate_e, up_e):
+    hg = jnp.dot(xb, gate_e, preferred_element_type=jnp.float32)
+    hu = jnp.dot(xb, up_e, preferred_element_type=jnp.float32)
+    return hg, hu
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def expert_ffn(x, weights, gate, up, down, plan: Plan, top_k: int, block: int):
+    """y (T, D) float32 = for every assignment (t, slot) to a held expert
+    e, weights[t, slot] * Expert_e(x[t]) added at row t. x: (T, D) in the
+    compute dtype; gate, up: (E, D, F); down: (E, F, D)."""
+    return _expert_fwd(x, weights, gate, up, down, plan, top_k, block)[0]
+
+
+def _expert_fwd(x, weights, gate, up, down, plan, top_k, block):
+    T, D = x.shape
+    dt = x.dtype
+    xp = jnp.concatenate([x, jnp.zeros((block, D), dt)])
+    w_flat = weights.reshape(-1)
+
+    def body(i, y):
+        e, a, tok, valid = _block_rows(plan, i, top_k, T, block)
+        with jax.named_scope("moe_dispatch"):
+            xb = xp[tok]
+            wb = jnp.where(valid, w_flat[a], 0.0)
+        with jax.named_scope("moe_experts"):
+            # the expert's matrices are rounded here, a block at a time:
+            # a copy of every held expert in the compute dtype would
+            # stand in HBM for the whole layer
+            hg, hu = _hidden(xb, gate[e].astype(dt), up[e].astype(dt))
+            h = (jax.nn.silu(hg) * hu).astype(dt)
+            ob = jnp.dot(h, down[e].astype(dt), preferred_element_type=jnp.float32)
+        with jax.named_scope("moe_dispatch"):
+            return y.at[tok].add(wb[:, None] * ob, unique_indices=True)
+
+    y = lax.fori_loop(0, plan.n_blocks, body,
+                      jnp.zeros((T + block, D), jnp.float32))
+    return y[:T], (x, weights, gate, up, down, plan)
+
+
+def _expert_bwd(top_k, block, saved, dy):
+    x, weights, gate, up, down, plan = saved
+    T, D = x.shape
+    dt = x.dtype
+    A = weights.size
+    xp = jnp.concatenate([x, jnp.zeros((block, D), dt)])
+    dyp = jnp.concatenate([dy.astype(jnp.float32),
+                           jnp.zeros((block, D), jnp.float32)])
+    w_flat = weights.reshape(-1)
+    spare = jnp.arange(block, dtype=jnp.int32)
+
+    def body(i, carry):
+        dx, dw, dgate, dup, ddown = carry
+        e, a, tok, valid = _block_rows(plan, i, top_k, T, block)
+        with jax.named_scope("moe_dispatch"):
+            xb, dyb = xp[tok], dyp[tok]
+            wb = jnp.where(valid, w_flat[a], 0.0)
+        with jax.named_scope("moe_experts"):
+            g16, u16, d16 = (gate[e].astype(dt), up[e].astype(dt),
+                             down[e].astype(dt))
+            hg, hu = _hidden(xb, g16, u16)
+            sg = jax.nn.sigmoid(hg)
+            act = hg * sg
+            h = (act * hu).astype(dt)
+            ob = jnp.dot(h, d16, preferred_element_type=jnp.float32)
+            dwb = jnp.sum(dyb * ob, axis=-1)
+            dob = (wb[:, None] * dyb).astype(dt)
+            ddown_e = jnp.dot(h.T, dob, preferred_element_type=jnp.float32)
+            dh = jnp.dot(dob, d16.T, preferred_element_type=jnp.float32)
+            dhu = (dh * act).astype(dt)
+            dhg = (dh * hu * sg * (1.0 + hg * (1.0 - sg))).astype(dt)
+            dgate_e = jnp.dot(xb.T, dhg, preferred_element_type=jnp.float32)
+            dup_e = jnp.dot(xb.T, dhu, preferred_element_type=jnp.float32)
+            dxb = (jnp.dot(dhg, g16.T, preferred_element_type=jnp.float32)
+                   + jnp.dot(dhu, u16.T, preferred_element_type=jnp.float32))
+            dgate, dup, ddown = (dgate.at[e].add(dgate_e), dup.at[e].add(dup_e),
+                                 ddown.at[e].add(ddown_e))
+        with jax.named_scope("moe_dispatch"):
+            dx = dx.at[tok].add(dxb, unique_indices=True)
+            dw = dw.at[jnp.where(valid, a, A + spare)].set(
+                dwb, unique_indices=True)
+        return dx, dw, dgate, dup, ddown
+
+    init = (jnp.zeros((T + block, D), jnp.float32),
+            jnp.zeros((A + block,), jnp.float32),
+            jnp.zeros(gate.shape, jnp.float32), jnp.zeros(up.shape, jnp.float32),
+            jnp.zeros(down.shape, jnp.float32))
+    dx, dw, dgate, dup, ddown = lax.fori_loop(0, plan.n_blocks, body, init)
+    return (dx[:T].astype(dt), dw[:A].reshape(weights.shape).astype(weights.dtype),
+            dgate.astype(gate.dtype), dup.astype(up.dtype),
+            ddown.astype(down.dtype), None)
+
+
+expert_ffn.defvjp(_expert_fwd, _expert_bwd)
+
+
+def moe_apply(params: Dict, bias, x, real, cfg):
+    """The routed part of an expert layer over x: (T, D); `real` (T,)
+    marks the tokens that are not padding: a pad token is routed
+    nowhere and counted nowhere (every pad has the same input, so they
+    would all fall on the same experts). Returns (y (T, D) in x's dtype,
+    stats): `load` counts all `n_routed_experts` (what the balance bias
+    follows), `held_counts` / `dropped` are the step's counters for this
+    chip's share, `ids` the experts chosen (`n_routed_experts` at a pad)."""
+    ids, weights = route(x, params["router"], bias, cfg.num_experts_per_tok,
+                         cfg.routed_scaling_factor, cfg.norm_topk_prob)
+    ids = jnp.where(real[:, None], ids, cfg.n_routed_experts)
+    plan = plan_dispatch(ids, cfg.experts_held, cfg.expert_offset,
+                         cfg.expert_block)
+    experts = params["experts"]
+    y = expert_ffn(x, weights, experts["gate"], experts["up"], experts["down"],
+                   plan, cfg.num_experts_per_tok, cfg.expert_block)
+    with jax.named_scope("moe_router"):
+        load = jnp.zeros((cfg.n_routed_experts + 1,), jnp.int32).at[
+            ids.reshape(-1)].add(1)[:-1]
+    return y.astype(x.dtype), {"load": load, "held_counts": plan.held_counts,
+                               "dropped": plan.dropped, "ids": ids}

@@ -92,6 +92,14 @@ def _leaf_spec(path, leaf, mesh: Mesh) -> P:
         if len(shape) >= 2 and shape[-2] % model_n == 0:
             return P(*([None] * (len(shape) - 2) + ["model", None]))
 
+    # Expert parallelism: the decoder's routed experts (models/glm_moe.py,
+    # leaves (..., E, in, out) under "experts") go over 'model' on their
+    # expert axis; the router, the shared expert and attention stay
+    # whole on every chip, as in the deployment the preset states.
+    if (model_n > 1 and _path_has(path, "experts") and len(shape) >= 3
+            and shape[-3] % model_n == 0):
+        return P(*([None] * (len(shape) - 3) + ["model", None, None]))
+
     # The token-embedding table is REPLICATED: at 26 x local_dim it is
     # a few KB at every preset, so FSDP-sharding it saves nothing — and
     # a feature-sharded table makes the token-lookup gather produce

@@ -166,7 +166,11 @@ class StepTimer:
         n_chips: int = 1,
         warmup_steps: int = 2,
     ):
-        self.flops_per_step = train_flops(cfg, batch, seq_len)
+        # No MFU for a model this module has no count for (the decoder:
+        # what a packed batch of it needs depends on its documents and
+        # its routing, which `benchmark/lm_flops.py` counts batch by batch).
+        self.flops_per_step = (train_flops(cfg, batch, seq_len)
+                               if isinstance(cfg, ModelConfig) else None)
         self.residues_per_step = batch * seq_len
         self.n_chips = max(n_chips, 1)
         self.warmup_steps = warmup_steps
@@ -242,14 +246,16 @@ class StepTimer:
 
     def _rates(self, steps: int, dt: float, prefix: str) -> Dict[str, float]:
         steps_per_sec = steps / dt
-        return {
+        rates = {
             f"{prefix}steps_per_sec": steps_per_sec,
             f"{prefix}step_ms": 1000.0 / steps_per_sec,
             f"{prefix}residues_per_sec_per_chip": steps_per_sec
             * self.residues_per_step / self.n_chips,
-            f"{prefix}mfu": steps_per_sec * self.flops_per_step
-            / (self.peak * self.n_chips),
         }
+        if self.flops_per_step is not None:
+            rates[f"{prefix}mfu"] = (steps_per_sec * self.flops_per_step
+                                     / (self.peak * self.n_chips))
+        return rates
 
     def summary(self) -> Dict[str, float]:
         if not self._steps_timed or self._t0 is None:

@@ -33,8 +33,8 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from proteinbert_tpu.configs import PretrainConfig
-from proteinbert_tpu.models import proteinbert
+from proteinbert_tpu.configs import DecoderConfig, PretrainConfig
+from proteinbert_tpu.models import glm_moe, proteinbert
 from proteinbert_tpu.data.corruption import corrupt_batch, corrupt_packed_batch
 from proteinbert_tpu.train.loss import (
     global_ranking_metrics, global_ranking_stats, packed_pretrain_loss,
@@ -157,6 +157,39 @@ def corrupt_forward_grads(
     return key, grads, metrics
 
 
+def _decoder_batch(batch: Dict[str, jax.Array]):
+    """(tokens, segment ids) of a decoder batch; a batch without
+    segment ids is one document a row."""
+    tokens = batch["tokens"]
+    return tokens, batch.get("segment_ids", jnp.ones_like(tokens))
+
+
+def decoder_forward_grads(
+    state: "TrainState", batch: Dict[str, jax.Array], cfg: PretrainConfig,
+) -> Tuple[jax.Array, Any, Dict[str, jax.Array], Any]:
+    """The front half of a step of the causal expert decoder
+    (models/glm_moe.py): next-token and multi-token-prediction loss over
+    a packed batch of token documents, and its gradients. Nothing is
+    corrupted, so the state's key passes through. Returns (key, grads,
+    metrics, the expert layers' counters that `update_balance_bias`
+    reads); no gradient reaches `params["balance_bias"]`."""
+    tokens, seg = _decoder_batch(batch)
+
+    def loss_fn(params):
+        with jax.named_scope("forward"):
+            return glm_moe.loss_and_stats(params, tokens, seg, cfg.model)
+
+    grads, (out, counters) = jax.grad(loss_fn, has_aux=True)(state.params)
+    with jax.named_scope("step_metrics"):
+        metrics = glm_moe.step_metrics(out, counters, seg, cfg.model)
+        # The one metric that is no scalar: the experts every token chose,
+        # (expert layers + prediction module, tokens, k), `n_routed_experts`
+        # at a pad. The trainer's log fetch leaves it on the device; a
+        # caller of the step may read which way the routing went.
+        metrics["route_ids"] = counters["ids"]
+    return state.key, grads, metrics, counters
+
+
 def plateau_observation(cfg_opt, metrics: Dict[str, jax.Array],
                         plateau_value: Any):
     """The value the plateau transform observes this step: the train
@@ -191,7 +224,8 @@ def snapshot_train_state(state: TrainState) -> TrainState:
 
 def create_train_state(key: jax.Array, cfg: PretrainConfig) -> TrainState:
     k_init, k_state = jax.random.split(key)
-    params = proteinbert.init(k_init, cfg.model)
+    model = glm_moe if isinstance(cfg.model, DecoderConfig) else proteinbert
+    params = model.init(k_init, cfg.model)
     tx = make_optimizer(cfg.optimizer)
     # optax initialises some scalars (reduce_on_plateau's best/avg value)
     # from Python numbers, i.e. weakly typed; the first step returns them
@@ -224,12 +258,23 @@ def train_step(
     for direct callers of this function, and such callers should know
     the fallback mixes train-scale values into the plateau window
     (ADVICE r4)."""
-    key, grads, metrics = corrupt_forward_grads(state, batch, cfg)
+    counters = None
+    if isinstance(cfg.model, DecoderConfig):    # the model, by its config's type
+        key, grads, metrics, counters = decoder_forward_grads(state, batch, cfg)
+    else:
+        key, grads, metrics = corrupt_forward_grads(state, batch, cfg)
     value = plateau_observation(cfg.optimizer, metrics, plateau_value)
     params, opt_state = gradient_update(
         make_optimizer(cfg.optimizer), state.params, grads, state.opt_state,
         value, needs_loss_value(cfg.optimizer),
     )
+    if counters is not None:
+        # The one state leaf no gradient trains (its gradient, and so
+        # Adam's update of it, is exactly zero): moved AFTER the step,
+        # from the step's own expert loads.
+        with jax.named_scope("optimizer"):
+            params = dict(params, balance_bias=glm_moe.update_balance_bias(
+                params["balance_bias"], counters, cfg.model))
 
     metrics = dict(metrics)
     with jax.named_scope("step_metrics"):
@@ -251,7 +296,14 @@ def eval_step(
     Packed batches (a "segment_ids" key) are scored with the per-segment
     loss; the ranking metrics see each packed protein as its own row
     ((B, S, A) flattened to (B·S, A) — empty segment slots carry zero
-    weight and are excluded by the metrics' own validity masks)."""
+    weight and are excluded by the metrics' own validity masks). The
+    decoder has no corruption and no ranking head: its eval is the
+    training loss on held-out documents."""
+    if isinstance(cfg.model, DecoderConfig):
+        tokens, seg = _decoder_batch(batch)
+        _, (out, counters) = glm_moe.loss_and_stats(
+            state.params, tokens, seg, cfg.model)
+        return glm_moe.step_metrics(out, counters, seg, cfg.model)
     if "segment_ids" in batch:
         seg = batch["segment_ids"]
         X, Y, W = corrupt_packed_batch(

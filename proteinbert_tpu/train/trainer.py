@@ -86,6 +86,17 @@ def _fault_eval_stall_secs():
         return None
 
 
+def _losses_said(m: dict, prefix: str = "") -> str:
+    """The log line's detail: the two losses a step's total is made of,
+    under the model's own names (ProteinBERT: local and global track; the
+    decoder: main head and prediction module), and the first's accuracy."""
+    first, second = (("main", "mtp") if f"{prefix}main_loss" in m
+                     else ("local", "global"))
+    return "(%s %.4f %s %.4f) acc %.3f" % (
+        first, m[f"{prefix}{first}_loss"], second,
+        m[f"{prefix}{second}_loss"], m[f"{prefix}{first}_acc"])
+
+
 def pretrain(
     cfg: PretrainConfig,
     batch_iterator,
@@ -470,11 +481,8 @@ def pretrain(
         timer.discount(time.perf_counter() - t0)
         history.append({"step": e_step, **em})
         tele.emit("eval", step=e_step, metrics=em, overlapped=True)
-        logger.info(
-            "step %d eval loss %.4f (local %.4f global %.4f) acc %.3f",
-            e_step, em["eval_loss"], em["eval_local_loss"],
-            em["eval_global_loss"], em["eval_local_acc"],
-        )
+        logger.info("step %d eval loss %.4f %s", e_step, em["eval_loss"],
+                    _losses_said(em, "eval_"))
         if log_fn is not None:
             log_fn(e_step, em)
         last_eval_loss = np.float32(em["eval_loss"])
@@ -568,8 +576,9 @@ def pretrain(
                 # ONE device_get for the whole metrics dict (per-key float()
                 # paid ~10 device→host roundtrips per log point).
                 with span("train.log_fetch"):
-                    m = {k: float(v)
-                         for k, v in jax.device_get(metrics).items()}
+                    m = {k: float(v) for k, v in jax.device_get(
+                        {k: v for k, v in metrics.items()
+                         if not v.ndim}).items()}   # the scalars
                 # That fetch drained the async dispatch queue through this
                 # step — fold the wait into the timing window, else
                 # summary() reports host enqueue rate.
@@ -646,16 +655,17 @@ def pretrain(
                     reg.counter("steps_total").inc(cfg.train.log_every)
                     reg.set_many(m)  # loss/acc + StepTimer summary as gauges
                 logger.info(
-                    "step %d loss %.4f (local %.4f global %.4f) acc %.3f %s",
-                    step + 1, m["loss"], m["local_loss"], m["global_loss"],
-                    m["local_acc"],
+                    "step %d loss %.4f %s %s",
+                    step + 1, m["loss"], _losses_said(m),
                     (f"{m['residues_per_sec_per_chip']:.0f} res/s/chip "
-                     f"MFU {m['mfu']:.3f} on {n_chips}x {device_kind}"
+                     + (f"MFU {m['mfu']:.3f} " if "mfu" in m else "")
+                     + f"on {n_chips}x {device_kind}"
                      # The since-last-log rate tells a live operator
                      # "currently slow" apart from "was slow once" — the
                      # cumulative MFU alone re-reports an old stall forever.
                      + (f" (window {m['window_mfu']:.3f})"
-                        if "window_mfu" in m else "")) if "mfu" in m else "",
+                        if "window_mfu" in m else ""))
+                    if "residues_per_sec_per_chip" in m else "",
                 )
                 if log_fn is not None:
                     log_fn(step + 1, m)
@@ -729,12 +739,8 @@ def pretrain(
                     timer.discount(time.perf_counter() - t_eval)
                     history.append({"step": step + 1, **em})
                     tele.emit("eval", step=step + 1, metrics=em)
-                    logger.info(
-                        "step %d eval loss %.4f (local %.4f global %.4f) "
-                        "acc %.3f",
-                        step + 1, em["eval_loss"], em["eval_local_loss"],
-                        em["eval_global_loss"], em["eval_local_acc"],
-                    )
+                    logger.info("step %d eval loss %.4f %s", step + 1,
+                                em["eval_loss"], _losses_said(em, "eval_"))
                     if log_fn is not None:
                         log_fn(step + 1, em)
                     last_eval_loss = np.float32(em["eval_loss"])
@@ -867,9 +873,10 @@ def dispatch_eval(
         b_rows = len(next(iter(batch.values())))
         m = dict(ts.eval_step(state, put(batch),
                               jax.random.fold_in(base_key, n), cfg))
-        stats = m.pop("ranking_stats")
-        rank_stats = stats if rank_stats is None else jax.tree.map(
-            lambda a, b: a + b, rank_stats, stats)
+        stats = m.pop("ranking_stats", None)    # the decoder has none
+        if stats is not None:
+            rank_stats = stats if rank_stats is None else jax.tree.map(
+                lambda a, b: a + b, rank_stats, stats)
         acc.add(m, weight=b_rows, key_fn=rename)
         n += 1
         rows += b_rows

@@ -194,3 +194,36 @@ def test_base_train_step_fits_the_chip(chip):
     assert 0 < need < V5E_HBM_BYTES, (need, m)
     # Donation took: the new state lives in the old one's buffers.
     assert m.alias_size_in_bytes > 0.9 * m.output_size_in_bytes, m
+
+
+def test_decoder_expert_layer_compiles_for_v5e(chip):
+    """One expert layer of the causal decoder at GLM-4.7-Flash's
+    published widths (models/glm_moe.py: latent attention through the
+    Pallas flash kernel, the router over 64, grouped products over the 8
+    experts held, the shared expert), forward and backward, one row of
+    8,192 tokens: the flash kernels are in the compiled program (forward,
+    and the two of the backward pass), and so is the grouped products'
+    loop with its data-dependent trip count."""
+    import dataclasses
+
+    from proteinbert_tpu.models import glm_moe
+
+    cfg = dataclasses.replace(get_preset("glm47flash_ep8").model,
+                              num_hidden_layers=2, num_nextn_predict_layers=0)
+    shapes = glm_moe.param_shapes(cfg)["layers"]
+    layer = jax.tree.map(lambda s: _sds(s[1:], jnp.float32), shapes,
+                         is_leaf=lambda s: isinstance(s, tuple))
+    x = _sds((1, 8192, cfg.hidden_size))
+    seg = _sds((1, 8192), jnp.int32)
+    bias = _sds((cfg.n_routed_experts,), jnp.float32)
+
+    def f(p, bias, x, seg):
+        def loss(p, x):
+            y, stats = glm_moe.expert_layer(
+                p, bias, x, seg, jnp.zeros_like(seg), cfg)
+            return y.astype(jnp.float32).sum(), stats["dropped"]
+        return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(p, x)
+
+    text = jax.jit(f).lower(*_on(chip, (layer, bias, x, seg))).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3, "flash forward + two backward kernels"
+    assert "while(" in text or " while" in text

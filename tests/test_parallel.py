@@ -131,6 +131,34 @@ def _assert_sharded_step_matches(cfg):
 
 
 @requires_8
+def test_pinning_the_same_step_again_compiles_nothing():
+    """A caller that drives the first steps itself (the benchmark's mesh
+    driver) and the trainer each wrap `train_step` in
+    `pin_state_sharding`: two jitted wrappers, one executable."""
+    from proteinbert_tpu.parallel.sharding import pin_state_sharding
+
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, secs, **kw: compiles.append(name)
+        if "backend_compile" in name else None)
+    cfg = cfg_for(MeshConfig(data=2, fsdp=4))
+    mesh = make_mesh(cfg.mesh)
+    state = shard_train_state(create_train_state(jax.random.PRNGKey(0), cfg), mesh)
+    bsh = batch_sharding(mesh)
+    batch = {k: jax.device_put(v, bsh[k]) for k, v in make_batch(cfg).items()}
+    first = pin_state_sharding(train_step, state, static_argnums=2)
+    state, metrics = first(state, batch, cfg)
+    float(metrics["loss"])
+    assert compiles
+    before = len(compiles)
+    again = pin_state_sharding(train_step, state, static_argnums=2)
+    assert again is not first
+    state, metrics = again(state, batch, cfg)
+    float(metrics["loss"])
+    assert len(compiles) == before
+
+
+@requires_8
 @pytest.mark.parametrize("model_kw", [
     # num_blocks=5 with unroll=2 keeps a REAL loop (2 iterations of 2
     # bodies + remainder) — at the default num_blocks=2 the scan would

@@ -279,25 +279,39 @@ def encode(
     per-segment as (B, S, G) and every cross-position op is segment-
     masked (see block_apply).
     """
+    from proteinbert_tpu.parallel.sharding import (
+        gathered_over_fsdp, pin_to_batch_layout,
+    )
+
     dtype = jnp.dtype(cfg.dtype)
     if pad_mask is None:
         pad_mask = (segment_ids > 0 if segment_ids is not None
                     else tokens != PAD_ID)
+
+    def pinned(l, g):
+        # Under a mesh the two tracks keep the layout their batch came in
+        # with, from block to block, so that it is the WEIGHTS a sharded
+        # step moves (parallel/sharding.py); with no mesh, nothing.
+        return pin_to_batch_layout(l, positions=1), pin_to_batch_layout(g)
 
     with jax.named_scope("embed"):
         local = embedding_apply(params["embedding"], tokens, dtype)
         global_ = jax.nn.gelu(
             dense_apply(params["global_in"], annotations.astype(dtype))
         )
+        local, global_ = pinned(local, global_)
 
     body = remat_wrap(
         partial(block_apply, cfg=cfg, segment_ids=segment_ids), cfg)
 
     if cfg.scan_blocks:
         def scan_body(carry, blk):
-            l, g = carry
+            # One all-gather a leaf a block, outside the remat: the
+            # backward reads the forward's gathered copy.
+            blk = gathered_over_fsdp(blk)
+            l, g = pinned(*carry)
             l, g = body(blk, l, g, pad_mask)
-            return (l, g), None
+            return pinned(l, g), None
 
         (local, global_), _ = lax.scan(
             scan_body, (local, global_), _cast_blocks(params["blocks"], dtype),
@@ -306,8 +320,9 @@ def encode(
         )
     else:
         for blk in params["blocks"]:
-            local, global_ = body(blk, local, global_, pad_mask)
-    return local, global_
+            local, global_ = pinned(
+                *body(gathered_over_fsdp(blk), local, global_, pad_mask))
+    return pinned(local, global_)
 
 
 def encode_trunk(

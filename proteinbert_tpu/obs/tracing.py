@@ -39,6 +39,7 @@ import gzip
 import itertools
 import json
 import logging
+import math
 import os
 import re
 import sys
@@ -337,6 +338,124 @@ def scopes_from_hlo(text: str) -> Dict[str, str]:
         if scope is not None:
             named[instruction] = scope
     return named
+
+
+_COLLECTIVE = re.compile(
+    r"=\s*(\(.*?\)|\S+)\s+(all-gather|all-reduce|reduce-scatter|all-to-all"
+    r"|collective-permute)(-start)?\(")
+_ARRAY = re.compile(r"\b([a-z]+\d+[a-z0-9]*|pred)\[([\d,]*)\]")
+_WHILE = re.compile(
+    r"\swhile\(.*?\bcondition=%?([\w.\-]+),\s*body=%?([\w.\-]+)")
+_BOUND = re.compile(r"=\s*s32\[\]\S*\s+constant\((\d+)\)")
+_TRIPS = re.compile(r'"known_trip_count":\{"n":"(\d+)"')
+_CALLED = re.compile(r"\b(?:calls|to_apply|condition|body)=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+
+
+def _element_bytes(dtype: str) -> int:
+    bits = re.search(r"\d+", dtype)
+    return max(int(bits.group()) // 8, 1) if bits else 1     # pred: 1
+
+
+def collective_census(text: str, rows: int, parameter_shapes) -> Dict[str, Any]:
+    """What a compiled (partitioned) step moves between chips, from its
+    module's text (`compiled.as_text()`): a count, not a time.
+
+    `rows` is the GLOBAL row count of the step's batch;
+    `parameter_shapes` every parameter leaf's shape: a parameter travels
+    in that shape or, a slice of a stack, without its leading axis, and
+    extents of 1 are dropped on both sides (such a slice keeps one). Each
+    collective is taken by its results: `parameter` if every one has a parameter's
+    shape, `activation` if one has rank 2 or more and `rows` as its
+    first extent and no parameter's shape (a chip's own share of the
+    batch has fewer rows: such a result is the batch, or what was
+    computed from it, brought together), else `other` (loss sums, a
+    vector, a reshard of a chip's own rows). A collective inside a loop
+    counts once a trip: the loop's `known_trip_count`, else the one
+    integer constant its condition compares with (a scan counts up from
+    0), else 1.
+
+    Returns {"by_kind": {kind: {"count", "bytes"}}, "activation": count,
+    "parameter": count, "parameter_gathers": count (`all-gather`s among
+    them), "activation_shapes": the distinct result shapes of the
+    first, "bytes": of every result, a step}."""
+    parameter_shapes = {_squeezed(s[cut:]) for s in parameter_shapes
+                        for cut in (0, 1)}
+    trips: Dict[str, int] = {}      # loop body -> its trips
+    conditions: Dict[str, str] = {}  # loop body -> condition, trips unsaid
+    bounds: Dict[str, List[int]] = {}   # computation -> its s32 constants
+    callers: Dict[str, str] = {}    # computation -> the one that names it
+    found = []                      # (computation, kind, [(dtype, dims)])
+    computation = None
+    for line in text.splitlines():
+        header = _COMPUTATION.match(line)
+        if header and " = " not in line.split("(")[0]:
+            computation = header.group(1)
+            continue
+        if computation is None or " = " not in line:
+            continue
+        bound = _BOUND.search(line)
+        if bound:
+            bounds.setdefault(computation, []).append(int(bound.group(1)))
+        loop = _WHILE.search(line)
+        if loop:
+            n = _TRIPS.search(line)
+            if n:
+                trips[loop.group(2)] = int(n.group(1))
+            else:
+                conditions[loop.group(2)] = loop.group(1)
+        called = _CALLED.findall(line)
+        branches = _BRANCHES.search(line)
+        if branches:
+            called += [b.strip().lstrip("%") for b in branches.group(1).split(",")]
+        for name in called:
+            callers.setdefault(name, computation)
+        m = _COLLECTIVE.search(line)
+        if m:
+            arrays = [(d, _squeezed(int(x) for x in dims.split(",") if x))
+                      for d, dims in _ARRAY.findall(m.group(1))]
+            if m.group(3) and m.group(2) in ("all-gather", "collective-permute"):
+                # A start's result is (operands, results[, two scalars]).
+                arrays = [a for a in arrays if a[1]] or arrays
+                arrays = arrays[len(arrays) // 2:]
+            found.append((computation, m.group(2), arrays))
+
+    for body, condition in conditions.items():
+        said = bounds.get(condition, [])
+        trips[body] = said[0] if len(said) == 1 else 1
+
+    def times(comp):
+        n, seen = 1, set()
+        while comp is not None and comp not in seen:
+            seen.add(comp)
+            n *= trips.get(comp, 1)
+            comp = callers.get(comp)
+        return n
+
+    census = {"by_kind": {}, "activation": 0, "parameter": 0,
+              "parameter_gathers": 0, "activation_shapes": [], "bytes": 0}
+    for comp, kind, arrays in found:
+        n = times(comp)
+        size = n * sum(_element_bytes(d) * math.prod(dims) for d, dims in arrays)
+        entry = census["by_kind"].setdefault(kind, {"count": 0, "bytes": 0})
+        entry["count"] += n
+        entry["bytes"] += size
+        census["bytes"] += size
+        shapes = [dims for _, dims in arrays]
+        batch = [list(s) for s in shapes if len(s) >= 2 and s[0] == rows
+                 and s not in parameter_shapes]
+        if shapes and all(s in parameter_shapes for s in shapes):
+            census["parameter"] += n
+            census["parameter_gathers"] += n * (kind == "all-gather")
+        elif batch:
+            census["activation"] += n
+            census["activation_shapes"] += [
+                s for s in batch if s not in census["activation_shapes"]]
+    return census
+
+
+def _squeezed(dims) -> tuple:
+    return tuple(d for d in dims if d != 1)
 
 
 def program_scopes(name: str) -> Optional[Dict[str, str]]:

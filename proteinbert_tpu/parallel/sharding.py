@@ -9,6 +9,18 @@ framework lays out the ProteinBERT train state and input batches over the
   (XLA adds halo exchange) and the attention softmax (psum over seq).
 - batch annotations (B, A): B over (data, fsdp); the 8943-dim annotation
   vector stays whole per example.
+- activations of the ProteinBERT trunk (`pin_to_batch_layout`, PR 29): the
+  layout their batch came in with — rows over (data, fsdp), positions
+  over seq, features whole — stated at the embedding, on the carry of the
+  scan over blocks and before the heads, whenever the step is traced
+  under a mesh (`pin_state_sharding` runs it under the state's). Batch
+  and block weights both name 'fsdp'; with the activations pinned, and
+  each block's weights stated whole at the top of the block
+  (`gathered_over_fsdp`), that conflict is resolved by all-gathering the
+  weights and reducing their gradients, which is what `fsdp` means. Left
+  to choose, the partitioner gathered the batch and ran tensor
+  parallelism over the axis (obs/tracing.collective_census counts
+  which).
 - params: tensor parallelism on the two A-sized matmuls — `global_head`
   kernel (G, A) column-sharded and `global_in` kernel (A, G) row-sharded
   over 'model' (the A dim is the big one, SURVEY §7 hard-part (e));
@@ -28,23 +40,73 @@ memory is allocated before shardings are known.
 
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
+# The batch's layout, which its activations keep (`pin_to_batch_layout`).
+BATCH_ROW_AXES = ("data", "fsdp")
+BATCH_POSITION_AXIS = "seq"
+
+
 def batch_sharding(mesh: Mesh) -> Dict[str, NamedSharding]:
     return {
-        "tokens": NamedSharding(mesh, P(("data", "fsdp"), "seq")),
+        "tokens": NamedSharding(
+            mesh, P(BATCH_ROW_AXES, BATCH_POSITION_AXIS)),
         # Packed batches (data/packing.py): the per-position segment map
         # shards exactly like the tokens it annotates; the per-segment
         # (B, S, A) annotation tensor keeps batch-only sharding (the
         # trailing spec axes replicate, so the 2D unpacked (B, A) shape
         # uses the same entry).
-        "segment_ids": NamedSharding(mesh, P(("data", "fsdp"), "seq")),
-        "annotations": NamedSharding(mesh, P(("data", "fsdp"), None)),
+        "segment_ids": NamedSharding(
+            mesh, P(BATCH_ROW_AXES, BATCH_POSITION_AXIS)),
+        "annotations": NamedSharding(mesh, P(BATCH_ROW_AXES, None)),
     }
+
+
+def pin_to_batch_layout(x: jax.Array,
+                        positions: Optional[int] = None) -> jax.Array:
+    """`x`, an activation with the batch's rows on axis 0 (and its
+    positions on axis `positions`, where it has them), constrained to the
+    layout of `batch_sharding`: rows over ('data','fsdp'), positions over
+    'seq', every other axis whole.
+
+    The mesh is the one the step is being traced under (`jax.set_mesh`,
+    which `pin_state_sharding` enters; inside a `shard_map`, its mesh).
+    With none, or one whose data, fsdp and seq extents are all 1, `x`
+    comes back as it is and the traced program gains nothing. An axis
+    that is manual where this is traced (the bodies of parallel/zero.py,
+    quant.py and seq_parallel.py) already holds its shard and may not be
+    named: it is left out."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return x
+    free = [a for a in BATCH_ROW_AXES + (BATCH_POSITION_AXIS,)
+            if mesh.shape.get(a, 1) > 1 and a not in mesh.manual_axes]
+    spec = [None] * x.ndim
+    spec[0] = tuple(a for a in free if a in BATCH_ROW_AXES) or None
+    if positions is not None and BATCH_POSITION_AXIS in free:
+        spec[positions] = BATCH_POSITION_AXIS
+    if not any(spec):
+        return x
+    return jax.lax.with_sharding_constraint(x, P(*spec))
+
+
+def gathered_over_fsdp(tree: Any) -> Any:
+    """A block's weights, every leaf whole on every chip, where the step
+    is traced under a mesh whose 'fsdp' axis shards them (not manual,
+    extent over 1); otherwise `tree` itself. Stated once at the top of a
+    block, it is the all-gather of `fsdp`: one a leaf a block, whatever
+    the partitioner would have chosen use by use (for a product with few
+    rows it gathers the rows instead), and its transpose reduces the
+    leaf's gradient."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if (mesh.empty or mesh.shape.get("fsdp", 1) == 1
+            or "fsdp" in mesh.manual_axes):
+        return tree
+    return jax.lax.with_sharding_constraint(tree, P())
 
 
 def serve_batch_sharding(mesh: Mesh) -> Dict[str, NamedSharding]:
@@ -244,7 +306,33 @@ def pin_state_sharding(step, state, static_argnums=()):
     then sees new input shardings and is traced and COMPILED a second
     time, and the state no longer sits where `state_sharding` put it.
     The trainer wraps every sharded step in this; one executable, one
-    layout."""
+    layout.
+
+    The step runs (so: is traced) under the state's mesh, which is how
+    the model learns there is one (`pin_to_batch_layout`)."""
     pinned = jax.tree.map(lambda a: a.sharding, state)
-    return jax.jit(step, static_argnums=static_argnums, donate_argnums=0,
-                   out_shardings=(pinned, None))
+    jitted = jax.jit(step, static_argnums=static_argnums, donate_argnums=0,
+                     out_shardings=(pinned, None))
+    meshes = {s.mesh for s in jax.tree.leaves(pinned)
+              if isinstance(s, NamedSharding)}
+    if len(meshes) != 1:
+        return jitted
+    return _UnderMesh(jitted, meshes.pop())
+
+
+class _UnderMesh:
+    """A jitted step, called and lowered under `jax.set_mesh(mesh)`. The
+    mesh is part of jit's cache key, so two wrappers of one step and
+    mesh share one trace and one executable."""
+
+    def __init__(self, jitted, mesh: Mesh):
+        self._jitted, self._mesh = jitted, mesh
+        self.__name__ = jitted.__name__
+
+    def __call__(self, *args, **kwargs):
+        with jax.set_mesh(self._mesh):
+            return self._jitted(*args, **kwargs)
+
+    def lower(self, *args, **kwargs):
+        with jax.set_mesh(self._mesh):
+            return self._jitted.lower(*args, **kwargs)

@@ -556,6 +556,11 @@ def pretrain(
                     device_memory_report,
                 )
 
+                if (mesh is not None and mesh.size > 1
+                        and not eval_keyed_plateau):
+                    # While the device runs that first step: what the
+                    # compiled step moves between the chips.
+                    _log_collective_census(step_fn, state, batch, cfg, mesh)
                 float(metrics["loss"])
                 stats = next((s for s in device_memory_report().values()
                               if "bytes_in_use" in s), None)
@@ -926,6 +931,35 @@ def _evaluate(state, batches, put, cfg, step) -> Dict[str, float]:
     metrics, _, _ = evaluate_batches(
         state, batches, put, cfg, eval_base_key(cfg, step))
     return metrics
+
+
+def _log_collective_census(step_fn, state, batch, cfg, mesh) -> None:
+    """One log line for a step pinned on a mesh: the collectives of its
+    compiled module (obs/tracing.collective_census). The executable is
+    the one the first call compiled, found again by jit's own caches, so
+    this costs its text and no compile. On an `fsdp` mesh a sound step
+    reads 0 collectives over activations and a gather for every use of a
+    block's weights (docs/distributed.md)."""
+    from proteinbert_tpu.obs.tracing import collective_census
+
+    try:
+        census = collective_census(
+            step_fn.lower(state, batch, cfg).compile().as_text(),
+            batch["tokens"].shape[0],
+            [leaf.shape for leaf in jax.tree.leaves(state.params)])
+    except Exception:   # the compiler's text is not an interface
+        logger.debug("collective census failed", exc_info=True)
+        return
+    logger.info(
+        "sharded step on mesh %s: %d collectives over activations with the "
+        "global row count%s, %d parameter all-gathers, %.2f GB a step (%s)",
+        {a: n for a, n in mesh.shape.items() if n > 1},
+        census["activation"],
+        f" (results {census['activation_shapes']})"
+        if census["activation"] else "",
+        census["parameter_gathers"], census["bytes"] / 1e9,
+        ", ".join(f"{k} x {v['count']}"
+                  for k, v in sorted(census["by_kind"].items())))
 
 
 def _make_batch_put(mesh: Optional[jax.sharding.Mesh]):

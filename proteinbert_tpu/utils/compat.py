@@ -71,4 +71,37 @@ def configure_compile_cache() -> str:
     jax.config.update("jax_compilation_cache_dir", directory)
     if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    _compile_multi_device_cpu_programs_afresh()
     return directory
+
+
+def _compile_multi_device_cpu_programs_afresh() -> None:
+    """A CPU executable over more than one device is never LOADED from
+    the cache: it is compiled again (and written again).
+
+    jaxlib 0.9.0 runs such an executable, once loaded, with its
+    independent collectives in no fixed order, each blocking a thread of
+    the one pool all the virtual devices share: a sharded step whose
+    scan body opens with one all-gather a weight (PR 29) runs when
+    freshly compiled, but loaded its eight devices on a `data=2,fsdp=4`
+    mesh wait at three different collectives and the runtime aborts the
+    process after 40 s, every time (a pool of 64 threads, `NPROC=64`,
+    lets it through: starvation, not a wrong program). jax offers no
+    public switch per program, so this wraps the one function through
+    which jax reads an entry; tests/test_parallel.py fails if that function
+    moves. The chip's executables, and one-device CPU ones, load as
+    before."""
+    from jax._src import compilation_cache
+
+    read = compilation_cache.get_executable_and_time
+    if getattr(read, "compiles_multi_device_cpu_afresh", False):
+        return
+
+    def get_executable_and_time(cache_key, compile_options, backend,
+                                executable_devices):
+        if backend.platform == "cpu" and len(executable_devices) > 1:
+            return None, None
+        return read(cache_key, compile_options, backend, executable_devices)
+
+    get_executable_and_time.compiles_multi_device_cpu_afresh = True
+    compilation_cache.get_executable_and_time = get_executable_and_time

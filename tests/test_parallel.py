@@ -10,7 +10,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from proteinbert_tpu.configs import (
     DataConfig, MeshConfig, ModelConfig, OptimizerConfig, PretrainConfig,
@@ -98,13 +98,19 @@ def test_sharding_rules_tp_and_fsdp():
     ],
     ids=["dp", "dp-fsdp-tp", "dp-sp", "dp-fsdp-sp"],
 )
-def test_sharded_train_step_matches_single_device(mesh_cfg):
+@pytest.mark.parametrize("pinned", [False, True], ids=["bare", "pinned"])
+def test_sharded_train_step_matches_single_device(mesh_cfg, pinned):
     """The compiled distributed step must be numerically equivalent to the
-    single-device step (XLA inserts psum/all-gather/halo automatically)."""
-    _assert_sharded_step_matches(cfg_for(mesh_cfg))
+    single-device step (XLA inserts psum/all-gather/halo automatically),
+    bare and as the trainer runs it: pinned to the state's layout and
+    traced under the mesh, its activations held to the batch's layout
+    and its block weights gathered (PR 29)."""
+    _assert_sharded_step_matches(cfg_for(mesh_cfg), pinned)
 
 
-def _assert_sharded_step_matches(cfg):
+def _assert_sharded_step_matches(cfg, pinned=False):
+    from proteinbert_tpu.parallel.sharding import pin_state_sharding
+
     mesh_cfg = cfg.mesh
     batch = make_batch(cfg)
 
@@ -116,7 +122,9 @@ def _assert_sharded_step_matches(cfg):
     state = shard_train_state(state, mesh)
     bsh = batch_sharding(mesh)
     dbatch = {k: jax.device_put(v, bsh[k]) for k, v in batch.items()}
-    new_state, metrics = train_step(state, dbatch, cfg)
+    step = (pin_state_sharding(train_step, state, static_argnums=2)
+            if pinned else train_step)
+    new_state, metrics = step(state, dbatch, cfg)
 
     assert float(metrics["loss"]) == pytest.approx(
         float(ref_metrics["loss"]), rel=2e-5
@@ -156,6 +164,35 @@ def test_pinning_the_same_step_again_compiles_nothing():
     state, metrics = again(state, batch, cfg)
     float(metrics["loss"])
     assert len(compiles) == before
+
+
+@requires_8
+@pytest.mark.parametrize("devices, loaded", [(1, True), (2, False)],
+                         ids=["one-device-loads", "two-devices-compile-afresh"])
+def test_multi_device_cpu_executable_is_never_loaded_from_the_cache(
+        devices, loaded):
+    """jaxlib 0.9.0 runs a LOADED multi-device CPU executable with its
+    collectives in no fixed order, and a pinned fsdp step then stops at a
+    rendezvous (utils/compat.configure_compile_cache, which the harness
+    calls): such a program compiles again, a one-device one still loads.
+    Fails if jax moves the function the helper wraps."""
+    hits = []
+    jax.monitoring.register_event_listener(
+        lambda name, **kw: hits.append(name)
+        if name.endswith("compilation_cache/cache_hits") else None)
+    mesh = jax.make_mesh((devices,), ("data",), devices=jax.devices()[:devices])
+    x = jax.device_put(jnp.arange(8.0), NamedSharding(mesh, P("data")))
+    program = lambda x: jnp.sum(jnp.tanh(x) * 3.25)            # noqa: E731
+    least = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    try:
+        jax.jit(program)(x)
+        jax.clear_caches()          # so the second call asks the disk
+        before = len(hits)
+        jax.jit(program)(x)
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", least)
+    assert (len(hits) > before) == loaded
 
 
 @requires_8
@@ -333,3 +370,118 @@ print("COMPILED-OK")
         "positive control failed: the GSPMD compile no longer emits the "
         "warning text this test greps for — update the marker (XLA may "
         "have reworded it) before trusting the negative assertion above")
+
+
+# ------------------------------------ fsdp moves the weights, not the batch
+
+def _pinned_step_and_args(mesh_cfg, rows=24):
+    """`train_step` pinned as the trainer pins it, with a state and a
+    batch on the mesh. 24 rows: no width of the tiny model is 24, so a
+    result with 24 rows is the batch and nothing else."""
+    import dataclasses
+
+    from proteinbert_tpu.parallel.sharding import pin_state_sharding
+
+    cfg = cfg_for(mesh_cfg)
+    cfg = dataclasses.replace(
+        cfg, data=dataclasses.replace(cfg.data, batch_size=rows))
+    mesh = make_mesh(mesh_cfg, jax.devices()[:mesh_cfg.num_devices])
+    state = shard_train_state(
+        create_train_state(jax.random.PRNGKey(0), cfg), mesh)
+    bsh = batch_sharding(mesh)
+    batch = {k: jax.device_put(v, bsh[k]) for k, v in make_batch(cfg).items()}
+    step = pin_state_sharding(train_step, state, static_argnums=2)
+    return step, state, batch, cfg, mesh
+
+
+def _census_of(step, state, batch, cfg):
+    from proteinbert_tpu.obs.tracing import collective_census
+
+    return collective_census(
+        step.lower(state, batch, cfg).compile().as_text(),
+        batch["tokens"].shape[0],
+        [leaf.shape for leaf in jax.tree.leaves(state.params)])
+
+
+def _assert_moves_weights_not_batch(census, state, mesh):
+    assert census["activation"] == 0, census
+    specs = jax.tree.leaves(
+        state_sharding(mesh, jax.eval_shape(lambda: state)).params["blocks"])
+    split = sum("fsdp" in jax.tree.leaves(tuple(s.spec)) for s in specs)
+    assert split and census["parameter_gathers"] >= split, (split, census)
+
+
+FSDP_MESHES = pytest.mark.parametrize(
+    "mesh_cfg", [MeshConfig(fsdp=4), MeshConfig(data=2, fsdp=2)],
+    ids=["fsdp4", "data2-fsdp2"])
+
+
+@requires_8
+@FSDP_MESHES
+def test_fsdp_step_gathers_weights_and_no_activation(mesh_cfg):
+    """The compiled step of an fsdp mesh brings no activation together
+    over the chips (no collective whose result has the global row count)
+    and all-gathers every sharded block weight (PR 29)."""
+    step, state, batch, cfg, mesh = _pinned_step_and_args(mesh_cfg)
+    _assert_moves_weights_not_batch(
+        _census_of(step, state, batch, cfg), state, mesh)
+
+
+@requires_8
+@FSDP_MESHES
+def test_fsdp_census_catches_a_step_that_gathers_the_batch(mesh_cfg, monkeypatch):
+    """The positive control: with the activations left unpinned and the
+    weights left where they are stored, the partitioner gathers the
+    batch (what the tree did before PR 29), and the assertion of the
+    test above must FAIL on that step."""
+    from proteinbert_tpu.parallel import sharding
+
+    monkeypatch.setattr(sharding, "pin_to_batch_layout",
+                        lambda x, positions=None: x)
+    monkeypatch.setattr(sharding, "gathered_over_fsdp", lambda tree: tree)
+    jax.clear_caches()      # the pinned trace of the same step is cached
+    try:
+        step, state, batch, cfg, mesh = _pinned_step_and_args(mesh_cfg)
+        census = _census_of(step, state, batch, cfg)
+    finally:
+        jax.clear_caches()
+    assert census["activation"] > 0, census
+    with pytest.raises(AssertionError):
+        _assert_moves_weights_not_batch(census, state, mesh)
+
+
+def _lowered_text(fn, *args):
+    """The lowering of a FRESH jit of `fn` (nothing of an earlier trace
+    is found again), as text."""
+    return jax.jit(lambda *a: fn(*a)).lower(*args).as_text()
+
+
+@pytest.mark.parametrize("program", ["train_step", "_packed_encode_batch"])
+def test_no_mesh_program_is_untouched_by_the_pin(program, monkeypatch):
+    """With no mesh the helper adds nothing: the one-chip cells' programs
+    lower to the same text, byte for byte, as with the helper patched to
+    identity."""
+    from proteinbert_tpu import inference
+    from proteinbert_tpu.parallel import sharding
+
+    cfg = cfg_for(MeshConfig())
+    state = create_train_state(jax.random.PRNGKey(0), cfg)
+    if program == "train_step":
+        batch = make_batch(cfg)
+        fn = lambda s, b: train_step.__wrapped__(s, b, cfg)      # noqa: E731
+        args = (state, batch)
+    else:
+        rows, S = 4, 3
+        seg = np.repeat(np.arange(1, S + 1), 10)[None].repeat(rows, 0)
+        seg = np.pad(seg, ((0, 0), (0, 2))).astype(np.int32)
+        tokens = np.where(seg > 0, 5, 0).astype(np.int32)
+        ann = np.zeros((rows, S, cfg.model.num_annotations), np.float32)
+        fn = lambda p, t, s, a: inference._packed_encode_batch.__wrapped__(  # noqa: E731
+            p, t, s, a, cfg.model)
+        args = (state.params, tokens, seg, ann)
+    with_pin = _lowered_text(fn, *args)
+    monkeypatch.setattr(sharding, "pin_to_batch_layout",
+                        lambda x, positions=None: x)
+    monkeypatch.setattr(sharding, "gathered_over_fsdp", lambda tree: tree)
+    assert _lowered_text(fn, *args) == with_pin
+    assert "sharding_constraint" not in with_pin and "Sharding" not in with_pin

@@ -230,6 +230,72 @@ def _names(paths):
     return {n for p in paths for n in re.findall(r"[A-Za-z_]\w*", p)}
 
 
+# Two loops as the two compilers write them: the CPU's names its trips
+# on the `while`, the TPU's leaves them to the condition's constant. In
+# the first a parameter travels (a slice of a (12, 9, 64, 64) stack,
+# gathered asynchronously: the start's result is (operand, result)); in
+# the second the batch (24 rows global, 6 a chip) is brought together.
+CENSUS_HLO = """
+HloModule jit_step
+
+%sum (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %add = f32[] add(%a, %b)
+}
+
+%cond.tpu (p: (s32[], bf16[6,32,64])) -> pred[] {
+  %p = (s32[], bf16[6,32,64]) parameter(0)
+  %i = s32[]{:T(128)} get-tuple-element(%p), index=0
+  %n = s32[]{:T(128)} constant(12)
+  ROOT %lt = pred[] compare(%i, %n), direction=LT
+}
+
+%body.tpu (p.1: (s32[], bf16[6,32,64])) -> (s32[], bf16[6,32,64]) {
+  %p.1 = (s32[], bf16[6,32,64]) parameter(0)
+  %x = bf16[6,32,64]{2,1,0:T(8,128)(2,1)} get-tuple-element(%p.1), index=1
+  %ag = bf16[24,32,64]{2,1,0:T(8,128)(2,1)} all-gather(%x), channel_id=1, dimensions={0}
+  %ar = (f32[24,64]{1,0}, f32[64]{0}) all-reduce(%y, %z), channel_id=2, to_apply=%sum
+  ROOT %t = (s32[], bf16[6,32,64]) tuple(%i.1, %x)
+}
+
+%cond.cpu (q: (s32[], f32[9,64,16])) -> pred[] {
+  %q = (s32[], f32[9,64,16]) parameter(0)
+  ROOT %lt.1 = pred[] compare(%j, %m), direction=LT
+}
+
+%body.cpu (q.1: (s32[], f32[9,64,16])) -> (s32[], f32[9,64,16]) {
+  %q.1 = (s32[], f32[9,64,16]) parameter(0)
+  %w = f32[1,9,64,16]{3,2,1,0} get-tuple-element(%q.1), index=1
+  %ags = (f32[1,9,64,16]{3,2,1,0}, f32[1,9,64,64]{3,2,1,0}) all-gather-start(%w), channel_id=3, dimensions={3}
+  %agd = f32[1,9,64,64]{3,2,1,0} all-gather-done(%ags)
+  ROOT %t.1 = (s32[], f32[9,64,16]) tuple(%j.1, %w)
+}
+
+ENTRY %main (s: f32[12,9,64,16], b: bf16[6,32,64]) -> f32[] {
+  %w.1 = (s32[], bf16[6,32,64]) while(%init), condition=%cond.tpu, body=%body.tpu
+  %w.2 = (s32[], f32[9,64,16]) while(%init.1), condition=%cond.cpu, body=%body.cpu, backend_config={"known_trip_count":{"n":"5"}}
+  ROOT %loss = f32[] all-reduce(%l), channel_id=4, to_apply=%sum
+}
+"""
+
+
+def test_collective_census_counts_by_result_and_by_trip():
+    census = tracing.collective_census(
+        CENSUS_HLO, rows=24, parameter_shapes=[(12, 9, 64, 64), (12, 64)])
+    assert census["by_kind"] == {
+        "all-gather": {"count": 12 + 5,
+                       "bytes": 12 * 24 * 32 * 64 * 2 + 5 * 9 * 64 * 64 * 4},
+        "all-reduce": {"count": 12 + 1, "bytes": 12 * (24 * 64 + 64) * 4 + 4}}
+    assert census["activation"] == 24       # the gather and the reduce, 12 trips
+    assert census["activation_shapes"] == [[24, 32, 64], [24, 64]]
+    assert census["parameter"] == census["parameter_gathers"] == 5
+    assert census["bytes"] == sum(
+        kind["bytes"] for kind in census["by_kind"].values())
+    # The same module under another batch size: nothing has that many rows.
+    assert tracing.collective_census(CENSUS_HLO, 48, [])["activation"] == 0
+
+
 def test_program_scopes_of_the_tiny_train_step_name_every_scope():
     from proteinbert_tpu.train import train_state as ts
 

@@ -57,7 +57,7 @@ class CheckConfig:
 
     root: str
     # Directories/files (repo-relative) the AST rules scan.
-    scan_roots: Tuple[str, ...] = ("proteinbert_tpu", "tools", "bench.py")
+    scan_roots: Tuple[str, ...] = ("proteinbert_tpu", "tools")
     # Files under the tmp→fsync→rename durability contract (rule 3).
     durability_files: Tuple[str, ...] = (
         "proteinbert_tpu/mapper/store.py",
@@ -73,7 +73,6 @@ class CheckConfig:
     # (rule 6) — tests/examples legitimately keep an export alive.
     reference_roots: Tuple[str, ...] = (
         "proteinbert_tpu", "tools", "tests", "examples", "experiments",
-        "bench.py",
     )
     # Functions allowed to read os.environ at trace time (rule 1): the
     # documented trace-time readers, e.g. PBT_FORCE_REFERENCE_KERNEL's.

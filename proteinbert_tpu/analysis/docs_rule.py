@@ -113,8 +113,8 @@ def _doc_metrics(text: str) -> Dict[str, str]:
 
 def _registered_metrics(ctx: CheckContext) -> Dict[str, Tuple[str, int]]:
     """{literal instrument name: (file, line)} across the scanned
-    PACKAGE roots (tools/bench are deliberately excluded — their
-    ad-hoc instruments are capture plumbing, not operator surface):
+    PACKAGE roots (tools/ is deliberately excluded — a drill's ad-hoc
+    instruments are not operator surface):
     registry-method calls plus the KernelPathCounter shim's
     metric-name argument."""
     pkg_roots = tuple(r.rstrip("/") + "/" for r in ctx.cfg.scan_roots

@@ -5,7 +5,7 @@ Every package `__init__.py` re-exports its public surface (plus
 re-export lingers, advertising API that nothing exercises and that no
 test would catch breaking. This rule flags any exported name that is
 referenced NOWHERE else in the repo — not in the package, not in
-tools/tests/examples/bench.
+tools, tests or examples.
 
 Matching is identifier-based and deliberately coarse (any `Name`,
 `Attribute` attr, or import of the same identifier anywhere counts as

@@ -125,9 +125,8 @@ def report_dict(new: List[Finding], suppressed: List[Finding],
                 rules_run: List[str],
                 errors: Optional[List[str]] = None) -> Dict[str, Any]:
     """The `pbt check --json` artifact. `check_findings_total` counts
-    new + suppressed — the series `tools/bench_trajectory.py` fits, so
-    suppression creep moves the trajectory even while the gate is
-    green."""
+    new + suppressed: it grows with every suppression added to
+    `tools/check_baseline.json`, the file that gates it."""
     return {
         "v": 1,
         "kind": "pbt_check_report",

@@ -90,17 +90,11 @@ def main(argv: Optional[List[str]] = None,
     ap.add_argument("--json", action="store_true",
                     help="print the machine-readable report to stdout")
     ap.add_argument("--json-artifact", default=None, metavar="PATH",
-                    help="ALSO write the JSON report here (the "
-                         "bench-trajectory check_findings_total input)")
+                    help="ALSO write the JSON report here")
     ap.add_argument("--write-baseline", action="store_true",
                     help="write every current finding into the "
                          "baseline file (reasons stubbed for human "
                          "review) and exit 0")
-    ap.add_argument("--events-jsonl", default=None, metavar="PATH",
-                    help="mirror the counts as a note(kind="
-                         "check_capture) event on this stream — the "
-                         "trajectory sentinel's suppression-creep "
-                         "series")
     args = ap.parse_args(argv)
 
     root = os.path.abspath(args.root)
@@ -146,22 +140,6 @@ def main(argv: Optional[List[str]] = None,
     new, suppressed, stale = split_by_baseline(findings, baseline)
     report = report_dict(new, suppressed, stale, baseline,
                          result["rules"], errors=result["errors"])
-    if args.events_jsonl:
-        # obs.events is stdlib-only, so this stays jax-free under the
-        # tools/pbt_check.py stub-package import.
-        from proteinbert_tpu.obs.events import EventLog
-
-        ev = EventLog(args.events_jsonl)
-        # platform="static" keys the same trajectory series
-        # ("check_findings_total/static") as the fresh --check-json
-        # artifact point, so checked-in history and the tier-1 run's
-        # point accumulate into ONE judged series.
-        ev.emit("note", source="pbt_check", kind="check_capture",
-                platform="static",
-                check_findings_total=report["counts"][
-                    "check_findings_total"],
-                check_baselined_total=report["counts"]["baselined"])
-        ev.close()
     if args.json_artifact:
         with open(args.json_artifact, "w") as f:
             json.dump(report, f, indent=1)

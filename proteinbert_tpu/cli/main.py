@@ -762,9 +762,8 @@ def cmd_eval_heads(args) -> int:
     """Downstream eval harness (ISSUE 8): score registered task heads
     against the resident trunk — per-residue accuracy / accuracy +
     AUC proxy / Spearman by task kind (heads/eval.py) — emitting one
-    schema-versioned `head_eval` event per head so finetune-quality
-    regressions gate through the bench-trajectory sentinel like perf
-    does. One JSON line per head on stdout."""
+    schema-versioned `head_eval` event per head. One JSON line per
+    head on stdout."""
     import numpy as np
 
     from proteinbert_tpu.heads import HeadRegistry, trunk_fingerprint
@@ -1631,8 +1630,6 @@ def cmd_check(args) -> int:
         argv.extend(["--json-artifact", args.json_artifact])
     for rule in args.rule or ():
         argv.extend(["--rule", rule])
-    if args.events_jsonl:
-        argv.extend(["--events-jsonl", args.events_jsonl])
     if args.baseline:
         argv.extend(["--baseline", args.baseline])
     if args.root:
@@ -2414,8 +2411,7 @@ def build_parser() -> argparse.ArgumentParser:
                          "enforced)")
     sv.add_argument("--nprobe", type=int, default=8,
                     help="with --index: centroid lists probed per "
-                         "query — the recall/latency dial (recall "
-                         "gate: bench.py --neighbors)")
+                         "query — the recall/latency dial")
     sv.set_defaults(fn=cmd_serve)
 
     mp = sub.add_parser("map",
@@ -2619,14 +2615,9 @@ def build_parser() -> argparse.ArgumentParser:
     ck.add_argument("--json", action="store_true",
                     help="machine-readable report on stdout")
     ck.add_argument("--json-artifact", type=creatable_path,
-                    help="also write the JSON report here (the "
-                         "bench-trajectory check_findings_total input)")
+                    help="also write the JSON report here")
     ck.add_argument("--rule", action="append", metavar="NAME",
                     help="run only this rule (repeatable)")
-    ck.add_argument("--events-jsonl", type=creatable_path,
-                    help="mirror the counts as a note(kind="
-                         "check_capture) event — the trajectory "
-                         "sentinel's suppression-creep series")
     ck.add_argument("--baseline",
                     help="suppression baseline JSON (default: "
                          "tools/check_baseline.json)")

@@ -45,9 +45,9 @@ class ModelConfig:
     scan_unroll: int = 1                # lax.scan unroll factor: XLA sees k
                                         # block bodies per iteration and can
                                         # keep activation layouts across
-                                        # them (the scan-boundary transposes
-                                        # are a measured cost,
-                                        # docs/performance.md); full unroll
+                                        # them (the scan's saves are a
+                                        # measured cost, PERF.md section
+                                        # 5); full unroll
                                         # (scan_blocks=False) is compile-
                                         # prohibitive at real sizes
     scan_split_transpose: bool = False  # lax.scan(_split_transpose=True):
@@ -268,7 +268,7 @@ class ParallelConfig:
       deterministic and multi-host lockstep, ~4x fewer wire bytes) —
       with the optimizer math fp32 on the dequantized shards and the
       clip norm measured on the dequantized sum. Wire bytes are
-      verified from compiled HLO (bench.py --comm,
+      verified from compiled HLO (tests/test_zero.py,
       zero.collective_wire_bytes_from_hlo); parity bounds are measured
       in tests/test_quant.py and documented in docs/distributed.md.
       Quantized payloads need a data/fsdp-only mesh (model>1 or seq>1

@@ -25,7 +25,7 @@ in one micro-batch, swapping only the cheap head matmuls.
 Producers: `train/finetune.finetune(..., registry=)` and the
 `pbt finetune --register-head` CLI. Consumers: the serving layer
 (`serve/dispatch.py` dynamic head kinds, `Server.predict_task`),
-`pbt eval-heads`, and `bench.py --heads`. docs/finetuning.md walks the
+and `pbt eval-heads`. docs/finetuning.md walks the
 train → register → serve → eval loop end to end.
 """
 

@@ -1,11 +1,8 @@
 """Downstream-task eval harness (ISSUE 8): score registered heads.
 
-Finetune QUALITY must gate like perf does: every eval produces a
-schema-versioned `head_eval` event (obs/events.py) on the shared
-telemetry stream, and `bench.py --heads` mirrors the aggregate score
-onto `bench_events.jsonl` where the trajectory sentinel
-(tools/bench_trajectory.py) fits noise bands over history — a silent
-finetune regression then surfaces exactly like a throughput regression.
+Every eval produces a schema-versioned `head_eval` event
+(obs/events.py) on the shared telemetry stream, one a head, so a
+finetune's quality is on the record beside the run that made it.
 
 Per-task metrics (the ProteinBERT paper's benchmark shapes):
 

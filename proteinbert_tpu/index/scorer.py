@@ -19,10 +19,8 @@ per-request recompilation. The int8 dequant (codes × scales) happens
 INSIDE the executable, so host memory keeps the small form.
 
 Exact brute-force helpers (`exact_topk`, `evaluate_recall`) live here
-too: the recall@k gate in bench.py --neighbors and the
-quantized-vs-fp32 bound in tests/test_index.py both score against
-them, and `evaluate_recall` is what feeds the `neighbors_recall_at_k`
-gauge.
+too: the recall@k bound in tests/test_index.py scores against them,
+and `evaluate_recall` is what feeds the `neighbors_recall_at_k` gauge.
 """
 
 from __future__ import annotations

@@ -39,8 +39,8 @@ int8-quantized RESIDUAL (v̂ − centroid) with per-channel symmetric
 scales per block (`parallel.quant.quantize_rows_int8` — the same
 amax/127 round-to-nearest convention as the int8 serving trunk). At
 ~1 byte/channel + one fp32 scale row per block the index holds ≤0.30×
-the fp32 vector bytes while recall@10 stays ≥0.95 (gated in
-bench.py --neighbors).
+the fp32 vector bytes while recall@10 stays ≥0.95 (both held by
+tests/test_index.py).
 
 Stdlib + numpy at module level (the jax-free verify contract of
 mapper/store.py); the quantizer import is deferred into the build path.

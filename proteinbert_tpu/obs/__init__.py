@@ -17,9 +17,9 @@ Four cooperating pieces behind one `Telemetry` facade:
 - **flight** — a bounded ring of the last N event records, dumped to
   `flight_<pid>.json` on SIGTERM / NaN-halt / unhandled exception.
 
-Consumers: `pbt diagnose` (obs/diagnose.py), `tools/validate_events.py`,
-the benchmark's per-layer readers (`benchmark/span_readers.py`), and
-`bench.py` (note events on the same stream). docs/observability.md documents the schema and conventions.
+Consumers: `pbt diagnose` (obs/diagnose.py), `tools/validate_events.py`
+and the benchmark's per-layer readers (`benchmark/span_readers.py`).
+docs/observability.md documents the schema and conventions.
 
 Overhead contract: `NULL` (the default when no telemetry is passed) is
 a do-nothing facade — `emit` returns None, `spans` is None, `metrics`

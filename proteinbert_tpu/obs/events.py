@@ -51,8 +51,8 @@ EVENT_FIELDS: Dict[str, Dict[str, Any]] = {
     "nan_halt": {"step": int, "metrics": dict},
     # Terminal record; outcome in OUTCOMES, perf is StepTimer.summary().
     "run_end": {"outcome": str, "perf": dict},
-    # Generic annotated event for tools (bench, the drills) that share
-    # the stream format without being training runs.
+    # Generic annotated event: the server's head add / remove / abort,
+    # the checkpointer's `restore_fallback`, the drills' own marks.
     "note": {"source": str},
     # ---- online serving lifecycle (proteinbert_tpu/serve/) ----
     # Server manifest: serving config (buckets, batch classes, queue
@@ -91,11 +91,10 @@ EVENT_FIELDS: Dict[str, Dict[str, Any]] = {
     # TaskConfig kind. Extra fields: name, trunk_fingerprint, metrics.
     "head_registered": {"head_id": str, "kind": str},
     # One downstream-task eval of a registered head (heads/eval.py,
-    # `pbt eval-heads`, bench.py --heads). `metrics` carries the
-    # per-task numbers (per_residue_accuracy / accuracy+auc_proxy /
-    # spearman+mse) plus a normalized `score` — the series the bench-
-    # trajectory sentinel fits so finetune-quality regressions gate
-    # like perf does. Extra fields: kind, name.
+    # `pbt eval-heads`). `metrics` carries the per-task numbers
+    # (per_residue_accuracy / accuracy+auc_proxy / spearman+mse) plus a
+    # normalized `score` in [0, 1], higher is better. Extra fields:
+    # kind, name.
     "head_eval": {"head_id": str, "metrics": dict},
     # ---- elastic topology (ISSUE 11) ----
     # One checkpoint resharded onto a new mesh layout
@@ -689,64 +688,6 @@ def validate_record(rec: Any) -> None:
                               or isinstance(n, bool) or n < 0):
             raise ValueError(f"rollout_fleet.fingerprints must be a "
                              f"non-negative int, got {n!r}")
-    if event == "note" and rec.get("kind") == "rollout_capture":
-        # The rollout drill capture (tools/rollout_drill.py): worst
-        # shadow parity through the good candidate + the atomic-flip
-        # latency are trajectory-sentinel inputs (both lower-is-
-        # better), so a writer bug must fail validation, not poison
-        # the series.
-        for name in ("rollout_shadow_parity_max", "rollout_flip_seconds"):
-            v = rec.get(name)
-            if v is None:
-                raise ValueError(
-                    f"note(kind=rollout_capture): missing required "
-                    f"field {name!r}")
-            if (isinstance(v, bool) or not isinstance(v, (int, float))
-                    or not math.isfinite(v) or v < 0):
-                raise ValueError(
-                    f"note(kind=rollout_capture).{name} must be a "
-                    f"non-negative finite number, got {v!r}")
-    if event == "note" and rec.get("kind") == "map_capture":
-        # The map-throughput capture (tools/map_drill.py --bench-events):
-        # its rate field is a trajectory-sentinel input, so a writer bug
-        # must fail validation, not poison the series.
-        v = rec.get("map_seqs_per_s")
-        if v is None:
-            raise ValueError(
-                "note(kind=map_capture): missing required field "
-                "'map_seqs_per_s'")
-        if (isinstance(v, bool) or not isinstance(v, (int, float))
-                or not math.isfinite(v) or v <= 0):
-            raise ValueError(
-                f"note(kind=map_capture).map_seqs_per_s must be a "
-                f"positive finite number, got {v!r}")
-        # Pipelined-mapper overlap evidence (ISSUE 19): the share of
-        # host fetch+commit seconds spent with a later block's device
-        # compute enqueued — a ratio, so [0, 1] by construction.
-        r = rec.get("map_overlap_ratio")
-        if r is not None and (isinstance(r, bool)
-                              or not isinstance(r, (int, float))
-                              or not math.isfinite(r)
-                              or not 0.0 <= r <= 1.0):
-            raise ValueError(
-                f"note(kind=map_capture).map_overlap_ratio must be a "
-                f"number in [0, 1], got {r!r}")
-    if event == "note" and rec.get("kind") == "check_capture":
-        # The static-analyzer capture (`pbt check --events-jsonl`,
-        # ISSUE 15): check_findings_total (new + baselined findings) is
-        # the trajectory sentinel's suppression-creep series, so a
-        # writer bug must fail validation, not poison the series.
-        for name in ("check_findings_total", "check_baselined_total"):
-            v = rec.get(name)
-            if name == "check_findings_total" and v is None:
-                raise ValueError(
-                    "note(kind=check_capture): missing required field "
-                    "'check_findings_total'")
-            if v is not None and (not isinstance(v, int)
-                                  or isinstance(v, bool) or v < 0):
-                raise ValueError(
-                    f"note(kind=check_capture).{name} must be a "
-                    f"non-negative int, got {v!r}")
     if event == "note" and rec.get("kind") == "restore_fallback":
         # The checkpointer's torn-final-checkpoint fallback report
         # (train/checkpoint.py): bad_step (the skipped torn step) is
@@ -763,174 +704,6 @@ def validate_record(rec: Any) -> None:
             raise ValueError(
                 f"note(kind=restore_fallback).landed_step must be a "
                 f"non-negative int, got {ls!r}")
-    if event == "note" and rec.get("kind") == "comm_quant":
-        # The quantized-collectives capture (bench.py --comm, ISSUE
-        # 12): its ratio fields are the trajectory-sentinel inputs, so
-        # a writer bug must fail validation, not poison the series.
-        for name in ("int8_grad_wire_ratio", "bf16_grad_wire_ratio"):
-            v = rec.get(name)
-            if name == "int8_grad_wire_ratio" and v is None:
-                raise ValueError(
-                    "note(kind=comm_quant): missing required field "
-                    "'int8_grad_wire_ratio'")
-            if v is not None and (isinstance(v, bool)
-                                  or not isinstance(v, (int, float))
-                                  or not math.isfinite(v) or v <= 0):
-                raise ValueError(
-                    f"note(kind=comm_quant).{name} must be a positive "
-                    f"finite number, got {v!r}")
-    if event == "note" and rec.get("kind") == "pack_attn_capture":
-        # The ragged-attention A/B capture (bench.py --pack, ISSUE 13):
-        # its speedup/MFU fields feed trajectory-sentinel series, so a
-        # writer bug must fail validation, not poison the series.
-        v = rec.get("attn_speedup_x")
-        if v is None:
-            raise ValueError(
-                "note(kind=pack_attn_capture): missing required field "
-                "'attn_speedup_x'")
-        if (isinstance(v, bool) or not isinstance(v, (int, float))
-                or not math.isfinite(v) or v <= 0):
-            raise ValueError(
-                f"note(kind=pack_attn_capture).attn_speedup_x must be "
-                f"a positive finite number, got {v!r}")
-        for name in ("mfu_effective", "mfu_raw", "parity_max_abs_diff"):
-            v = rec.get(name)
-            if v is not None and (isinstance(v, bool)
-                                  or not isinstance(v, (int, float))
-                                  or not math.isfinite(v) or v < 0):
-                raise ValueError(
-                    f"note(kind=pack_attn_capture).{name} must be a "
-                    f"non-negative finite number, got {v!r}")
-    if event == "note" and rec.get("kind") == "onepass_capture":
-        # The one-pass trunk A/B capture (bench.py --pack, ISSUE 16):
-        # single fused block-pass kernel vs the two-kernel composition.
-        # Its speedup/MFU fields feed trajectory-sentinel series, so a
-        # writer bug must fail validation, not poison the series.
-        v = rec.get("onepass_speedup_x")
-        if v is None:
-            raise ValueError(
-                "note(kind=onepass_capture): missing required field "
-                "'onepass_speedup_x'")
-        if (isinstance(v, bool) or not isinstance(v, (int, float))
-                or not math.isfinite(v) or v <= 0):
-            raise ValueError(
-                f"note(kind=onepass_capture).onepass_speedup_x must be "
-                f"a positive finite number, got {v!r}")
-        for name in ("mfu_effective", "mfu_raw", "parity_max_abs_diff"):
-            v = rec.get(name)
-            if v is not None and (isinstance(v, bool)
-                                  or not isinstance(v, (int, float))
-                                  or not math.isfinite(v) or v < 0):
-                raise ValueError(
-                    f"note(kind=onepass_capture).{name} must be a "
-                    f"non-negative finite number, got {v!r}")
-    if event == "note" and rec.get("kind") == "fleet_trace_capture":
-        # The fleet-propagation overhead A/B (bench.py --serve fleet
-        # arm, ISSUE 18): routed-throughput delta with trace
-        # propagation on vs off. The pct is a trajectory-sentinel
-        # input (lower-is-better), so a writer bug must fail
-        # validation, not poison the series. It is a DIFFERENCE, so
-        # negative values (measurement noise) are legal — finiteness
-        # is the bound.
-        v = rec.get("fleet_trace_overhead_pct")
-        if v is None:
-            raise ValueError(
-                "note(kind=fleet_trace_capture): missing required "
-                "field 'fleet_trace_overhead_pct'")
-        if (isinstance(v, bool) or not isinstance(v, (int, float))
-                or not math.isfinite(v)):
-            raise ValueError(
-                f"note(kind=fleet_trace_capture).fleet_trace_overhead_"
-                f"pct must be a finite number, got {v!r}")
-        for name in ("fleet_rps_on", "fleet_rps_off"):
-            v = rec.get(name)
-            if v is not None and (isinstance(v, bool)
-                                  or not isinstance(v, (int, float))
-                                  or not math.isfinite(v) or v <= 0):
-                raise ValueError(
-                    f"note(kind=fleet_trace_capture).{name} must be a "
-                    f"positive finite number, got {v!r}")
-        # ISSUE 19 satellite: the pct is the MEDIAN over this many A/B
-        # rounds (the PR 18 single-round number sign-flipped under
-        # load); typed when present so the sentinel can trust it.
-        n = rec.get("rounds")
-        if n is not None and (not isinstance(n, int)
-                              or isinstance(n, bool) or n < 1):
-            raise ValueError(
-                f"note(kind=fleet_trace_capture).rounds must be a "
-                f"positive int, got {n!r}")
-    if event == "note" and rec.get("kind") == "neighbors_capture":
-        # The ANN serving capture (bench.py --neighbors, ISSUE 17):
-        # its QPS and recall fields feed trajectory-sentinel series
-        # (recall is HIGHER-is-better), so a writer bug must fail
-        # validation, not poison the series.
-        for name in ("neighbors_qps", "neighbors_recall_at_10"):
-            v = rec.get(name)
-            if v is None:
-                raise ValueError(
-                    f"note(kind=neighbors_capture): missing required "
-                    f"field {name!r}")
-        v = rec.get("neighbors_qps")
-        if (isinstance(v, bool) or not isinstance(v, (int, float))
-                or not math.isfinite(v) or v <= 0):
-            raise ValueError(
-                f"note(kind=neighbors_capture).neighbors_qps must be "
-                f"a positive finite number, got {v!r}")
-        r = rec.get("neighbors_recall_at_10")
-        if (isinstance(r, bool) or not isinstance(r, (int, float))
-                or not math.isfinite(r) or not 0.0 <= r <= 1.0):
-            raise ValueError(
-                f"note(kind=neighbors_capture).neighbors_recall_at_10 "
-                f"must be a number in [0, 1], got {r!r}")
-        for name in ("embed_qps", "neighbors_qps_ratio",
-                     "index_bytes_ratio"):
-            v = rec.get(name)
-            if v is not None and (isinstance(v, bool)
-                                  or not isinstance(v, (int, float))
-                                  or not math.isfinite(v) or v <= 0):
-                raise ValueError(
-                    f"note(kind=neighbors_capture).{name} must be a "
-                    f"positive finite number, got {v!r}")
-    if event == "note" and rec.get("kind") == "serve_pipeline_capture":
-        # The pipelined-dispatch A/B capture (bench.py --serve pipeline
-        # phase, ISSUE 19): depth-2 vs depth-1 served throughput, gated
-        # on async-vs-sync output bit-parity and exactly-once sealing
-        # under drain with work in flight. The speedup is a trajectory-
-        # sentinel input, so a writer bug must fail validation, not
-        # poison the series.
-        v = rec.get("serve_pipeline_speedup_x")
-        if v is None:
-            raise ValueError(
-                "note(kind=serve_pipeline_capture): missing required "
-                "field 'serve_pipeline_speedup_x'")
-        if (isinstance(v, bool) or not isinstance(v, (int, float))
-                or not math.isfinite(v) or v <= 0):
-            raise ValueError(
-                f"note(kind=serve_pipeline_capture)."
-                f"serve_pipeline_speedup_x must be a positive finite "
-                f"number, got {v!r}")
-        for name in ("pipeline_rps", "serial_rps"):
-            v = rec.get(name)
-            if v is not None and (isinstance(v, bool)
-                                  or not isinstance(v, (int, float))
-                                  or not math.isfinite(v) or v <= 0):
-                raise ValueError(
-                    f"note(kind=serve_pipeline_capture).{name} must be "
-                    f"a positive finite number, got {v!r}")
-        r = rec.get("serve_overlap_ratio")
-        if r is not None and (isinstance(r, bool)
-                              or not isinstance(r, (int, float))
-                              or not math.isfinite(r)
-                              or not 0.0 <= r <= 1.0):
-            raise ValueError(
-                f"note(kind=serve_pipeline_capture).serve_overlap_"
-                f"ratio must be a number in [0, 1], got {r!r}")
-        im = rec.get("inflight_max")
-        if im is not None and (not isinstance(im, int)
-                               or isinstance(im, bool) or im < 0):
-            raise ValueError(
-                f"note(kind=serve_pipeline_capture).inflight_max must "
-                f"be a non-negative int, got {im!r}")
 
 
 def make_example(event: str) -> Dict[str, Any]:

@@ -27,7 +27,7 @@ then OURS to quantize:
     — deterministic and multi-host lockstep by construction)
   → `all_to_all` the payloads (THIS is the wire: int8 moves ~4x fewer
     bytes than fp32, bf16 2x — verified from compiled HLO by
-    `zero.collective_wire_bytes_from_hlo`, bench.py --comm)
+    `zero.collective_wire_bytes_from_hlo`, tests/test_zero.py)
   → dequantize + sum the n received slices = this replica's shard of
     the summed gradient
   → the SHARED optimizer-apply (train_state.gradient_update) on the
@@ -39,9 +39,10 @@ small replicated remainder of zero_update_spec's fallback) reduce by
 plain fp32 psum — honest bytes, negligible share. The gradient-clip
 norm is measured on the DEQUANTIZED summed gradient (the tensor the
 optimizer actually consumes). `payload="fp32"` runs the identical
-explicit reduce-scatter without rounding — the measurement baseline
-bench.py --comm compares the quantized wire against, and the isolation
-control for parity tests (harness error vs quantization error).
+explicit reduce-scatter without rounding — the baseline
+tests/test_zero.py compares the quantized wire against, and the
+isolation control for parity tests (harness error vs quantization
+error).
 
 Restrictions (typed `QuantConfigError`): the explicit replica
 shard_map replicates model/seq compute, so meshes with model>1 or
@@ -59,10 +60,9 @@ need) and XLA fuses the dequant into first use. `quant="int8_act"`
 additionally fake-quantizes the trunk's output activations (dynamic
 per-tensor int8) before the output heads — the opt-in activation arm.
 Parity vs the fp32 arm is measured per request and surfaced
-(serve/dispatch.py parity sampling, `serve_quant_parity_max`), and the
-`heads_eval_score_min` downstream sentinel gates the quantized arm in
-bench.py --heads so quantization can never silently degrade task
-accuracy.
+(serve/dispatch.py parity sampling, `serve_quant_parity_max`), and
+tests/test_heads.py holds the heads' downstream scores on the
+quantized trunk within 0.1 of the fp32 trunk's.
 """
 
 from __future__ import annotations

@@ -38,7 +38,7 @@ data-parallel shard_map producing per-replica partial gradients, and
 the reduction consumes quantized payloads — bf16 (stochastic
 rounding) or int8 (per-chunk scale + stochastic rounding) — so the
 wire really moves 2x/4x fewer bytes (verified from compiled HLO by
-`collective_wire_bytes_from_hlo`, bench.py --comm). The
+`collective_wire_bytes_from_hlo`, tests/test_zero.py). The
 `zero_gradient_update` function below keeps the PR-2 cast-only bf16
 behavior for the explicit seq-parallel step, whose shard_map computes
 grads itself: there the cast applies to already-reduced gradients and
@@ -263,9 +263,10 @@ def _iter_collectives(hlo_text: str):
 
 def collective_bytes_from_hlo(hlo_text: str) -> Dict[str, int]:
     """Per-collective output bytes of one compiled (per-device) HLO
-    module — the recorded evidence behind the comm claims (`bench.py
-    --comm`); under SPMD the module is the per-chip program, so shapes
-    are per-chip shapes. The 'total' key sums every kind."""
+    module — the evidence behind the comm claims (tests/test_zero.py
+    reads it per reduction mode); under SPMD the module is the per-chip
+    program, so shapes are per-chip shapes. The 'total' key sums every
+    kind."""
     out: Dict[str, int] = {k: 0 for k in _COLLECTIVES}
     for kind, nbytes, _ in _iter_collectives(hlo_text):
         out[kind] += nbytes
@@ -329,7 +330,7 @@ def grad_reduce_wire_bytes(wire: Dict[str, int]) -> int:
     collectives a grad reduction can lower to (reduce-scatter under
     implicit SPMD on TPU, all-reduce on backends that fuse the slice,
     all-to-all in the explicit quantized step) — the single number the
-    int8-vs-fp32 ratio gate compares (bench.py --comm,
+    int8-vs-fp32 ratio gate compares (tests/test_zero.py,
     tools/quant_smoke.py)."""
     return (wire["reduce-scatter"] + wire["all-reduce"]
             + wire["all-to-all"])
@@ -340,7 +341,7 @@ def record_comm_metrics(registry, hlo_text: str,
     """Fold one compiled module's per-collective bytes into a telemetry
     metrics registry (obs/metrics.py) as `collective_bytes{kind=...}`
     (output bytes) and `collective_wire_bytes{kind=...}` (per-device
-    wire estimate) gauges — so `bench.py --comm` evidence and any
+    wire estimate) gauges — so the tests' evidence and any
     consumer of the unified metrics stream read the SAME accounting
     instead of a private dict. Returns the collective_bytes_from_hlo
     breakdown."""

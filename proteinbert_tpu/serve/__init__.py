@@ -44,8 +44,8 @@ ONE warm executable per request kind serves every length mix
 outputs matching the bucketed dispatcher's within the documented
 jitted ≤1e-5 tolerance (docs/serving.md, "Ragged batching").
 
-Benchmarked by `bench.py --serve` (throughput + latency percentiles vs
-the one-request-at-a-time offline baseline); documented in
+Measured on the chip by the benchmark's serve cells (`python -m
+benchmark.run --workload serve-base-sat`); documented in
 docs/serving.md.
 
 Above single servers sits the FLEET layer (ISSUE 11, `serve/fleet.py`

@@ -12,8 +12,8 @@ registry is supplied, the `serve_cache_{hits,misses,evictions}_total`
 counters plus the `serve_cache_hit_rate` gauge (docs/observability.md).
 
 Thread-safe: submit paths race against scheduler-thread inserts.
-capacity == 0 disables the cache (every get misses, puts are dropped) —
-the contract bench.py --serve uses for its no-cache comparison.
+capacity == 0 disables the cache (every get misses, puts are dropped):
+what the benchmark's serve cells run with, every sequence distinct.
 """
 
 from __future__ import annotations

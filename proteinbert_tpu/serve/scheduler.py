@@ -6,9 +6,8 @@ the standard continuous-batching contract:
 - **max_batch**: a (kind, bucket) group that reaches `max_batch`
   queued rows dispatches immediately (throughput bound);
 - **max_wait_s**: otherwise, a group dispatches when its OLDEST member
-  has waited `max_wait_s` (latency bound — p99 queueing delay is
-  bounded by max_wait + one batch time, the property bench.py --serve
-  measures).
+  has waited `max_wait_s` (latency bound — below saturation the
+  queueing delay is bounded by max_wait + one batch time).
 
 Requests group by (kind, bucket_len): only same-kind, same-bucket rows
 can share a compiled executable. Within a group, FIFO order is
@@ -127,7 +126,8 @@ class MicroBatchScheduler:
         # kind "predict_task", so one micro-batch MIXES heads through
         # the shared trunk executable. partition_heads=True appends the
         # head id to the group key instead (per-head batches) — the
-        # baseline `bench.py --heads` measures the mixed win against.
+        # sequential reference tests/test_heads.py holds the mixed
+        # batch bit-identical to.
         self.partition_heads = bool(partition_heads)
         self.tele = as_telemetry(telemetry)
         self._latency = latency_observer or (lambda s: None)

@@ -100,7 +100,7 @@ from proteinbert_tpu.serve.trace import RequestTrace, stride_sampled
 SERVE_MODES = ("bucketed", "ragged")
 
 # Default result size for `/v1/neighbors` when the request carries no
-# `k` — matches the recall gate's k (bench.py --neighbors, recall@10).
+# `k` — matches the recall gate's k (tests/test_index.py, recall@10).
 DEFAULT_NEIGHBORS_K = 10
 
 

@@ -7,8 +7,8 @@ clock (`time.monotonic` in production, a fake clock in tests), so a
 trace's stage durations are deterministic under `poll(now=)` and the
 stage decomposition is exact by construction: stages are CONTIGUOUS
 intervals between consecutive marks, so they always sum to the
-end-to-end latency (the acceptance property `bench.py --serve` checks
-on live traffic).
+end-to-end latency (tests/test_serve_trace.py checks it on a live
+server's events).
 
 Stage names, in request order:
 

@@ -42,9 +42,9 @@ def forward_flops(cfg: ModelConfig, batch: int, seq_len: int,
     padded shape: every L-proportional term — the convs, the local
     dense/head, the attention K/V/score/sum — scales with real tokens,
     since pad FLOPs produce no useful output. This is the honest
-    denominator for pad-adjusted MFU (bench.py --pack; ISSUE 4
-    satellite): a 70%-pad batch at the padded count reports an MFU
-    three times the useful-work utilisation.
+    denominator for pad-adjusted MFU (ISSUE 4 satellite): a 70%-pad
+    batch at the padded count reports an MFU three times the
+    useful-work utilisation.
     """
     B, L = batch, seq_len
     C, G, A = cfg.local_dim, cfg.global_dim, cfg.num_annotations

@@ -3,7 +3,7 @@
 - `request_cpu_devices(n)`: n virtual CPU devices for the multi-device
   tests, the child scripts and the driver's dry run.
 - `configure_compile_cache()`: where the persistent XLA compilation
-  cache lives. Called once at the top of `cli.main.main`, `bench.py`,
+  cache lives. Called once at the top of `cli.main.main`,
   `chip_smoke.py` and the test harness, so every process of a run —
   and the next run — shares one cache.
 """

@@ -68,43 +68,6 @@ class Profiler:
         )
 
 
-class BoundaryStallMeter:
-    """Per-event host-stall meter: how long a dispatch loop spent inside
-    a boundary region (checkpoint save, eval bracket) instead of
-    enqueuing device work. This is the number the overlapped-boundary
-    work optimizes — wall seconds the train stream stood still — and
-    what `bench.py --boundary` reports for the synchronous vs staged
-    checkpoint paths. Distinct from StepTimer.overlap: that accounts
-    hidden seconds inside a live training run; this measures the stall
-    itself, in isolation, for before/after comparison."""
-
-    def __init__(self):
-        self.stalls: list = []
-
-    @contextlib.contextmanager
-    def boundary(self):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.stalls.append(time.perf_counter() - t0)
-
-    def summary(self) -> Dict[str, float]:
-        n = len(self.stalls)
-        if not n:
-            return {"boundaries": 0}
-        return {
-            "boundaries": n,
-            "mean_s": sum(self.stalls) / n,
-            # The comparison statistic for small samples: one GC pause
-            # or scheduler hiccup inside a single boundary swings a
-            # 4-sample mean by 2-3x; the median holds steady.
-            "median_s": sorted(self.stalls)[n // 2],
-            "max_s": max(self.stalls),
-            "total_s": sum(self.stalls),
-        }
-
-
 @contextlib.contextmanager
 def device_trace(log_dir: str, host_profile: bool = False):
     """Capture a jax.profiler trace (XLA ops, HBM, fusion view) to

@@ -7,8 +7,10 @@ same `run_check` the tier-1 stage uses — no monkeypatching of rule
 internals, so a rule that silently stopped matching its pattern fails
 its seeded fixture here before it silently passes the repo."""
 
+import functools
 import json
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -434,6 +436,52 @@ def test_obs_doc_drift_clean_with_brace_expansion(tmp_path):
     assert res["findings"] == []
 
 
+# ----------------------------------------- paths the documents name
+
+USER_DOCS = ["README.md"] + sorted(
+    "docs/" + f for f in os.listdir(os.path.join(REPO, "docs"))
+    if f.endswith(".md"))
+PATH_ROOTS = ("proteinbert_tpu/", "tools/", "tests/", "benchmark/",
+              "examples/", "docs/")
+
+
+@functools.lru_cache(maxsize=None)
+def _known_paths():
+    """Every file git would commit, and every directory above one; a
+    checkout without its .git is walked."""
+    proc = subprocess.run(["git", "ls-files"], capture_output=True,
+                          text=True, cwd=REPO)
+    files = proc.stdout.split() if proc.returncode == 0 else []
+    if not files:
+        files = [os.path.relpath(os.path.join(d, f), REPO)
+                 for d, _, names in os.walk(REPO) for f in names]
+    known = set(files)
+    for f in files:
+        while "/" in f:
+            f = f.rsplit("/", 1)[0]
+            known.add(f)
+    return known
+
+
+@pytest.mark.parametrize("doc", USER_DOCS)
+def test_paths_a_document_names_exist(doc):
+    """A document a user reads names no file that is gone: every
+    backticked token that begins with a directory of this repo (its
+    arguments, a trailing `:line` or `::name` stripped; globs and
+    placeholders left out) is a file or directory git knows."""
+    known = _known_paths()
+    with open(os.path.join(REPO, doc)) as f:
+        tokens = re.findall(r"`([^`\n]+)`", f.read())
+    dangling = []
+    for tok in tokens:
+        if not tok.startswith(PATH_ROOTS) or any(c in tok for c in "*<{"):
+            continue
+        path = re.sub(r"(::.*|:\d+(-\d+)?)$", "", tok.split()[0])
+        if path.rstrip("/") not in known:
+            dangling.append(tok)
+    assert not dangling, f"{doc} names paths that do not exist: {dangling}"
+
+
 # ------------------------------------------------------------ rule 6
 
 def test_dead_export_seeded_violation(tmp_path):
@@ -615,45 +663,6 @@ def test_schema_sync_mode_covers_every_event_type():
         capture_output=True, text=True, cwd=REPO)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "schema-sync OK" in proc.stdout
-
-
-def test_trajectory_learns_check_findings_series(tmp_path):
-    # History: three check_capture notes mirrored by `pbt check
-    # --events-jsonl` (emitted through the real runner so the
-    # platform="static" key can never drift from what the trajectory
-    # expects) + the fresh artifact — all FOUR points must land on ONE
-    # judged series.
-    events = tmp_path / "bench_events.jsonl"
-    for _ in range(3):
-        proc = run_pbt_check("--events-jsonl", str(events))
-        assert proc.returncode == 0, proc.stdout + proc.stderr
-    artifact = tmp_path / "check.json"
-    artifact.write_text(json.dumps(
-        {"v": 1, "kind": "pbt_check_report",
-         "counts": {"check_findings_total": 2}}))
-    out = tmp_path / "verdict.json"
-    proc = subprocess.run(
-        [sys.executable,
-         os.path.join(REPO, "tools", "bench_trajectory.py"),
-         "--repo", str(tmp_path), "--check-json", str(artifact),
-         "--output", str(out)],
-        capture_output=True, text=True, cwd=REPO)
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    verdict = json.loads(out.read_text())
-    series = verdict["series"]["check_findings_total/static"]
-    assert series["values"] == [0.0, 0.0, 0.0, 2.0]
-    assert series["higher_is_better"] is False
-    # With 3 prior points the newest is actually JUDGED (the whole
-    # point of unifying the event and artifact series keys).
-    assert series["verdict"] != "insufficient_data"
-    # A malformed artifact is an input ERROR (exit 2), never silence.
-    artifact.write_text("{}")
-    proc = subprocess.run(
-        [sys.executable,
-         os.path.join(REPO, "tools", "bench_trajectory.py"),
-         "--repo", str(tmp_path), "--check-json", str(artifact)],
-        capture_output=True, text=True, cwd=REPO)
-    assert proc.returncode == 2
 
 
 # ------------------------------------------- fixed-violation regressions

@@ -209,6 +209,9 @@ def test_force_reference_env_override(attn_inputs, monkeypatch):
     _ = ka.fused_packed_attention(params, x, g, seg, interpret=True)
     assert (ka.ATTN_PATH_TOTAL.get(("reference", "forced"), 0)
             == before.get(("reference", "forced"), 0))
+    # Unforced, a supported shape takes the kernel.
+    assert (ka.ATTN_PATH_TOTAL.get(("pallas", "packed"), 0)
+            == before.get(("pallas", "packed"), 0) + 1)
     monkeypatch.setenv(fb.FORCE_REFERENCE_ENV, "1")
     before = ka.ATTN_PATH_TOTAL.get(("reference", "forced"), 0)
     got = ka.fused_packed_attention(params, x, g, seg, interpret=True)

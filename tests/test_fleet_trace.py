@@ -5,7 +5,7 @@ Five areas, all against stub HTTP replicas (canned JSON, no jax):
 - **propagation round-trip**: the router's request id rides every
   attempt as `X-PBT-Trace`, seals as `fleet_request.trace_id`, and
   answers the client as `X-PBT-Request-Id` — one id end-to-end; the
-  off arm (`propagate_trace=False`, the bench A/B baseline) sends no
+  off arm (`propagate_trace=False`) sends no
   header and emits no `fleet_attempt`;
 - **sibling-attempt accounting**: attempts on record == retries spent
   + 1 per trace, indices dense from 0, `backoff_s` rides exactly the

@@ -484,19 +484,36 @@ def test_eval_metric_primitives():
     assert auc_proxy(scores[:2], np.array([0, 0])) is None  # one class
 
 
-def test_evaluate_head_and_events(tmp_path, params, registry):
+@pytest.mark.parametrize("trunk_arm", ["fp32", "int8"])
+def test_evaluate_head_and_events(tmp_path, params, registry, trunk_arm):
+    """`int8`: the same harness over dequantize(quantize(trunk)), which
+    is exactly what the quantized serving executables compute from
+    their int8 weights: the worst head's score stays within 0.1 of the
+    fp32 trunk's worst (docs/serving.md, the quantized arm)."""
     from proteinbert_tpu.heads.eval import evaluate_heads
     from proteinbert_tpu.obs import Telemetry, read_events
+    from proteinbert_tpu.parallel.quant import (
+        dequantize_params, quantize_params,
+    )
 
     reg, hids, heads = registry
+
+    def evaluate(trunk, telemetry=None):
+        return evaluate_heads(
+            trunk, MODEL, heads,
+            lambda head: make_task_batches(
+                16, np.random.default_rng(2), head.task.kind,
+                head.task.num_outputs, 64, 8),
+            telemetry=telemetry)
+
     events = str(tmp_path / "ev.jsonl")
     tele = Telemetry(events_path=events)
-    results = evaluate_heads(
-        params, MODEL, heads,
-        lambda head: make_task_batches(
-            16, np.random.default_rng(2), head.task.kind,
-            head.task.num_outputs, 64, 8),
-        telemetry=tele)
+    if trunk_arm == "int8":
+        results = evaluate(dequantize_params(quantize_params(params)), tele)
+        floor = min(m["score"] for m in evaluate(params).values()) - 0.1
+        assert min(m["score"] for m in results.values()) >= floor
+    else:
+        results = evaluate(params, tele)
     tele.close()
     assert set(results) == set(hids)
     for hid, m in results.items():

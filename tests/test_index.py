@@ -203,15 +203,19 @@ class TestScorerQuality:
         return (NeighborIndex.load(index_dir),
                 store_vectors_in_index_order(store), stats)
 
-    def test_quantized_recall_bound_at_full_probe(self, built):
+    @pytest.mark.parametrize("probe_share", [1.0, 0.5])
+    def test_quantized_recall_bound(self, built, probe_share):
         """The int8-residual representation must preserve the answers:
         at nprobe == num_centroids the shortlist is the whole corpus,
-        so any recall loss is PURELY quantization error — gate it at
-        the bench's 0.95 floor."""
+        so any recall loss is PURELY quantization error; at half the
+        lists (the serving default's share, 8 of 16) the shortlist
+        loses some too. recall@10 >= 0.95 against exact brute force
+        either way."""
         index, vectors, _stats = built
         queries = vectors[::5]
+        nprobe = int(index.centroids.shape[0] * probe_share)
         recall = evaluate_recall(index, vectors, queries, k=10,
-                                 nprobe=index.centroids.shape[0])
+                                 nprobe=nprobe)
         assert recall >= 0.95
 
     def test_lookup_rows_matches_lookup_one(self, built):
@@ -235,12 +239,23 @@ class TestScorerQuality:
                                      nprobe=index.centroids.shape[0])
             assert pairs[0][0] == index.ids[row].decode()
 
-    def test_bytes_ratio_accounting(self, built):
-        _index, _vectors, stats = built
-        assert stats["index_vector_bytes"] < stats["fp32_vector_bytes"]
+    @pytest.mark.parametrize("dim,block,bound", [(DIM, 8, 0.45),
+                                                 (64, 64, 0.30)])
+    def test_bytes_ratio_accounting(self, tmp_path, dim, block, bound):
+        """int8 codes + an int32 list assignment + one fp32 scale row a
+        block against 4 bytes a channel: 1/4 + 1/dim + 1/block. At a
+        trunk's width (global_dim >= 64, blocks of 64) the index holds
+        <= 0.30x the fp32 vector bytes (docs/neighbors.md, sizing); the
+        16-wide fixture pays its assignment and scales dearly."""
+        store = str(tmp_path / "store")
+        make_store(store, n=128, dim=dim)
+        stats = build_index(store, str(tmp_path / "index"),
+                            **dict(BUILD_KW, block_size=block))
+        assert stats["outcome"] == "completed"
         assert stats["bytes_ratio"] == pytest.approx(
             stats["index_vector_bytes"] / stats["fp32_vector_bytes"],
             abs=1e-4)
+        assert stats["bytes_ratio"] <= bound
 
     def test_clamp_validation(self, built):
         index, _vectors, _stats = built

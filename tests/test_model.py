@@ -174,9 +174,9 @@ def test_scan_unroll_matches(key):
 
 
 def test_scan_unroll_plus_split_transpose_matches(key):
-    """Both scheduling knobs TOGETHER (the bench's remat-convs-u2st
-    variant) must still be value- and gradient-equivalent to the
-    knob-off baseline — the sharded parity test alone compares the
+    """Both scheduling knobs TOGETHER (scan_unroll=2 and
+    scan_split_transpose under remat "convs") must still be value- and
+    gradient-equivalent to the knob-off baseline — the sharded parity test alone compares the
     combo against itself on both sides and would miss a numerics
     change common to both paths."""
     cfg1 = tiny_cfg(remat=True, remat_policy="convs", num_blocks=5)

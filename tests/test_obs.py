@@ -147,7 +147,7 @@ def test_metrics_registry_instruments_and_exports(tmp_path):
 
 def test_zero_comm_bytes_land_in_registry():
     """The registry absorbs the ZeRO comm accounting: the same HLO
-    parser bench.py --comm uses, exported as labeled gauges."""
+    parser tests/test_zero.py reads, exported as labeled gauges."""
     from proteinbert_tpu.parallel.zero import record_comm_metrics
 
     hlo = ("  x = f32[8,128]{1,0} all-reduce(f32[8,128]{1,0} p), "

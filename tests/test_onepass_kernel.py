@@ -133,6 +133,24 @@ def test_onepass_bit_identity_across_layouts(onepass_inputs, layout):
                                      0) == before
 
 
+@pytest.mark.parametrize("entry", ["packed", "dense"])
+def test_onepass_trace_has_one_kernel_boundary(onepass_inputs, entry):
+    """What the one-pass kernel is for: its trace holds exactly ONE
+    `pallas_call` where the composition holds two, so the activation
+    between the tracks never leaves VMEM for HBM."""
+    track, attn, x, bc, g = onepass_inputs
+    if entry == "packed":
+        args = (track, attn, x, bc, g, _seg_rows([(1, 100), (2, 80)],
+                                                 [(1, L)]))
+        one, two = _one, _two
+    else:
+        args = (track, attn, x, bc[:, 0, :], g[:, 0, :],
+                jnp.ones((B, L), bool))
+        one, two = _one_dense, _two_dense
+    assert str(jax.make_jaxpr(one)(*args)).count("pallas_call") == 1
+    assert str(jax.make_jaxpr(two)(*args)).count("pallas_call") == 2
+
+
 def test_cross_segment_leakage_is_plus_zero(onepass_inputs):
     """The exact +0.0 cross-segment contract: perturbing every token of
     segment 2 must leave segment 1's local-track rows and attention

@@ -203,7 +203,7 @@ def test_multi_device_cpu_executable_is_never_loaded_from_the_cache(
     # loop-plus-unroll pattern this test exists to cover.
     dict(scan_unroll=2, num_blocks=5, remat=True, remat_policy="convs"),
     dict(scan_split_transpose=True, remat=True, remat_policy="convs"),
-    # Both levers together — the bench's remat-convs-u2st variant.
+    # Both levers together.
     dict(scan_unroll=2, num_blocks=5, scan_split_transpose=True,
          remat=True, remat_policy="convs"),
 ], ids=["u2-remat-convs", "st-remat-convs", "u2st-remat-convs"])

@@ -7,7 +7,6 @@ rejections, and the quantized serving arm (weights, parity sampling,
 event fields)."""
 
 import dataclasses
-import json
 
 import numpy as np
 import pytest
@@ -455,11 +454,18 @@ class TestServeQuant:
                 for k in a:
                     worst = max(worst,
                                 float(np.max(np.abs(a[k] - b[k]))))
+            sampled = qs.stats()["quant"]["parity_max"]
             go_a = fp.predict_go(seqs[0], timeout=120)
             go_b = qs.predict_go(seqs[0], timeout=120)
             stats = qs.stats()
         tele.close()
         assert 0.0 < worst <= self.PARITY_BOUND, worst
+        # Every live batch is shadowed (parity_every=1), so the
+        # dispatcher's own maximum must TRACK the one measured across
+        # the two servers: a shadow that measures nothing (an arm
+        # against itself) passes both bounds and fails here.
+        assert abs(sampled - worst) <= 0.25 * worst + 1e-4, (sampled,
+                                                              worst)
         assert float(np.max(np.abs(go_a - go_b))) <= self.PARITY_BOUND
         assert stats["quant"]["mode"] == "int8"
         assert stats["quant"]["parity_samples"] >= 1
@@ -582,52 +588,3 @@ class TestServeQuant:
         assert srv2.quant == "fp32"
         assert srv2.dispatcher.qparams is None
         srv2.abort()
-
-
-# ------------------------------------------------- trajectory sentinel
-
-
-def test_trajectory_fits_quant_series(tmp_path):
-    """tools/bench_trajectory.py fits the new quant series from
-    bench_events.jsonl notes, with the ratio/parity series judged
-    LOWER-is-better (a rising int8 wire ratio must flag as a
-    regression, not an improvement)."""
-    import sys
-
-    sys.path.insert(0, "tools")
-    import bench_trajectory as bt
-
-    events = tmp_path / "bench_events.jsonl"
-    lines = []
-    seqn = 0
-
-    def note(**fields):
-        nonlocal seqn
-        rec = {"v": 1, "event": "note", "seq": seqn, "t": float(seqn),
-               "source": "bench", **fields}
-        seqn += 1
-        lines.append(json.dumps(rec))
-
-    for ratio in (0.27, 0.28, 0.27, 0.55):  # regressing ratio (UP)
-        note(kind="comm_quant", platform="cpu-virtual",
-             int8_grad_wire_ratio=ratio, bf16_grad_wire_ratio=0.51)
-    for rps, pmax in ((100.0, 0.02), (110.0, 0.021), (105.0, 0.02),
-                      (104.0, 0.019)):
-        note(kind="serve_quant_capture", platform="cpu",
-             quant_requests_per_sec=rps, parity_max=pmax,
-             weight_bytes_ratio=0.31)
-    for smin in (0.8, 0.81, 0.8, 0.82):
-        note(kind="heads_capture", platform="cpu",
-             eval_score_min_quant=smin, eval_score_min=0.9)
-    events.write_text("\n".join(lines) + "\n")
-
-    verdict = bt.build_verdict([], str(events))
-    s = verdict["series"]
-    assert s["comm_bytes_int8_ratio/cpu-virtual"]["verdict"] \
-        == "regression"
-    assert not s["comm_bytes_int8_ratio/cpu-virtual"]["higher_is_better"]
-    assert s["serve_quant_requests_per_sec/cpu"]["verdict"] == "ok"
-    assert s["serve_quant_parity_max/cpu"]["verdict"] == "ok"
-    assert s["heads_eval_score_min_quant/cpu"]["verdict"] == "ok"
-    assert verdict["overall"] == "regression"
-    assert not verdict["errors"]

@@ -100,8 +100,7 @@ def test_seq_parallel_forward_parity(key, mesh_cfg, unroll):
 @requires_8
 @pytest.mark.parametrize("variant", ["u1", "u2", "st"])
 def test_seq_parallel_gradient_parity(key, variant):
-    # u2 and st run under remat-convs — the exact backward regimes the
-    # bench's remat-convs-u2/-st variants execute (unrolled scan body /
+    # u2 and st run under remat-convs (an unrolled scan body / a
     # _split_transpose'd scan under shard_map); a grad regression there
     # is invisible to the forward-parity test.
     model = dataclasses.replace(

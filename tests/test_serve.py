@@ -549,6 +549,31 @@ class TestServedParity:
                 np.testing.assert_array_equal(row["local_mean"],
                                               offline["local_mean"][i])
 
+    @pytest.mark.parametrize("depth", [1, 2])
+    def test_started_server_bit_parity_at_pipeline_depth(self, trunk,
+                                                         depth):
+        """The in-flight window changes when a batch is fetched, not
+        what it computes: a STARTED server (depth 2 hands batches to
+        the live completer thread, depth 1 fetches in line) answers a
+        full same-bucket batch bit-identically to the offline bucketed
+        path, and so the two depths bit-identically to each other."""
+        params, cfg = trunk
+        seqs = ["MKTAYIAKQR", "GG", "ACDEF", "MKT"]
+        srv = Server(params, cfg, buckets=BUCKETS, max_batch=4,
+                     max_wait_s=60.0, cache_size=0, warm_kinds=(),
+                     pipeline_depth=depth).start()
+        try:
+            served = [f.result(timeout=120)
+                      for f in [srv.submit("embed", s) for s in seqs]]
+            assert srv.scheduler.pipeline_stats()["depth"] == depth
+        finally:
+            srv.close(drain=True, timeout=30)
+        offline = inference.embed(params, cfg, seqs, bucketed=True,
+                                  buckets=BUCKETS, batch_size=4)
+        for i, row in enumerate(served):
+            for key in ("global", "local_mean"):
+                np.testing.assert_array_equal(row[key], offline[key][i])
+
     def test_sync_facade_ragged_traffic(self, server, trunk):
         params, cfg = trunk
         offline = inference.embed(params, cfg, RAGGED, bucketed=True,
@@ -902,6 +927,37 @@ class TestHTTP:
         local = srv.embed("MKTAYIAKQR", timeout=30)
         np.testing.assert_allclose(body["global"], local["global"],
                                    rtol=1e-6, atol=1e-7)
+
+    def test_injected_trace_id_is_the_answers_request_id(self, trunk):
+        """The replica's half of the fleet join: a traced server
+        answers the id a router sent as X-PBT-Trace back as
+        X-PBT-Request-Id, so one id names the request in both
+        processes."""
+        import urllib.request
+
+        from proteinbert_tpu.obs import Telemetry
+        from proteinbert_tpu.serve.http import make_http_server
+
+        params, cfg = trunk
+        srv = Server(params, cfg, buckets=BUCKETS, max_batch=4,
+                     max_wait_s=0.002, cache_size=0, warm_kinds=(),
+                     telemetry=Telemetry(), trace_sample_rate=0.0,
+                     replica_id="r0").start()
+        httpd = make_http_server(srv, port=0)
+        threading.Thread(target=httpd.serve_forever, daemon=True).start()
+        try:
+            req = urllib.request.Request(
+                f"http://127.0.0.1:{httpd.server_address[1]}/v1/embed",
+                data=json.dumps({"seq": "MKTAY"}).encode(),
+                headers={"Content-Type": "application/json",
+                         "X-PBT-Trace": "f1a2-probe"})
+            with urllib.request.urlopen(req, timeout=30) as resp:
+                assert resp.status == 200
+                assert resp.headers.get("X-PBT-Request-Id") == "f1a2-probe"
+        finally:
+            httpd.shutdown()
+            httpd.server_close()
+            srv.close(drain=True, timeout=30)
 
     def test_predict_routes(self, endpoint):
         _, base = endpoint

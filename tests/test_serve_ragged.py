@@ -51,6 +51,7 @@ from proteinbert_tpu.train import create_train_state
 
 SEQ_LEN = 48
 BUCKETS = (16, 32, 48)
+DENSE_BUCKETS = (8, 16, 24, 32, 40, 48)
 MODEL = ModelConfig(local_dim=16, global_dim=32, key_dim=8, num_heads=2,
                     num_blocks=2, num_annotations=32, dtype="float32")
 
@@ -538,9 +539,16 @@ class TestRaggedParity:
     """THE acceptance gate: identical traffic, bucketed vs ragged,
     per-request outputs within the documented jitted ≤1e-5 tolerance."""
 
-    def test_embed_parity_and_executable_collapse(self, trunk, seqs):
-        b, bs = _serve(trunk, "bucketed", "embed", seqs)
-        r, rs = _serve(trunk, "ragged", "embed", seqs)
+    @pytest.mark.parametrize("ladder", [BUCKETS, DENSE_BUCKETS],
+                             ids=["matched", "dense"])
+    def test_embed_parity_and_executable_collapse(self, trunk, seqs,
+                                                  ladder):
+        """In ragged mode the ladder only quantizes spans (the compiled
+        shape stays rows x seq_len), so one twice as dense costs the
+        ragged server no executable and the bucketed one a shape a
+        rung."""
+        b, bs = _serve(trunk, "bucketed", "embed", seqs, buckets=ladder)
+        r, rs = _serve(trunk, "ragged", "embed", seqs, buckets=ladder)
         for x, y in zip(b, r):
             np.testing.assert_allclose(x["global"], y["global"],
                                        atol=1e-5, rtol=1e-5)

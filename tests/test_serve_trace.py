@@ -1,6 +1,6 @@
-"""Per-request serve tracing + perf sentinel (ISSUE 6).
+"""Per-request serve tracing (ISSUE 6).
 
-Three tiers in one file:
+Two tiers in one file:
 
 - **RequestTrace invariants** — stages are contiguous clock intervals,
   so they tile [submit, done] and sum to the end-to-end latency by
@@ -12,15 +12,8 @@ Three tiers in one file:
   trunk: drain vs abort leave no orphaned spans, failed batches close
   their traces with error status, sampling suppresses ok-requests but
   never failures, SLO burn rates surface on stats()/metrics/events.
-- **perf-regression sentinel** — tools/bench_trajectory.py flags a
-  synthetic 20% regression, stays quiet on the checked-in real bench
-  history (the zero-false-positive acceptance), and fails only on
-  malformed inputs.
 """
 
-import importlib.util
-import json
-import os
 import threading
 from concurrent.futures import Future
 
@@ -42,7 +35,6 @@ from proteinbert_tpu.serve import (
 from proteinbert_tpu.serve.trace import STAGES, stride_sampled
 from proteinbert_tpu.train import create_train_state
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEQ_LEN = 48
 BUCKETS = (16, 32, 48)
 
@@ -571,103 +563,3 @@ class TestServerTracing:
         text = render_serve(s)
         assert "where the time went" in text
         assert "e2e latency" in text
-
-
-# --------------------------------------------- perf-regression sentinel
-
-@pytest.fixture(scope="module")
-def sentinel():
-    spec = importlib.util.spec_from_file_location(
-        "bench_trajectory", os.path.join(REPO, "tools",
-                                         "bench_trajectory.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-class TestSentinel:
-    def test_flags_synthetic_20pct_regression(self, sentinel):
-        s = sentinel.judge_series([100.0, 101.0, 99.0, 100.0, 80.0])
-        assert s["verdict"] == "regression"
-        assert "20.0% below" in s["reason"]
-
-    def test_quiet_inside_noise_band(self, sentinel):
-        # The band floors at 10% of baseline: a 5% dip is noise.
-        s = sentinel.judge_series([100.0, 101.0, 99.0, 100.0, 95.0])
-        assert s["verdict"] == "ok"
-        # …and a genuinely noisy history widens it via the MAD.
-        s = sentinel.judge_series([100.0, 300.0, 50.0, 200.0, 80.0])
-        assert s["verdict"] == "ok"
-
-    def test_improvement_and_direction(self, sentinel):
-        s = sentinel.judge_series([100.0, 101.0, 99.0, 100.0, 120.0])
-        assert s["verdict"] == "improved"
-        # Lower-is-better flips the sign (latency-style series).
-        s = sentinel.judge_series([100.0, 101.0, 99.0, 100.0, 120.0],
-                                  higher_is_better=False)
-        assert s["verdict"] == "regression"
-
-    def test_two_points_are_an_anecdote(self, sentinel):
-        s = sentinel.judge_series([100.0, 50.0])
-        assert s["verdict"] == "insufficient_data"
-
-    def test_zero_false_positives_on_real_history(self, sentinel):
-        """The acceptance contract: the checked-in bench trajectory
-        must produce no regression verdicts and no input errors."""
-        import glob
-
-        verdict = sentinel.build_verdict(
-            sorted(glob.glob(os.path.join(REPO, "BENCH_r*.json"))),
-            os.path.join(REPO, "bench_events.jsonl"))
-        assert verdict["errors"] == []
-        assert verdict["overall"] in ("ok", "insufficient_data")
-        flagged = [k for k, s in verdict["series"].items()
-                   if s["verdict"] == "regression"]
-        assert flagged == []
-        assert len(verdict["series"]) >= 3  # it actually read history
-
-    def _write_rounds(self, d, values):
-        for i, v in enumerate(values, start=1):
-            with open(os.path.join(d, f"BENCH_r{i:02d}.json"), "w") as f:
-                json.dump({"parsed": {"metric": "residues_per_sec",
-                                      "platform": "cpu",
-                                      "value": v}}, f)
-
-    def test_main_report_only_vs_fail_on_regression(self, sentinel,
-                                                    tmp_path):
-        d = str(tmp_path)
-        self._write_rounds(d, [100.0, 101.0, 99.0, 100.0, 80.0])
-        out = os.path.join(d, "verdict.json")
-        assert sentinel.main(["--repo", d, "--output", out]) == 0
-        verdict = json.load(open(out))
-        assert verdict["overall"] == "regression"
-        assert verdict["kind"] == "bench_trajectory_verdict"
-        assert verdict["series"]["residues_per_sec/cpu"]["verdict"] \
-            == "regression"
-        assert sentinel.main(["--repo", d, "--fail-on-regression"]) == 1
-
-    def test_malformed_input_is_the_only_gate(self, sentinel, tmp_path):
-        d = str(tmp_path)
-        self._write_rounds(d, [100.0, 101.0, 99.0, 100.0])
-        with open(os.path.join(d, "BENCH_r06.json"), "w") as f:
-            f.write("{not json")
-        assert sentinel.main(["--repo", d]) == 2
-
-    def test_verdict_mirrors_onto_event_stream(self, sentinel,
-                                               tmp_path):
-        d = str(tmp_path)
-        self._write_rounds(d, [100.0, 101.0, 99.0, 100.0, 80.0])
-        ev_path = os.path.join(d, "mirror.jsonl")
-        assert sentinel.main(["--repo", d, "--events-jsonl",
-                              ev_path]) == 0
-        recs = read_events(ev_path, strict=True)
-        assert len(recs) == 1
-        assert recs[0]["event"] == "note"
-        assert recs[0]["source"] == "bench_trajectory"
-        assert recs[0]["overall"] == "regression"
-        assert recs[0]["regressions"] == ["residues_per_sec/cpu"]
-
-
-def test_run_tier1_has_sentinel_stage():
-    sh = open(os.path.join(REPO, "tools", "run_tier1.sh")).read()
-    assert "bench_trajectory.py" in sh

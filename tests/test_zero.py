@@ -5,6 +5,7 @@ bound, and byte-identical checkpoint resume (including the PR-1 staged
 overlapped save path)."""
 
 import dataclasses
+import functools
 
 import numpy as np
 import pytest
@@ -23,8 +24,10 @@ from proteinbert_tpu.parallel import (
     zero_extent,
 )
 from proteinbert_tpu.parallel.sharding import state_sharding
+from proteinbert_tpu.parallel.quant import make_quant_zero_train_step
 from proteinbert_tpu.parallel.zero import (
-    collective_bytes_from_hlo, per_chip_state_bytes, zero_gradient_update,
+    collective_bytes_from_hlo, collective_wire_bytes_from_hlo,
+    grad_reduce_wire_bytes, per_chip_state_bytes, zero_gradient_update,
 )
 from proteinbert_tpu.train import Checkpointer, create_train_state, pretrain, train_step
 from tests.conftest import make_random_proteins
@@ -342,6 +345,81 @@ def test_zero_with_eval_keyed_plateau(tmp_path):
     for step, loss in runs["rep"].items():
         assert abs(runs["zero"][step] - loss) <= 2e-5 * max(1.0, abs(loss)), (
             step, loss, runs["zero"][step])
+
+
+# The compiled step's bytes, one case a reduction mode: `replicated`
+# (no zero), `zero` (the partitioner's own fp32 reduce-scatter),
+# `zero_rs_fp32` (the EXPLICIT reduce-scatter at an fp32 payload: the
+# same program as the quantized ones, only the payload differs),
+# `zero_bf16` / `zero_int8` (quantized payloads).
+COMM_MODES = {
+    "replicated": (False, "fp32"), "zero": (True, "fp32"),
+    "zero_rs_fp32": (True, "fp32"), "zero_bf16": (True, "bf16"),
+    "zero_int8": (True, "int8"),
+}
+COMM_MESH = MeshConfig(data=4, fsdp=2)
+
+
+@functools.lru_cache(maxsize=None)
+def _comm_row(mode):
+    """Counts of one mode's step compiled for data=4 x fsdp=2 (from
+    abstract arguments: nothing runs), kept for the modes after it."""
+    zero, grd = COMM_MODES[mode]
+    cfg = cfg_for(COMM_MESH, parallel=ParallelConfig(
+        zero_update=zero, grad_reduce_dtype=grd))
+    mesh = make_mesh(COMM_MESH)
+    abstract = jax.eval_shape(
+        lambda: create_train_state(jax.random.PRNGKey(0), cfg))
+    sh = state_sharding(mesh, abstract, zero_update=zero)
+    st = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        abstract, sh)
+    bsh = batch_sharding(mesh)
+    batch = {
+        "tokens": jax.ShapeDtypeStruct(
+            (cfg.data.batch_size, cfg.data.seq_len), np.int32,
+            sharding=bsh["tokens"]),
+        "annotations": jax.ShapeDtypeStruct(
+            (cfg.data.batch_size, cfg.model.num_annotations), np.float32,
+            sharding=bsh["annotations"]),
+    }
+    if mode == "zero_rs_fp32":
+        lowered = make_quant_zero_train_step(
+            mesh, cfg, payload="fp32").lower(st, batch)
+    elif zero:
+        lowered = make_zero_train_step(mesh, cfg).lower(st, batch)
+    else:
+        lowered = train_step.lower(st, batch, cfg)
+    hlo = lowered.compile().as_text()
+    wire = collective_wire_bytes_from_hlo(hlo, mesh.size)
+    return {"collective": collective_bytes_from_hlo(hlo), "wire": wire,
+            "grad_wire": grad_reduce_wire_bytes(wire),
+            "state": per_chip_state_bytes(mesh, abstract, zero_update=zero)}
+
+
+@requires_8
+@pytest.mark.parametrize("mode", sorted(COMM_MODES))
+def test_compiled_step_bytes_by_reduction_mode(mode):
+    """What ZeRO-1 and the quantized wire claim, read from the COMPILED
+    per-chip program and the sharding rules on data=4 x fsdp=2: Adam
+    state a chip shrinks ~4x and parameters a chip do not move;
+    reduce-scatter + all-gather stay within 1.5x the replicated
+    all-reduce's bytes; an int8 payload moves <= 0.30x and a bf16 one
+    <= 0.60x the gradient-reduction wire bytes of the SAME explicit
+    reduce-scatter at fp32."""
+    row, rep = _comm_row(mode), _comm_row("replicated")
+    assert row["collective"]["total"] > 0 and row["wire"]["total"] > 0
+    assert row["state"]["params"] == rep["state"]["params"]
+    if mode == "replicated":
+        return
+    assert rep["state"]["opt_state"] / row["state"]["opt_state"] >= 3.0
+    if mode == "zero":
+        ratio = row["collective"]["total"] / rep["collective"]["total"]
+        assert 0 < ratio <= 1.5, ratio
+    bound = {"zero_int8": 0.30, "zero_bf16": 0.60}.get(mode)
+    if bound is not None:
+        ratio = row["grad_wire"] / _comm_row("zero_rs_fp32")["grad_wire"]
+        assert 0 < ratio <= bound, ratio
 
 
 def test_collective_bytes_from_hlo_parses_ops():

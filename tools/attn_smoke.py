@@ -23,8 +23,6 @@ the same kernels Mosaic compiles on TPU), gates:
      `fused_local_track_segments` runs the channel-tiled SEGMENT
      variant (pallas/packed, zero reason=segments) and matches the
      boundary-masked reference at bf16 tolerance.
-  6. NOTE SCHEMA — a synthetic `note(kind=pack_attn_capture)` record
-     round-trips the events validator (the sentinel-series contract).
 
 Exit nonzero on any violation — this stage GATES (run_tier1.sh).
 """
@@ -194,28 +192,6 @@ def main() -> int:
          and dsg == 0,
          f"tiled segment C=1024 parity {diff_t:.3f} (bf16) on the "
          f"Pallas path (pallas/packed +{dp}, reference/segments +{dsg})")
-
-    # ---- gate 6: pack_attn_capture note schema -----------------------
-    from proteinbert_tpu.obs.events import validate_record
-
-    rec = {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-           "source": "bench", "kind": "pack_attn_capture",
-           "platform": "cpu", "attn_speedup_x": 1.0,
-           "parity_max_abs_diff": diff, "mfu_raw": 0.01,
-           "mfu_effective": 0.01}
-    try:
-        validate_record(rec)
-        ok = True
-    except ValueError as e:
-        ok = False
-        print(f"  validator rejected a well-formed capture: {e}")
-    bad_rejected = False
-    try:
-        validate_record({**rec, "attn_speedup_x": 0.0})
-    except ValueError:
-        bad_rejected = True
-    gate(ok and bad_rejected,
-         "note(kind=pack_attn_capture) schema round-trip + negative")
 
     print(f"\n{len(failures)} failure(s)")
     return 1 if failures else 0

@@ -42,7 +42,7 @@ Gates (exit nonzero on violation — tier-1 runs this as a smoke stage):
 
 Usage:
   python tools/map_drill.py [--outdir DIR] [--json] [--seed N]
-      [--corpus N] [--bench-events PATH]
+      [--corpus N]
 """
 
 from __future__ import annotations
@@ -389,31 +389,6 @@ def run_drill(args) -> dict:
         "failures": failures,
         "ok": not failures,
     }
-    if args.bench_events and not failures:
-        # Throughput capture for the trajectory sentinel: seqs/s of the
-        # CONTROL run (uninterrupted — the honest rate), platform-split
-        # like every other capture.
-        from proteinbert_tpu.obs import EventLog
-
-        ctrl_end = [r for r in read_events(evc, strict=True)
-                    if r["event"] == "map_end"][-1]
-        elog = EventLog(args.bench_events)
-        # overlap_ratio rides the control map_end stats (ISSUE 19):
-        # overlapped-commit seconds / total commit seconds for the
-        # pipelined drive loop — honestly near-meaningless on CPU
-        # wall-clock terms but the sentinel tracks it platform-split.
-        elog.emit("note", source="map_drill", kind="map_capture",
-                  platform="cpu",
-                  map_seqs_per_s=ctrl_end["stats"]["seqs_per_s"],
-                  map_overlap_ratio=ctrl_end["stats"].get(
-                      "overlap_ratio", 0.0),
-                  blocks=ctrl_end["stats"]["blocks"],
-                  seqs=ctrl_end["stats"]["seqs"],
-                  corpus=args.corpus)
-        elog.close()
-        summary["map_seqs_per_s"] = ctrl_end["stats"]["seqs_per_s"]
-        summary["map_overlap_ratio"] = ctrl_end["stats"].get(
-            "overlap_ratio", 0.0)
     return summary
 
 
@@ -426,11 +401,6 @@ def main(argv=None) -> int:
     ap.add_argument("--outdir", help="artifact dir (default: temp)")
     ap.add_argument("--json", action="store_true",
                     help="print the summary as one JSON object only")
-    ap.add_argument("--bench-events",
-                    help="append a note(kind=map_capture) throughput "
-                         "record to this bench events stream "
-                         "(tools/bench_trajectory.py fits the "
-                         "map_seqs_per_s series from it)")
     args = ap.parse_args(argv)
     if args.corpus < 3 * NUM_SHARDS * BLOCK_SIZE - BLOCK_SIZE + 1:
         ap.error(f"--corpus must give every shard >= 3 blocks "

@@ -25,8 +25,6 @@ the same kernel Mosaic compiles on TPU), gates:
   6. INT8 IN-KERNEL DEQUANT — `quantize_params` int8 weights + scales
      dequantized inside the kernel bit-match HLO-dequantizing the same
      tree first (both entries).
-  7. NOTE SCHEMA — a synthetic `note(kind=onepass_capture)` record
-     round-trips the events validator (the sentinel-series contract).
 
 Exit nonzero on any violation — this stage GATES (run_tier1.sh).
 """
@@ -220,28 +218,6 @@ def main() -> int:
                  for a, b in zip(got_qd, want_qd))
     gate(bit_q and bit_qd,
          "int8 in-kernel dequant bit-matches HLO dequant (both entries)")
-
-    # ---- gate 7: onepass_capture note schema -------------------------
-    from proteinbert_tpu.obs.events import validate_record
-
-    rec = {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-           "source": "bench", "kind": "onepass_capture",
-           "platform": "cpu", "onepass_speedup_x": 1.0,
-           "parity_max_abs_diff": 0.0, "mfu_raw": 0.01,
-           "mfu_effective": 0.01}
-    try:
-        validate_record(rec)
-        ok = True
-    except ValueError as e:
-        ok = False
-        print(f"  validator rejected a well-formed capture: {e}")
-    bad_rejected = False
-    try:
-        validate_record({**rec, "onepass_speedup_x": 0.0})
-    except ValueError:
-        bad_rejected = True
-    gate(ok and bad_rejected,
-         "note(kind=onepass_capture) schema round-trip + negative")
 
     print(f"\n{len(failures)} failure(s)")
     return 1 if failures else 0

@@ -33,8 +33,7 @@ Gates (exit nonzero on violation — tier-1 runs this as a smoke stage):
     merged fleet stream (FleetCollector) is schema-valid with
     exactly-once sealing and attempts == retries + 1 per trace —
     shadows never contaminate the attempt plane;
-  - every rollout_* event round-trips the schema validator; the
-    note(kind=rollout_capture) sentinel sample lands on the stream.
+  - every rollout_* event round-trips the schema validator.
 
 Usage:
   python tools/rollout_drill.py [--outdir DIR] [--json]
@@ -124,7 +123,7 @@ class LocalReplica:
 
 class SpyTele:
     """Telemetry pass-through that records every finite shadow parity —
-    the drill's source for the rollout_capture sentinel sample."""
+    the good rollout must have measured at least one."""
 
     def __init__(self, inner):
         self._inner = inner
@@ -451,14 +450,11 @@ def run_drill(args) -> dict:
         failures.append(f"rollback numerics are NOT bit-identical to "
                         f"the baseline (parity {rollback_parity})")
 
-    # ------------------------------------- capture + teardown + audits
+    # ------------------------------------------------ teardown + audits
     finite = [p for p in spy.parities if math.isfinite(p)]
     if not finite:
         failures.append("the good rollout produced no finite shadow "
                         "parity sample")
-    tele.emit("note", source="rollout_drill", kind="rollout_capture",
-              rollout_shadow_parity_max=max(finite, default=0.0),
-              rollout_flip_seconds=ctl._flip_seconds or 0.0)
 
     httpd.shutdown()
     httpd.server_close()
@@ -499,10 +495,6 @@ def run_drill(args) -> dict:
     if orphan:
         failures.append(f"shadow events reference unsealed traces: "
                         f"{orphan[:5]}")
-    captures = [r for r in rrecs if r["event"] == "note"
-                and r.get("kind") == "rollout_capture"]
-    if len(captures) != 1:
-        failures.append("the rollout_capture sentinel note is missing")
 
     collector = FleetCollector({"router": router_events})
     for r in replicas:
@@ -562,28 +554,8 @@ def main(argv=None) -> int:
     ap.add_argument("--outdir", help="artifact dir (default: temp)")
     ap.add_argument("--json", action="store_true",
                     help="print the summary as one JSON object only")
-    ap.add_argument("--bench-events",
-                    help="append a note(kind=rollout_capture) record to "
-                         "this bench events stream "
-                         "(tools/bench_trajectory.py fits the "
-                         "rollout_shadow_parity_max and "
-                         "rollout_flip_seconds series from it)")
     args = ap.parse_args(argv)
     summary = run_drill(args)
-    if args.bench_events and summary["ok"]:
-        # Sentinel mirror (map_drill idiom): the worst shadow parity
-        # through the GOOD candidate + the atomic-flip latency,
-        # platform-split like every other capture.
-        from proteinbert_tpu.obs import EventLog
-
-        elog = EventLog(args.bench_events)
-        elog.emit("note", source="rollout_drill", kind="rollout_capture",
-                  platform="cpu",
-                  rollout_shadow_parity_max=summary["shadow_parity_max"]
-                  or 0.0,
-                  rollout_flip_seconds=summary["flip_seconds"] or 0.0,
-                  shadow_events=summary["shadow_events"])
-        elog.close()
     if args.json:
         print(json.dumps(summary))
     else:

@@ -1,6 +1,14 @@
 #!/usr/bin/env bash
-# The ROADMAP tier-1 verify command, verbatim — one place to edit, so a
-# local run, CI, and the driver's gate can never drift apart.
+# Tier-1, then the smokes and drills. The FIRST stage is the gate: the
+# pytest command as the driver runs it after every PR (the `commands`
+# of /root/TESTS_LAST_RUN.json: six xdist workers, --dist loadfile, a
+# 1,470 s cap; passes counted from the junit file). It asserts
+# behaviour and counts, never a time or a rate: speed is measured by
+# `python -m benchmark.run` on the chip and recorded in
+# PERF_LEDGER.jsonl. The driver runs ONLY that first command; the
+# stages after it (schema self-test, `pbt check`, smokes, drills) are
+# for a local run and repeat, end to end, what tier-1 tests hold piece
+# by piece (ROADMAP C14).
 #
 # --pod64: ALSO run the opt-in 64-virtual-device pod-shape tier
 # (tests/test_parallel64.py) after the tier-1 suite. It is slow-marked
@@ -38,10 +46,12 @@ for arg in "$@"; do
   esac
 done
 
-rm -f /tmp/_t1.log
-timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' --continue-on-collection-errors -p no:cacheprovider -p no:xdist -p no:randomly 2>&1 | tee /tmp/_t1.log
+rm -rf /tmp/_t1.log /tmp/_t1.xml
+timeout -k 10 1470 env JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 python -m pytest tests/ -q -m 'not slow' --continue-on-collection-errors -p no:cacheprovider -p xdist -n 6 --dist loadfile --junitxml=/tmp/_t1.xml -p no:randomly 2>&1 | tee /tmp/_t1.log
 rc=${PIPESTATUS[0]}
-echo DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log | tr -cd . | wc -c)
+said=$(sed -n 's/.*<testsuite [^>]*errors="\([0-9]*\)" failures="\([0-9]*\)" skipped="\([0-9]*\)" tests="\([0-9]*\)".*/\4 \1 \2 \3/p' /tmp/_t1.xml 2>/dev/null | head -n 1 | awk '{n=$1-$2-$3-$4; print (n<0 ? 0 : n)}')
+echo DOTS_PASSED=${said:-$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log | tr -cd . | wc -c)}
+echo WORKERS_DOWN=$(grep -acE '\[gw[0-9]+\] node down' /tmp/_t1.log 2>/dev/null)
 mark_stage pytest
 
 # Events-schema validator self-test (ISSUE 3 satellite): every telemetry
@@ -61,64 +71,13 @@ mark_stage events_schema
 # sites, obs-doc drift, dead exports) over the whole tree, GATED — a
 # non-baselined finding fails tier-1. Pure python, no jax import
 # (tools/pbt_check.py stub-imports the analysis package past the jax-
-# importing package root); the JSON artifact feeds the trajectory
-# sentinel's suppression-creep series below. docs/analysis.md is the
-# rule catalog + suppression format.
+# importing package root). docs/analysis.md is the rule catalog +
+# suppression format; tools/check_baseline.json holds the suppressions.
 echo "=== pbt check (project-invariant static analyzer, gated) ==="
-check_json=$(mktemp /tmp/_pbt_check.XXXXXX.json)
-timeout -k 10 120 python "$(dirname "$0")/pbt_check.py" \
-  --json-artifact "$check_json"
+timeout -k 10 120 python "$(dirname "$0")/pbt_check.py"
 rcc=$?
-echo "check artifact: $check_json"
 mark_stage pbt_check
 [ "$rc" -eq 0 ] && rc=$rcc
-
-# Perf-regression sentinel (ISSUE 6 satellite): fit per-metric
-# baselines over the checked-in bench trajectory (BENCH_r*.json +
-# bench_events.jsonl) and report any point outside the noise band.
-# REPORT-ONLY: verdicts never fail the gate — only parse/schema errors
-# in the inputs do (exit 2). Stdlib+obs only, <2 s, no jax.
-echo "=== bench trajectory sentinel (report-only) ==="
-verdict_json=$(mktemp /tmp/_bench_verdict.XXXXXX.json)
-python "$(dirname "$0")/bench_trajectory.py" --output "$verdict_json" \
-  --check-json "$check_json"
-rct=$?
-echo "verdict artifact: $verdict_json"
-mark_stage sentinel
-[ "$rc" -eq 0 ] && rc=$rct
-
-# Serving smoke (ISSUE 5 satellite): in-process server on CPU under
-# concurrent clients — continuous micro-batching vs the sequential
-# baseline, per-bucket bit-parity, bounded-queue rejection. Small knobs
-# keep it ~1 min; contract failures (parity / lost / un-rejected
-# overflow) exit nonzero and fail the gate, wall-clock ratios are
-# reported, not gated (bench.py --serve docstring).
-echo "=== serve smoke (in-process server, CPU, concurrent clients) ==="
-timeout -k 10 420 env JAX_PLATFORMS=cpu \
-  PBT_SERVE_BENCH_SEQ_LEN=256 PBT_SERVE_BENCH_DIM=32 \
-  PBT_SERVE_BENCH_REQUESTS=64 PBT_SERVE_BENCH_CLIENTS=24 \
-  PBT_SERVE_BENCH_TRACE_ROUNDS=3 PBT_SERVE_BENCH_PHASES=core \
-  python "$(dirname "$0")/../bench.py" --serve
-rcs=$?
-mark_stage serve_smoke
-[ "$rc" -eq 0 ] && rc=$rcs
-
-# Ragged serve smoke (ISSUE 9 satellite): bucketed vs ragged packed
-# serving on a mixed-length log-normal workload. GATED: per-request
-# parity within the documented jitted 1e-5 tolerance (matched ladder vs
-# the live bucketed server, dense ladder vs the offline dense-bucketed
-# reference), no lost requests, ragged warm-executable count O(kinds).
-# Wall-clock speedup and pad_wasted are reported, not gated.
-echo "=== ragged serve smoke (bucketed vs packed A/B, mixed lengths) ==="
-timeout -k 10 420 env JAX_PLATFORMS=cpu \
-  PBT_SERVE_BENCH_SEQ_LEN=256 PBT_SERVE_BENCH_DIM=32 \
-  PBT_SERVE_BENCH_REQUESTS=96 PBT_SERVE_BENCH_CLIENTS=12 \
-  PBT_SERVE_BENCH_PHASES=ragged PBT_SERVE_BENCH_RAGGED_ROUNDS=3 \
-  python "$(dirname "$0")/../bench.py" --serve \
-  --serve-length-mix 'median=32,sigma=1.0,seed=7'
-rcr=$?
-mark_stage ragged_smoke
-[ "$rc" -eq 0 ] && rc=$rcr
 
 # Pipeline smoke (ISSUE 19 satellite): the pipelined-dispatch window on
 # an in-process depth-1 vs depth-2 server pair. GATED: overlap observed
@@ -131,35 +90,14 @@ rcpl=$?
 mark_stage pipeline_smoke
 [ "$rc" -eq 0 ] && rc=$rcpl
 
-# Packed fused fast-path smoke (ISSUE 10 satellite): a tiny packed
-# batch through the segment-aware Pallas kernel at a lane-aligned dim
-# (the bench --pack fused A/B arm — which since ISSUE 13 ALSO runs the
-# attention fused-vs-reference arm and emits its pack_attn_capture
-# note under the same gates). GATED: fused-vs-reference parity
-# within the documented 1e-5 jitted tolerance, supported shapes take
-# the Pallas path with ZERO reason=segments fallbacks, and the
-# PBT_FORCE_REFERENCE_KERNEL debug override (documented in
-# docs/performance.md) still routes a fresh trace onto the reference
-# path. Wall-clock is reported, not gated (interpret mode on CPU).
-echo "=== packed fused smoke (fused-vs-reference A/B, CPU) ==="
-timeout -k 10 420 env JAX_PLATFORMS=cpu \
-  PBT_PACK_BENCH_SEQ_LEN=128 PBT_PACK_BENCH_BATCH=2 \
-  PBT_PACK_BENCH_DIM=32 PBT_PACK_BENCH_STEPS=2 \
-  PBT_PACK_BENCH_MEDIAN_LEN=40 PBT_PACK_BENCH_FUSED_DIM=128 \
-  PBT_PACK_BENCH_FUSED_REPS=2 \
-  python "$(dirname "$0")/../bench.py" --pack
-rcf=$?
-mark_stage pack_smoke
-[ "$rc" -eq 0 ] && rc=$rcf
-
 # Packed attention smoke (ISSUE 13): the ragged Pallas attention
 # kernel and the tiled-segment fused block through their real dispatch
 # entries on tiny shapes. GATED: packed/dense/serving-real_mask parity
 # within the documented 1e-5 jitted tolerance, custom-VJP gradient
 # parity, supported shapes take the Pallas path with ZERO
 # reason=segments fallbacks (attention AND the C=1024 tiled segment
-# fused block), PBT_FORCE_REFERENCE_KERNEL routes attention onto the
-# reference path, and the pack_attn_capture note schema round-trips.
+# fused block), and PBT_FORCE_REFERENCE_KERNEL routes attention onto
+# the reference path.
 echo "=== packed attention smoke (Pallas attention + tiled segment, CPU) ==="
 timeout -k 10 420 python "$(dirname "$0")/attn_smoke.py"
 rca=$?
@@ -172,8 +110,8 @@ mark_stage attn_smoke
 # serving-real_mask BIT-identity vs the two-kernel composition, exactly
 # one pallas_call boundary in the one-pass trace (the HBM round-trip
 # is eliminated, not just faster), custom-VJP gradient parity, the
-# PBT_FORCE_REFERENCE_KERNEL override, int8 in-kernel dequant
-# bit-matching the HLO dequant, and the onepass_capture note schema.
+# PBT_FORCE_REFERENCE_KERNEL override, and int8 in-kernel dequant
+# bit-matching the HLO dequant.
 echo "=== one-pass trunk smoke (fused block pass + int8 dequant, CPU) ==="
 timeout -k 10 420 python "$(dirname "$0")/onepass_smoke.py"
 rco=$?
@@ -212,8 +150,7 @@ mark_stage fleet_drill
 # immediately before its flip verb — fleet must converge with zero
 # lost requests and exactly-once sealing), then a forced breach
 # (rollback bit-identical to the pre-rollout baseline, head pins
-# restored). GATED: all of the above + schema-valid rollout_* events
-# + the note(kind=rollout_capture) sentinel sample on the stream.
+# restored). GATED: all of the above + schema-valid rollout_* events.
 echo "=== rollout drill smoke (shadow → gate → flip → rollback, CPU) ==="
 timeout -k 10 420 python "$(dirname "$0")/rollout_drill.py" --json
 rcro=$?
@@ -262,22 +199,6 @@ timeout -k 10 420 python "$(dirname "$0")/quant_smoke.py"
 rcq=$?
 mark_stage quant_smoke
 [ "$rc" -eq 0 ] && rc=$rcq
-
-# Multi-tenant heads smoke (ISSUE 8 satellite): the platform loop end
-# to end — tiny finetune → register into a head registry → serve one
-# mixed-head micro-batch through the shared trunk → downstream eval.
-# Contract failures (mixed-batch parity, trunk-recompile-on-add, lost
-# requests, schema-invalid events) exit nonzero and fail the gate; the
-# mixed-vs-partitioned throughput is reported, not gated.
-echo "=== heads smoke (finetune → register → mixed serve → eval, CPU) ==="
-timeout -k 10 420 env JAX_PLATFORMS=cpu \
-  PBT_HEADS_BENCH_SEQ_LEN=96 PBT_HEADS_BENCH_DIM=32 \
-  PBT_HEADS_BENCH_REQUESTS=36 PBT_HEADS_BENCH_CLIENTS=9 \
-  PBT_HEADS_BENCH_ROUNDS=2 \
-  python "$(dirname "$0")/../bench.py" --heads
-rch=$?
-mark_stage heads_smoke
-[ "$rc" -eq 0 ] && rc=$rch
 
 if [ "$PACKED_MD" = "1" ]; then
   echo "=== packed multi-device parity tier (8 virtual devices, opt-in) ==="

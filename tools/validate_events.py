@@ -138,47 +138,6 @@ NEGATIVE_CASES = [
         {"v": 1, "event": "serve_request", "seq": 0, "t": 0.0,
          "kind": "embed", "outcome": "ok", "request_id": "r1",
          "stages": {}, "quant_parity_max": float("inf")},  # finite
-        # the comm_quant capture note (bench --comm): the sentinel's
-        # input series, so its ratio fields are typed + required.
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "bench", "kind": "comm_quant"},  # missing ratio
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "bench", "kind": "comm_quant",
-         "int8_grad_wire_ratio": 0.0},  # ratio must be > 0
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "bench", "kind": "comm_quant",
-         "int8_grad_wire_ratio": 0.27,
-         "bf16_grad_wire_ratio": "half"},  # typed when present
-        # the pack_attn_capture note (bench --pack attention arm,
-        # ISSUE 13): sentinel-input fields are typed + required.
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "bench", "kind": "pack_attn_capture"},  # no speedup
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "bench", "kind": "pack_attn_capture",
-         "attn_speedup_x": 0.0},  # speedup must be > 0
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "bench", "kind": "pack_attn_capture",
-         "attn_speedup_x": 1.1,
-         "mfu_effective": -0.2},  # MFU must be >= 0 when present
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "bench", "kind": "pack_attn_capture",
-         "attn_speedup_x": 1.1,
-         "parity_max_abs_diff": float("nan")},  # finite when present
-        # the onepass_capture note (bench --pack one-pass arm, ISSUE
-        # 16): sentinel-input fields are typed + required.
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "bench", "kind": "onepass_capture"},  # no speedup
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "bench", "kind": "onepass_capture",
-         "onepass_speedup_x": 0.0},  # speedup must be > 0
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "bench", "kind": "onepass_capture",
-         "onepass_speedup_x": 1.3,
-         "mfu_effective": -0.1},  # MFU must be >= 0 when present
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "bench", "kind": "onepass_capture",
-         "onepass_speedup_x": 1.3,
-         "parity_max_abs_diff": float("inf")},  # finite when present
         # offline batch inference (ISSUE 14): map_* rows are typed —
         # the chaos drill audits streams with this validator, so a
         # writer bug must fail here, not corrupt the drill's verdict.
@@ -199,13 +158,6 @@ NEGATIVE_CASES = [
          "seqs_per_s": float("inf")},  # finite when present
         {"v": 1, "event": "map_end", "seq": 0, "t": 0.0,
          "outcome": "vanished", "stats": {}},  # unknown outcome
-        # the map_capture throughput note (tools/map_drill.py
-        # --bench-events): the sentinel's input series, typed+required.
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "map_drill", "kind": "map_capture"},  # missing rate
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "map_drill", "kind": "map_capture",
-         "map_seqs_per_s": 0.0},  # rate must be > 0
         # the checkpointer's restore_fallback note: bad_step required,
         # landed_step (ISSUE 14 satellite) typed when present.
         {"v": 1, "event": "note", "seq": 0, "t": 0.0,
@@ -216,17 +168,6 @@ NEGATIVE_CASES = [
         {"v": 1, "event": "note", "seq": 0, "t": 0.0,
          "source": "checkpoint", "kind": "restore_fallback",
          "bad_step": 3, "landed_step": 2.5},  # landed_step is an int
-        # the check_capture note (`pbt check --events-jsonl`, ISSUE
-        # 15): the suppression-creep series, typed + required.
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "pbt_check", "kind": "check_capture"},  # no count
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "pbt_check", "kind": "check_capture",
-         "check_findings_total": -1},  # count must be >= 0
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "pbt_check", "kind": "check_capture",
-         "check_findings_total": 2,
-         "check_baselined_total": 1.5},  # typed when present
         # the ANN index + /v1/neighbors subsystem (ISSUE 17): build
         # lifecycle, shard durability, and served-lookup rows are
         # typed — the index drill audits streams with this validator.
@@ -249,22 +190,6 @@ NEGATIVE_CASES = [
         {"v": 1, "event": "neighbor_query", "seq": 0, "t": 0.0,
          "k": 10, "nprobe": 8,
          "outcome": "vanished"},  # not a request outcome
-        # the neighbors_capture note (bench --neighbors): QPS + recall
-        # feed trajectory-sentinel series, typed + required.
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "bench", "kind": "neighbors_capture"},  # no fields
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "bench", "kind": "neighbors_capture",
-         "neighbors_qps": 0.0,
-         "neighbors_recall_at_10": 0.97},  # qps must be > 0
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "bench", "kind": "neighbors_capture",
-         "neighbors_qps": 5000.0,
-         "neighbors_recall_at_10": 1.2},  # recall in [0, 1]
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "bench", "kind": "neighbors_capture",
-         "neighbors_qps": 5000.0, "neighbors_recall_at_10": 0.97,
-         "index_bytes_ratio": -0.3},  # typed when present
         # fleet-scope causal tracing (ISSUE 18): the propagated trace
         # context is optional but TYPED on every carrier event, and
         # fleet_attempt (one sibling record per router try) is fully
@@ -297,40 +222,6 @@ NEGATIVE_CASES = [
         {"v": 1, "event": "fleet_attempt", "seq": 0, "t": 0.0,
          "trace_id": 99, "attempt": 0, "replica": "r0",
          "outcome": "ok"},  # trace_id must be a string
-        # the fleet_trace_capture note (bench --serve fleet A/B arm):
-        # the propagation-overhead sentinel's input, typed + required.
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "bench", "kind": "fleet_trace_capture"},  # no pct
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "bench", "kind": "fleet_trace_capture",
-         "fleet_trace_overhead_pct": float("nan")},  # finite
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "bench", "kind": "fleet_trace_capture",
-         "fleet_trace_overhead_pct": 0.4,
-         "fleet_rps_on": 0.0},  # throughput must be > 0 when present
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "bench", "kind": "fleet_trace_capture",
-         "fleet_trace_overhead_pct": 0.4,
-         "rounds": 0},  # median round count must be >= 1 when present
-        # the serve_pipeline_capture note (bench --serve pipeline A/B,
-        # ISSUE 19): the pipelined-dispatch sentinel's input.
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "bench", "kind": "serve_pipeline_capture"},  # no x
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "bench", "kind": "serve_pipeline_capture",
-         "serve_pipeline_speedup_x": 0.0},  # speedup must be > 0
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "bench", "kind": "serve_pipeline_capture",
-         "serve_pipeline_speedup_x": 1.2,
-         "serve_overlap_ratio": 1.5},  # a ratio: [0, 1]
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "bench", "kind": "serve_pipeline_capture",
-         "serve_pipeline_speedup_x": 1.2,
-         "inflight_max": -1},  # window depth watermark is >= 0
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "map_drill", "kind": "map_capture",
-         "map_seqs_per_s": 10.0,
-         "map_overlap_ratio": -0.1},  # a ratio: [0, 1]
         # blue-green trunk rollout (ISSUE 20): lifecycle, window
         # verdicts, shadow siblings, flips, and fleet coherence are
         # typed — the rollout drill audits the merged stream with this
@@ -371,19 +262,6 @@ NEGATIVE_CASES = [
          "state": "mixed"},  # state is coherent|degraded
         {"v": 1, "event": "rollout_fleet", "seq": 0, "t": 0.0,
          "state": "degraded", "fingerprints": -2},  # count >= 0
-        # the rollout_capture note (tools/rollout_drill.py): shadow
-        # parity + flip latency feed trajectory-sentinel series,
-        # typed + required.
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "rollout_drill", "kind": "rollout_capture"},  # none
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "rollout_drill", "kind": "rollout_capture",
-         "rollout_shadow_parity_max": -1e-6,
-         "rollout_flip_seconds": 0.2},  # parity must be >= 0
-        {"v": 1, "event": "note", "seq": 0, "t": 0.0,
-         "source": "rollout_drill", "kind": "rollout_capture",
-         "rollout_shadow_parity_max": 1e-6,
-         "rollout_flip_seconds": float("inf")},  # finite
 ]
 
 

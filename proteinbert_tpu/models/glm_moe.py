@@ -48,6 +48,7 @@ from jax import lax
 from proteinbert_tpu.configs import DecoderConfig
 from proteinbert_tpu.ops.attention import (
     causal_segment_attention, flash_segment_attention, flash_tiles_fit,
+    tiles_walked_share,
 )
 from proteinbert_tpu.ops.layers import (
     rms_norm_apply, rotary_apply, segment_positions, swiglu_apply,
@@ -162,13 +163,14 @@ def latent_attention(p: Params, x, segment_ids, positions, cfg: DecoderConfig):
         # that names them, not a path that is slow or does not fit.
         sizes = dict(scale=float(nope + rope) ** -0.5, block=cfg.attention_block)
         plain = partial(causal_segment_attention, **sizes)
-        if (flash_tiles_fit(L, cfg.attention_block, nope + rope, dv)
-                or jax.default_backend() == "tpu"):
-            out = lax.platform_dependent(
-                q, k, kv_up[..., nope:], segment_ids,
-                tpu=partial(flash_segment_attention, **sizes), default=plain)
-        else:
-            out = plain(q, k, kv_up[..., nope:], segment_ids)
+        with jax.named_scope("mla_core"):
+            if (flash_tiles_fit(L, cfg.attention_block, nope + rope, dv)
+                    or jax.default_backend() == "tpu"):
+                out = lax.platform_dependent(
+                    q, k, kv_up[..., nope:], segment_ids,
+                    tpu=partial(flash_segment_attention, **sizes), default=plain)
+            else:
+                out = plain(q, k, kv_up[..., nope:], segment_ids)
         return out.reshape(B, L, H * dv) @ p["o"].astype(dt)
 
 
@@ -308,8 +310,9 @@ def update_balance_bias(bias: Params, counters, cfg: DecoderConfig) -> Params:
 def step_metrics(out, counters, segment_ids, cfg: DecoderConfig
                  ) -> Dict[str, jax.Array]:
     """The scalars a step reports, fetched at the log cadence like the
-    loss: both losses under their own names, and this chip's share of
-    the routing."""
+    loss: both losses under their own names, this chip's share of the
+    routing, and the share of the causal tiles that the attention core
+    walks (the flash kernels' own bounds; 1.0 for one document a row)."""
     held = counters["held_counts"].astype(jnp.float32)
     real = (segment_ids > 0).sum().astype(jnp.float32)
     assigned = jnp.maximum(real * cfg.num_experts_per_tok * held.shape[0], 1.0)
@@ -323,4 +326,6 @@ def step_metrics(out, counters, segment_ids, cfg: DecoderConfig
         "assignments_held": held.sum(),
         "routed_here_share": held.sum() / assigned,
         "dropped_assignments": counters["dropped"].astype(jnp.float32),
+        "attn_tiles_walked_share": tiles_walked_share(
+            segment_ids, cfg.attention_block),
     }

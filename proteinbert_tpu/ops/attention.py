@@ -37,6 +37,7 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from proteinbert_tpu.ops.layers import Params
 
@@ -202,41 +203,77 @@ def _attention_block(q, k, v, seg_q, seg_k, q_start, scale):
 
 def flash_tiles_fit(seq_len: int, block: int, head_dim: int,
                     v_head_dim: int) -> bool:
-    """Whether the shipped flash kernel's tiles take these sizes."""
-    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+    """Whether the flash kernels' tiles take these sizes."""
+    from proteinbert_tpu.kernels.segment_flash import LANES
 
     b = min(block, seq_len)
-    return not (b % fa.MIN_BLOCK_SIZE or seq_len % b
-                or head_dim % 128 or v_head_dim % 128)
+    return not (b % LANES or seq_len % b or head_dim % LANES
+                or v_head_dim % LANES)
+
+
+def segment_tile_bounds(segment_ids: jax.Array, block: int):
+    """(lo, hi), each (B, ceil(L / block)) int32: the tiles of `block`
+    positions that causal attention inside segments can reach. Query tile
+    i of row b has keys only in key tiles lo[b, i] .. i; key tile j has
+    queries only in query tiles j .. hi[b, j].
+
+    Rests on one contract: each segment id occupies ONE contiguous run of
+    its row (padding, id 0, one run too), which `data/packing.py`
+    guarantees. A run's start never falls as positions rise, so the
+    earliest key any query of a tile reaches is the start of the run that
+    holds the tile's FIRST position, and the latest query that reaches
+    any key of a tile is the end of the run that holds its LAST. Where an
+    id comes back after another, the bounds are those of the runs, and
+    pairs across the gap fall outside them."""
+    B, L = segment_ids.shape
+    at = jnp.arange(L, dtype=jnp.int32)
+    edge = segment_ids[:, 1:] != segment_ids[:, :-1]
+    first = jnp.concatenate([jnp.ones((B, 1), bool), edge], axis=1)
+    last = jnp.concatenate([edge, jnp.ones((B, 1), bool)], axis=1)
+    run_start = lax.cummax(jnp.where(first, at, 0), axis=1)
+    run_end = lax.cummin(jnp.where(last, at, L - 1), axis=1, reverse=True)
+    tile_first = at[::block]
+    tile_last = jnp.minimum(tile_first + block - 1, L - 1)
+    return run_start[:, tile_first] // block, run_end[:, tile_last] // block
+
+
+def tiles_walked_share(segment_ids: jax.Array, block: int) -> jax.Array:
+    """Tiles inside `segment_tile_bounds` over tiles on or under the
+    diagonal: what the flash kernels walk of the causal walk. 1.0 for
+    rows that are one document each, and for a `block` past the row's
+    end (one tile)."""
+    lo, _ = segment_tile_bounds(segment_ids, block)
+    n = lo.shape[1]
+    walked = (jnp.arange(n, dtype=jnp.int32) - lo + 1).sum()
+    return walked.astype(jnp.float32) / (lo.shape[0] * n * (n + 1) // 2)
 
 
 def flash_segment_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, segment_ids: jax.Array,
     scale: float, block: int,
 ) -> jax.Array:
-    """`causal_segment_attention` by the Pallas TPU flash-attention kernel
-    that ships with jax (`jax.experimental.pallas.ops.tpu.flash_attention`,
-    forward and both backward kernels): online softmax over blocks of
-    keys in float32, nothing of size L x L ever in HBM. Same arguments
-    and result; `block` is the tile of queries and of keys. Sizes the
-    tiles do not take are an error that names them, never another path."""
-    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+    """`causal_segment_attention` by this repo's Pallas TPU flash kernels
+    (`kernels/segment_flash.py`, forward and both backward kernels):
+    online softmax over tiles of keys in float32, nothing of size L x L
+    ever in HBM, and of the causal tiles only those between
+    `segment_tile_bounds` walked. Same arguments and result; `block` is
+    the tile of queries and of keys. Sizes the tiles do not take are an
+    error that names them, never another path."""
+    from proteinbert_tpu.kernels.segment_flash import (
+        LANES, segment_flash_attention,
+    )
 
     L = q.shape[1]
     if not flash_tiles_fit(L, block, q.shape[-1], v.shape[-1]):
         raise ValueError(
             f"flash attention takes rows of a multiple of the block (itself a "
-            f"multiple of {fa.MIN_BLOCK_SIZE}) and head sizes that are "
-            f"multiples of 128; got rows of {L}, block {block}, heads of "
+            f"multiple of {LANES}) and head sizes that are multiples of "
+            f"{LANES}; got rows of {L}, block {block}, heads of "
             f"{q.shape[-1]} / {v.shape[-1]}")
     b = min(block, L)
-    sizes = fa.BlockSizes(
-        block_q=b, block_k_major=b, block_k=b, block_b=1,
-        block_q_major_dkv=b, block_k_major_dkv=b, block_k_dkv=b, block_q_dkv=b,
-        block_k_major_dq=b, block_k_dq=b, block_q_dq=b)
+    lo, hi = segment_tile_bounds(segment_ids, b)
     heads_first = lambda a: a.transpose(0, 2, 1, 3)  # noqa: E731
-    out = fa.flash_attention(
-        heads_first(q), heads_first(k), heads_first(v),
-        segment_ids=fa.SegmentIds(q=segment_ids, kv=segment_ids),
-        causal=True, sm_scale=scale, block_sizes=sizes)
+    out = segment_flash_attention(
+        heads_first(q), heads_first(k), heads_first(v), segment_ids, lo, hi,
+        scale, b)
     return heads_first(out)

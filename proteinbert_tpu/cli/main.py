@@ -642,6 +642,44 @@ def _load_inference_trunk(args):
     return params, cfg
 
 
+def _load_serving_model(args):
+    """(params, cfg) for `pbt serve`. The model is picked by the type of
+    the preset's `model`: ProteinBERT loads its trunk from --pretrained;
+    the causal decoder (`--preset ling3flash_ep4`) has no checkpoint
+    format on the serving path yet, so its weights are made on the
+    device from the run's seed (`models/glm_moe.init_served`), and it is
+    served the only way it is built: ragged, `embed`, no result cache,
+    the preset's rows and documents per row."""
+    from proteinbert_tpu.configs import DecoderConfig, get_preset
+
+    probe = apply_overrides(get_preset(args.preset), args.pretrained_set or [])
+    if not isinstance(probe.model, DecoderConfig):
+        if not args.pretrained:
+            raise SystemExit("--pretrained is required for this preset")
+        return _load_inference_trunk(args)
+    if args.pretrained:
+        raise SystemExit("--pretrained: checkpoints are not built for the "
+                         "served decoder; leave it out and its weights are "
+                         "made from the seed (--pretrained-set train.seed=N)")
+    if getattr(args, "replica_id", None):
+        raise SystemExit("`pbt fleet` is not built for the decoder")
+    import jax
+
+    from proteinbert_tpu.models import glm_moe
+
+    args.serve_mode, args.cache_size = "ragged", 0
+    args.max_batch = probe.data.batch_size
+    args.pack_max_segments = probe.data.pack_max_segments
+    params = glm_moe.init_served(jax.random.PRNGKey(probe.train.seed), probe.model)
+    log(f"decoder {args.preset}: {glm_moe.served_param_count(probe.model) / 1e6:.1f} M "
+        f"parameters made from seed {probe.train.seed} in {probe.model.param_dtype}; "
+        f"served ragged, {args.max_batch} rows x {probe.data.seq_len}, up to "
+        f"{args.pack_max_segments} documents a row, no result cache "
+        "(--serve-mode, --cache-size, --max-batch, --pack-max-segments are "
+        "not read)")
+    return params, probe
+
+
 def _write_run_dir(cfg, params, step: int, output: str) -> None:
     """Seed an orbax run directory from imported params (shared by
     convert-torch and import-weights): fresh TrainState carrying the
@@ -1171,7 +1209,7 @@ def cmd_serve(args) -> int:
     from proteinbert_tpu.serve.http import make_http_server
     from proteinbert_tpu.train.resilience import GracefulShutdown
 
-    params, cfg = _load_inference_trunk(args)
+    params, cfg = _load_serving_model(args)
 
     def _candidate_loader(source: str):
         """Rollout candidate arm (ISSUE 20): load a second trunk from
@@ -2288,10 +2326,13 @@ def build_parser() -> argparse.ArgumentParser:
     sv = sub.add_parser("serve",
                         help="online JSON/HTTP inference server "
                              "(continuous micro-batching)")
-    sv.add_argument("--pretrained", required=True,
-                    help="pretrain checkpoint dir for the trunk")
+    sv.add_argument("--pretrained", default=None,
+                    help="pretrain checkpoint dir for the trunk (required "
+                         "but for the decoder presets, whose weights are "
+                         "made from the seed)")
     sv.add_argument("--preset", default="tiny",
-                    choices=["tiny", "base", "long", "large"])
+                    choices=["tiny", "base", "long", "large",
+                             "ling3flash_ep4", "ling_tiny"])
     sv.add_argument("--pretrained-set", action="append",
                     metavar="PATH=VALUE",
                     help="config override the pretrain run was made with")

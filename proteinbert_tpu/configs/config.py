@@ -77,6 +77,15 @@ class DecoderConfig:
     multi-token-prediction module. Key names follow the published
     config.json; the defaults are its values.
 
+    With `layer_group_size` > 0 the same decoder is a HYBRID stack
+    (Ling-3.0-flash, `model_type: bailing_hybrid`): the layer with the
+    published index i has the latent mixer where (i + 1) %
+    layer_group_size == 0 and the delta-rule linear-attention mixer (KDA,
+    `ops/kda.py`) elsewhere; the router chooses among `topk_group` of
+    `n_group` groups of experts; both mixers end in a head-wise sigmoid
+    gate. `first_layer_index` is the published index of the first layer
+    held here (the stack's phase in the period).
+
     Three fields describe THIS CHIP'S SHARE of an expert-parallel group
     rather than the model: `experts_held` of the `n_routed_experts` the
     router scores (ids `expert_offset` ..), and `vocab_size` rows of the
@@ -97,18 +106,33 @@ class DecoderConfig:
     norm_topk_prob: bool = True
     experts_held: int = 64              # routed experts this chip holds
     expert_offset: int = 0              # id of the first one held
+    n_group: int = 1                    # groups of experts the router
+    topk_group: int = 1                 # chooses among, and how many it keeps
     num_attention_heads: int = 20
-    q_lora_rank: int = 768
+    q_lora_rank: Optional[int] = 768    # None: q straight from x (no low rank)
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 192
     qk_rope_head_dim: int = 64
     v_head_dim: int = 256
     rope_theta: float = 1e6
+    rope_interleave: bool = False       # rotary pairs (2j, 2j + 1), not half-split
     rms_norm_eps: float = 1e-5
     num_nextn_predict_layers: int = 1
+    layer_group_size: int = 0           # 0: every mixer is latent attention
+    first_layer_index: int = 0          # published index of the first layer held
+    mixer_output_gate: bool = False     # y = W_o[heads * sigmoid(x W_g)], head-wise
+    kda_head_dim: int = 128             # d_k = d_v of a KDA head (`head_dim`)
+    short_conv_kernel_size: int = 4
+    kda_lower_bound: float = -5.0       # the log decay lies in (this, 0)
+    kda_chunk: int = 64                 # tokens per chunk of the KDA scan
     mtp_loss_weight: float = 0.3        # lambda (assumed; not in config.json)
     bias_update_speed: float = 1e-3     # gamma (assumed)
     init_std: float = 0.02              # (assumed)
+    # The served hybrid tree's recipe (`glm_moe.init_served`; assumed):
+    # the embedding's rows, and the products that write into the
+    # residual stream (a mixer's `o`, an FFN's `down`). None: `init_std`.
+    embed_init_std: Optional[float] = None
+    out_init_std: Optional[float] = None
     dtype: str = "bfloat16"
     param_dtype: str = "float32"
     attention_block: int = 512          # queries (and keys, on a TPU: the flash
@@ -119,6 +143,10 @@ class DecoderConfig:
     @property
     def num_moe_layers(self) -> int:
         return self.num_hidden_layers - self.first_k_dense_replace
+
+    @property
+    def hybrid(self) -> bool:
+        return self.layer_group_size > 0
 
     @property
     def qk_head_dim(self) -> int:
@@ -529,6 +557,65 @@ def _glm_tiny() -> PretrainConfig:
     )
 
 
+# The served decoder's span ladder: a document takes the smallest span
+# that holds it; what is left of the span is padding the model skips.
+def _span_ladder(seq_len: int, step: int) -> Tuple[int, ...]:
+    return tuple(range(step, seq_len + 1, step))
+
+
+def _ling3flash_ep4() -> PretrainConfig:
+    # Ling-3.0-flash (huggingface.co/inclusionAI/Ling-3.0-flash
+    # config.json, bailing_hybrid) on the SERVING path as ONE chip of a
+    # four-chip expert-parallel layer group holds it: every width as
+    # published; 128 of the 512 routed experts (ids 0-127) and 39,296 of
+    # the 157,184 embedding rows; published layers 1-7: one leading dense
+    # layer, then KDA, KDA, KDA, latent, KDA, KDA (a whole period of six
+    # at the phase of layer 2). 5,068.7 M parameters, bfloat16 in HBM;
+    # the output head and the prediction module are not on this path.
+    # `expert_block` 384 holds one held expert's tokens of a 16,384-token
+    # batch (256 +- 16) in one block.
+    return PretrainConfig(
+        model=DecoderConfig(
+            vocab_size=39_296, hidden_size=2560, num_hidden_layers=7,
+            first_k_dense_replace=1, first_layer_index=1, layer_group_size=6,
+            intermediate_size=6144, moe_intermediate_size=768,
+            n_routed_experts=512, experts_held=128, num_experts_per_tok=8,
+            n_group=8, topk_group=4, routed_scaling_factor=2.5,
+            num_attention_heads=32, q_lora_rank=None, kv_lora_rank=512,
+            qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+            rope_theta=6e6, rope_interleave=True, rms_norm_eps=1e-6,
+            mixer_output_gate=True, num_nextn_predict_layers=0,
+            embed_init_std=1.0, out_init_std=0.02 / (2 * 42) ** 0.5,
+            param_dtype="bfloat16", expert_block=384),
+        data=DataConfig(seq_len=8192, batch_size=2, packing=True,
+                        pack_max_segments=16,
+                        buckets=_span_ladder(8192, 128)),
+    )
+
+
+def _ling_tiny() -> PretrainConfig:
+    # The hybrid decoder at CPU-test size: published layers 1-7 as in
+    # `ling3flash_ep4` (dense, 3 KDA, latent, 2 KDA; period 6), 16
+    # experts in 4 groups (2 kept, top 4), all held, float32 throughout.
+    return PretrainConfig(
+        model=DecoderConfig(
+            vocab_size=512, hidden_size=64, num_hidden_layers=7,
+            first_k_dense_replace=1, first_layer_index=1, layer_group_size=6,
+            intermediate_size=128, moe_intermediate_size=32,
+            n_routed_experts=16, experts_held=16, num_experts_per_tok=4,
+            n_group=4, topk_group=2, routed_scaling_factor=2.5,
+            num_attention_heads=4, q_lora_rank=None, kv_lora_rank=24,
+            qk_nope_head_dim=12, qk_rope_head_dim=4, v_head_dim=16,
+            rope_theta=6e6, rope_interleave=True, rms_norm_eps=1e-6,
+            mixer_output_gate=True, num_nextn_predict_layers=0,
+            embed_init_std=1.0, out_init_std=0.02 / (2 * 42) ** 0.5,
+            kda_head_dim=16, kda_chunk=16, dtype="float32",
+            attention_block=16, expert_block=8, loss_chunk=32),
+        data=DataConfig(seq_len=64, batch_size=2, packing=True,
+                        pack_max_segments=4, buckets=_span_ladder(64, 8)),
+    )
+
+
 PRESETS = {
     "tiny": _tiny,
     "base": _base,
@@ -536,6 +623,8 @@ PRESETS = {
     "large": _large,
     "glm47flash_ep8": _glm47flash_ep8,
     "glm_tiny": _glm_tiny,
+    "ling3flash_ep4": _ling3flash_ep4,
+    "ling_tiny": _ling_tiny,
 }
 
 

@@ -36,9 +36,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from proteinbert_tpu.configs import ModelConfig, PretrainConfig
+from proteinbert_tpu.configs import DecoderConfig, ModelConfig, PretrainConfig
 from proteinbert_tpu.data.vocab import EOS_ID, PAD_ID, SOS_ID, UNK_ID, get_vocab
-from proteinbert_tpu.models import proteinbert
+from proteinbert_tpu.models import glm_moe, proteinbert
 
 logger = logging.getLogger(__name__)
 
@@ -138,6 +138,21 @@ def _packed_encode_batch(params, tokens, segment_ids, annotations,
                       / jnp.maximum(m.sum(-1)[..., None], 1.0))
         return {"local_mean": local_mean,
                 "global": global_.astype(jnp.float32)}
+
+
+@partial(jax.jit, static_argnames="cfg")
+def _packed_decoder_embed_batch(params, tokens, segment_ids, annotations,
+                                cfg: DecoderConfig):
+    """`_packed_encode_batch` of the causal decoder (models/glm_moe.py,
+    the hybrid stack): one (rows, seq_len) packed batch of token
+    documents -> {"global": (B, S, D) the final-norm hidden state at
+    each document's last token, "local_mean": (B, S, D) its mean over
+    the document, "routing": the batch's expert counters}. Same
+    signature as the ProteinBERT entry so the dispatcher calls either;
+    `annotations` is (B, S, 0) and gives S. A negative token id marks a
+    position of a span past its document's end."""
+    return glm_moe.served_embed(params, tokens, segment_ids,
+                                annotations.shape[1], cfg)
 
 
 @partial(jax.jit, static_argnames="cfg")

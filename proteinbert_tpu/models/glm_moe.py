@@ -29,6 +29,21 @@ with each layer recomputed in the backward pass (only its input is
 kept). The head's loss walks chunks of positions so that the logits
 (positions x vocabulary slice, float32) never exist whole.
 
+The same decoder carries a HYBRID stack (Ling-3.0-flash,
+`bailing_hybrid`; `cfg.layer_group_size` > 0), built on the serving path:
+the layer with the published index i has the latent mixer where
+(i + 1) % layer_group_size == 0 and the KDA mixer (`kda_mixer`, the
+delta-rule linear attention of `ops/kda.py` behind a short causal
+convolution) elsewhere; both end in a head-wise sigmoid gate; the latent
+mixer has no low-rank query path and turns its rotary pairs interleaved;
+the router is group-limited. The period is carried by the stack: the
+expert layers held are whole periods (`hybrid_schedule`), a scan over
+periods whose body is a scan of `pre` KDA layers, one latent layer and a
+scan of `post` KDA layers, `pre` set by the first held layer's index.
+`served_embed` is what the server runs: the final-norm hidden state at
+each document's last token and its mean over the document, with the
+step's routing counters.
+
 The router's balance bias is in the parameter tree
 (`params["balance_bias"]`) so that it is sharded, saved and restored
 with everything else, but no gradient reaches it (`stop_gradient`, so
@@ -43,6 +58,7 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from proteinbert_tpu.configs import DecoderConfig
@@ -50,6 +66,7 @@ from proteinbert_tpu.ops.attention import (
     causal_segment_attention, flash_segment_attention, flash_tiles_fit,
     tiles_walked_share,
 )
+from proteinbert_tpu.ops.kda import kda_chunked, segment_conv
 from proteinbert_tpu.ops.layers import (
     rms_norm_apply, rotary_apply, segment_positions, swiglu_apply,
 )
@@ -63,6 +80,11 @@ Params = Dict[str, Any]
 def param_shapes(cfg: DecoderConfig) -> Dict[str, Any]:
     """The parameter tree as shapes. A leaf named `*norm*` starts at 1,
     `balance_bias` at 0, every other leaf is normal(0, init_std)."""
+    if cfg.hybrid:
+        raise NotImplementedError(
+            "the hybrid stack (layer_group_size > 0) is built on the serving "
+            "path only (`init_served`, `served_embed`): its output head, its "
+            "prediction module and the KDA kernel's backward pass are not")
     D, H = cfg.hidden_size, cfg.num_attention_heads
     E, F = cfg.experts_held, cfg.moe_intermediate_size
     attn = {
@@ -142,16 +164,21 @@ def latent_attention(p: Params, x, segment_ids, positions, cfg: DecoderConfig):
         B, L, _ = x.shape
         H, dt = cfg.num_attention_heads, x.dtype
         nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-        c_q = rms_norm_apply(p["q_norm"], x @ p["q_a"].astype(dt), cfg.rms_norm_eps)
-        q = (c_q @ p["q_b"].astype(dt)).reshape(B, L, H, nope + rope)
+        rotary = partial(rotary_apply, positions=positions, theta=cfg.rope_theta,
+                         interleave=cfg.rope_interleave)
+        if cfg.q_lora_rank is None:
+            q = x @ p["q"].astype(dt)
+        else:
+            c_q = rms_norm_apply(p["q_norm"], x @ p["q_a"].astype(dt),
+                                 cfg.rms_norm_eps)
+            q = c_q @ p["q_b"].astype(dt)
+        q = q.reshape(B, L, H, nope + rope)
         kv = x @ p["kv_a"].astype(dt)
         c_kv = rms_norm_apply(p["kv_norm"], kv[..., :cfg.kv_lora_rank],
                               cfg.rms_norm_eps)
-        k_rope = rotary_apply(kv[..., cfg.kv_lora_rank:], positions, cfg.rope_theta)
+        k_rope = rotary(kv[..., cfg.kv_lora_rank:])
         kv_up = (c_kv @ p["kv_b"].astype(dt)).reshape(B, L, H, nope + dv)
-        q = jnp.concatenate(
-            [q[..., :nope], rotary_apply(q[..., nope:], positions, cfg.rope_theta)],
-            axis=-1)
+        q = jnp.concatenate([q[..., :nope], rotary(q[..., nope:])], axis=-1)
         k = jnp.concatenate(
             [kv_up[..., :nope],
              jnp.broadcast_to(k_rope[:, :, None, :], (B, L, H, rope))], axis=-1)
@@ -163,15 +190,64 @@ def latent_attention(p: Params, x, segment_ids, positions, cfg: DecoderConfig):
         # that names them, not a path that is slow or does not fit.
         sizes = dict(scale=float(nope + rope) ** -0.5, block=cfg.attention_block)
         plain = partial(causal_segment_attention, **sizes)
+        # A head of 192 is a lane tile and a half: the flash kernel takes
+        # it padded with zeros to the next whole tile (the scores are the
+        # same; a third of the score products multiply zeros).
+        from proteinbert_tpu.kernels.segment_flash import LANES
+
+        lanes = -(nope + rope) % LANES
+
+        def flash(q, k, v, segment_ids):
+            widen = lambda a: jnp.pad(a, [(0, 0)] * 3 + [(0, lanes)])  # noqa: E731
+            return flash_segment_attention(widen(q), widen(k), v, segment_ids,
+                                           **sizes)
+
         with jax.named_scope("mla_core"):
-            if (flash_tiles_fit(L, cfg.attention_block, nope + rope, dv)
+            if (flash_tiles_fit(L, cfg.attention_block, nope + rope + lanes, dv)
                     or jax.default_backend() == "tpu"):
                 out = lax.platform_dependent(
                     q, k, kv_up[..., nope:], segment_ids,
-                    tpu=partial(flash_segment_attention, **sizes), default=plain)
+                    tpu=flash if lanes else partial(flash_segment_attention, **sizes),
+                    default=plain)
             else:
                 out = plain(q, k, kv_up[..., nope:], segment_ids)
+        if cfg.mixer_output_gate:
+            out = _head_gate(out, x, p["g"])
         return out.reshape(B, L, H * dv) @ p["o"].astype(dt)
+
+
+def _head_gate(heads, x, gate_kernel):
+    """heads (B, L, H, d) * sigmoid(x W_g), one scalar a head, float32."""
+    gate = jax.nn.sigmoid(jnp.dot(x, gate_kernel.astype(x.dtype),
+                                  preferred_element_type=jnp.float32))
+    return (heads.astype(jnp.float32) * gate[..., None]).astype(x.dtype)
+
+
+def kda_mixer(p: Params, x, segment_ids, cfg: DecoderConfig):
+    """The delta-rule linear-attention mixer (KDA): projections in the
+    activation dtype accumulated in float32; convolution, norms, gates
+    and the recurrence's state in float32."""
+    with jax.named_scope("kda"):
+        B, L, _ = x.shape
+        H, dk, dt = cfg.num_attention_heads, cfg.kda_head_dim, x.dtype
+        f32 = jnp.float32
+        wide = lambda name: jnp.dot(  # noqa: E731
+            x, p[name].astype(dt), preferred_element_type=f32)
+        heads = lambda a: a.reshape(B, L, H, dk)  # noqa: E731
+        proj = lambda name: heads(jax.nn.silu(segment_conv(  # noqa: E731
+            wide(name), p["conv_" + name].astype(f32), segment_ids)))
+        unit = lambda a: a * lax.rsqrt(  # noqa: E731
+            jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+        q, k, v = unit(proj("q")) * dk ** -0.5, unit(proj("k")), proj("v")
+        rate = jnp.exp(p["A_log"].astype(f32))[:, None]
+        g = cfg.kda_lower_bound * jax.nn.sigmoid(
+            rate * heads(wide("f") + p["dt_bias"].astype(f32)))
+        beta = jax.nn.sigmoid(wide("beta"))
+        with jax.named_scope("kda_core"):
+            o = kda_chunked(q, k, v, g, beta, segment_ids, cfg.kda_chunk)
+        o = rms_norm_apply(p["o_norm"].astype(f32), o, cfg.rms_norm_eps)
+        o = _head_gate(o, x, p["g"])
+        return o.reshape(B, L, H * dk) @ p["o"].astype(dt)
 
 
 def dense_layer(p: Params, x, segment_ids, positions, cfg: DecoderConfig):
@@ -215,6 +291,291 @@ def trunk(params: Params, tokens, segment_ids, positions, cfg: DecoderConfig):
     x, _ = lax.scan(jax.checkpoint(dense_body), x, params["dense"])
     return lax.scan(jax.checkpoint(expert_body), x,
                     (params["layers"], params["balance_bias"]["layers"]))
+
+
+# ---------------------------------------------- hybrid stack, serving path
+
+TOP_INDEX = 2 ** 20     # the "layer index" of the embedding and the final norm
+
+
+def hybrid_schedule(cfg: DecoderConfig):
+    """(periods, pre, post): the expert layers held are `periods` whole
+    periods of `layer_group_size`, each `pre` KDA layers, the latent
+    layer, `post` KDA layers; `pre` follows from the published index of
+    the first of them. Depths the stack does not carry are refused."""
+    G, n_dense = cfg.layer_group_size, cfg.first_k_dense_replace
+    first = cfg.first_layer_index + n_dense
+    if any((cfg.first_layer_index + j + 1) % G == 0 for j in range(n_dense)):
+        raise ValueError("a leading dense layer with the latent mixer is not "
+                         "carried: the dense layers held have the KDA mixer")
+    periods, rest = divmod(cfg.num_moe_layers, G)
+    if rest or not periods:
+        raise ValueError(
+            f"the hybrid stack carries whole periods of {G} expert layers; "
+            f"{cfg.num_moe_layers} held from the published index {first} are not")
+    pre = (G - 1 - first % G) % G
+    return periods, pre, G - 1 - pre
+
+
+def hybrid_layer_shapes(cfg: DecoderConfig, mixer: str, ffn: str) -> Dict[str, Any]:
+    """One layer's tree as shapes; the names and their sorted order are
+    part of the weights' recipe (`init_served`)."""
+    D, H = cfg.hidden_size, cfg.num_attention_heads
+    if mixer == "kda":
+        W, K = H * cfg.kda_head_dim, cfg.short_conv_kernel_size
+        mix = {"q": (D, W), "k": (D, W), "v": (D, W), "f": (D, W), "o": (W, D),
+               "beta": (D, H), "g": (D, H), "conv_q": (K, W), "conv_k": (K, W),
+               "conv_v": (K, W), "A_log": (H,), "dt_bias": (W,),
+               "o_norm": (cfg.kda_head_dim,)}
+    else:
+        mix = {"q": (D, H * cfg.qk_head_dim),
+               "kv_a": (D, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+               "kv_norm": (cfg.kv_lora_rank,),
+               "kv_b": (cfg.kv_lora_rank, H * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+               "o": (H * cfg.v_head_dim, D), "g": (D, H)}
+    swiglu = lambda width: {"gate": (D, width), "up": (D, width),  # noqa: E731
+                            "down": (width, D)}
+    tree = {"mixer": mix, "norm1": (D,), "norm2": (D,)}
+    if ffn == "dense":
+        tree["mlp"] = swiglu(cfg.intermediate_size)
+    else:
+        E, F = cfg.experts_held, cfg.moe_intermediate_size
+        tree["moe"] = {"router": (D, cfg.n_routed_experts),
+                       "router_bias": (cfg.n_routed_experts,),
+                       "experts": {"gate": (E, D, F), "up": (E, D, F),
+                                   "down": (E, F, D)}}
+        tree["shared"] = swiglu(cfg.n_shared_experts * F)
+    return tree
+
+
+def _hybrid_stacks(cfg: DecoderConfig):
+    """[(name in the tree, leading shape, mixer, ffn, published index of
+    each layer in the order of the leading shape)]."""
+    periods, pre, post = hybrid_schedule(cfg)
+    G, n_dense = cfg.layer_group_size, cfg.first_k_dense_replace
+    first = cfg.first_layer_index + n_dense
+    at = lambda p, j: first + p * G + j  # noqa: E731
+    stacks = [("dense", (n_dense,), "kda", "dense",
+               [cfg.first_layer_index + j for j in range(n_dense)]),
+              ("mla", (periods,), "mla", "moe", [at(p, pre) for p in range(periods)])]
+    if pre:
+        stacks.append(("pre", (periods, pre), "kda", "moe",
+                       [at(p, j) for p in range(periods) for j in range(pre)]))
+    if post:
+        stacks.append(("post", (periods, post), "kda", "moe",
+                       [at(p, pre + 1 + j) for p in range(periods) for j in range(post)]))
+    return stacks
+
+
+def served_param_count(cfg: DecoderConfig) -> int:
+    """Parameters the served tree holds (the router's bias is not one)."""
+    def count(tree):
+        flat, _ = jax.tree_util.tree_flatten_with_path(
+            tree, is_leaf=lambda s: isinstance(s, tuple))
+        return sum(int(np.prod(shape)) for path, shape in flat
+                   if path[-1].key != "router_bias")
+
+    return (cfg.vocab_size * cfg.hidden_size + cfg.hidden_size
+            + sum(len(indices) * count(hybrid_layer_shapes(cfg, mixer, ffn))
+                  for _, _, mixer, ffn, indices in _hybrid_stacks(cfg)))
+
+
+def served_abstract(cfg: DecoderConfig) -> Params:
+    """The served tree as `jax.ShapeDtypeStruct`s: what it takes to lower
+    `served_embed` without making a weight."""
+    dt = jnp.dtype(cfg.param_dtype)
+    is_shape = lambda s: isinstance(s, tuple)  # noqa: E731
+    leaf = lambda s: jax.ShapeDtypeStruct(s, dt)  # noqa: E731
+    tree = {"embed": leaf((cfg.vocab_size, cfg.hidden_size)),
+            "final_norm": leaf((cfg.hidden_size,))}
+    for name, lead, mixer, ffn, _ in _hybrid_stacks(cfg):
+        tree[name] = jax.tree.map(lambda s: leaf(lead + s),
+                                  hybrid_layer_shapes(cfg, mixer, ffn),
+                                  is_leaf=is_shape)
+    return tree
+
+
+@partial(jax.jit, static_argnames=("name", "shape", "heads", "std", "dtype"))
+def _draw(key, index, j, name, shape, heads, std, dtype):
+    if "norm" in name:
+        leaf = jnp.ones(shape, jnp.float32)
+    elif name == "router_bias":
+        leaf = jnp.zeros(shape, jnp.float32)
+    elif name == "A_log":
+        leaf = jnp.log(1.0 + 3.0 * jnp.arange(heads, dtype=jnp.float32)
+                       / max(heads - 1, 1))
+    elif name == "dt_bias":
+        leaf = jnp.full(shape, -4.0, jnp.float32)
+    else:
+        leaf = std * jax.random.normal(
+            jax.random.fold_in(jax.random.fold_in(key, index), j), shape,
+            jnp.float32)
+    return lax.reduce_precision(leaf, exponent_bits=8, mantissa_bits=7).astype(dtype)
+
+
+@partial(jax.jit, donate_argnums=0)
+def _put(stack, leaf, at):
+    return lax.dynamic_update_slice(
+        stack, leaf[(None,) * at.shape[0]],
+        tuple(at) + (jnp.zeros((), at.dtype),) * leaf.ndim)
+
+
+def _leaf_std(name: str, cfg: DecoderConfig) -> float:
+    """The embedding's rows and the products that write into the
+    residual stream (`o`, `down`) have a deviation of their own."""
+    own = {"embed": cfg.embed_init_std, "o": cfg.out_init_std,
+           "down": cfg.out_init_std}.get(name)
+    return cfg.init_std if own is None else own
+
+
+def _tree_of(key, index: int, shapes, cfg: DecoderConfig):
+    flat, treedef = jax.tree_util.tree_flatten_with_path(
+        shapes, is_leaf=lambda s: isinstance(s, tuple))
+    return jax.tree.unflatten(treedef, [
+        _draw(key, index, j, str(path[-1].key), shape, cfg.num_attention_heads,
+              _leaf_std(str(path[-1].key), cfg), cfg.param_dtype)
+        for j, (path, shape) in enumerate(flat)])
+
+
+def init_served(key: jax.Array, cfg: DecoderConfig) -> Params:
+    """The served hybrid tree, made on the device LEAF BY LEAF in
+    `cfg.param_dtype`: the layer with the published index i draws leaf
+    number j of its own tree (keys sorted) as init_std * normal(
+    fold_in(fold_in(key, i), j)) in float32, rounded to bfloat16 as it
+    is made (the published weights are bfloat16 values) and written into
+    its place in its stack, so the share never stands whole in float32.
+    Norm scales 1, the router's bias 0, A_log_h = log(1 + 3 h / (H - 1)),
+    dt_bias -4, every other leaf (the conv taps too) init_std * normal,
+    but the embedding's rows (`embed_init_std`) and the products that
+    write into the residual stream (`out_init_std`: a mixer's `o`, an
+    FFN's `down`): a recipe a reference can follow without this module.
+    (With every leaf at 0.02 a layer's result is as large as the stream
+    it is added to, and the seeded network passed a rounding on with a
+    gain of ~20 over seven layers: no comparison could tell bfloat16
+    products from int8. Rows of unit size and results a tenth of the
+    stream, as a depth-scaled init gives a model of the published 42
+    layers, keep the gain near 1. Taps of the size
+    of K^-1/2 were tried first: SiLU of a unit-variance input has a mean
+    of a quarter of its rms, linear attention sums that mean coherently
+    over a document, every token's hidden state collapses onto one
+    vector and every token picks the same experts. With taps of 0.02 the
+    SiLU is all but linear at its input's size and the mean is gone; q,
+    k and v are rescaled after it by their norms.) Tree: `embed`,
+    `final_norm`, and the stacks `dense` (n,), `mla` (periods,), `pre`
+    (periods, pre), `post` (periods, post) of layer trees."""
+    top = {"embed": (cfg.vocab_size, cfg.hidden_size),
+           "final_norm": (cfg.hidden_size,)}
+    params = _tree_of(key, TOP_INDEX, top, cfg)
+    dt = jnp.dtype(cfg.param_dtype)
+    for name, lead, mixer, ffn, indices in _hybrid_stacks(cfg):
+        shapes = hybrid_layer_shapes(cfg, mixer, ffn)
+        stack = jax.tree.map(lambda s: jnp.zeros(lead + s, dt), shapes,
+                             is_leaf=lambda s: isinstance(s, tuple))
+        for n, index in enumerate(indices):
+            at = jnp.asarray(np.unravel_index(n, lead), jnp.int32)
+            stack = jax.tree.map(lambda big, leaf: _put(big, leaf, at), stack,
+                                 _tree_of(key, index, shapes, cfg))
+        params[name] = stack
+    return params
+
+
+def _hybrid_layer(stack: Params, at, x, segment_ids, positions, real,
+                  cfg: DecoderConfig, mixer: str):
+    """Layer `at` (leading indices) of a stack. The held experts'
+    matrices are handed to the grouped products as the stack they lie in."""
+    experts = stack.get("moe", {}).get("experts")
+    p = jax.tree.map(lambda a: a[at], {
+        k: ({m: w for m, w in v.items() if m != "experts"} if k == "moe" else v)
+        for k, v in stack.items()})
+    dt = jnp.dtype(cfg.dtype)
+    h = rms_norm_apply(p["norm1"], x, cfg.rms_norm_eps).astype(dt)
+    if mixer == "kda":
+        x = x + kda_mixer(p["mixer"], h, segment_ids, cfg)
+    else:
+        x = x + latent_attention(p["mixer"], h, segment_ids, positions, cfg)
+    h = rms_norm_apply(p["norm2"], x, cfg.rms_norm_eps).astype(dt)
+    if experts is None:
+        with jax.named_scope("dense_mlp"):
+            return x + swiglu_apply(p["mlp"], h), None
+    B, L, D = h.shape
+    routed, stats = moe_apply(
+        dict(p["moe"], experts=experts), p["moe"]["router_bias"].astype(jnp.float32),
+        h.reshape(B * L, D), real.reshape(B * L), cfg, at=at)
+    with jax.named_scope("shared_expert"):
+        shared = swiglu_apply(p["shared"], h)
+    return x + routed.reshape(B, L, D) + shared, (stats["held_counts"],
+                                                  stats["dropped"])
+
+
+def hybrid_trunk(params: Params, tokens, segment_ids, real, cfg: DecoderConfig):
+    """-> (h (B, L, D) before the final norm, held_counts (expert layers,
+    experts_held), dropped ()). `real` (B, L) marks the positions that
+    hold a token: a span's tail past its document is routed nowhere."""
+    periods, pre, post = hybrid_schedule(cfg)
+    positions = segment_positions(segment_ids)
+    # The residual stream is float32 (a layer's result, in the activation
+    # dtype, is added to it): rounding it to bfloat16 at every add would
+    # cost as much accuracy as int8 products do.
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], jnp.maximum(tokens, 0),
+                     axis=0).astype(jnp.float32)
+
+    def run(x, name, mixer, count, lead=()):
+        def body(x, l):
+            return _hybrid_layer(params[name], lead + (l,), x, segment_ids,
+                                 positions, real, cfg, mixer)
+        return lax.scan(body, x, jnp.arange(count))
+
+    x, _ = run(x, "dense", "kda", cfg.first_k_dense_replace)
+
+    def period(x, p):
+        counts, dropped = [], []
+        for name, mixer, count in (("pre", "kda", pre), ("mla", "mla", 1),
+                                   ("post", "kda", post)):
+            if not count:
+                continue
+            if name == "mla":
+                x, (c, d) = _hybrid_layer(params["mla"], (p,), x, segment_ids,
+                                          positions, real, cfg, "mla")
+                c, d = c[None], d[None]
+            else:
+                x, (c, d) = run(x, name, mixer, count, lead=(p,))
+            counts.append(c)
+            dropped.append(d)
+        return x, (jnp.concatenate(counts), jnp.concatenate(dropped).sum())
+
+    x, (counts, dropped) = lax.scan(period, x, jnp.arange(periods))
+    return x, counts.reshape(-1, cfg.experts_held), dropped.sum()
+
+
+def served_embed(params: Params, tokens, segment_ids, num_segments: int,
+                 cfg: DecoderConfig):
+    """What `embed` answers for every document of a packed batch.
+    tokens: (B, L) int32, a NEGATIVE id where a span holds no token (a
+    request's span is the smallest of the ladder that holds it);
+    segment_ids: (B, L), 0 outside every span, 1..num_segments inside.
+    -> {"global": (B, S, D) the final-norm hidden state at each
+    document's last token, "local_mean": (B, S, D) its mean over the
+    document's tokens, "routing": the batch's counters}, float32."""
+    real = (segment_ids > 0) & (tokens >= 0)
+    with jax.named_scope("encode"):
+        h, counts, dropped = hybrid_trunk(params, tokens, segment_ids, real, cfg)
+        h = rms_norm_apply(params["final_norm"], h, cfg.rms_norm_eps)
+    with jax.named_scope("pool"):
+        h = h.astype(jnp.float32)
+        m = ((segment_ids[:, None, :]
+              == jnp.arange(1, num_segments + 1, dtype=segment_ids.dtype)[None, :, None])
+             & real[:, None, :])                                    # (B, S, L)
+        n = m.sum(-1)
+        last = jnp.argmax(jnp.where(m, jnp.arange(m.shape[-1]), -1), axis=-1)
+        mean = (jnp.einsum("bsl,bld->bsd", m.astype(jnp.float32), h,
+                           precision=lax.Precision.HIGHEST)
+                / jnp.maximum(n, 1)[..., None])
+        at_last = jnp.take_along_axis(h, last[..., None], axis=1)
+        return {"global": jnp.where((n > 0)[..., None], at_last, 0.0),
+                "local_mean": mean,
+                "routing": {"held_counts": counts, "dropped": dropped,
+                            "real_tokens": real.sum()}}
 
 
 def head_loss(params: Params, h, targets, valid, cfg: DecoderConfig):

@@ -130,9 +130,11 @@ def segment_positions(segment_ids: jax.Array) -> jax.Array:
     return idx - lax.cummax(jnp.where(starts, idx, 0), axis=segment_ids.ndim - 1)
 
 
-def rotary_apply(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def rotary_apply(x: jax.Array, positions: jax.Array, theta: float,
+                 interleave: bool = False) -> jax.Array:
     """Rotary position embedding over ALL of the last axis, half-split
-    pairing (dimension j turns with dimension j + d/2). x: (B, L, ..., d),
+    pairing (dimension j turns with dimension j + d/2) or, with
+    `interleave`, neighbours (2j with 2j + 1). x: (B, L, ..., d),
     positions: (B, L). Angles in float32."""
     d = x.shape[-1]
     inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
@@ -140,6 +142,10 @@ def rotary_apply(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     angles = angles.reshape(angles.shape[:2] + (1,) * (x.ndim - 3) + (d // 2,))
     cos, sin = jnp.cos(angles), jnp.sin(angles)
     x32 = x.astype(jnp.float32)
+    if interleave:
+        a, b = x32[..., 0::2], x32[..., 1::2]
+        return jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                         axis=-1).reshape(x.shape).astype(x.dtype)
     a, b = x32[..., : d // 2], x32[..., d // 2:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
                            axis=-1).astype(x.dtype)

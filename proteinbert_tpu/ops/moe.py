@@ -9,6 +9,10 @@ chip of an expert-parallel group, without its exchange).
 
     s       = sigmoid(x W_r)                 float32, all experts
     chosen  = top-k of s + b                 b: balance bias, no gradient
+              (group-limited where the router has groups: the experts in
+              `n_group` equal groups, a group's score the sum of its two
+              best s + b, the best `topk_group` groups kept, the top k
+              among their experts)
     weights = s[chosen] / (sum + 1e-20) * routed_scaling_factor
     y       = sum over chosen experts HELD HERE of weight * Expert_e(x)
 
@@ -55,7 +59,7 @@ class Plan(NamedTuple):
 
 
 def route(x, router_kernel, bias, top_k: int, scaling: float,
-          norm_topk: bool = True):
+          norm_topk: bool = True, n_group: int = 1, topk_group: int = 1):
     """x: (T, D) -> (ids (T, k) int32, weights (T, k) float32). The
     product runs in float32 at full precision, as published; the bias
     moves the choice only."""
@@ -64,7 +68,16 @@ def route(x, router_kernel, bias, top_k: int, scaling: float,
                          router_kernel.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
         scores = jax.nn.sigmoid(logits)
-        _, ids = lax.top_k(scores + lax.stop_gradient(bias), top_k)
+        choice = scores + lax.stop_gradient(bias)
+        if n_group > 1:
+            T, R = choice.shape
+            group_score = lax.top_k(
+                choice.reshape(T, n_group, R // n_group), 2)[0].sum(-1)
+            kept = lax.top_k(group_score, topk_group)[1]
+            open_ = (kept[:, :, None] == jnp.arange(n_group)).any(1)
+            choice = jnp.where(jnp.repeat(open_, R // n_group, axis=1),
+                               choice, -jnp.inf)
+        _, ids = lax.top_k(choice, top_k)
         weights = jnp.take_along_axis(scores, ids, axis=-1)
         if norm_topk:
             weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
@@ -132,7 +145,11 @@ def expert_ffn(x, weights, gate, up, down, plan: Plan, top_k: int, block: int):
     return _expert_fwd(x, weights, gate, up, down, plan, top_k, block)[0]
 
 
-def _expert_fwd(x, weights, gate, up, down, plan, top_k, block):
+def _expert_fwd(x, weights, gate, up, down, plan, top_k, block, at=()):
+    """`at`: leading indices of the held experts' matrices inside stacks
+    of several layers' (the served hybrid decoder hands the whole stack
+    and the layer's place in it, so that no layer's experts are copied
+    out of the stack before the loop)."""
     T, D = x.shape
     dt = x.dtype
     xp = jnp.concatenate([x, jnp.zeros((block, D), dt)])
@@ -147,9 +164,10 @@ def _expert_fwd(x, weights, gate, up, down, plan, top_k, block):
             # the expert's matrices are rounded here, a block at a time:
             # a copy of every held expert in the compute dtype would
             # stand in HBM for the whole layer
-            hg, hu = _hidden(xb, gate[e].astype(dt), up[e].astype(dt))
+            hg, hu = _hidden(xb, gate[(*at, e)].astype(dt), up[(*at, e)].astype(dt))
             h = (jax.nn.silu(hg) * hu).astype(dt)
-            ob = jnp.dot(h, down[e].astype(dt), preferred_element_type=jnp.float32)
+            ob = jnp.dot(h, down[(*at, e)].astype(dt),
+                         preferred_element_type=jnp.float32)
         with jax.named_scope("moe_dispatch"):
             return y.at[tok].add(wb[:, None] * ob, unique_indices=True)
 
@@ -214,22 +232,26 @@ def _expert_bwd(top_k, block, saved, dy):
 expert_ffn.defvjp(_expert_fwd, _expert_bwd)
 
 
-def moe_apply(params: Dict, bias, x, real, cfg):
+def moe_apply(params: Dict, bias, x, real, cfg, at=()):
     """The routed part of an expert layer over x: (T, D); `real` (T,)
     marks the tokens that are not padding: a pad token is routed
     nowhere and counted nowhere (every pad has the same input, so they
     would all fall on the same experts). Returns (y (T, D) in x's dtype,
     stats): `load` counts all `n_routed_experts` (what the balance bias
     follows), `held_counts` / `dropped` are the step's counters for this
-    chip's share, `ids` the experts chosen (`n_routed_experts` at a pad)."""
+    chip's share, `ids` the experts chosen (`n_routed_experts` at a pad).
+    With `at` the experts' matrices are stacks of several layers' and
+    this layer's lie at those leading indices: forward only."""
     ids, weights = route(x, params["router"], bias, cfg.num_experts_per_tok,
-                         cfg.routed_scaling_factor, cfg.norm_topk_prob)
+                         cfg.routed_scaling_factor, cfg.norm_topk_prob,
+                         cfg.n_group, cfg.topk_group)
     ids = jnp.where(real[:, None], ids, cfg.n_routed_experts)
     plan = plan_dispatch(ids, cfg.experts_held, cfg.expert_offset,
                          cfg.expert_block)
     experts = params["experts"]
-    y = expert_ffn(x, weights, experts["gate"], experts["up"], experts["down"],
-                   plan, cfg.num_experts_per_tok, cfg.expert_block)
+    operands = (x, weights, experts["gate"], experts["up"], experts["down"],
+                plan, cfg.num_experts_per_tok, cfg.expert_block)
+    y = _expert_fwd(*operands, at=at)[0] if at else expert_ffn(*operands)
     with jax.named_scope("moe_router"):
         load = jnp.zeros((cfg.n_routed_experts + 1,), jnp.int32).at[
             ids.reshape(-1)].add(1)[:-1]

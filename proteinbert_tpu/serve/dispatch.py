@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from proteinbert_tpu.configs import PretrainConfig
+from proteinbert_tpu.configs import DecoderConfig, PretrainConfig
 from proteinbert_tpu.data.vocab import EOS_ID, PAD_ID, SOS_ID
 from proteinbert_tpu import inference
 from proteinbert_tpu.heads import apply as heads_apply
@@ -72,6 +72,10 @@ def _device_hbm_bytes() -> Optional[int]:
 # a trunk compile (the executable-count-stays-flat contract,
 # tests/test_heads.py).
 TASK_KIND = "predict_task"
+
+# What fills a decoder request's span past its document's end: every id
+# of its vocabulary is a real token, 0 included.
+DECODER_PAD = -1
 
 # The ANN request kind (ISSUE 17): a `neighbors` request's DEVICE work
 # is exactly an embed — the query rides the same warm embed executables
@@ -1083,6 +1087,16 @@ class RaggedDispatcher(BucketDispatcher):
     shared packed trunk for predict_task at each class + per-head-
     structure tails, versus the bucketed |buckets| x |classes| x kinds
     zoo — tracked by the same `serve_executable_count` gauge.
+
+    The model is picked by the type of `cfg.model` (ISSUE 33): a
+    `DecoderConfig` (the causal hybrid decoder, models/glm_moe.py) is
+    served through the same rows, ladder and classes with `embed` alone.
+    Its requests are documents of token ids with no special tokens (a
+    span is the smallest of the ladder that holds the document, the
+    rest of it marked by the id DECODER_PAD), its packed executable
+    returns the batch's routing counters with the answers
+    (`routing_stats`), and what is not built for it (int8, heads, the
+    other kinds) is refused by name.
     """
 
     def __init__(
@@ -1116,6 +1130,11 @@ class RaggedDispatcher(BucketDispatcher):
         # split evenly across the replicas (the parent ctor enforces it
         # and builds self._shardings); a smaller class that does not is
         # left out of the ladder.
+        self.decoder = isinstance(cfg.model, DecoderConfig)
+        if self.decoder and quant != "fp32":
+            raise ValueError(
+                f"quant={quant!r}: int8 weights are not built for the "
+                "decoder (its weights are held in bfloat16)")
         divisor = 1
         if mesh is not None:
             divisor = mesh.shape.get("data", 1) * mesh.shape.get("fsdp", 1)
@@ -1127,6 +1146,47 @@ class RaggedDispatcher(BucketDispatcher):
                          quant_parity_every=quant_parity_every)
         self.rows_per_batch = int(rows_per_batch)
         self.max_segments = int(max_segments)
+        # The decoder's routing counters, summed over the batches run
+        # (warm-up's are not counted).
+        self._routing_lock = threading.Lock()
+        self._routing = {"batches": 0, "assignments_held": 0,  # guarded-by: _routing_lock
+                         "dropped_assignments": 0, "real_tokens": 0,
+                         "load_max_over_mean_sum": 0.0}
+
+    def bucket_len(self, length: int) -> int:
+        """The decoder's documents carry no <sos> / <eos>."""
+        if not self.decoder:
+            return super().bucket_len(length)
+        return self.buckets[int(np.searchsorted(
+            self.buckets, min(length, self.cfg.data.seq_len)))]
+
+    def add_head(self, head: LoadedHead, warm: bool = False) -> float:
+        if self.decoder:
+            raise ValueError("heads are not built for the decoder: its "
+                             "server answers `embed` only")
+        return super().add_head(head, warm=warm)
+
+    def routing_stats(self) -> Optional[Dict]:
+        """The decoder's counters over the batches run so far: batches,
+        (token, slot) assignments that fell on held experts, those of
+        them no block took, real tokens, and the sum over batches of the
+        fullest held expert's load over the mean (all expert layers
+        pooled). None for a model without experts."""
+        if not self.decoder:
+            return None
+        with self._routing_lock:
+            return dict(self._routing)
+
+    def _note_routing(self, routing) -> None:
+        held = np.asarray(routing["held_counts"], np.float64)
+        with self._routing_lock:
+            r = self._routing
+            r["batches"] += 1
+            r["assignments_held"] += int(held.sum())
+            r["dropped_assignments"] += int(routing["dropped"])
+            r["real_tokens"] += int(routing["real_tokens"])
+            r["load_max_over_mean_sum"] += float(
+                held.max() / max(held.mean(), 1.0))
 
     # ----------------------------------------------------------- execution
 
@@ -1144,6 +1204,12 @@ class RaggedDispatcher(BucketDispatcher):
     def _packed_fn(self, kind: str, quantized: Optional[bool] = None):
         if quantized is None:
             quantized = self.quant != "fp32"
+        if self.decoder:
+            if kind != "embed":
+                raise ValueError(
+                    f"request kind {kind!r} is not built for the decoder: "
+                    "its server answers `embed` only")
+            return inference._packed_decoder_embed_batch
         if quantized:
             from proteinbert_tpu.parallel.quant import quant_packed_entry
 
@@ -1238,7 +1304,8 @@ class RaggedDispatcher(BucketDispatcher):
         timings: Dict[str, float] = {}
         with tracing.span("serve.place", batch=batch) as placed:
             if timed:
-                real = int((tokens != PAD_ID).sum())
+                real = int(((segment_ids > 0) & (tokens != DECODER_PAD)).sum()
+                           if self.decoder else (tokens != PAD_ID).sum())
                 timings["pad_fraction"] = round(1.0 - real / (R * L), 6)
                 timings["segments"] = len(riders)
                 timings["segments_per_row"] = round(len(riders) / R, 4)
@@ -1280,7 +1347,10 @@ class RaggedDispatcher(BucketDispatcher):
             self._note_warm((kind, L, R))
 
             def fetch():
-                return jax.tree.map(np.asarray, res)
+                host = jax.tree.map(np.asarray, res)
+                if self.decoder and not self._warming:
+                    self._note_routing(host["routing"])
+                return host
 
             def fan_out(host):
                 fanned = []
@@ -1330,6 +1400,12 @@ class RaggedDispatcher(BucketDispatcher):
         R = self.rows_per_batch if rows is None else rows
         L = self.cfg.data.seq_len
         span = self.buckets[0]
+        if self.decoder:
+            seg = np.zeros((R, L), np.int32)
+            seg[:, :span] = 1
+            return (np.zeros((R, L), np.int32), seg,
+                    np.zeros((R, self.max_segments, 0), np.float32),
+                    [(r, 0, 0, span) for r in range(R)])
         tokens = np.full((R, L), PAD_ID, np.int32)
         tokens[:, 0] = SOS_ID
         tokens[:, 1] = EOS_ID

@@ -287,8 +287,11 @@ def make_handler(server: Server):
             try:
                 body = self._read_body()
                 seq = body["seq"]
-                if not isinstance(seq, str):
-                    raise ValueError("'seq' must be a string")
+                # a protein is a string; the decoder's document a list
+                # of token ids, checked by Server.submit
+                if not isinstance(seq, list if server.decoder else str):
+                    raise ValueError("'seq' must be a string (a list of "
+                                     "token ids for the decoder)")
                 deadline_ms = body.get("deadline_ms")
                 if deadline_ms is not None and (
                         isinstance(deadline_ms, bool)

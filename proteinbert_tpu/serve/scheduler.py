@@ -849,7 +849,8 @@ class PackedBatchScheduler(MicroBatchScheduler):
                 if len(packer) == 0:
                     del self._packers[kind]
             assembling.ids.update(rows=len(rows), cls=R)
-            num_ann = self.dispatcher.cfg.model.num_annotations
+            # the decoder (configs.DecoderConfig) has no annotations
+            num_ann = getattr(self.dispatcher.cfg.model, "num_annotations", 0)
             tokens = np.zeros((R, L), np.int32)
             segment_ids = np.zeros((R, L), np.int32)
             annotations = np.zeros((R, S, num_ann), np.float32)

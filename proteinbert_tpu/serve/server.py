@@ -79,14 +79,15 @@ from typing import Any, Dict, Optional
 import numpy as np
 
 from proteinbert_tpu import inference
-from proteinbert_tpu.configs import PretrainConfig
+from proteinbert_tpu.configs import DecoderConfig, PretrainConfig
 from proteinbert_tpu.heads.registry import (
     HeadRegistry, LoadedHead, TrunkMismatchError, UnknownHeadError,
     trunk_fingerprint,
 )
 from proteinbert_tpu.serve.cache import EmbeddingCache, content_key
 from proteinbert_tpu.serve.dispatch import (
-    KINDS, NEIGHBORS_KIND, TASK_KIND, BucketDispatcher, RaggedDispatcher,
+    DECODER_PAD, KINDS, NEIGHBORS_KIND, TASK_KIND, BucketDispatcher,
+    RaggedDispatcher,
 )
 from proteinbert_tpu.serve.errors import (
     SequenceTooLongError, ServerClosedError,
@@ -150,6 +151,23 @@ class Server:
             raise ValueError(f"serve_mode must be one of {SERVE_MODES}, "
                              f"got {serve_mode!r}")
         self.cfg = cfg
+        # The model is picked by the type of `cfg.model` (ISSUE 33): the
+        # causal decoder rides the same queue, packer, row classes and
+        # dispatcher, ragged and `embed` only; what is not built for it
+        # is refused here, by name.
+        self.decoder = isinstance(cfg.model, DecoderConfig)
+        if self.decoder:
+            unbuilt = {
+                "bucketed serving (serve_mode='bucketed')": serve_mode != "ragged",
+                "the result cache (cache_size > 0)": bool(cache_size),
+                "heads (heads= / registry=)": bool(heads) or registry is not None,
+                "the neighbor index (index=)": index is not None,
+            }
+            named = [what for what, asked in unbuilt.items() if asked]
+            if named:
+                raise ValueError(
+                    "not built for the decoder: " + "; ".join(named)
+                    + " (it is served ragged, `embed` only, cache_size=0)")
         self.on_long = on_long
         self.default_deadline_s = default_deadline_s
         self.clock = clock
@@ -509,6 +527,9 @@ class Server:
         `CandidateUnfitError` refusal when both arms don't fit (see
         dispatch.load_candidate). Returns the candidate report
         {fingerprint, warm_seconds, weight bytes...}."""
+        if self.decoder:
+            raise ValueError("a rollout candidate is not built for the "
+                             "decoder (two trees do not fit one chip)")
         if (params is None) == (source is None):
             raise ValueError("pass exactly one of params= / source=")
         if params is None:
@@ -761,7 +782,14 @@ class Server:
                 "this server has no neighbor index attached — start it "
                 "with index= (pbt serve --index DIR) to serve "
                 "/v1/neighbors")
-        if not seq:
+        if self.decoder:
+            if kind != "embed" or annotations is not None:
+                raise ValueError(
+                    f"kind {kind!r} / annotations are not built for the "
+                    "decoder: its server answers `embed` of a document "
+                    "of token ids")
+            seq = self._document(seq)
+        if not len(seq):
             raise ValueError("empty sequence")
         if (kind == TASK_KIND) != (head_id is not None):
             raise ValueError(
@@ -801,7 +829,7 @@ class Server:
                 if trace is not None:
                     exc.pbt_request_id = trace.public_id()
                 raise
-        window = self.cfg.data.seq_len - 2
+        window = self.cfg.data.seq_len - (0 if self.decoder else 2)
         if len(seq) > window:
             if (self.on_long == "reject"
                     or (kind == "predict_residues"
@@ -867,8 +895,13 @@ class Server:
                            kind=kind)
                 return future
         bucket_len = self.dispatcher.bucket_len(len(seq))
-        tokens = inference._tokenize_masked(
-            [seq], self.cfg.data.seq_len, on_overflow="count")[0, :bucket_len]
+        if self.decoder:    # a document longer than the window keeps its head
+            tokens = np.full(bucket_len, DECODER_PAD, np.int32)
+            tokens[:min(len(seq), bucket_len)] = seq[:bucket_len]
+        else:
+            tokens = inference._tokenize_masked(
+                [seq], self.cfg.data.seq_len,
+                on_overflow="count")[0, :bucket_len]
         now = self.clock()
         if deadline_s is None:
             deadline_s = self.default_deadline_s
@@ -903,6 +936,23 @@ class Server:
                            kind=old.kind)
         self._depth_g.set(len(self.queue))
         return future
+
+    def _document(self, seq) -> np.ndarray:
+        """A decoder request's document as int32 token ids of the
+        vocabulary slice held here; anything else is the request's
+        error."""
+        try:
+            ids = np.asarray(seq)
+            if ids.ndim != 1 or ids.dtype.kind not in "iu":
+                raise TypeError
+        except (TypeError, ValueError):
+            raise ValueError("a decoder request is a 1-D sequence of whole "
+                             "token ids") from None
+        vocab = self.cfg.model.vocab_size
+        if ids.size and (ids.min() < 0 or ids.max() >= vocab):
+            raise ValueError(f"token id outside the {vocab} rows of the "
+                             "vocabulary this server holds")
+        return ids.astype(np.int32)
 
     # -------------------------------------------------------- sync facade
 
@@ -1178,6 +1228,10 @@ class Server:
             "lookup_executables": self.index.executables(),
             "by_outcome": neighbors_by_outcome,
         })
+        # The decoder's expert counters over the batches run (ISSUE 33);
+        # None for a model without experts.
+        out["routing"] = (self.dispatcher.routing_stats() if self.decoder
+                          else None)
         if self.slo:
             out["slo"] = self.slo.status()
         return out

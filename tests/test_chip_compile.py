@@ -227,3 +227,44 @@ def test_decoder_expert_layer_compiles_for_v5e(chip):
     text = jax.jit(f).lower(*_on(chip, (layer, bias, x, seg))).compile().as_text()
     assert text.count("tpu_custom_call") >= 3, "flash forward + two backward kernels"
     assert "while(" in text or " while" in text
+
+
+def test_kda_core_compiles_for_v5e(chip):
+    """The KDA core at Ling-3.0-flash's published sizes (2 rows x 8,192,
+    32 heads of 128, chunks of 64): the two Pallas kernels
+    (kernels/kda.py: A and B; the walk of the state, float32, the state
+    in VMEM) and the triangular inverse between them in XLA compile for
+    one v5e."""
+    from functools import partial
+
+    from proteinbert_tpu.ops import kda
+
+    shape = (2, 8192, 32, 128)
+    args = (_sds(shape), _sds(shape), _sds(shape), _sds(shape, jnp.float32),
+            _sds(shape[:3], jnp.float32), _sds(shape[:2], jnp.int32))
+    text = jax.jit(partial(kda._kda_tpu, chunk=64)).lower(
+        *_on(chip, args)).compile().as_text()
+    assert text.count("tpu_custom_call") == 2, "A and B; the state walk"
+
+
+def test_served_hybrid_decoder_fits_the_chip(chip):
+    """The packed executable `serve-ling3flash-sat` times (the whole
+    served share of Ling-3.0-flash: 5.07 B bfloat16 parameters, 2 rows x
+    8,192 x 16 documents) compiles for one v5e and fits it: the two
+    kernels' calls are there (the KDA core's two in each of the three
+    KDA scans, the flash core), and arguments + temporaries stay
+    under the 15.75 GiB the compiler holds a program to."""
+    from proteinbert_tpu import inference
+    from proteinbert_tpu.models import glm_moe
+
+    cfg = get_preset("ling3flash_ep4").model
+    params = glm_moe.served_abstract(cfg)
+    assert glm_moe.served_param_count(cfg) == 5_068_766_144
+    grid = _sds((2, 8192), jnp.int32)
+    compiled = inference._packed_decoder_embed_batch.lower(
+        *_on(chip, (params, grid, grid, _sds((2, 16, 0), jnp.float32))),
+        cfg=cfg).compile()
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes > 9.4 * 2 ** 30
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * 2 ** 30, m
+    assert compiled.as_text().count("tpu_custom_call") >= 7

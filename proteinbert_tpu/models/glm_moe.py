@@ -503,14 +503,15 @@ def _hybrid_layer(stack: Params, at, x, segment_ids, positions, real,
         h.reshape(B * L, D), real.reshape(B * L), cfg, at=at)
     with jax.named_scope("shared_expert"):
         shared = swiglu_apply(p["shared"], h)
-    return x + routed.reshape(B, L, D) + shared, (stats["held_counts"],
-                                                  stats["dropped"])
+    return x + routed.reshape(B, L, D) + shared, (
+        stats["held_counts"], stats["dropped"], stats["block_rows"])
 
 
 def hybrid_trunk(params: Params, tokens, segment_ids, real, cfg: DecoderConfig):
     """-> (h (B, L, D) before the final norm, held_counts (expert layers,
-    experts_held), dropped ()). `real` (B, L) marks the positions that
-    hold a token: a span's tail past its document is routed nowhere."""
+    experts_held), dropped (), block_rows ()). `real` (B, L) marks the
+    positions that hold a token: a span's tail past its document is
+    routed nowhere."""
     periods, pre, post = hybrid_schedule(cfg)
     positions = segment_positions(segment_ids)
     # The residual stream is float32 (a layer's result, in the activation
@@ -529,23 +530,24 @@ def hybrid_trunk(params: Params, tokens, segment_ids, real, cfg: DecoderConfig):
     x, _ = run(x, "dense", "kda", cfg.first_k_dense_replace)
 
     def period(x, p):
-        counts, dropped = [], []
+        counts, sums = [], []
         for name, mixer, count in (("pre", "kda", pre), ("mla", "mla", 1),
                                    ("post", "kda", post)):
             if not count:
                 continue
             if name == "mla":
-                x, (c, d) = _hybrid_layer(params["mla"], (p,), x, segment_ids,
-                                          positions, real, cfg, "mla")
-                c, d = c[None], d[None]
+                x, (c, d, b) = _hybrid_layer(params["mla"], (p,), x, segment_ids,
+                                             positions, real, cfg, "mla")
+                c = c[None]
             else:
-                x, (c, d) = run(x, name, mixer, count, lead=(p,))
+                x, (c, d, b) = run(x, name, mixer, count, lead=(p,))
             counts.append(c)
-            dropped.append(d)
-        return x, (jnp.concatenate(counts), jnp.concatenate(dropped).sum())
+            sums.append(jnp.stack([d.sum(), b.sum()]))
+        return x, (jnp.concatenate(counts), sum(sums))
 
-    x, (counts, dropped) = lax.scan(period, x, jnp.arange(periods))
-    return x, counts.reshape(-1, cfg.experts_held), dropped.sum()
+    x, (counts, sums) = lax.scan(period, x, jnp.arange(periods))
+    dropped, block_rows = sums.sum(0)
+    return x, counts.reshape(-1, cfg.experts_held), dropped, block_rows
 
 
 def served_embed(params: Params, tokens, segment_ids, num_segments: int,
@@ -559,7 +561,8 @@ def served_embed(params: Params, tokens, segment_ids, num_segments: int,
     document's tokens, "routing": the batch's counters}, float32."""
     real = (segment_ids > 0) & (tokens >= 0)
     with jax.named_scope("encode"):
-        h, counts, dropped = hybrid_trunk(params, tokens, segment_ids, real, cfg)
+        h, counts, dropped, block_rows = hybrid_trunk(
+            params, tokens, segment_ids, real, cfg)
         h = rms_norm_apply(params["final_norm"], h, cfg.rms_norm_eps)
     with jax.named_scope("pool"):
         h = h.astype(jnp.float32)
@@ -575,6 +578,7 @@ def served_embed(params: Params, tokens, segment_ids, num_segments: int,
         return {"global": jnp.where((n > 0)[..., None], at_last, 0.0),
                 "local_mean": mean,
                 "routing": {"held_counts": counts, "dropped": dropped,
+                            "block_rows": block_rows,
                             "real_tokens": real.sum()}}
 
 
@@ -628,7 +632,8 @@ def loss_and_stats(params: Params, tokens, segment_ids, cfg: DecoderConfig):
     main = main_sum / n_main
     out = {"loss": main, "main_loss": main, "main_acc": main_right / n_main}
     counters = {"load": stats["load"], "held_counts": stats["held_counts"],
-                "dropped": stats["dropped"].sum(), "ids": stats["ids"]}
+                "dropped": stats["dropped"].sum(), "ids": stats["ids"],
+                "block_rows": stats["block_rows"].sum()}
     if cfg.num_nextn_predict_layers:
         with jax.named_scope("mtp"):
             m, dt = params["mtp"], h.dtype
@@ -650,6 +655,7 @@ def loss_and_stats(params: Params, tokens, segment_ids, cfg: DecoderConfig):
         counters["held_counts"] = jnp.concatenate(
             [counters["held_counts"], mtp_stats["held_counts"][None]])
         counters["dropped"] = counters["dropped"] + mtp_stats["dropped"]
+        counters["block_rows"] = counters["block_rows"] + mtp_stats["block_rows"]
         counters["ids"] = jnp.concatenate([counters["ids"], mtp_stats["ids"][None]])
     return out["loss"], (out, counters)
 
@@ -687,6 +693,7 @@ def step_metrics(out, counters, segment_ids, cfg: DecoderConfig
         "assignments_held": held.sum(),
         "routed_here_share": held.sum() / assigned,
         "dropped_assignments": counters["dropped"].astype(jnp.float32),
+        "block_rows": counters["block_rows"].astype(jnp.float32),
         "attn_tiles_walked_share": tiles_walked_share(
             segment_ids, cfg.attention_block),
     }

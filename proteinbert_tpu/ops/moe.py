@@ -21,8 +21,20 @@ Stages, each under its `jax.named_scope`:
 - `moe_router`: the scores, the choice, the weights.
 - `moe_dispatch`: the (token, slot) assignments to held experts sorted by
   expert, cut into blocks of `block` rows of ONE expert each (the last
-  block of an expert is part full); inside the loop, the gather of a
-  block's token rows and the scatter-add of its weighted result.
+  block of an expert is part full). For the length of the loop the
+  layer's token arrays stand as SLABS, (tokens + block, S, 128) with the
+  token axis leading, where the width is whole lanes (one relayout a
+  layer each way; `kernels/moe_rows.py`), and two movers carry a block's
+  rows, forward and backward alike: `_gather_rows` brings the block's
+  REAL rows (`plan.block_rows[i]` of them, ascending, no token twice)
+  with zeros after them, `_scatter_add_rows` adds the block's weighted
+  result at the same rows, in float32, in place, block after block in
+  the loop's order. On a TPU they are Pallas kernels that move a token's
+  slab as one DMA and never touch a row past the real ones; elsewhere,
+  and where the width is not whole lanes, XLA's gather and scatter-add
+  over unique indices, the rows past the real ones pointing at
+  `block` spare rows of zeros. `kernels/moe_rows.MOE_ROWS_PATH_TOTAL`
+  counts which of the two a traced loop got.
 - `moe_experts`: the grouped products: a loop over the blocks that hold
   anything, each block three products against its expert's matrices.
   The trip count is the number of blocks the step's routing filled, so
@@ -44,6 +56,8 @@ from typing import Dict, NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from proteinbert_tpu.kernels import moe_rows
 
 
 class Plan(NamedTuple):
@@ -131,6 +145,64 @@ def _block_rows(plan: Plan, i, top_k: int, tokens: int, block: int):
         return plan.block_expert[i], a, tok, valid
 
 
+def _loop_form(a, block: int):
+    """(T, D) -> the form the loop moves rows of: `block` spare rows of
+    zeros after the T, as slabs where D is whole lanes."""
+    a = jnp.pad(a, [(0, block), (0, 0)])
+    return moe_rows.pack(a) if moe_rows.slabs_fit(a.shape[1]) else a
+
+
+def _tokens_form(a, tokens: int, width: int):
+    """The loop's form -> (tokens, width)."""
+    return (moe_rows.unpack(a, width) if a.ndim == 3 else a)[:tokens]
+
+
+def _note_path(tokens: int, width: int, block: int) -> None:
+    """Which movers this traced loop gets (trace time, once a loop)."""
+    if not moe_rows.slabs_fit(width):
+        moe_rows.note_moe_rows_path("reference", "row_not_lanes",
+                                    (tokens, width, block))
+    elif jax.default_backend() != "tpu":
+        moe_rows.note_moe_rows_path("reference", "not_tpu",
+                                    (tokens, width, block))
+    else:
+        moe_rows.note_moe_rows_path("pallas", "slabs")
+
+
+# A block's `tok` is ascending too, but XLA is NOT told so: on a v5e its
+# gather and scatter over (T, D) run 4.7 x slower with
+# `indices_are_sorted=True` (PERF.md section 6, PR 34).
+_UNIQUE = dict(unique_indices=True)
+
+
+def _plain_gather(src, tok, n):
+    """The rows past n come from the spare rows tok points at there."""
+    return src.at[tok].get(**_UNIQUE)
+
+
+def _plain_scatter_add(dst, upd, tok, n):
+    """The zeros past n are added to the spare rows."""
+    return dst.at[tok].add(upd, **_UNIQUE)
+
+
+def _gather_rows(src, tok, n, width: int):
+    """(block, width): src's rows tok[r] for r < n, zeros from n on."""
+    if src.ndim == 2:
+        return _plain_gather(src, tok, n)
+    return moe_rows.unpack(lax.platform_dependent(
+        src, tok, n, tpu=moe_rows.gather_rows, default=_plain_gather), width)
+
+
+def _scatter_add_rows(dst, upd, tok, n):
+    """dst with upd[r] (block, width) float32 added at row tok[r] for
+    r < n."""
+    if dst.ndim == 2:
+        return _plain_scatter_add(dst, upd, tok, n)
+    return lax.platform_dependent(
+        dst, moe_rows.pack(upd), tok, n, tpu=moe_rows.scatter_add_rows,
+        default=_plain_scatter_add)
+
+
 def _hidden(xb, gate_e, up_e):
     hg = jnp.dot(xb, gate_e, preferred_element_type=jnp.float32)
     hu = jnp.dot(xb, up_e, preferred_element_type=jnp.float32)
@@ -152,13 +224,16 @@ def _expert_fwd(x, weights, gate, up, down, plan, top_k, block, at=()):
     out of the stack before the loop)."""
     T, D = x.shape
     dt = x.dtype
-    xp = jnp.concatenate([x, jnp.zeros((block, D), dt)])
+    _note_path(T, D, block)
+    with jax.named_scope("moe_dispatch"):
+        xp = _loop_form(x, block)
+        y0 = jnp.zeros_like(xp, jnp.float32)
     w_flat = weights.reshape(-1)
 
     def body(i, y):
         e, a, tok, valid = _block_rows(plan, i, top_k, T, block)
         with jax.named_scope("moe_dispatch"):
-            xb = xp[tok]
+            xb = _gather_rows(xp, tok, plan.block_rows[i], D)
             wb = jnp.where(valid, w_flat[a], 0.0)
         with jax.named_scope("moe_experts"):
             # the expert's matrices are rounded here, a block at a time:
@@ -169,11 +244,13 @@ def _expert_fwd(x, weights, gate, up, down, plan, top_k, block, at=()):
             ob = jnp.dot(h, down[(*at, e)].astype(dt),
                          preferred_element_type=jnp.float32)
         with jax.named_scope("moe_dispatch"):
-            return y.at[tok].add(wb[:, None] * ob, unique_indices=True)
+            return _scatter_add_rows(y, wb[:, None] * ob, tok,
+                                     plan.block_rows[i])
 
-    y = lax.fori_loop(0, plan.n_blocks, body,
-                      jnp.zeros((T + block, D), jnp.float32))
-    return y[:T], (x, weights, gate, up, down, plan)
+    y = lax.fori_loop(0, plan.n_blocks, body, y0)
+    with jax.named_scope("moe_dispatch"):
+        y = _tokens_form(y, T, D)
+    return y, (x, weights, gate, up, down, plan)
 
 
 def _expert_bwd(top_k, block, saved, dy):
@@ -181,17 +258,20 @@ def _expert_bwd(top_k, block, saved, dy):
     T, D = x.shape
     dt = x.dtype
     A = weights.size
-    xp = jnp.concatenate([x, jnp.zeros((block, D), dt)])
-    dyp = jnp.concatenate([dy.astype(jnp.float32),
-                           jnp.zeros((block, D), jnp.float32)])
+    _note_path(T, D, block)
+    with jax.named_scope("moe_dispatch"):
+        xp = _loop_form(x, block)
+        dyp = _loop_form(dy.astype(jnp.float32), block)
+        dx0 = jnp.zeros_like(dyp)
     w_flat = weights.reshape(-1)
     spare = jnp.arange(block, dtype=jnp.int32)
 
     def body(i, carry):
         dx, dw, dgate, dup, ddown = carry
         e, a, tok, valid = _block_rows(plan, i, top_k, T, block)
+        n = plan.block_rows[i]
         with jax.named_scope("moe_dispatch"):
-            xb, dyb = xp[tok], dyp[tok]
+            xb, dyb = _gather_rows(xp, tok, n, D), _gather_rows(dyp, tok, n, D)
             wb = jnp.where(valid, w_flat[a], 0.0)
         with jax.named_scope("moe_experts"):
             g16, u16, d16 = (gate[e].astype(dt), up[e].astype(dt),
@@ -214,17 +294,18 @@ def _expert_bwd(top_k, block, saved, dy):
             dgate, dup, ddown = (dgate.at[e].add(dgate_e), dup.at[e].add(dup_e),
                                  ddown.at[e].add(ddown_e))
         with jax.named_scope("moe_dispatch"):
-            dx = dx.at[tok].add(dxb, unique_indices=True)
+            dx = _scatter_add_rows(dx, dxb, tok, n)
             dw = dw.at[jnp.where(valid, a, A + spare)].set(
                 dwb, unique_indices=True)
         return dx, dw, dgate, dup, ddown
 
-    init = (jnp.zeros((T + block, D), jnp.float32),
-            jnp.zeros((A + block,), jnp.float32),
+    init = (dx0, jnp.zeros((A + block,), jnp.float32),
             jnp.zeros(gate.shape, jnp.float32), jnp.zeros(up.shape, jnp.float32),
             jnp.zeros(down.shape, jnp.float32))
     dx, dw, dgate, dup, ddown = lax.fori_loop(0, plan.n_blocks, body, init)
-    return (dx[:T].astype(dt), dw[:A].reshape(weights.shape).astype(weights.dtype),
+    with jax.named_scope("moe_dispatch"):
+        dx = _tokens_form(dx, T, D)
+    return (dx.astype(dt), dw[:A].reshape(weights.shape).astype(weights.dtype),
             dgate.astype(gate.dtype), dup.astype(up.dtype),
             ddown.astype(down.dtype), None)
 
@@ -239,7 +320,9 @@ def moe_apply(params: Dict, bias, x, real, cfg, at=()):
     would all fall on the same experts). Returns (y (T, D) in x's dtype,
     stats): `load` counts all `n_routed_experts` (what the balance bias
     follows), `held_counts` / `dropped` are the step's counters for this
-    chip's share, `ids` the experts chosen (`n_routed_experts` at a pad).
+    chip's share, `block_rows` the rows of the blocks they filled (held
+    assignments over it: the share of a block's rows that are real and
+    moved), `ids` the experts chosen (`n_routed_experts` at a pad).
     With `at` the experts' matrices are stacks of several layers' and
     this layer's lie at those leading indices: forward only."""
     ids, weights = route(x, params["router"], bias, cfg.num_experts_per_tok,
@@ -256,4 +339,5 @@ def moe_apply(params: Dict, bias, x, real, cfg, at=()):
         load = jnp.zeros((cfg.n_routed_experts + 1,), jnp.int32).at[
             ids.reshape(-1)].add(1)[:-1]
     return y.astype(x.dtype), {"load": load, "held_counts": plan.held_counts,
-                               "dropped": plan.dropped, "ids": ids}
+                               "dropped": plan.dropped, "ids": ids,
+                               "block_rows": plan.n_blocks * cfg.expert_block}

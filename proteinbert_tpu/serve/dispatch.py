@@ -1150,8 +1150,8 @@ class RaggedDispatcher(BucketDispatcher):
         # (warm-up's are not counted).
         self._routing_lock = threading.Lock()
         self._routing = {"batches": 0, "assignments_held": 0,  # guarded-by: _routing_lock
-                         "dropped_assignments": 0, "real_tokens": 0,
-                         "load_max_over_mean_sum": 0.0}
+                         "dropped_assignments": 0, "block_rows": 0,
+                         "real_tokens": 0, "load_max_over_mean_sum": 0.0}
 
     def bucket_len(self, length: int) -> int:
         """The decoder's documents carry no <sos> / <eos>."""
@@ -1169,9 +1169,12 @@ class RaggedDispatcher(BucketDispatcher):
     def routing_stats(self) -> Optional[Dict]:
         """The decoder's counters over the batches run so far: batches,
         (token, slot) assignments that fell on held experts, those of
-        them no block took, real tokens, and the sum over batches of the
-        fullest held expert's load over the mean (all expert layers
-        pooled). None for a model without experts."""
+        them no block took, the rows of the blocks they filled
+        (`assignments_held` over `block_rows`: the share of them that
+        is real, which is what the experts' loop moves), real tokens,
+        and the sum over batches of the fullest held expert's load over
+        the mean (all expert layers pooled). None for a model without
+        experts."""
         if not self.decoder:
             return None
         with self._routing_lock:
@@ -1184,6 +1187,7 @@ class RaggedDispatcher(BucketDispatcher):
             r["batches"] += 1
             r["assignments_held"] += int(held.sum())
             r["dropped_assignments"] += int(routing["dropped"])
+            r["block_rows"] += int(routing["block_rows"])
             r["real_tokens"] += int(routing["real_tokens"])
             r["load_max_over_mean_sum"] += float(
                 held.max() / max(held.mean(), 1.0))

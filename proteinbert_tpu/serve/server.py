@@ -382,6 +382,9 @@ class Server:
         from proteinbert_tpu.kernels.fused_block import (
             register_path_observer,
         )
+        from proteinbert_tpu.kernels.moe_rows import (
+            register_moe_rows_path_observer,
+        )
         from proteinbert_tpu.kernels.one_pass import (
             register_onepass_path_observer,
         )
@@ -409,12 +412,17 @@ class Server:
         def _mirror_onepass_path(path: str, reason: str) -> None:
             _mirror("onepass_kernel_path_total", path, reason)
 
+        def _mirror_moe_rows_path(path: str, reason: str) -> None:
+            _mirror("moe_rows_kernel_path_total", path, reason)
+
         self._path_cb = _mirror_path
         self._attn_path_cb = _mirror_attn_path
         self._onepass_path_cb = _mirror_onepass_path
+        self._moe_rows_path_cb = _mirror_moe_rows_path
         register_path_observer(self._path_cb)
         register_attention_path_observer(self._attn_path_cb)
         register_onepass_path_observer(self._onepass_path_cb)
+        register_moe_rows_path_observer(self._moe_rows_path_cb)
 
     def _bump(self, mirror: str, reason: Optional[str] = None) -> None:
         with self._mirror_lock:
@@ -714,6 +722,9 @@ class Server:
         from proteinbert_tpu.kernels.fused_block import (
             unregister_path_observer,
         )
+        from proteinbert_tpu.kernels.moe_rows import (
+            unregister_moe_rows_path_observer,
+        )
         from proteinbert_tpu.kernels.one_pass import (
             unregister_onepass_path_observer,
         )
@@ -721,6 +732,7 @@ class Server:
         unregister_path_observer(self._path_cb)
         unregister_attention_path_observer(self._attn_path_cb)
         unregister_onepass_path_observer(self._onepass_path_cb)
+        unregister_moe_rows_path_observer(self._moe_rows_path_cb)
 
     def abort(self) -> None:
         """Hard shutdown: fail all queued + pending work with
@@ -1144,6 +1156,7 @@ class Server:
             neighbors_by_outcome = dict(self.neighbors_total)
         from proteinbert_tpu.kernels.attention import ATTN_PATH_TOTAL
         from proteinbert_tpu.kernels.fused_block import PATH_TOTAL
+        from proteinbert_tpu.kernels.moe_rows import MOE_ROWS_PATH_TOTAL
         from proteinbert_tpu.kernels.one_pass import ONEPASS_PATH_TOTAL
 
         qw = self.scheduler.queue_wait
@@ -1181,6 +1194,12 @@ class Server:
             "onepass_path": {f"{p}/{r}": n
                              for (p, r), n
                              in sorted(ONEPASS_PATH_TOTAL.items())},
+            # The experts' loop's row movers (kernels/moe_rows.py):
+            # "pallas/slabs" where a block's rows move as slabs by DMA,
+            # "reference/*" where XLA's gather and scatter move them.
+            "moe_rows_path": {f"{p}/{r}": n
+                              for (p, r), n
+                              in sorted(MOE_ROWS_PATH_TOTAL.items())},
             # Quantized executable arm (ISSUE 12): which arm serves,
             # the measured weight-HBM footprint, and the worst sampled
             # parity deviation vs the fp32 shadow (None = fp32 arm).

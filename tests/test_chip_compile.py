@@ -247,6 +247,30 @@ def test_kda_core_compiles_for_v5e(chip):
     assert text.count("tpu_custom_call") == 2, "A and B; the state walk"
 
 
+@pytest.mark.parametrize("width,block", [(2560, 384), (2048, 512)])
+def test_the_experts_row_movers_compile_for_v5e(chip, width, block):
+    """The experts' loop's two movers (kernels/moe_rows.py) at the served
+    decoder's and the trained decoder's sizes, 16,384 tokens: a row of
+    2,560 is a slab of 24 sublanes (Mosaic refuses one of 20), and the
+    sum is updated in place."""
+    from proteinbert_tpu.kernels import moe_rows
+
+    rows = 16384 + block
+    slabs = lambda dt, n=rows: jax.eval_shape(  # noqa: E731
+        moe_rows.pack, _sds((n, width), dt))
+    tok, n = _sds((block,), jnp.int32), _sds((), jnp.int32)
+    for dt in (jnp.bfloat16, jnp.float32):
+        text = jax.jit(moe_rows.gather_rows).lower(
+            *_on(chip, (slabs(dt), tok, n))).compile().as_text()
+        assert text.count("tpu_custom_call") == 1
+    compiled = jax.jit(moe_rows.scatter_add_rows, donate_argnums=0).lower(
+        *_on(chip, (slabs(jnp.float32), slabs(jnp.float32, block),
+                    tok, n))).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= rows * width * 4, m
+
+
 def test_served_hybrid_decoder_fits_the_chip(chip):
     """The packed executable `serve-ling3flash-sat` times (the whole
     served share of Ling-3.0-flash: 5.07 B bfloat16 parameters, 2 rows x

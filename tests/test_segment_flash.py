@@ -195,7 +195,8 @@ def test_the_step_reports_the_share_the_kernels_walk():
     seg = jnp.asarray(np.stack([runs((1, 20), (2, 30), (3, 10), (0, 4)),
                                 runs((1, 64))]))
     out = {"loss": jnp.zeros(()), "main_loss": jnp.zeros(()), "main_acc": jnp.zeros(())}
-    counters = {"held_counts": jnp.ones((2, 8)), "dropped": jnp.zeros(())}
+    counters = {"held_counts": jnp.ones((2, 8)), "dropped": jnp.zeros(()),
+                "block_rows": jnp.full((), 128.0)}
     got = glm_moe.step_metrics(out, counters, seg, model)
     # tiles of 16: row 0 walks 1 + 2 + 2 + 3 (id 2 starts in tile 1 and opens
     # tiles 2 and 3), row 1 all 10

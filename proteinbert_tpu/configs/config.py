@@ -86,6 +86,19 @@ class DecoderConfig:
     gate. `first_layer_index` is the published index of the first layer
     held here (the stack's phase in the period).
 
+    With `mixer` "cca" the same decoder is ZAYA1 (`model_type: zaya`),
+    built on the serving path: every layer has the compressed
+    convolutional attention mixer (`models/glm_moe.cca_mixer`:
+    `num_attention_heads` query heads read `num_key_value_heads` key and
+    value heads of `cca_head_dim`, q and k mixed by two short causal
+    convolutions of `cca_time0` / `cca_time1` taps, rotary over
+    `partial_rotary_factor` of a head) and routed experts alone, chosen
+    by an MLP router (`ops/moe.route_mlp`) whose `router_hidden_size`-wide
+    state is carried from layer to layer, and both sublayers scale and
+    shift the stream and their own result by learned vectors. The router's
+    kind and the residual scaling come with the mixer and cannot be asked
+    for apart from it (`router`, `residual_scaling` below).
+
     Three fields describe THIS CHIP'S SHARE of an expert-parallel group
     rather than the model: `experts_held` of the `n_routed_experts` the
     router scores (ids `expert_offset` ..), and `vocab_size` rows of the
@@ -116,6 +129,13 @@ class DecoderConfig:
     v_head_dim: int = 256
     rope_theta: float = 1e6
     rope_interleave: bool = False       # rotary pairs (2j, 2j + 1), not half-split
+    partial_rotary_factor: float = 1.0  # the share of a CCA head that turns
+    mixer: str = "latent"               # "latent" | "cca": the token-to-token mixer
+    num_key_value_heads: Optional[int] = None   # CCA: key / value heads (None: as queries)
+    cca_head_dim: int = 128
+    cca_time0: int = 2                  # taps of CCA's depthwise convolution
+    cca_time1: int = 2                  # taps of its grouped (head-wise) one
+    router_hidden_size: int = 256       # the MLP router's width and carried state
     rms_norm_eps: float = 1e-5
     num_nextn_predict_layers: int = 1
     layer_group_size: int = 0           # 0: every mixer is latent attention
@@ -151,6 +171,17 @@ class DecoderConfig:
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def router(self) -> str:
+        # "sigmoid" (one matrix, `ops/moe.route`) | "mlp" (ZAYA1's, which
+        # carries its state: `ops/moe.route_mlp`): the CCA stack's alone
+        return "mlp" if self.mixer == "cca" else "sigmoid"
+
+    @property
+    def residual_scaling(self) -> bool:
+        # learned scale and bias on stream and result: the CCA stack's alone
+        return self.mixer == "cca"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -616,6 +647,56 @@ def _ling_tiny() -> PretrainConfig:
     )
 
 
+def _zaya(**sizes) -> DecoderConfig:
+    # What names ZAYA1 whatever its widths: CCA in every layer (the MLP
+    # router and residual scaling come with it), routed experts alone (no
+    # shared expert, no leading dense layer), top 1 of a softmax, float32
+    # stream.
+    return DecoderConfig(**{**dict(
+        mixer="cca", first_k_dense_replace=0, n_shared_experts=0, num_experts_per_tok=1,
+        norm_topk_prob=False, routed_scaling_factor=1.0,
+        partial_rotary_factor=0.5, rope_theta=5e6, rms_norm_eps=1e-5,
+        num_nextn_predict_layers=0, q_lora_rank=None,
+        embed_init_std=1.0, out_init_std=0.02 / (2 * 40) ** 0.5), **sizes})
+
+
+def _zaya1_8b_pp2() -> PretrainConfig:
+    # ZAYA1-8B (huggingface.co/Zyphra/ZAYA1-8B config.json, `zaya`) on
+    # the SERVING path as the first of two pipeline stages holds it:
+    # every width, all 16 experts and the whole vocabulary as published;
+    # published layers 0-23 of 40 (the router's carried state starts as
+    # published). 5,519.2 M parameters, bfloat16 in HBM; the tied output
+    # head is not on this path.
+    return PretrainConfig(
+        model=_zaya(
+            vocab_size=262_272, hidden_size=2048, num_hidden_layers=24,
+            moe_intermediate_size=2048, n_routed_experts=16, experts_held=16,
+            num_attention_heads=8, num_key_value_heads=2, cca_head_dim=128,
+            router_hidden_size=256, param_dtype="bfloat16"),
+        data=DataConfig(seq_len=8192, batch_size=2, packing=True,
+                        pack_max_segments=16,
+                        buckets=_span_ladder(8192, 128)),
+    )
+
+
+def _zaya_tiny() -> PretrainConfig:
+    # ZAYA1 at CPU-test size: 8 query heads on 2 key heads, 16 experts
+    # top 1, four layers (the router's state is carried three times),
+    # float32 throughout; weights large enough at this width for a
+    # sublayer's result to be a third of the stream it is added to.
+    return PretrainConfig(
+        model=_zaya(
+            vocab_size=512, hidden_size=64, num_hidden_layers=4,
+            moe_intermediate_size=32, n_routed_experts=16, experts_held=16,
+            num_attention_heads=8, num_key_value_heads=2, cca_head_dim=16,
+            router_hidden_size=24, init_std=0.1, out_init_std=0.05,
+            dtype="float32", attention_block=16, expert_block=8,
+            loss_chunk=32),
+        data=DataConfig(seq_len=64, batch_size=2, packing=True,
+                        pack_max_segments=4, buckets=_span_ladder(64, 8)),
+    )
+
+
 PRESETS = {
     "tiny": _tiny,
     "base": _base,
@@ -625,6 +706,8 @@ PRESETS = {
     "glm_tiny": _glm_tiny,
     "ling3flash_ep4": _ling3flash_ep4,
     "ling_tiny": _ling_tiny,
+    "zaya1_8b_pp2": _zaya1_8b_pp2,
+    "zaya_tiny": _zaya_tiny,
 }
 
 

@@ -28,6 +28,14 @@ short documents few. What the bounds rest on is the packer's contract
 (`data/packing.py`): an id never comes back after another id followed
 it. A row that breaks it loses the pairs that reach across the gap.
 
+GROUPED KEYS, forward only: where k and v hold fewer heads than q (H
+query heads on H / group key heads, each key head read by `group`
+consecutive query heads), the key and value tile of query head h is
+that of key head h // group, chosen by the block index map: K and V are
+never repeated to H heads in HBM. The backward kernels write one dK and
+dV tile a query head and are not built for it (`segment_flash_attention`
+refuses the derivative by name).
+
 Products take the inputs' dtype and accumulate in float32, as do the
 online softmax's running maximum and sum. The per-query maximum, sum and
 `sum(o * do)` travel 128 lanes wide, the layout the kernel that ships
@@ -38,12 +46,36 @@ whose structure this one was started.
 from __future__ import annotations
 
 import functools
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from proteinbert_tpu.kernels.path_counter import KernelPathCounter
+
+# Which core a traced CCA mixer got (trace time, once a traced mixer):
+# `pallas/grouped_keys` where it is this file's forward kernel,
+# `reference/not_tpu` and `reference/tiles_do_not_fit` where plain jax
+# over keys repeated to the query heads.
+_CCA_CORE = KernelPathCounter("cca_core", "cca_core_kernel_path_total")
+CCA_CORE_PATH_TOTAL: Dict[Tuple[str, str], int] = _CCA_CORE.total
+
+
+def register_cca_core_path_observer(cb) -> None:
+    _CCA_CORE.register(cb)
+
+
+def unregister_cca_core_path_observer(cb) -> None:
+    _CCA_CORE.unregister(cb)
+
+
+def note_cca_core_path(path: str, reason: str,
+                       shape: Optional[tuple] = None) -> None:
+    _CCA_CORE.note(path, reason, shape)
+
 
 LANES = 128        # a tile's edge and a head's size are multiples of it
 SUBLANES = 8
@@ -110,12 +142,13 @@ def _call(kernel, name, walk, bound, operands, in_specs, out_specs, out_shape,
     )(*walk, bound, *operands)
 
 
-def _specs(block, d, dv, q_tile, k_tile):
+def _specs(block, d, dv, q_tile, k_tile, group=1):
     """BlockSpecs of (q, k, v, qseg, kseg) and of a per-query operand and
     a (block, dv) operand on the queries' side; `q_tile` / `k_tile` give
-    the query / key tile of a grid step from the walk."""
+    the query / key tile of a grid step from the walk; query head h reads
+    key head h // group."""
     at_q = lambda b, h, t, *walk: (b, h, q_tile(b, t, *walk), 0)  # noqa: E731
-    at_k = lambda b, h, t, *walk: (b, h, k_tile(b, t, *walk), 0)  # noqa: E731
+    at_k = lambda b, h, t, *walk: (b, h // group, k_tile(b, t, *walk), 0)  # noqa: E731
     five = [pl.BlockSpec((1, 1, block, d), at_q),
             pl.BlockSpec((1, 1, block, d), at_k),
             pl.BlockSpec((1, 1, block, dv), at_k),
@@ -182,7 +215,8 @@ def _forward(q, k, v, segment_ids, lo, scale, block, interpret, residuals):
     B, H, L, d = q.shape
     dv, n = v.shape[-1], L // block
     tile = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), lo.shape)
-    five, per_query, per_query_dv, _ = _specs(block, d, dv, _outer, _inner)
+    five, per_query, per_query_dv, _ = _specs(block, d, dv, _outer, _inner,
+                                              group=H // k.shape[1])
     per_query_shape = jax.ShapeDtypeStruct((B, H, L, LANES), jnp.float32)
     o, *lm = _call(
         functools.partial(_fwd_kernel, scale=scale), "segment_flash_fwd",
@@ -307,13 +341,22 @@ def segment_flash_attention(q, k, v, segment_ids, lo, hi, scale: float,
     (B, L); lo, hi: (B, L // block) int32, the tile bounds of
     `ops/attention.segment_tile_bounds(segment_ids, block)`. L is a
     multiple of `block`, and `block`, d and dv of 128
-    (`ops/attention.flash_tiles_fit`). Returns (B, H, L, dv) in q's
-    dtype. `interpret` runs the kernels in Pallas's interpreter (the CPU
+    (`ops/attention.flash_tiles_fit`). k and v may hold H / group heads
+    (grouped keys: forward only). Returns (B, H, L, dv) in q's dtype.
+    `interpret` runs the kernels in Pallas's interpreter (the CPU
     tests)."""
+    if q.shape[1] % k.shape[1] or k.shape[1] != v.shape[1]:
+        raise ValueError(f"{q.shape[1]} query heads do not divide over "
+                         f"{k.shape[1]} key and {v.shape[1]} value heads")
     return _forward(q, k, v, segment_ids, lo, scale, block, interpret, False)[0]
 
 
 def _vjp_fwd(q, k, v, segment_ids, lo, hi, scale, block, interpret):
+    if k.shape[1] != q.shape[1]:
+        raise NotImplementedError(
+            "segment_flash_attention with grouped keys "
+            f"({q.shape[1]} query heads on {k.shape[1]} key heads) has no "
+            "backward pass: its dK/dV kernel writes one tile a query head")
     o, l, m = _forward(q, k, v, segment_ids, lo, scale, block, interpret, True)
     return o, (q, k, v, segment_ids, lo, hi, o, l, m)
 

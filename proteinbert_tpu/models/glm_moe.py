@@ -44,6 +44,16 @@ scan of `post` KDA layers, `pre` set by the first held layer's index.
 each document's last token and its mean over the document, with the
 step's routing counters.
 
+And a third stack (ZAYA1, `zaya`; `cfg.mixer` "cca"), on the serving
+path too: every layer is compressed convolutional attention (`cca_mixer`:
+8 query heads read 2 key / value heads through the flash forward kernel's
+grouped keys; q and k mixed by two short causal convolutions, half the
+value heads the previous token's: `ops/cca.py`) and routed experts alone,
+top 1 of a softmax by an MLP router (`ops/moe.route_mlp`) whose narrow
+state the scan over layers carries beside the stream, `(x, r)`; both
+sublayers write `(a x + c) + (a' f(N(x)) + c')` with learned vectors
+(`cfg.residual_scaling`). Its period is one layer: one stack, one scan.
+
 The router's balance bias is in the parameter tree
 (`params["balance_bias"]`) so that it is sharded, saved and restored
 with everything else, but no gradient reaches it (`stop_gradient`, so
@@ -66,11 +76,12 @@ from proteinbert_tpu.ops.attention import (
     causal_segment_attention, flash_segment_attention, flash_tiles_fit,
     tiles_walked_share,
 )
+from proteinbert_tpu.ops.cca import cca_mix
 from proteinbert_tpu.ops.kda import kda_chunked, segment_conv
 from proteinbert_tpu.ops.layers import (
     rms_norm_apply, rotary_apply, segment_positions, swiglu_apply,
 )
-from proteinbert_tpu.ops.moe import moe_apply
+from proteinbert_tpu.ops.moe import moe_apply, router_probs
 
 Params = Dict[str, Any]
 
@@ -85,6 +96,11 @@ def param_shapes(cfg: DecoderConfig) -> Dict[str, Any]:
             "the hybrid stack (layer_group_size > 0) is built on the serving "
             "path only (`init_served`, `served_embed`): its output head, its "
             "prediction module and the KDA kernel's backward pass are not")
+    if cfg.mixer == "cca":
+        raise NotImplementedError(
+            "the CCA mixer (mixer='cca') is built on the serving path only "
+            "(`init_served`, `served_embed`): the flash kernel's backward "
+            "pass takes no grouped keys, and the tied head is not built")
     D, H = cfg.hidden_size, cfg.num_attention_heads
     E, F = cfg.experts_held, cfg.moe_intermediate_size
     attn = {
@@ -250,6 +266,52 @@ def kda_mixer(p: Params, x, segment_ids, cfg: DecoderConfig):
         return o.reshape(B, L, H * dk) @ p["o"].astype(dt)
 
 
+def _note_cca_core(fits: bool, shape) -> None:
+    """Which core this traced mixer gets (trace time, once a mixer)."""
+    from proteinbert_tpu.kernels.segment_flash import note_cca_core_path
+
+    if jax.default_backend() == "tpu":
+        note_cca_core_path("pallas", "grouped_keys")
+    else:
+        note_cca_core_path(
+            "reference", "not_tpu" if fits else "tiles_do_not_fit", shape)
+
+
+def cca_mixer(p: Params, x, segment_ids, positions, cfg: DecoderConfig):
+    """Compressed convolutional attention (`ops/cca.py` has the
+    equations): projections in the activation dtype accumulated in
+    float32; convolutions, means, norms and rotary in float32 (the
+    grouped convolution's products in the activation dtype); the core
+    over `num_key_value_heads` key and value heads, each read by its
+    group of query heads and never repeated in HBM on a TPU."""
+    with jax.named_scope("cca"):
+        B, L, _ = x.shape
+        H, G, d, dt = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                       cfg.cca_head_dim, x.dtype)
+        wide = lambda name: jnp.dot(  # noqa: E731
+            x, p[name].astype(dt), preferred_element_type=jnp.float32)
+        projected = [wide(name) for name in ("q", "k", "v1", "v2")]
+        with jax.named_scope("cca_mix"):
+            q, k, v = cca_mix(
+                p, *projected, segment_ids, positions, H, G,
+                int(d * cfg.partial_rotary_factor), cfg.rope_theta, dt)
+        # As `latent_attention`: the flash kernel where the program is
+        # lowered for a TPU, plain jax (keys repeated) elsewhere; sizes
+        # the tiles do not take are an error on a TPU.
+        sizes = dict(scale=float(d) ** -0.5, block=cfg.attention_block)
+        plain = partial(causal_segment_attention, **sizes)
+        fits = flash_tiles_fit(L, cfg.attention_block, d, d)
+        _note_cca_core(fits, (B, L, H, G, d))
+        with jax.named_scope("cca_core"):
+            if fits or jax.default_backend() == "tpu":
+                out = lax.platform_dependent(
+                    q, k, v, segment_ids,
+                    tpu=partial(flash_segment_attention, **sizes), default=plain)
+            else:
+                out = plain(q, k, v, segment_ids)
+        return out.reshape(B, L, H * d) @ p["o"].astype(dt)
+
+
 def dense_layer(p: Params, x, segment_ids, positions, cfg: DecoderConfig):
     x = x + latent_attention(
         p["attn"], rms_norm_apply(p["norm1"], x, cfg.rms_norm_eps),
@@ -321,7 +383,15 @@ def hybrid_layer_shapes(cfg: DecoderConfig, mixer: str, ffn: str) -> Dict[str, A
     """One layer's tree as shapes; the names and their sorted order are
     part of the weights' recipe (`init_served`)."""
     D, H = cfg.hidden_size, cfg.num_attention_heads
-    if mixer == "kda":
+    if mixer == "cca":
+        G, d = cfg.num_key_value_heads, cfg.cca_head_dim
+        C = (H + G) * d
+        mix = {"q": (D, H * d), "k": (D, G * d), "v1": (D, G * d // 2),
+               "v2": (D, G * d // 2), "o": (H * d, D),
+               "conv0": (cfg.cca_time0, C), "conv0_bias": (C,),
+               "conv1": (cfg.cca_time1, H + G, d, d), "conv1_bias": (C,),
+               "tau": (G,)}
+    elif mixer == "kda":
         W, K = H * cfg.kda_head_dim, cfg.short_conv_kernel_size
         mix = {"q": (D, W), "k": (D, W), "v": (D, W), "f": (D, W), "o": (W, D),
                "beta": (D, H), "g": (D, H), "conv_q": (K, W), "conv_k": (K, W),
@@ -336,8 +406,21 @@ def hybrid_layer_shapes(cfg: DecoderConfig, mixer: str, ffn: str) -> Dict[str, A
     swiglu = lambda width: {"gate": (D, width), "up": (D, width),  # noqa: E731
                             "down": (width, D)}
     tree = {"mixer": mix, "norm1": (D,), "norm2": (D,)}
+    if cfg.residual_scaling:
+        vectors = {"res_scale": (D,), "res_bias": (D,), "out_scale": (D,),
+                   "out_bias": (D,)}
+        tree.update(res1=vectors, res2=dict(vectors))
     if ffn == "dense":
         tree["mlp"] = swiglu(cfg.intermediate_size)
+    elif ffn == "routed":
+        E, F, R = cfg.experts_held, cfg.moe_intermediate_size, cfg.router_hidden_size
+        tree["moe"] = {"router": {"proj": (D, R), "proj_bias": (R,), "carry": (R,),
+                                  "norm": (R,), "w1": (R, R), "b1": (R,),
+                                  "w2": (R, R), "b2": (R,),
+                                  "w3": (R, cfg.n_routed_experts)},
+                       "router_bias": (cfg.n_routed_experts,),
+                       "experts": {"gate": (E, D, F), "up": (E, D, F),
+                                   "down": (E, F, D)}}
     else:
         E, F = cfg.experts_held, cfg.moe_intermediate_size
         tree["moe"] = {"router": (D, cfg.n_routed_experts),
@@ -351,6 +434,14 @@ def hybrid_layer_shapes(cfg: DecoderConfig, mixer: str, ffn: str) -> Dict[str, A
 def _hybrid_stacks(cfg: DecoderConfig):
     """[(name in the tree, leading shape, mixer, ffn, published index of
     each layer in the order of the leading shape)]."""
+    if cfg.mixer == "cca":
+        if cfg.first_k_dense_replace or cfg.n_shared_experts or cfg.hybrid:
+            raise ValueError(
+                "the CCA stack carries ZAYA1's layer alone: routed experts "
+                "chosen by the MLP router, residual scaling, no leading "
+                "dense layer, no shared expert, no layer period")
+        return [("cca", (cfg.num_hidden_layers,), "cca", "routed",
+                 [cfg.first_layer_index + j for j in range(cfg.num_hidden_layers)])]
     periods, pre, post = hybrid_schedule(cfg)
     G, n_dense = cfg.layer_group_size, cfg.first_k_dense_replace
     first = cfg.first_layer_index + n_dense
@@ -399,13 +490,15 @@ def served_abstract(cfg: DecoderConfig) -> Params:
 def _draw(key, index, j, name, shape, heads, std, dtype):
     if "norm" in name:
         leaf = jnp.ones(shape, jnp.float32)
-    elif name == "router_bias":
-        leaf = jnp.zeros(shape, jnp.float32)
     elif name == "A_log":
         leaf = jnp.log(1.0 + 3.0 * jnp.arange(heads, dtype=jnp.float32)
                        / max(heads - 1, 1))
     elif name == "dt_bias":
         leaf = jnp.full(shape, -4.0, jnp.float32)
+    elif name == "carry" or name.endswith("_scale"):
+        leaf = jnp.ones(shape, jnp.float32)
+    elif name.endswith("_bias") or name in ("b1", "b2", "tau"):
+        leaf = jnp.zeros(shape, jnp.float32)
     else:
         leaf = std * jax.random.normal(
             jax.random.fold_in(jax.random.fold_in(key, index), j), shape,
@@ -425,16 +518,49 @@ def _leaf_std(name: str, cfg: DecoderConfig) -> float:
     residual stream (`o`, `down`) have a deviation of their own."""
     own = {"embed": cfg.embed_init_std, "o": cfg.out_init_std,
            "down": cfg.out_init_std}.get(name)
+    if name in ("conv0", "conv1", "w1", "w2", "w3"):
+        # CCA's convolutions and the MLP router's layers by their fan-in:
+        # at `init_std` the convolved part of q and k would be a
+        # hundredth of the mean part and the router's logits all but
+        # equal, and neither mechanism would move an answer.
+        own = {"conv0": cfg.cca_time0,
+               "conv1": cfg.cca_time1 * cfg.cca_head_dim}.get(
+                   name, cfg.router_hidden_size) ** -0.5
     return cfg.init_std if own is None else own
+
+
+BIAS_PROBES, BIAS_INDEX = 4096, 2 ** 16
+
+
+@partial(jax.jit, static_argnames=("eps", "dtype"))
+def _balanced_bias(key, router: Params, eps: float, dtype):
+    """The MLP router's balance bias as the training rule that no
+    gradient reaches would leave it: b_e = 1 / E - mean p_e over
+    `BIAS_PROBES` seeded normal states, so that every expert's p + b has
+    one mean. (A seeded MLP's logits have a part that no token moves,
+    the GELUs' mean through W_2 and W_3; with b = 0 the fullest expert of
+    a layer took 9 x the mean load on the chip, which no trained router
+    does: PERF.md section 6, PR 35.)"""
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    probe = jax.random.normal(
+        key, (BIAS_PROBES, router["norm"].shape[0]), jnp.float32)
+    mean = router_probs(jax.tree.map(f32, router), probe, eps).mean(0)
+    return lax.reduce_precision(1.0 / mean.shape[0] - mean, exponent_bits=8,
+                                mantissa_bits=7).astype(dtype)
 
 
 def _tree_of(key, index: int, shapes, cfg: DecoderConfig):
     flat, treedef = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=lambda s: isinstance(s, tuple))
-    return jax.tree.unflatten(treedef, [
+    tree = jax.tree.unflatten(treedef, [
         _draw(key, index, j, str(path[-1].key), shape, cfg.num_attention_heads,
               _leaf_std(str(path[-1].key), cfg), cfg.param_dtype)
         for j, (path, shape) in enumerate(flat)])
+    if cfg.router == "mlp" and "moe" in tree:
+        tree["moe"]["router_bias"] = _balanced_bias(
+            jax.random.fold_in(jax.random.fold_in(key, index), BIAS_INDEX),
+            tree["moe"]["router"], cfg.rms_norm_eps, cfg.param_dtype)
+    return tree
 
 
 def init_served(key: jax.Array, cfg: DecoderConfig) -> Params:
@@ -448,7 +574,12 @@ def init_served(key: jax.Array, cfg: DecoderConfig) -> Params:
     dt_bias -4, every other leaf (the conv taps too) init_std * normal,
     but the embedding's rows (`embed_init_std`) and the products that
     write into the residual stream (`out_init_std`: a mixer's `o`, an
-    FFN's `down`): a recipe a reference can follow without this module.
+    FFN's `down`); in the CCA stack besides: the router's `carry` and
+    the residual scales 1, every bias and `tau` 0, the convolutions and
+    the MLP router's three layers normal at fan_in^-1/2 (`_leaf_std`),
+    the balance bias from the layer's own router over seeded probes
+    (`_balanced_bias`): a recipe a reference can follow without this
+    module.
     (With every leaf at 0.02 a layer's result is as large as the stream
     it is added to, and the seeded network passed a rounding on with a
     gain of ~20 over seven layers: no comparison could tell bfloat16
@@ -462,7 +593,8 @@ def init_served(key: jax.Array, cfg: DecoderConfig) -> Params:
     SiLU is all but linear at its input's size and the mean is gone; q,
     k and v are rescaled after it by their norms.) Tree: `embed`,
     `final_norm`, and the stacks `dense` (n,), `mla` (periods,), `pre`
-    (periods, pre), `post` (periods, post) of layer trees."""
+    (periods, pre), `post` (periods, post) of layer trees; the CCA
+    stack's one stack is `cca` (layers,)."""
     top = {"embed": (cfg.vocab_size, cfg.hidden_size),
            "final_norm": (cfg.hidden_size,)}
     params = _tree_of(key, TOP_INDEX, top, cfg)
@@ -479,14 +611,20 @@ def init_served(key: jax.Array, cfg: DecoderConfig) -> Params:
     return params
 
 
+def _layer_at(stack: Params, at):
+    """(layer `at` (leading indices) of a stack but for its experts, the
+    held experts' matrices as the stack they lie in, or None): the
+    grouped products are handed the stack and the layer's place in it."""
+    experts = stack.get("moe", {}).get("experts")
+    return jax.tree.map(lambda a: a[at], {
+        k: ({m: w for m, w in v.items() if m != "experts"} if k == "moe" else v)
+        for k, v in stack.items()}), experts
+
+
 def _hybrid_layer(stack: Params, at, x, segment_ids, positions, real,
                   cfg: DecoderConfig, mixer: str):
-    """Layer `at` (leading indices) of a stack. The held experts'
-    matrices are handed to the grouped products as the stack they lie in."""
-    experts = stack.get("moe", {}).get("experts")
-    p = jax.tree.map(lambda a: a[at], {
-        k: ({m: w for m, w in v.items() if m != "experts"} if k == "moe" else v)
-        for k, v in stack.items()})
+    """Layer `at` (leading indices) of a stack."""
+    p, experts = _layer_at(stack, at)
     dt = jnp.dtype(cfg.dtype)
     h = rms_norm_apply(p["norm1"], x, cfg.rms_norm_eps).astype(dt)
     if mixer == "kda":
@@ -550,6 +688,62 @@ def hybrid_trunk(params: Params, tokens, segment_ids, real, cfg: DecoderConfig):
     return x, counts.reshape(-1, cfg.experts_held), dropped, block_rows
 
 
+def _scaled_residual(p: Params, x, y):
+    """(a x + c) + (a' y + c'), float32: ZAYA1's residual scaling."""
+    f32 = lambda name: p[name].astype(jnp.float32)  # noqa: E731
+    return ((f32("res_scale") * x + f32("res_bias"))
+            + (f32("out_scale") * y.astype(jnp.float32) + f32("out_bias")))
+
+
+def _cca_layer(stack: Params, at, x, r, segment_ids, positions, real,
+               cfg: DecoderConfig):
+    """Layer `at` of the CCA stack over the stream x (B, L, D) float32
+    and the router's carried state r (B L, R) float32 -> (x, r, the
+    layer's counters). The router reads the normed stream in float32
+    (a top-1 choice moves a token's whole expert: it is not rounded to
+    the activation dtype first)."""
+    p, experts = _layer_at(stack, at)
+    dt = jnp.dtype(cfg.dtype)
+    # `residual`: what the float32 stream costs outside the two sublayers,
+    # its two norms and its two scaled adds
+    with jax.named_scope("residual"):
+        h = rms_norm_apply(p["norm1"], x, cfg.rms_norm_eps).astype(dt)
+    mixed = cca_mixer(p["mixer"], h, segment_ids, positions, cfg)
+    with jax.named_scope("residual"):
+        x = _scaled_residual(p["res1"], x, mixed)
+        h = rms_norm_apply(p["norm2"], x, cfg.rms_norm_eps)
+    B, L, D = h.shape
+    routed, stats = moe_apply(
+        dict(p["moe"], experts=experts), p["moe"]["router_bias"].astype(jnp.float32),
+        h.astype(dt).reshape(B * L, D), real.reshape(B * L), cfg, at=at,
+        router_x=h.reshape(B * L, D), router_state=r)
+    with jax.named_scope("residual"):
+        x = _scaled_residual(p["res2"], x, routed.reshape(B, L, D))
+    return x, stats["router_state"], (
+        stats["held_counts"], stats["dropped"], stats["block_rows"])
+
+
+def cca_trunk(params: Params, tokens, segment_ids, real, cfg: DecoderConfig):
+    """`hybrid_trunk` of the CCA stack: one scan over the layers that
+    carries TWO streams, x and the router's state (zeros before the
+    first layer held: the stack starts at the published layer 0, or at a
+    pipeline stage's first layer with the state it would be handed)."""
+    positions = segment_positions(segment_ids)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], jnp.maximum(tokens, 0),
+                     axis=0).astype(jnp.float32)
+    r = jnp.zeros((x.shape[0] * x.shape[1], cfg.router_hidden_size), jnp.float32)
+
+    def body(carry, l):
+        x, r, counters = _cca_layer(params["cca"], (l,), *carry, segment_ids,
+                                    positions, real, cfg)
+        return (x, r), counters
+
+    (x, _), (counts, dropped, block_rows) = lax.scan(
+        body, (x, r), jnp.arange(cfg.num_hidden_layers))
+    return x, counts, dropped.sum(), block_rows.sum()
+
+
 def served_embed(params: Params, tokens, segment_ids, num_segments: int,
                  cfg: DecoderConfig):
     """What `embed` answers for every document of a packed batch.
@@ -561,7 +755,8 @@ def served_embed(params: Params, tokens, segment_ids, num_segments: int,
     document's tokens, "routing": the batch's counters}, float32."""
     real = (segment_ids > 0) & (tokens >= 0)
     with jax.named_scope("encode"):
-        h, counts, dropped, block_rows = hybrid_trunk(
+        trunk_of = cca_trunk if cfg.mixer == "cca" else hybrid_trunk
+        h, counts, dropped, block_rows = trunk_of(
             params, tokens, segment_ids, real, cfg)
         h = rms_norm_apply(params["final_norm"], h, cfg.rms_norm_eps)
     with jax.named_scope("pool"):

@@ -174,11 +174,16 @@ def causal_segment_attention(
     q, k: (B, L, H, d), v: (B, L, H, dv), segment_ids: (B, L). Position i
     attends to j <= i with segment_ids[j] == segment_ids[i]; pad positions
     (segment 0) see only each other, so no row of scores is ever empty.
+    k and v may hold H / group heads (grouped keys: query head h reads
+    key head h // group); here they are repeated to H.
     Queries go in blocks of `block`, each against the keys up to its own
     end (the keys after it are never multiplied), each block's scores in
     float32 and recomputed in the backward pass rather than kept:
     (B, H, block, L) at a time, never (B, H, L, L)."""
     L = q.shape[1]
+    if k.shape[2] != q.shape[2]:
+        group = q.shape[2] // k.shape[2]
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     outs = []
     for start in range(0, L, block):
         end = min(start + block, L)
@@ -256,7 +261,8 @@ def flash_segment_attention(
     (`kernels/segment_flash.py`, forward and both backward kernels):
     online softmax over tiles of keys in float32, nothing of size L x L
     ever in HBM, and of the causal tiles only those between
-    `segment_tile_bounds` walked. Same arguments and result; `block` is
+    `segment_tile_bounds` walked. Same arguments and result (grouped
+    keys too, forward only, never repeated in HBM); `block` is
     the tile of queries and of keys. Sizes the tiles do not take are an
     error that names them, never another path."""
     from proteinbert_tpu.kernels.segment_flash import (

@@ -131,11 +131,17 @@ def segment_positions(segment_ids: jax.Array) -> jax.Array:
 
 
 def rotary_apply(x: jax.Array, positions: jax.Array, theta: float,
-                 interleave: bool = False) -> jax.Array:
-    """Rotary position embedding over ALL of the last axis, half-split
-    pairing (dimension j turns with dimension j + d/2) or, with
-    `interleave`, neighbours (2j with 2j + 1). x: (B, L, ..., d),
-    positions: (B, L). Angles in float32."""
+                 interleave: bool = False,
+                 rotary_dim: Optional[int] = None) -> jax.Array:
+    """Rotary position embedding over the last axis, half-split pairing
+    (dimension j turns with dimension j + d/2) or, with `interleave`,
+    neighbours (2j with 2j + 1). x: (B, L, ..., d), positions: (B, L).
+    With `rotary_dim` only the first `rotary_dim` of the d turn (pairs
+    and angles are those of a head of that size) and the rest pass
+    unchanged. Angles in float32."""
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        turned = rotary_apply(x[..., :rotary_dim], positions, theta, interleave)
+        return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     d = x.shape[-1]
     inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angles = positions.astype(jnp.float32)[..., None] * inv_freq    # (B, L, d/2)

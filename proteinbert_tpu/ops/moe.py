@@ -16,6 +16,11 @@ chip of an expert-parallel group, without its exchange).
     weights = s[chosen] / (sum + 1e-20) * routed_scaling_factor
     y       = sum over chosen experts HELD HERE of weight * Expert_e(x)
 
+A second router (`route_mlp`, ZAYA1's) is not one matrix: a
+down-projection to a narrow state that adds the previous layer's, a
+norm, a small MLP, a softmax, the top k with the chosen probabilities
+themselves as weights; it hands its state on to the next layer.
+
 Stages, each under its `jax.named_scope`:
 
 - `moe_router`: the scores, the choice, the weights.
@@ -58,6 +63,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from proteinbert_tpu.kernels import moe_rows
+from proteinbert_tpu.ops.layers import rms_norm_apply
 
 
 class Plan(NamedTuple):
@@ -96,6 +102,42 @@ def route(x, router_kernel, bias, top_k: int, scaling: float,
         if norm_topk:
             weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
         return ids.astype(jnp.int32), weights * scaling
+
+
+def router_probs(p: Dict, r, eps: float):
+    """The MLP router from its state on: r (T, R) float32 ->
+    softmax(W_3 gelu(W_2 gelu(W_1 RMSNorm(r) + b_1) + b_2)), (T, experts),
+    float32 at full precision."""
+    f32 = jnp.float32
+    w = lambda name: p[name].astype(f32)  # noqa: E731
+    dot = partial(jnp.dot, precision=lax.Precision.HIGHEST)
+    h = rms_norm_apply(w("norm"), r, eps)
+    h = jax.nn.gelu(dot(h, w("w1")) + w("b1"), approximate=False)
+    h = jax.nn.gelu(dot(h, w("w2")) + w("b2"), approximate=False)
+    return jax.nn.softmax(dot(h, w("w3")), axis=-1)
+
+
+def route_mlp(x, p: Dict, state, bias, top_k: int, eps: float):
+    """ZAYA1's router. x: (T, D); p: `proj` (D, R), `proj_bias`, `carry`
+    (the R scales on the previous layer's state), `norm`, `w1`, `b1`,
+    `w2`, `b2` (R, R), `w3` (R, experts); state: (T, R) float32, the
+    previous layer's r (zeros before the first layer).
+
+        r = x W_proj + b_proj + carry * state
+        p = `router_probs(r)`;  chosen = top-k of p + b;  weights = p[chosen]
+
+    -> (ids (T, k) int32, weights (T, k) float32, r (T, R) float32: what
+    the next layer's router adds). Float32 at full precision throughout,
+    as `route`; the bias moves the choice only."""
+    with jax.named_scope("moe_router"):
+        f32 = jnp.float32
+        r = (jnp.dot(x.astype(f32), p["proj"].astype(f32),
+                     precision=lax.Precision.HIGHEST)
+             + p["proj_bias"].astype(f32) + p["carry"].astype(f32) * state)
+        probs = router_probs(p, r, eps)
+        _, ids = lax.top_k(probs + lax.stop_gradient(bias), top_k)
+        return (ids.astype(jnp.int32),
+                jnp.take_along_axis(probs, ids, axis=-1), r)
 
 
 def max_blocks(tokens: int, top_k: int, experts_held: int, block: int) -> int:
@@ -313,7 +355,8 @@ def _expert_bwd(top_k, block, saved, dy):
 expert_ffn.defvjp(_expert_fwd, _expert_bwd)
 
 
-def moe_apply(params: Dict, bias, x, real, cfg, at=()):
+def moe_apply(params: Dict, bias, x, real, cfg, at=(), router_x=None,
+              router_state=None):
     """The routed part of an expert layer over x: (T, D); `real` (T,)
     marks the tokens that are not padding: a pad token is routed
     nowhere and counted nowhere (every pad has the same input, so they
@@ -324,10 +367,18 @@ def moe_apply(params: Dict, bias, x, real, cfg, at=()):
     assignments over it: the share of a block's rows that are real and
     moved), `ids` the experts chosen (`n_routed_experts` at a pad).
     With `at` the experts' matrices are stacks of several layers' and
-    this layer's lie at those leading indices: forward only."""
-    ids, weights = route(x, params["router"], bias, cfg.num_experts_per_tok,
-                         cfg.routed_scaling_factor, cfg.norm_topk_prob,
-                         cfg.n_group, cfg.topk_group)
+    this layer's lie at those leading indices: forward only. The MLP
+    router (`cfg.router` "mlp") reads `router_x` (x where None) and the
+    previous layer's `router_state`, and its own is `stats["router_state"]`."""
+    stats = {}
+    if cfg.router == "mlp":
+        ids, weights, stats["router_state"] = route_mlp(
+            x if router_x is None else router_x, params["router"],
+            router_state, bias, cfg.num_experts_per_tok, cfg.rms_norm_eps)
+    else:
+        ids, weights = route(x, params["router"], bias, cfg.num_experts_per_tok,
+                             cfg.routed_scaling_factor, cfg.norm_topk_prob,
+                             cfg.n_group, cfg.topk_group)
     ids = jnp.where(real[:, None], ids, cfg.n_routed_experts)
     plan = plan_dispatch(ids, cfg.experts_held, cfg.expert_offset,
                          cfg.expert_block)
@@ -338,6 +389,6 @@ def moe_apply(params: Dict, bias, x, real, cfg, at=()):
     with jax.named_scope("moe_router"):
         load = jnp.zeros((cfg.n_routed_experts + 1,), jnp.int32).at[
             ids.reshape(-1)].add(1)[:-1]
-    return y.astype(x.dtype), {"load": load, "held_counts": plan.held_counts,
-                               "dropped": plan.dropped, "ids": ids,
-                               "block_rows": plan.n_blocks * cfg.expert_block}
+    stats.update(load=load, held_counts=plan.held_counts, dropped=plan.dropped,
+                 ids=ids, block_rows=plan.n_blocks * cfg.expert_block)
+    return y.astype(x.dtype), stats

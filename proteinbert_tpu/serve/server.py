@@ -388,6 +388,9 @@ class Server:
         from proteinbert_tpu.kernels.one_pass import (
             register_onepass_path_observer,
         )
+        from proteinbert_tpu.kernels.segment_flash import (
+            register_cca_core_path_observer,
+        )
 
         self._path_c: Dict[Any, Any] = {}
 
@@ -415,6 +418,11 @@ class Server:
         def _mirror_moe_rows_path(path: str, reason: str) -> None:
             _mirror("moe_rows_kernel_path_total", path, reason)
 
+        def _mirror_cca_core_path(path: str, reason: str) -> None:
+            _mirror("cca_core_kernel_path_total", path, reason)
+
+        self._cca_core_path_cb = _mirror_cca_core_path
+        register_cca_core_path_observer(self._cca_core_path_cb)
         self._path_cb = _mirror_path
         self._attn_path_cb = _mirror_attn_path
         self._onepass_path_cb = _mirror_onepass_path
@@ -728,11 +736,15 @@ class Server:
         from proteinbert_tpu.kernels.one_pass import (
             unregister_onepass_path_observer,
         )
+        from proteinbert_tpu.kernels.segment_flash import (
+            unregister_cca_core_path_observer,
+        )
 
         unregister_path_observer(self._path_cb)
         unregister_attention_path_observer(self._attn_path_cb)
         unregister_onepass_path_observer(self._onepass_path_cb)
         unregister_moe_rows_path_observer(self._moe_rows_path_cb)
+        unregister_cca_core_path_observer(self._cca_core_path_cb)
 
     def abort(self) -> None:
         """Hard shutdown: fail all queued + pending work with
@@ -1158,6 +1170,7 @@ class Server:
         from proteinbert_tpu.kernels.fused_block import PATH_TOTAL
         from proteinbert_tpu.kernels.moe_rows import MOE_ROWS_PATH_TOTAL
         from proteinbert_tpu.kernels.one_pass import ONEPASS_PATH_TOTAL
+        from proteinbert_tpu.kernels.segment_flash import CCA_CORE_PATH_TOTAL
 
         qw = self.scheduler.queue_wait
         # One coherent locked read of the dispatch counters: the
@@ -1200,6 +1213,13 @@ class Server:
             "moe_rows_path": {f"{p}/{r}": n
                               for (p, r), n
                               in sorted(MOE_ROWS_PATH_TOTAL.items())},
+            # The CCA mixer's attention core (ISSUE 35): "pallas/
+            # grouped_keys" where it is the flash forward kernel reading
+            # each key head for its group of query heads, "reference/*"
+            # where plain jax over repeated keys.
+            "cca_core_path": {f"{p}/{r}": n
+                              for (p, r), n
+                              in sorted(CCA_CORE_PATH_TOTAL.items())},
             # Quantized executable arm (ISSUE 12): which arm serves,
             # the measured weight-HBM footprint, and the worst sampled
             # parity deviation vs the fp32 shadow (None = fp32 arm).

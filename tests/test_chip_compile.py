@@ -292,3 +292,32 @@ def test_served_hybrid_decoder_fits_the_chip(chip):
     assert m.argument_size_in_bytes > 9.4 * 2 ** 30
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * 2 ** 30, m
     assert compiled.as_text().count("tpu_custom_call") >= 7
+
+
+def test_served_cca_decoder_fits_the_chip(chip):
+    """The packed executable `serve-zaya1-8b-sat` times (ZAYA1-8B's
+    published layers 0-23 whole: 5.52 B bfloat16 parameters, 2 rows x
+    8,192 x 16 documents) compiles for one v5e beside Ling's and fits
+    it: the flash forward kernel with grouped keys (8 query heads on 2
+    key heads of 128, K and V never repeated) and the experts' two row
+    movers are there, and arguments + temporaries stay under the
+    15.75 GiB the compiler holds a program to."""
+    from proteinbert_tpu import inference
+    from proteinbert_tpu.models import glm_moe
+
+    cfg = get_preset("zaya1_8b_pp2").model
+    params = glm_moe.served_abstract(cfg)
+    assert glm_moe.served_param_count(cfg) == 5_519_138_864
+    grid = _sds((2, 8192), jnp.int32)
+    compiled = inference._packed_decoder_embed_batch.lower(
+        *_on(chip, (params, grid, grid, _sds((2, 16, 0), jnp.float32))),
+        cfg=cfg).compile()
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes > 10.2 * 2 ** 30
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * 2 ** 30, m
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert "segment_flash_fwd" in text
+    # no copy of K or V at the eight query heads: the kernel's key
+    # operand is (rows, 2 key heads, positions, 128)
+    assert "bf16[2,2,8192,128]" in text and "bf16[2,8,8192,128]" in text

@@ -1,0 +1,7 @@
+"""Tracing's own cost: seconds of the `tracing.program_scopes` spans of the
+run, one map a row class that ran; listed last, after the readers that ask."""
+from benchmark import startup_readers
+
+
+def read(obs):
+    return startup_readers.scope_map_s(obs)

@@ -670,7 +670,12 @@ def _load_serving_model(args):
     args.serve_mode, args.cache_size = "ragged", 0
     args.max_batch = probe.data.batch_size
     args.pack_max_segments = probe.data.pack_max_segments
-    params = glm_moe.init_served(jax.random.PRNGKey(probe.train.seed), probe.model)
+    from proteinbert_tpu.obs import tracing
+
+    tracing.backend()
+    with tracing.startup_span("startup.init_state"):
+        params = jax.block_until_ready(glm_moe.init_served(
+            jax.random.PRNGKey(probe.train.seed), probe.model))
     log(f"decoder {args.preset}: {glm_moe.served_param_count(probe.model) / 1e6:.1f} M "
         f"parameters made from seed {probe.train.seed} in {probe.model.param_dtype}; "
         f"served ragged, {args.max_batch} rows x {probe.data.seq_len}, up to "

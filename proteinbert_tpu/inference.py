@@ -39,6 +39,7 @@ import numpy as np
 from proteinbert_tpu.configs import DecoderConfig, ModelConfig, PretrainConfig
 from proteinbert_tpu.data.vocab import EOS_ID, PAD_ID, SOS_ID, UNK_ID, get_vocab
 from proteinbert_tpu.models import glm_moe, proteinbert
+from proteinbert_tpu.obs import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -66,10 +67,13 @@ def load_state(checkpoint_dir: str, cfg: PretrainConfig):
     """
     from proteinbert_tpu.train import Checkpointer, create_train_state
 
-    template = create_train_state(jax.random.PRNGKey(cfg.train.seed), cfg)
+    tracing.backend()
+    with tracing.startup_span("startup.init_state"):
+        template = create_train_state(jax.random.PRNGKey(cfg.train.seed), cfg)
     ck = Checkpointer(checkpoint_dir, async_save=False)
     try:
-        state, _ = ck.restore(template)
+        with tracing.startup_span("startup.restore"):
+            state, _ = ck.restore(template)
     finally:
         ck.close()
     if state is None:

@@ -166,6 +166,16 @@ def _key(name: str, labels: Dict[str, Any]) -> str:
     return f"{name}{{{inner}}}"
 
 
+# Counters of the process itself, not of one run or server (what the
+# tracing layer did for a scope map): every enabled registry exports
+# them beside its own, so `/metrics` and a run's snapshot show them.
+_PROCESS_COUNTERS: Dict[str, Counter] = {}
+
+
+def process_counter(name: str, **labels) -> Counter:
+    return _PROCESS_COUNTERS.setdefault(_key(name, labels), Counter())
+
+
 class MetricsRegistry:
     def __init__(self, enabled: bool = True):
         self.enabled = enabled
@@ -243,9 +253,14 @@ class MetricsRegistry:
 
     # ----------------------------------------------------- export
 
+    def _all_counters(self) -> Dict[str, Counter]:
+        if not self.enabled:
+            return self._counters
+        return {**_PROCESS_COUNTERS, **self._counters}
+
     def snapshot(self) -> Dict[str, Any]:
         out = {
-            "counters": {k: c.value for k, c in self._counters.items()},
+            "counters": {k: c.value for k, c in self._all_counters().items()},
             "gauges": {k: g.value for k, g in self._gauges.items()},
             "histograms": {
                 k: {"count": h.count, "sum": h.total,
@@ -295,7 +310,7 @@ class MetricsRegistry:
             labels = ("{" + labels) if labels else ""
             lines.append(f"{family}{labels} {value:.9g}")
 
-        for k, c in sorted(self._counters.items()):
+        for k, c in sorted(self._all_counters().items()):
             metric(k, "", "counter", c.value)
         for k, g in sorted(self._gauges.items()):
             metric(k, "", "gauge", g.value)

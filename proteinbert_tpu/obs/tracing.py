@@ -21,10 +21,18 @@ id, the id of the span that enclosed it on that thread, and the ids
 passed in. `SpanCollector.dump` writes Perfetto `traceEvents` JSON on the
 wall clock.
 
+The process's START has a collector of its own, `startup_spans()`: the
+compiles, cache loads, lowerings and traces `jax.monitoring` tells of and
+the `startup.*` spans (`startup_span`), from the import of this module
+until the first profiler session goes live or the bound is reached. It
+is sealed for good after that, and the hot loops' spans never go there.
+
 `note_program` keeps the abstract arguments of a hot jitted program at
-its first call; `program_scopes` compiles from them on demand and returns
+its first call; `program_scopes` lowers from them on demand and returns
 `{HLO instruction name: scope path}` from each instruction's
-`metadata={op_name=...}`. Instruction names are what a device trace's
+`metadata={op_name=...}`: read from the executable this process compiled,
+or from the map kept beside the compile cache, and only then from a
+compile of its own. Instruction names are what a device trace's
 "XLA Ops" events carry (`fusion.1081`), so the map joins the device trace
 with the `jax.named_scope`s inside the program.
 
@@ -36,6 +44,7 @@ machines.
 from __future__ import annotations
 
 import gzip
+import hashlib
 import itertools
 import json
 import logging
@@ -151,16 +160,30 @@ def recorder() -> SpanCollector:
 # --------------------------------------------------------- the profiler
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+# A duration jax.monitoring tells of -> the span it is recorded as.
+_EVENT_SPANS = {
+    _COMPILE_EVENT: "jax.compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax.cache_load",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+}
+_TRACE_FLOOR_S = 1e-3           # one tiny step fires hundreds of 0.0 s traces
 _profiler = None                # jax.profiler, once jax is live
 _is_enabled = None              # TraceAnnotation.is_enabled, where it exists
 _lock = threading.Lock()
+# fun_name -> [compiled here, loaded from the persistent cache], since the
+# listener was armed: what `program_scopes` knows an executable's names by.
+_compiled: Dict[str, List[int]] = {}
 
 
-def _arm():
+def arm():
     """Bind to jax.profiler the first time jax is found imported, and
-    hang the compile listener on jax.monitoring (once: two threads may
-    open their first span together). Checked through sys.modules:
-    telemetry must not be the thing that pays the jax import."""
+    hang the listeners on jax.monitoring (once: two threads may open
+    their first span together). Checked through sys.modules: telemetry
+    must not be the thing that pays the jax import. Every span arms;
+    `utils/compat.configure_compile_cache` does it before a process's
+    first compile, so that the start-up collector sees that one too."""
     global _profiler, _is_enabled
     jax = sys.modules.get("jax")
     if jax is None:
@@ -169,6 +192,7 @@ def _arm():
         if _profiler is None:
             _is_enabled = getattr(jax.profiler.TraceAnnotation,
                                   "is_enabled", None)
+            jax.monitoring.register_event_listener(_on_event)
             jax.monitoring.register_event_duration_secs_listener(
                 _on_duration)
             _profiler = jax.profiler
@@ -181,22 +205,112 @@ def _live() -> bool:
     return _is_enabled is not None and _is_enabled()
 
 
-def _on_duration(event: str, duration_secs: float, **_kw) -> None:
-    """While recording, every backend compile (or load from the
-    persistent cache) is a `jax.compile` span on the compiling thread:
-    which step recompiled."""
-    if event == _COMPILE_EVENT and _live():
+def _on_event(event: str, **_kw) -> None:
+    """A hit of the persistent cache comes before the compile event of
+    the same program on the same thread: that one is a load."""
+    if event == _HIT_EVENT:
+        _tls.hit = True
+
+
+def _on_duration(event: str, duration_secs: float, **kw) -> None:
+    """A backend compile, or the load from the persistent cache that
+    fires the same event, is a `jax.compile` span on the compiling
+    thread with `cached=` telling which: into the recorder while a
+    session is live (which step recompiled), into the start-up collector
+    while that is open, where a load's retrieval, a lowering and a trace
+    of 1 ms or more are spans too."""
+    name = _EVENT_SPANS.get(event)
+    if name is None or (name == "jax.trace" and duration_secs < _TRACE_FLOOR_S):
+        return
+    # the retrieval's event names no program; its `jax.compile` does
+    ids = {} if name == "jax.cache_load" else {"program": kw.get("fun_name", "")}
+    if name == "jax.compile":
+        cached = getattr(_tls, "hit", False)
+        _tls.hit = False
+        ids["cached"] = int(cached)
+        _compiled.setdefault(ids["program"], [0, 0])[cached] += 1
+        watch = getattr(_tls, "watch", None)
+        if watch is not None:       # `program_scopes` is asking: see there
+            watch.append(cached)
+    if _live():
+        _seal_startup()
+        sink = _RECORDER if name == "jax.compile" else None
+    else:
+        sink = _startup_sink()
+    if sink is not None:
         end = time.perf_counter_ns()
-        _RECORDER._append("jax.compile", end - round(duration_secs * 1e9),
-                          end, threading.get_ident(), next(_ids),
-                          getattr(_tls, "open", None), {})
+        sink._append(name, end - round(duration_secs * 1e9), end,
+                     threading.get_ident(), next(_ids),
+                     getattr(_tls, "open", None), ids)
+
+
+# ------------------------------------------------------------- start-up
+
+STARTUP_CAPACITY = 4096
+_STARTUP = SpanCollector(STARTUP_CAPACITY)
+_startup_open = True
+
+
+def _seal_startup() -> None:
+    global _startup_open
+    _startup_open = False
+
+
+def _startup_sink() -> Optional[SpanCollector]:
+    """The start-up collector while it takes records. It is sealed, for
+    good, by the first span or compile that finds a profiler session
+    live, or here once the bound is reached (its oldest records are the
+    ones to keep)."""
+    if not _startup_open:
+        return None
+    if _profiler is None:
+        arm()
+    if _live() or len(_STARTUP) >= STARTUP_CAPACITY:
+        _seal_startup()
+        return None
+    return _STARTUP
+
+
+def startup_spans() -> List[Dict[str, Any]]:
+    """What the process spent before its first profiler session: the
+    `jax.compile` (`program=`, `cached=`), `jax.cache_load`, `jax.lower`
+    and `jax.trace` records of the monitoring listener and the
+    `startup.*` spans, oldest first, on the clock of every other span."""
+    return _STARTUP.spans()
+
+
+def startup_span(name: str, **ids) -> "span":
+    """A `startup.*` span: kept by the start-up collector while that is
+    open; after its seal an annotation and the span's own seconds, never
+    a record (a window's spans are the hot loops' alone)."""
+    started = span(name, collector=_startup_sink(), **ids)
+    started._mute = started._sink is None
+    return started
+
+
+_backend_up = False
+
+
+def backend():
+    """`jax.devices()`, the process's first call of it under a
+    `startup.backend` span: the runtime's start. The entry points that
+    may be first to touch the backend call this first."""
+    global _backend_up
+    jax = sys.modules["jax"]
+    if _backend_up:
+        return jax.devices()
+    _backend_up = True
+    with startup_span("startup.backend") as up:
+        devices = jax.devices()
+        up.ids.update(platform=devices[0].platform, devices=len(devices))
+    return devices
 
 
 class span:
     """Nested host span; see the module docstring."""
 
     __slots__ = ("name", "ids", "start_ns", "end_ns", "_step", "_sink",
-                 "_ann", "_id", "_parent")
+                 "_ann", "_id", "_parent", "_mute")
 
     def __init__(self, name: str, collector: Optional[SpanCollector] = None,
                  step: Optional[int] = None, **ids):
@@ -204,11 +318,12 @@ class span:
         self.ids = ids          # a record's ids: `step` joins them at exit
         self._step = step
         self._sink = collector
+        self._mute = False      # a start-up span after the collector's seal
         self._ann = None
         self.start_ns = self.end_ns = 0
 
     def __enter__(self) -> "span":
-        prof = _profiler or _arm()
+        prof = _profiler or arm()
         if prof is not None:
             if self._step is not None:
                 ann = prof.StepTraceAnnotation(
@@ -217,8 +332,10 @@ class span:
                 ann = prof.TraceAnnotation(self.name, **self.ids)
             ann.__enter__()
             self._ann = ann
-            if self._sink is None and _live():
+            if self._sink is None and not self._mute and _live():
                 self._sink = _RECORDER
+                if _startup_open:
+                    _seal_startup()
         if self._sink is not None:
             self._id = next(_ids)
             self._parent = getattr(_tls, "open", None)
@@ -247,17 +364,23 @@ class span:
 # ------------------------------------------- programs and their scopes
 
 _programs: Dict[str, tuple] = {}    # name -> (jitted, args, kwargs)
+# name -> which leaves of (args, kwargs), by their place among the
+# leaves, were arrays never committed to a device
+_uncommitted: Dict[str, frozenset] = {}
+_scope_maps: Dict[str, Dict[str, str]] = {}     # name -> its map, once read
 _compiling = threading.Lock()       # one around-the-cache compile at a time
 
 
 def note_program(name: str, jitted, args: tuple,
                  kwargs: Optional[Dict] = None) -> None:
-    """Keep what it takes to compile `jitted` again as it was called:
+    """Keep what it takes to lower `jitted` again as it was called:
     every array argument as its shape, dtype and sharding, everything
-    else (static arguments) as it is. Only the first call under a name
-    costs anything, and that is one `tree.map`."""
-    if name in _programs:
-        return
+    else (static arguments) as it is, and which arrays were never
+    committed to a device (`_as_called` lowers from those WITHOUT their
+    sharding, as the call did). Only the first call under a name costs
+    anything: a pass over the leaves and two `tree.map`s."""
+    if name in _programs or not hasattr(jitted, "lower"):
+        return      # noted already, or a plain function around jitted parts
     jax = sys.modules["jax"]
 
     def abstract(x):
@@ -267,8 +390,25 @@ def note_program(name: str, jitted, args: tuple,
             x.shape, x.dtype, sharding=getattr(x, "sharding", None),
             weak_type=getattr(getattr(x, "aval", None), "weak_type", False))
 
-    _programs[name] = (jitted, jax.tree.map(abstract, tuple(args)),
-                       jax.tree.map(abstract, dict(kwargs or {})))
+    args, kwargs = tuple(args), dict(kwargs or {})
+    _uncommitted[name] = frozenset(
+        i for i, x in enumerate(jax.tree.leaves((args, kwargs)))
+        if not getattr(x, "committed", True))
+    _programs[name] = (jitted, jax.tree.map(abstract, args),
+                       jax.tree.map(abstract, kwargs))
+
+
+def _as_called(name: str) -> tuple:
+    """(args, kwargs) of the program noted under `name` to lower from:
+    the lowering is then the call's own, and jax hands back the
+    executable that ran instead of compiling another."""
+    jax = sys.modules["jax"]
+    _, args, kwargs = _programs[name]
+    loose = _uncommitted.get(name, ())
+    leaves, tree = jax.tree.flatten((args, kwargs))
+    return jax.tree.unflatten(tree, [
+        jax.ShapeDtypeStruct(x.shape, x.dtype, weak_type=x.weak_type)
+        if i in loose else x for i, x in enumerate(leaves)])
 
 
 def noted_programs() -> List[str]:
@@ -458,39 +598,166 @@ def _squeezed(dims) -> tuple:
     return tuple(d for d in dims if d != 1)
 
 
+# What a map's key must not depend on: where in which file a line stands.
+_LOCATION = re.compile(
+    r'\s?(?:stack_frame_id|source_(?:end_)?(?:line|column))=\d+'
+    r'|\s?source_file="[^"]*"')
+_LOCATION_TABLES = ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames")
+
+
+def names_key(lowered) -> Optional[str]:
+    """A key equal for two lowerings iff the computation AND the names
+    its instructions carry are: a hash of the module printed WITH its
+    metadata and without source files, lines and stack frames, and of
+    what else decides the compiler's instruction names (the backend's
+    version, the device, the compiler's flags). None where this jax
+    cannot print a module so."""
+    jax = sys.modules["jax"]
+    try:
+        from jax._src.lib import _jax
+
+        options = _jax.HloPrintOptions.short_parsable()
+        options.print_metadata = True
+        text = lowered.compiler_ir("hlo").as_hlo_module().to_string(options)
+        device = jax.devices()[0]
+        said = (jax.__version__, device.client.platform_version,
+                device.device_kind, os.environ.get("XLA_FLAGS", ""),
+                os.environ.get("LIBTPU_INIT_ARGS", ""))
+    except Exception as e:
+        logger.warning("no key for a scope map: %r", e)
+        return None
+    digest = hashlib.sha256(repr(said).encode())
+    table = False
+    for line in text.splitlines():
+        if table:                   # a table of locations ends at a blank line
+            table = bool(line.strip())
+        elif line.strip() in _LOCATION_TABLES:
+            table = True
+        else:
+            digest.update(_LOCATION.sub("", line).encode())
+            digest.update(b"\n")
+    return digest.hexdigest()
+
+
+def _map_file(key: Optional[str]) -> Optional[str]:
+    """Where the map under `key` is kept: `scope_maps/` inside the
+    compile cache's directory, so the maps live and die with it."""
+    directory = sys.modules["jax"].config.jax_compilation_cache_dir
+    if not key or not directory:
+        return None
+    return os.path.join(directory, "scope_maps", key + ".json")
+
+
+def _stored_map(key: Optional[str]) -> Optional[Dict[str, str]]:
+    path = _map_file(key)
+    try:
+        with open(path) as f:
+            return json.load(f)["scopes"] or None
+    except (TypeError, OSError, ValueError, KeyError):
+        return None
+
+
+def _store_map(key: Optional[str], program: str, scopes: Dict[str, str]) -> None:
+    path = _map_file(key)
+    if path is None or not scopes:
+        return
+    try:
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        tmp = f"{path}.tmp.{os.getpid()}"
+        with open(tmp, "w") as f:
+            json.dump({"program": program, "scopes": scopes}, f)
+        os.replace(tmp, path)       # a reader never sees half a map
+    except OSError as e:
+        logger.warning("scope map of %s not kept: %r", program, e)
+
+
+def _read_or_compile(jitted, lowered):
+    """(map, "own_compile" | "compiled") of a lowering nobody has mapped
+    yet. `lowered.compile()` hands back the executable the process
+    already has for this lowering, else loads or compiles it; the
+    listener tells which, and whether every executable of the program's
+    name this process made was compiled HERE: those carry this
+    checkout's names. One that was loaded may carry another checkout's
+    (the cache's key leaves the metadata out), so it is compiled again
+    AROUND the cache: the process-wide `jax_enable_compilation_cache`
+    off and on again (one caller at a time, `_compiling`; a compile
+    another thread starts meanwhile merely misses the cache too), and
+    with a compiler option at its default, because a lowering keeps the
+    executable it made and would hand that back."""
+    jax = sys.modules["jax"]
+    here, loaded = _compiled.get(f"jit({getattr(jitted, '__name__', '')})",
+                                 (0, 0))
+    _tls.watch = watch = []
+    try:
+        compiled = lowered.compile()
+    finally:
+        _tls.watch = None
+    fresh = bool(watch) and not any(watch)      # compiled just now: a miss
+    # No event at all: jax handed back an executable this process holds.
+    # A load just now (a 1 in `watch`) may be another checkout's entry.
+    if fresh or (here and not loaded and not watch):
+        scopes = scopes_from_hlo(compiled.as_text())
+        if scopes:
+            return scopes, "compiled" if fresh else "own_compile"
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()     # the cache memoizes "am I used"
+    try:
+        text = lowered.compile(
+            compiler_options={"xla_hlo_profile": False}).as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        compilation_cache.reset_cache()
+    return scopes_from_hlo(text), "compiled"
+
+
+_SAID = {"own_compile": "read from the executable compiled here",
+         "stored": "read from the map kept beside the cache",
+         "compiled": "compiled for it"}
+
+
 def program_scopes(name: str) -> Optional[Dict[str, str]]:
-    """`scopes_from_hlo` of the program noted under `name`, compiled
-    from its abstract arguments; None where none was noted.
+    """`scopes_from_hlo` of the program noted under `name`, lowered from
+    its abstract arguments; None where none was noted.
 
-    This one compile goes AROUND the persistent cache. The cache's key
-    leaves the metadata out, so a cache shared with another checkout
-    hands back THAT checkout's executable, names and all (the device
-    trace's own per-operation names are stale the same way); and an
-    entry keyed on the metadata would grow a shared cache by one
-    executable a program a checkout. So it is a real compile every time
-    it is asked for, which is after a traced window, never inside one;
-    its seconds are logged.
-
-    Going around the cache means turning the process-wide
-    `jax_enable_compilation_cache` off and on again: one caller at a time
-    (`_compiling`), and a compile another thread starts meanwhile merely
-    misses the cache too — slower, never another program."""
+    What a map needs is {instruction of the executable that ran: scope
+    path under THIS checkout's names}. The instructions are the
+    compiler's and the same for the same computation; the names are not,
+    and the persistent cache's key leaves them out, so a cache shared
+    with another checkout may hand back THAT checkout's executable,
+    names and all. So, in this order: the map kept under the lowering's
+    `names_key` in `scope_maps/` beside the cache (`source="stored"`:
+    one small file read); the text of the executable, where this process
+    compiled it itself (`"own_compile"`); a compile (`"compiled"`:
+    because nothing was cached, or around the cache). Whatever was read
+    is kept under the key, so a (computation, names) costs a cache
+    directory one compile at most. The seconds are logged, counted in
+    `program_scopes_total{source=}` and recorded as a
+    `tracing.program_scopes` span (`program=`, `source=`) in the
+    process's recorder, session or none: it is asked for after a traced
+    window, never inside one."""
+    if name in _scope_maps:
+        return _scope_maps[name]
     noted = _programs.get(name)
     if noted is None:
         return None
-    jax = sys.modules["jax"]
-    from jax.experimental.compilation_cache import compilation_cache
+    from proteinbert_tpu.obs.metrics import process_counter
 
-    jitted, args, kwargs = noted
-    with _compiling, span("tracing.program_scopes", program=name) as took:
-        was_on = jax.config.jax_enable_compilation_cache
-        jax.config.update("jax_enable_compilation_cache", False)
-        compilation_cache.reset_cache()     # the cache memoizes "am I used"
-        try:
-            text = jitted.lower(*args, **kwargs).compile().as_text()
-        finally:
-            jax.config.update("jax_enable_compilation_cache", was_on)
-            compilation_cache.reset_cache()
-    logger.info("program_scopes(%s): compiled around the cache in %.1f s",
-                name, took.seconds)
-    return scopes_from_hlo(text)
+    args, kwargs = _as_called(name)
+    with _compiling, span("tracing.program_scopes", collector=_RECORDER,
+                          program=name) as took:
+        lowered = noted[0].lower(*args, **kwargs)
+        key = names_key(lowered)
+        scopes, source = _stored_map(key), "stored"
+        if scopes is None:
+            scopes, source = _read_or_compile(noted[0], lowered)
+            _store_map(key, name, scopes)
+        took.ids["source"] = source
+    process_counter("program_scopes_total", source=source).inc()
+    logger.info("program_scopes(%s): %s in %.1f s", name, _SAID[source],
+                took.seconds)
+    _scope_maps[name] = scopes
+    return scopes

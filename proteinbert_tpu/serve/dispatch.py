@@ -953,13 +953,12 @@ class BucketDispatcher:
                         if (kind, L, cls) in self._warm:
                             continue
                         dummy, _ = self._dummy_batch(L, cls)
+                        with tracing.startup_span(
+                                "startup.warmup", cls=cls, kind=kind,
+                                bucket=L) as warmed:
+                            self.run(kind, dummy)
                         if self._compile_hist is not None:
-                            t0 = time.perf_counter()
-                            self.run(kind, dummy)
-                            self._compile_hist.observe(
-                                time.perf_counter() - t0)
-                        else:
-                            self.run(kind, dummy)
+                            self._compile_hist.observe(warmed.seconds)
                         n += 1
             if TASK_KIND in kinds or self.heads:
                 n += self._warmup_task()
@@ -985,11 +984,12 @@ class BucketDispatcher:
                 tb, ab = self._place(tokens, ann)
                 with self._warm_lock:
                     new = ("trunk", L, cls) not in self._warm
-                t0 = time.perf_counter()
-                trunk_out = self._trunk_fn()(self._run_params(), tb, ab,
-                                             self.cfg.model)
-                jax.block_until_ready(trunk_out)
-                dt = time.perf_counter() - t0
+                with tracing.startup_span("startup.warmup", cls=cls,
+                                          kind="trunk", bucket=L) as warmed:
+                    trunk_out = self._trunk_fn()(self._run_params(), tb, ab,
+                                                 self.cfg.model)
+                    jax.block_until_ready(trunk_out)
+                dt = warmed.seconds
                 if new:
                     self._note_warm(("trunk", L, cls))
                     report["trunk_executables"] += 1
@@ -1489,11 +1489,11 @@ class RaggedDispatcher(BucketDispatcher):
                     continue
                 tokens, seg, ann, riders = self._dummy_packed(cls)
                 for kind in cold:
-                    t0 = time.perf_counter()
-                    self.run_packed(kind, tokens, seg, ann, riders)
+                    with tracing.startup_span("startup.warmup", cls=cls,
+                                              kind=kind) as warmed:
+                        self.run_packed(kind, tokens, seg, ann, riders)
                     if self._compile_hist is not None:
-                        self._compile_hist.observe(
-                            time.perf_counter() - t0)
+                        self._compile_hist.observe(warmed.seconds)
                     n += 1
             if TASK_KIND in kinds or self.heads:
                 n += self._warmup_task()
@@ -1516,11 +1516,12 @@ class RaggedDispatcher(BucketDispatcher):
             tb, sb, ab = self._place_packed(tokens, seg, ann)
             with self._warm_lock:
                 new = ("trunk", L, cls) not in self._warm
-            t0 = time.perf_counter()
-            trunk_out = self._packed_trunk_fn()(
-                self._run_params(), tb, sb, ab, self.cfg.model)
-            jax.block_until_ready(trunk_out)
-            dt = time.perf_counter() - t0
+            with tracing.startup_span("startup.warmup", cls=cls,
+                                      kind="trunk") as warmed:
+                trunk_out = self._packed_trunk_fn()(
+                    self._run_params(), tb, sb, ab, self.cfg.model)
+                jax.block_until_ready(trunk_out)
+            dt = warmed.seconds
             if new:
                 self._note_warm(("trunk", L, cls))
                 report["trunk_executables"] += 1

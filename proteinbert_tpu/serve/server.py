@@ -84,6 +84,7 @@ from proteinbert_tpu.heads.registry import (
     HeadRegistry, LoadedHead, TrunkMismatchError, UnknownHeadError,
     trunk_fingerprint,
 )
+from proteinbert_tpu.obs import tracing
 from proteinbert_tpu.serve.cache import EmbeddingCache, content_key
 from proteinbert_tpu.serve.dispatch import (
     DECODER_PAD, KINDS, NEIGHBORS_KIND, TASK_KIND, BucketDispatcher,
@@ -445,6 +446,7 @@ class Server:
         """Warm the compiled shape classes and start the scheduler."""
         if self._started:
             raise RuntimeError("server already started")
+        tracing.backend()
         warmed = self.dispatcher.warmup(self._warm_kinds)
         if self.index is not None:
             # Warm the one lookup executable every single-request probe

@@ -16,6 +16,7 @@ What changed vs the reference `pretrain()`:
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import time
@@ -26,7 +27,9 @@ import numpy as np
 
 from proteinbert_tpu.configs import PretrainConfig
 from proteinbert_tpu.obs import as_telemetry
-from proteinbert_tpu.obs.tracing import note_program, span
+from proteinbert_tpu.obs.tracing import (
+    backend as touch_backend, note_program, span, startup_span,
+)
 from proteinbert_tpu.train import train_state as ts
 from proteinbert_tpu.train.checkpoint import Checkpointer
 from proteinbert_tpu.train.metrics import DeviceMetricAccumulator, StepTimer
@@ -154,8 +157,11 @@ def pretrain(
     last_eval_loss = np.float32(np.inf)
     best_eval_loss = float("inf")
     stalled_evals = 0
+    touch_backend()     # the runtime's start, where nothing touched it yet
     if state is None:
-        state = ts.create_train_state(jax.random.PRNGKey(cfg.train.seed), cfg)
+        with startup_span("startup.init_state"):
+            state = ts.create_train_state(
+                jax.random.PRNGKey(cfg.train.seed), cfg)
         if mesh is not None:
             # Place the fresh state per the sharding rules BEFORE any
             # restore: the checkpoint template's shardings tell orbax
@@ -174,7 +180,8 @@ def pretrain(
                 # with a note event (restore() docstring) — wired BEFORE
                 # the restore so the fallback is on the run's record.
                 checkpointer.on_note = lambda **f: tele.emit("note", **f)
-            state, data_state = checkpointer.restore(state)
+            with startup_span("startup.restore"):
+                state, data_state = checkpointer.restore(state)
             batches_consumed = int((data_state or {}).get("batches_consumed", 0))
             es = (data_state or {}).get("eval_stream") or {}
             if es:
@@ -513,7 +520,8 @@ def pretrain(
       for step in range(start_step, cfg.train.max_steps):
         # One span an iteration: every instant of the loop lies inside a
         # `train.*` span, so an idle gap of the device takes one's name.
-        with span("train.step", step=step + 1):
+        with span("train.step", step=step + 1), \
+                contextlib.ExitStack() as first_step:
             with span("train.data_wait") as waited:
                 batch = next(batch_iterator)
             data_wait_s += waited.seconds
@@ -527,6 +535,11 @@ def pretrain(
                 # What the device trace's operations are joined to the
                 # scopes by (obs/tracing.program_scopes), kept once.
                 note_program(step_fn.__name__, step_fn, (state, batch, cfg))
+            if step == start_step:
+                # From the first call of the step to its first result
+                # fetched (below), or to the error that ends the step:
+                # the trace, lowering and compile or load nest inside.
+                first_step.enter_context(startup_span("startup.first_step"))
             with span("train.dispatch"):
                 if eval_keyed_plateau:
                     state, metrics = plateau_step(state, batch, last_eval_loss)
@@ -562,6 +575,7 @@ def pretrain(
                     # compiled step moves between the chips.
                     _log_collective_census(step_fn, state, batch, cfg, mesh)
                 float(metrics["loss"])
+                first_step.close()
                 stats = next((s for s in device_memory_report().values()
                               if "bytes_in_use" in s), None)
                 if stats:

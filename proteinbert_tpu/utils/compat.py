@@ -72,6 +72,11 @@ def configure_compile_cache() -> str:
     if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     _compile_multi_device_cpu_programs_afresh()
+    # Before the first compile of the process, so that the span spine's
+    # start-up collector sees that one too (obs/tracing.startup_spans).
+    from proteinbert_tpu.obs import tracing
+
+    tracing.arm()
     return directory
 
 

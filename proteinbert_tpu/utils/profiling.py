@@ -79,7 +79,9 @@ def device_trace(log_dir: str, host_profile: bool = False):
     trace-event JSON, and `program_scopes.json`, for every hot jitted
     program noted during the capture the map from its compiled
     instructions' names (what the trace's "XLA Ops" carry) to the
-    `jax.named_scope`s they came from."""
+    `jax.named_scope`s they came from (`tracing.program_scopes`: read
+    from the step this run compiled or from the map kept beside the
+    compile cache; a compile only for a loaded step nobody has mapped)."""
     import glob
     import json
 
@@ -105,8 +107,8 @@ def device_trace(log_dir: str, host_profile: bool = False):
             log(f"device trace, host spans and program scopes written to "
                 f"{beside}")
         except Exception as e:
-            # The xplane is on disk; a failed extra (the scope map is a
-            # compile) must not mask whatever the body itself raised.
+            # The xplane is on disk; a failed extra (the scope map lowers
+            # the step again) must not mask whatever the body itself raised.
             log(f"device trace written to {log_dir}; host spans / program "
                 f"scopes NOT written: {e!r}", level=logging.WARNING)
 

@@ -4,6 +4,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from benchmark import blocked
+
 
 def _leaves(tree):
     import jax
@@ -25,41 +27,45 @@ def leaf_dir_gaps(program_tree, reference_tree) -> np.ndarray:
     """For every leaf, the norm of the DIFFERENCE between the program's
     and the reference's, against the reference's norm of that leaf or of
     the median leaf, whichever is larger. Where `worst_leaf_gap` sees
-    only a leaf's length, this sees its direction too."""
+    only a leaf's length, this sees its direction too. The difference is
+    taken in float32, squares and sums in float64 (`blocked`: block by
+    block, over a few threads)."""
     import jax
 
-    diff, ref = [], []
-    for p, r in zip(jax.tree.leaves(program_tree),
-                    jax.tree.leaves(reference_tree)):
-        r = np.asarray(r, np.float32)
-        d = np.asarray(p, np.float32) - r
-        diff.append(np.sqrt(np.sum(np.square(d, dtype=np.float64))))
-        ref.append(np.sqrt(np.sum(np.square(r, dtype=np.float64))))
-    diff, ref = np.array(diff), np.array(ref)
+    as_f32 = lambda t: [np.asarray(x, np.float32) for x in jax.tree.leaves(t)]  # noqa: E731
+    program, reference = as_f32(program_tree), as_f32(reference_tree)
+    diff = np.sqrt(blocked.sq_sums(program, minus=reference, diff_dtype=np.float32))
+    ref = np.sqrt(blocked.sq_sums(reference))
     return diff / np.maximum(ref, np.median(ref))
 
 
-def leaf_dir_spread(program_tree, reference_tree) -> list:
+def spread_of(gaps) -> list:
     """The median, the quartile, the decile and the widest of the leaves'
     gaps: printed beside the one that is compared."""
-    gaps = leaf_dir_gaps(program_tree, reference_tree)
     return [float(np.quantile(gaps, q)) for q in (0.5, 0.75, 0.9, 1.0)]
 
 
-def training_checks(program: dict, reference: dict) -> dict:
+def leaf_dir_spread(program_tree, reference_tree) -> list:
+    return spread_of(leaf_dir_gaps(program_tree, reference_tree))
+
+
+def training_checks(program: dict, reference: dict, dir_gaps=None) -> dict:
     """program / reference: {"losses", "first_grad", "first_grad_norms",
     "change_norms"}. `grad_dir_gap` is the MEDIAN leaf's gap: rounding of
     activations differs from position to position and averages out of a
     gradient summed over a batch, so the median leaf is steady from seed
-    to seed; products in a lower precision move every leaf they feed."""
+    to seed; products in a lower precision move every leaf they feed.
+    `dir_gaps`: `leaf_dir_gaps` of the two first gradients, where the
+    caller has them already (two passes over two trees of 2.83 GB)."""
     loss_gap = max(abs(p - r) / abs(r)
                    for p, r in zip(program["losses"], reference["losses"]))
+    if dir_gaps is None:
+        dir_gaps = leaf_dir_gaps(program["first_grad"], reference["first_grad"])
     return {
         "loss_rel_gap": float(loss_gap),
         "grad_norm_gap": worst_leaf_gap(program["first_grad_norms"],
                                         reference["first_grad_norms"]),
-        "grad_dir_gap": float(np.median(leaf_dir_gaps(
-            program["first_grad"], reference["first_grad"]))),
+        "grad_dir_gap": float(np.median(dir_gaps)),
         "change_norm_gap": worst_leaf_gap(program["change_norms"],
                                           reference["change_norms"]),
     }

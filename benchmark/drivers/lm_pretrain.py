@@ -11,7 +11,8 @@ sync to the trainer's own drained return, closed by SIGTERM.
 The feed is the program's own pipeline: `TokenDocumentDataset` ->
 `make_packed_iterator` (the `PackPlanner`, first-fit) -> the trainer's
 prefetch thread. Documents: every block the mix's fixed multiset of
-lengths in another order; ids Zipf-distributed over the vocabulary slice
+lengths in another order, the orders one draw for every seed where the
+mix states an `order_seed`; ids Zipf-distributed over the vocabulary slice
 under a seeded permutation. A residue of this model is a TOKEN:
 `train_residues_per_s` counts the real (non-pad) positions of the steps
 completed, batch by batch as the feed handed them out.
@@ -96,11 +97,20 @@ def documents(mix: dict, n_blocks: int, seed: int) -> list:
     """n_blocks x block documents of token ids: each block the mix's
     lengths in an order of its own; ids drawn with probability
     proportional to rank ** -exponent, ranks laid over the vocabulary
-    slice by a permutation of the seed's own."""
+    slice by a permutation of the seed's own.
+
+    Where the mix states an `order_seed` (the rehearsal's states none, by
+    null), the orders are drawn from that number and not from the run's
+    seed: the packer turns the order of the lengths into the rows' segment
+    layout, and the attention core walks only the tiles inside a segment
+    (a third of a step), so another order is another amount of work. Every
+    seed then trains on the same layout with ids and weights of its own."""
     rng = np.random.default_rng([seed, 1])
+    order = (np.random.default_rng([mix["order_seed"], 0])
+             if mix.get("order_seed") is not None else rng)
     spec = mix["ids"]
     base = traffic.block_lengths(mix)
-    lengths = np.concatenate([rng.permutation(base) for _ in range(n_blocks)])
+    lengths = np.concatenate([order.permutation(base) for _ in range(n_blocks)])
     weights = np.arange(1, spec["vocab_size"] + 1, dtype=np.float64) ** -spec["zipf_exponent"]
     ranks = np.searchsorted(np.cumsum(weights / weights.sum()),
                             rng.random(int(lengths.sum())), side="right")
@@ -170,12 +180,13 @@ def route_mismatch_share(program_ids, reference_ids, segment_ids) -> float:
                  / max(1, np.broadcast_to(real, shared.shape).sum()))
 
 
-def gaps_against(program: dict, reference: dict, first_segments) -> dict:
+def gaps_against(program: dict, reference: dict, first_segments,
+                 dir_gaps=None) -> dict:
     """Every number of the comparison: `compare.training_checks` and the
     routing of the first step. `program` is the program's readings or,
     for a control, the reference's own in its place; both carry
-    `first_ids`."""
-    gaps = compare.training_checks(program, reference)
+    `first_ids`. `dir_gaps`: as `compare.training_checks` takes them."""
+    gaps = compare.training_checks(program, reference, dir_gaps)
     gaps["route_mismatch_share"] = route_mismatch_share(
         program["first_ids"], reference["first_ids"], first_segments)
     return gaps
@@ -251,14 +262,17 @@ def run(run, devices):
     t_ref = time.perf_counter()
     reference = follow_reference(run, feed.kept, c)
     print(f"reference: {CHECKED_STEPS} steps in {time.perf_counter() - t_ref:.1f} s")
-    gaps = gaps_against(program, reference, feed.kept[0]["segment_ids"])
+    t_cmp = time.perf_counter()
+    dir_gaps = compare.leaf_dir_gaps(program["first_grad"], reference["first_grad"])
+    gaps = gaps_against(program, reference, feed.kept[0]["segment_ids"], dir_gaps)
     checks = limit_checks(gaps, wl)
     checks.append(("param_count", float(glm_moe.param_count(cfg.model)), float(stated)))
     for name in wl.get("not_compared", ()):
         print(f"not compared {name}: {gaps[name]:.6g}")
-    spread = compare.leaf_dir_spread(program["first_grad"], reference["first_grad"])
+    spread = compare.spread_of(dir_gaps)
     print("first gradient, gap by leaf: median {:.6g}, 75 % {:.6g}, 90 % {:.6g}, "
           "widest {:.6g}".format(*spread))
+    print(f"comparison: {time.perf_counter() - t_cmp:.1f} s", flush=True)
     bias_off = max(float(np.abs(np.asarray(program_bias[k]) - reference["bias"][k]).max())
                    for k in program_bias)
     print(f"balance bias after {CHECKED_STEPS} steps, widest gap from the "

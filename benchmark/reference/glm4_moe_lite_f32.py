@@ -37,25 +37,49 @@ the router stays float32, as in the program); "bf16_params" the control
 one step below float32 parameters. `operands="bf16"`: the weights enter
 the products rounded to bfloat16 (the router's not), all else float32.
 
-The master parameters, the optimizer's moments and the gradient being
-summed live on the host (updated in place, leaf by leaf), so that the device holds one rounded copy of
-the weights and one row's gradient (5.7 GB at 706.5 M parameters) beside
-one row's activations; rows go one at a time, queries in blocks, the
-head's logits in chunks, each layer, each held expert and each block
-recomputed in the backward pass.
+The master parameters and the optimizer's moments live on the host
+(updated in place, block by block: `benchmark/blocked.py`), so that the
+device holds one rounded copy of the weights and the gradient summed
+over the rows so far (5.7 GB at 706.5 M parameters) beside one row's
+activations and what of its gradient is not yet added; rows go one at a time, queries in blocks,
+the head's logits in chunks, each layer, each held expert and each block
+recomputed in the backward pass; the summed gradient comes back to the
+host once a step.
 """
 
 from __future__ import annotations
 
+import resource
+import time
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from benchmark import blocked
+
 _HI = jax.lax.Precision.HIGHEST
 QUERY_BLOCK = 256
 HEAD_CHUNK = 2048
+
+
+class Laps:
+    """A line a phase: its seconds, the process's peak on the host, the
+    device's memory in use (`tools/process_wall.py` stamps every line of a
+    run with the process's clock; PERF.md section 5 has the table)."""
+
+    def __init__(self):
+        self.at = time.perf_counter()
+
+    def __call__(self, what):
+        now = time.perf_counter()
+        held = jax.local_devices()[0].memory_stats() or {}
+        print(f"reference: {what} {now - self.at:.2f} s (host peak "
+              f"{resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 2 ** 20:.2f} GiB, "
+              f"on the device {held.get('bytes_in_use', 0) / 2 ** 30:.2f} GiB, its peak "
+              f"{held.get('peak_bytes_in_use', 0) / 2 ** 30:.2f})", flush=True)
+        self.at = time.perf_counter()
 
 
 # ------------------------------------------------------------------ weights
@@ -346,9 +370,11 @@ def restacked(params):
     return dict(params, dense=stack(params["dense"]), layers=stack(params["layers"]))
 
 
-@partial(jax.jit, static_argnames=("c_items", "precision"))
-def _row_value_and_grad(params, bias, tokens, seg, inv_main, inv_module,
+@partial(jax.jit, static_argnames=("c_items", "precision"), donate_argnames="acc")
+def _row_value_and_grad(params, bias, tokens, seg, inv_main, inv_module, acc,
                         c_items, precision):
+    """One row's share of the loss, and `acc` (donated) with the row's
+    gradient added to it: the sum over the rows stays on the device."""
     c = dict(c_items)
 
     def f(p):
@@ -357,7 +383,7 @@ def _row_value_and_grad(params, bias, tokens, seg, inv_main, inv_module,
                 (loads, ids))
 
     value, grads = jax.value_and_grad(f, has_aux=True)(unstacked(params))
-    return value, restacked(grads)
+    return value, jax.tree.map(jnp.add, acc, restacked(grads))
 
 
 def target_counts(tokens, seg):
@@ -369,10 +395,28 @@ def target_counts(tokens, seg):
     return int((real & (pad1 == seg)).sum()), int((real & (pad2 == seg)).sum())
 
 
-def loss_and_grads(params, bias, batch, c, precision="f32", operands="f32"):
+def fetch(tree):
+    """Writable numpy copies, in C order, of a tree on the device, whose
+    leaves are freed one by one as they arrive (a few transfers ahead of
+    the copy). `order="C"`: the TPU hands some leaves back with their
+    dimensions in another order in memory (the stacked experts'), and a
+    flat view of such a leaf, as `blocked` takes, would be a copy."""
+    leaves, treedef = jax.tree.flatten(tree)
+    out = []
+    for i, x in enumerate(leaves):
+        for ahead in leaves[i:i + 3]:
+            ahead.copy_to_host_async()
+        out.append(np.array(x, order="C"))
+        x.delete()
+    return jax.tree.unflatten(treedef, out)
+
+
+def loss_and_grads(params, bias, batch, c, precision="f32", operands="f32",
+                   lap=lambda what: None):
     """Loss and gradients of one step on a packed batch, a row at a
     time; `params` and `bias` come from the host and the gradients go
-    back to it. -> (loss, gradients, loads, ids (rows, layers, L, k))."""
+    back to it, summed over the rows on the device and fetched once.
+    -> (loss, gradients, loads, ids (rows, layers, L, k))."""
     tokens, seg = np.asarray(batch["tokens"]), np.asarray(batch["segment_ids"])
     n_main, n_module = target_counts(tokens, seg)
     run, bias = jax.device_put(params), jax.device_put(bias)
@@ -381,23 +425,30 @@ def loss_and_grads(params, bias, batch, c, precision="f32", operands="f32"):
     elif operands == "bf16":
         run = round_product_weights(run)    # the unrounded copy is dropped
     arith = "int8" if precision == "int8" else "f32"
-    loss, grads, loads, ids = 0.0, None, 0, []
+    jax.block_until_ready(run)
+    lap("weights to the device and rounded")
+    # Every row is dispatched before any of its results is waited for; a
+    # row's program adds its gradient into the sum it is handed (12.1 GiB
+    # at the peak at 706.5 M parameters and 8,192 tokens, by the v5e
+    # compiler's count: the sum apart from the program would take 13.6).
+    acc, rows = jax.tree.map(jnp.zeros_like, run), []
     for r in range(tokens.shape[0]):
-        (v, (load, chosen)), g = _row_value_and_grad(
+        (v, (load, chosen)), acc = _row_value_and_grad(
             run, bias, jnp.asarray(tokens[r]), jnp.asarray(seg[r]),
-            1.0 / max(n_main, 1), 1.0 / max(n_module, 1), _hashable(c), arith)
+            1.0 / max(n_main, 1), 1.0 / max(n_module, 1), acc,
+            c_items=_hashable(c), precision=arith)
+        rows.append((v, load, chosen))
+    del run
+    jax.block_until_ready(acc)
+    lap(f"{len(rows)} rows forward and backward, summed on the device")
+    grads = fetch(acc)
+    loss = 0.0
+    for v, _, _ in rows:
         loss = loss + float(v)
-        loads = loads + np.asarray(load)
-        ids.append(np.asarray(chosen))
-        # summed on the host, leaf by leaf: one row's gradient on the
-        # device, one sum and one leaf in flight on the host
-        if grads is None:
-            grads = jax.tree.map(np.array, g)
-        else:
-            for acc, leaf in zip(jax.tree.leaves(grads), jax.tree.leaves(g)):
-                acc += np.asarray(leaf)
-        del g
-    return loss, grads, loads, np.stack(ids)
+    loads = sum(np.asarray(load) for _, load, _ in rows)
+    ids = np.stack([np.asarray(chosen) for _, _, chosen in rows])
+    lap("gradient fetched")
+    return loss, grads, loads, ids
 
 
 def learning_rate(count, o):
@@ -405,27 +456,48 @@ def learning_rate(count, o):
 
 
 def adam_step(params, grads, mu, nu, count, o, precision="f32"):
-    """Clip by the global norm, then Adam: numpy, on the host, leaf by
-    leaf and IN PLACE (706.5 M parameters: a second copy of the four
-    trees would not fit beside the first). `grads` becomes the clipped
-    gradient, `params`, `mu`, `nu` their next values."""
-    leaves = jax.tree.leaves(grads)
-    gnorm = float(np.sqrt(sum(float(np.sum(np.square(g, dtype=np.float64)))
-                              for g in leaves)))
+    """Clip by the global norm, then Adam: numpy, on the host, IN PLACE
+    (706.5 M parameters: a second copy of the four trees would not fit
+    beside the first), block by block through a thread's own scratch,
+    the pieces of all leaves over `blocked`'s pool. `grads` becomes the
+    clipped gradient, `params`, `mu`, `nu` their next values. Every
+    element sees float32 operations in this order:
+        g *= clip; a = a * b1 + (1 - b1) * g; b = b * b2 + ((1 - b2) * g) * g;
+        p -= (lr * (a / c1)) / (sqrt(b / c2) + 1e-8)"""
+    gnorm = float(np.sqrt(sum(blocked.sq_sums(grads))))
     clip = np.float32(1.0 if gnorm < o["grad_clip_norm"]
                       else o["grad_clip_norm"] / gnorm)
     b1, b2 = np.float32(o["b1"]), np.float32(o["b2"])
     t = count + 1
     lr = np.float32(learning_rate(count, o))
     c1, c2 = np.float32(1 - o["b1"] ** t), np.float32(1 - o["b2"] ** t)
-    for p, g, a, b in zip(*map(jax.tree.leaves, (params, grads, mu, nu))):
-        g *= clip
-        a *= b1
-        a += (1 - b1) * g
-        b *= b2
-        b += (1 - b2) * g * g
-        p -= lr * (a / c1) / (np.sqrt(b / c2) + np.float32(1e-8))
-        if precision == "bf16_params":
+    eps, rest1, rest2 = np.float32(1e-8), 1 - b1, 1 - b2
+
+    def piece(p, g, a, b, lo, hi):
+        s, u = blocked.scratch(np.float32), blocked.scratch(np.float32, 1)
+        for at in range(lo, hi, blocked.BLOCK):
+            n = min(blocked.BLOCK, hi - at)
+            p_, g_, a_, b_ = (x[at:at + n] for x in (p, g, a, b))
+            s_, u_ = s[:n], u[:n]
+            np.multiply(g_, clip, out=g_)
+            np.multiply(a_, b1, out=a_)
+            np.multiply(g_, rest1, out=s_)
+            np.add(a_, s_, out=a_)
+            np.multiply(b_, b2, out=b_)
+            np.multiply(g_, rest2, out=s_)
+            np.multiply(s_, g_, out=s_)
+            np.add(b_, s_, out=b_)
+            np.divide(a_, c1, out=s_)
+            np.multiply(s_, lr, out=s_)
+            np.divide(b_, c2, out=u_)
+            np.sqrt(u_, out=u_)
+            np.add(u_, eps, out=u_)
+            np.divide(s_, u_, out=s_)
+            np.subtract(p_, s_, out=p_)
+
+    blocked.over_pieces(piece, params, grads, mu, nu, in_place=True)
+    if precision == "bf16_params":
+        for p in jax.tree.leaves(params):
             p[...] = np.asarray(_bf16(jnp.asarray(p)))
 
 
@@ -441,9 +513,7 @@ def update_bias(bias, loads, c):
     return new
 
 
-def host_norms(tree):
-    return jax.tree.map(
-        lambda x: float(np.sqrt(np.sum(np.square(np.asarray(x, np.float64))))), tree)
+host_norms = blocked.norms
 
 
 def follow_steps(seed, batches, c, o, precision="f32", operands="f32"):
@@ -459,30 +529,31 @@ def follow_steps(seed, batches, c, o, precision="f32", operands="f32"):
         params, bias = jax.jit(partial(init_params, c=c))(k_init)
         if precision == "bf16_params":
             params = jax.tree.map(_bf16, params)
-        return params, bias
+        return fetch(params), jax.device_get(bias)
 
+    lap = Laps()
     params, bias = first_weights()
-    params = jax.tree.map(np.array, params)     # on the host, writable
-    bias = jax.device_get(bias)
+    lap("first weights made and fetched")
     mu = jax.tree.map(np.zeros_like, params)
     nu = jax.tree.map(np.zeros_like, params)
     losses, first_grad, first_ids = [], None, None
     for count, batch in enumerate(batches):
         loss, grads, loads, ids = loss_and_grads(
-            params, bias, batch, c, precision, operands)
+            params, bias, batch, c, precision, operands, lap)
         adam_step(params, grads, mu, nu, count, o, precision)
+        lap(f"step {count} clip and Adam")
         bias = update_bias(bias, loads, c)
         losses.append(loss)
         if first_grad is None:
             first_grad, first_ids = grads, ids      # the clipped gradient
         del grads
     del mu, nu
-    # the change against the first weights, made again from the seed
-    # rather than kept through the steps, one leaf at a time
-    change = jax.tree.map(
-        lambda p, s: float(np.sqrt(np.sum(np.square(
-            p.astype(np.float64) - np.asarray(s, np.float64))))),
-        params, first_weights()[0])
+    # the change against the first weights, made again from the seed rather
+    # than kept through the steps: 2.83 GB less at the host's peak for ~4 s
+    change = blocked.norms(params, minus=first_weights()[0])
+    lap("first weights made again, change norms")
+    norms = host_norms(first_grad)
+    lap("first gradient's norms")
     return {"losses": losses, "first_grad": first_grad,
-            "first_grad_norms": host_norms(first_grad),
+            "first_grad_norms": norms,
             "change_norms": change, "first_ids": first_ids, "bias": bias}

@@ -63,7 +63,10 @@ def test_the_manifest_lists_the_new_cells_and_their_metrics():
     manifest = _manifest()
     cells = {w["name"]: w for w in manifest["workloads"]}
     assert cells[LM]["chips"] == 1 and cells[MESH]["chips"] == 4
-    assert len(cells) == 5 and sum(w["chips"] == 4 for w in cells.values()) == 1
+    # however many cells the manifest counts: a quarter of them at most, and
+    # one always, may ask for four chips
+    assert len(cells) == len(manifest["workloads"]) >= 5
+    assert 1 <= sum(w["chips"] == 4 for w in cells.values()) <= max(1, len(cells) // 4)
     for w in cells.values():
         assert len(w["why"]) <= 200
     for m in manifest["per_layer"]:
@@ -195,3 +198,25 @@ def test_the_documents_of_a_block_hold_the_same_lengths_whatever_the_seed():
     # Zipf at exponent 0.5: the commonest id carries ~0.4 % of the positions
     assert np.bincount(ids).max() / len(ids) < 0.01
     assert lm_pretrain.segment_pairs(np.array([[1, 1, 1, 2, 2, 0]])) == 6 + 3
+
+
+def test_the_cells_documents_come_in_one_order_with_ids_of_the_seeds_own():
+    """The packer turns the order of the lengths into the rows' segment
+    layout, and the attention core's time follows the layout: the cell's
+    mix states the order, so that every seed does the same work."""
+    from benchmark import traffic
+    from benchmark.drivers import lm_pretrain
+
+    mix = traffic.load_mix("lm-packed-2x8192")
+    assert "order_seed" in mix
+    a = lm_pretrain.documents(mix, 2, 4100000011)
+    b = lm_pretrain.documents(mix, 2, 5)
+    assert list(map(len, a)) == list(map(len, b))
+    assert list(map(len, a[:mix["block"]])) != list(map(len, a[mix["block"]:]))
+    assert not np.array_equal(np.concatenate(a), np.concatenate(b))
+    again = lm_pretrain.documents(mix, 2, 4100000011)
+    assert all(np.array_equal(x, y) for x, y in zip(a, again))
+    # without the key the order is the seed's, as the rehearsal's mix has it
+    loose = dict(mix, order_seed=None)
+    assert (list(map(len, lm_pretrain.documents(loose, 2, 4100000011)))
+            != list(map(len, lm_pretrain.documents(loose, 2, 5))))

@@ -56,64 +56,70 @@ def test_rehearsal_prints_the_contracts_line(trace):
     assert line["compared"]["param_count"]["value"] == 835152
 
 
-def test_the_manifest_lists_the_cell_its_configuration_and_its_metrics():
+def test_the_manifest_lists_lings_cell_configuration_and_metrics_by_name():
     manifest = _manifest()
     cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
-    assert cell == manifest["workloads"][-1] and cell["chips"] == 1
+    assert cell["chips"] == 1
     assert cell["config"] == CONFIG and cell["traffic"] == "lm-ragged-sat"
     assert len(cell["why"]) <= 200 and "RATE" not in cell["why"]
-    entry = manifest["configs"][-1]
-    assert entry["name"] == CONFIG and len(entry["why"]) <= 200
+    entry = next(e for e in manifest["configs"] if e["name"] == CONFIG)
+    assert len(entry["why"]) <= 200
     assert entry["reduced"] == ["num_hidden_layers", "first_k_dense_replace",
                                 "num_experts", "vocab_size"]
     tput = next(m for m in manifest["end_to_end"]
                 if m["name"] == "embed_residues_per_s")
-    assert tput["workloads"] == ["serve-base-sat", CELL] and tput["bound"] == 0.07
+    assert {"serve-base-sat", CELL} <= set(tput["workloads"]) and tput["bound"] == 0.07
     new = ["kda_device_ms.tput", "kda_core_device_ms.tput", "mla_device_ms.tput",
            "moe_router_device_ms.tput", "moe_dispatch_device_ms.tput",
            "moe_experts_device_ms.tput", "shared_expert_device_ms.tput",
            "expert_load_max_over_mean.tput", "routed_here_share_pct.tput",
            "dropped_assignments.tput", "kda_core_roofline"]
-    assert [m["name"] for m in manifest["per_layer"][-len(new):]] == new
-    for m in manifest["per_layer"][-len(new):]:
-        assert m["workloads"] == [CELL] and m["moves"] == "embed_residues_per_s"
+    per_layer = {m["name"]: m for m in manifest["per_layer"]}
+    for name in new:        # a later cell of the same stack may share a reader
+        assert CELL in per_layer[name]["workloads"], name
+        assert per_layer[name]["moves"] == "embed_residues_per_s"
     shared = {m["name"] for m in manifest["per_layer"]
               if CELL in m.get("workloads", ()) and m["name"] not in new}
     assert {"mfu_pct.tput", "batch_device_ms.tput", "peak_hbm_gib.tput",
             "device_idle_pct.tput", "compiles_in_window.tput"} <= shared
 
 
-def test_the_manifest_has_six_cells_and_one_on_four_chips():
-    # what `test_glm_cells.py` held of five cells (conftest.py), of six
+def test_the_manifest_has_its_cells_and_one_on_four_chips():
+    # the six cells PR 33 knew are among however many the manifest counts
     cells = {w["name"]: w for w in _manifest()["workloads"]}
-    assert len(cells) == 6 and sum(w["chips"] == 4 for w in cells.values()) == 1
+    assert {"pretrain-base-dense", "serve-base-sat", "pretrain-large-dense",
+            "pretrain-glm47flash-packed8k", "pretrain-large-fsdp4", CELL} <= set(cells)
+    assert 1 <= sum(w["chips"] == 4 for w in cells.values()) <= max(1, len(cells) // 4)
     assert cells["pretrain-large-fsdp4"]["chips"] == 4
     for m in _manifest()["per_layer"]:
         assert os.path.exists(os.path.join(
             ROOT, "benchmark", "layer_metrics", m["name"] + ".py"))
 
 
-def test_the_older_entries_stand_as_they_were_but_for_the_cells_name():
-    # what `test_mla_core_metric.py` and `test_serve_classes.py` held
-    # (conftest.py), found by name and not by place
+def test_the_older_entries_stand_found_by_name():
+    # what `test_mla_core_metric.py` and `test_serve_classes.py` hold,
+    # found by name and not by place
     per_layer = {m["name"]: m for m in _manifest()["per_layer"]}
     assert per_layer["mla_core_device_ms.train"] == {
         "name": "mla_core_device_ms.train", "unit": "ms", "better": "lower",
         "source": "device_trace", "layer": "Kernels and XLA ops",
         "moves": "train_residues_per_s",
         "workloads": ["pretrain-glm47flash-packed8k"]}
-    assert per_layer["mfu_pct.tput"]["workloads"] == ["serve-base-sat", CELL]
+    assert {"serve-base-sat", CELL} <= set(per_layer["mfu_pct.tput"]["workloads"])
 
 
-def test_the_three_pinned_assertions_are_expected_to_fail_and_no_other():
-    from tests.benchmark import conftest
-
-    pinned = conftest.PINNED_TO_AN_OLDER_MANIFEST
-    assert len(pinned) == 3
-    for tail in pinned:
-        name = tail.split("::")[1].split("[")[0]
-        with open(os.path.join(ROOT, "tests", "benchmark", tail.split("::")[0])) as f:
-            assert "def " + name + "(" in f.read()
+def test_no_assertion_here_reads_the_manifest_by_place_or_counts_a_whole_list():
+    """What PR 41 put right in the eight assertions that were marked
+    `xfail(strict=True)`: an entry is found by its NAME, a list of cells is
+    held as a subset, so a cell appended to the manifest breaks nothing."""
+    here = os.path.join(ROOT, "tests", "benchmark")
+    assert not os.path.exists(os.path.join(here, "conftest.py")), "no pin is left"
+    for name in sorted(os.listdir(here)):
+        if name.startswith("test_") and name != os.path.basename(__file__):
+            with open(os.path.join(here, name)) as f:
+                text = f.read()
+            for by_place in ('["per_layer"][-', '["workloads"][-', '["configs"][-'):
+                assert by_place not in text, (name, by_place)
 
 
 def test_the_configuration_file_holds_the_published_widths_and_states_the_cut():

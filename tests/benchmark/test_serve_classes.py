@@ -449,7 +449,7 @@ def test_every_per_layer_metric_lists_its_cells():
     ("stalled_seconds.lat", "serve-base-steady")])
 def test_the_four_metrics_of_pr_27_are_listed_with_a_reader_each(metric, cell):
     entry = next(m for m in _manifest()["per_layer"] if m["name"] == metric)
-    assert entry["workloads"] == [cell]
+    assert cell in entry["workloads"]       # later cells may share the reader
     assert callable(_layer_metric(metric))
     assert _layer_metric(metric)({}) is None
 

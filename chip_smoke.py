@@ -2,7 +2,8 @@
 """chip_smoke.py — the quickest proof that the system still starts on the chip.
 
     python chip_smoke.py              one TPU chip, `base` width
-    python chip_smoke.py --chips 4    the sharded trainer on a four-chip host
+    python chip_smoke.py --chips 4    a ragged server's mesh executable and the
+                                      sharded trainer on a four-chip host
 
 Drives the main path once through the entry points users type
 (`python -m proteinbert_tpu pretrain | serve | map`), at the full width of
@@ -67,7 +68,12 @@ SIZES = {
             ("paper", 128, 512, 4, 64, 512, 8),
         ),
         tiled=dict(C=1024, L=512, B=2),
+        # The serving shape: the smallest row class of `base` ragged.
+        served=dict(C=512, L=1024, B=64, S=8),
         multichip=dict(preset="base", long_preset="long", steps=3),
+        # `--serve-mode ragged --mesh` on a host: the 256-row class, a
+        # chip's quarter of it the smallest class one chip serves.
+        served_mesh=dict(preset="base", rows=256, seq_len=1024, segments=8),
     ),
     "cpu": dict(
         preset="tiny", steps=12, ckpt_every=8,
@@ -78,7 +84,9 @@ SIZES = {
             ("paper", 128, 512, 4, 64, 128, 2),
         ),
         tiled=dict(C=1024, L=128, B=1),
+        served=dict(C=128, L=256, B=2, S=4),
         multichip=dict(preset="tiny", long_preset="tiny", steps=2),
+        served_mesh=dict(preset="tiny", rows=8, seq_len=128, segments=4),
     ),
 }
 
@@ -616,7 +624,7 @@ def phase_kernels(opts, size) -> None:
     t0 = time.monotonic()
     out = last_json("kernels", run_child("kernels", "kernels", fn_cmd(
         "kernels", {"shapes": size["kernel_shapes"], "tiled": size["tiled"],
-                    "tol": KERNEL_TOL}), opts))
+                    "served": size["served"], "tol": KERNEL_TOL}), opts))
     for row in out["rows"]:
         key = (row["shape"], row["L"], row["packed"])
         check(key in KERNEL_DECISIONS, "kernels", f"no decision row {key}")
@@ -631,6 +639,12 @@ def phase_kernels(opts, size) -> None:
                   f"{key}: no tpu_custom_call in the compiled text")
     check(out["tiled"]["max_err"] <= KERNEL_TOL, "kernels",
           f"C=1024 channel-tiled kernels: {out['tiled']}")
+    served = out["served"]
+    check(served["kernel_vs_xla"]["max"] <= KERNEL_TOL
+          and served["kernel_vs_f32"]["max"] <= KERNEL_TOL, "kernels",
+          f"the served packed local track: {served}")
+    check(served["decision"] == "pallas/packed", "kernels",
+          f"the served shape took {served['decision']}, not the kernel")
     emit("kernels", t0, compile_stats("kernels"), out)
 
 
@@ -770,15 +784,218 @@ def _child_kernels(payload: str) -> None:
     e_attn = err(
         K.fused_packed_attention(aparams, x, gseg, seg, interpret=interp),
         packed_global_attention_apply(aparams, x, gseg, seg))
+    served = _served_track(args["served"], interp, platform)
     print(json.dumps({
         "platform": platform, "interpret": interp, "tolerance": args["tol"],
-        "rows": rows,
+        "rows": rows, "served": served,
         "tiled": {"C": C, "L": L, "plan": [tc, tile],
                   "dense_err": round(e_dense, 5),
                   "segments_err": round(e_seg, 5),
                   "attention_err": round(e_attn, 5),
                   "max_err": round(max(e_dense, e_seg, e_attn), 5)},
     }))
+
+
+def _served_track(shape: dict, interp: bool, platform: str) -> dict:
+    """The local track of a FORWARD-ONLY packed batch at the serving shape
+    (ISSUE 42): the segment kernel against the XLA composition it replaces
+    there (`local_track_segment_reference`, bfloat16) and both against the
+    same track in float32 over the same bfloat16-rounded weights and input.
+    Rows as the ragged server packs them: spans one after another with a
+    boundary ON a tile edge and one inside the wide conv's halo of it, a
+    pad tail, and the last row all pad. Gaps over the real positions: norm
+    of the difference over the reference's norm (`rel`), and the largest
+    deviation over the reference's largest value (`max`). On the chip also
+    the mean time of a call, each path jitted alone."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from proteinbert_tpu import kernels as K
+    from proteinbert_tpu.configs import ModelConfig
+    from proteinbert_tpu.kernels.fused_block import _segment_tile
+    from proteinbert_tpu.models import proteinbert
+
+    C, L, B, S = shape["C"], shape["L"], shape["B"], shape["S"]
+    cfg = ModelConfig(local_dim=C, global_dim=64, key_dim=16, num_heads=4,
+                      num_blocks=1, num_annotations=32, dtype="bfloat16")
+    kp, kx, kb = jax.random.split(jax.random.PRNGKey(42), 3)
+    block = proteinbert.block_init(kp, cfg)
+    params = {k: block[k] for k in ("narrow_conv", "wide_conv", "local_ln1",
+                                    "local_dense", "local_ln2")}
+    rounded = jax.tree.map(
+        lambda a: (a.astype(jnp.bfloat16).astype(jnp.float32)
+                   if a.ndim >= 2 else a), params)
+    x = jax.random.normal(kx, (B, L, C), jnp.bfloat16)
+    bc = jax.random.normal(kb, (B, S, C), jnp.bfloat16)
+    tile = _segment_tile(C, L, S, "bfloat16", 9, 9, 20)
+    assert tile > 0, f"no plan at the served shape {shape}"
+    rng = np.random.default_rng(42)
+    seg = np.zeros((B, L), np.int32)
+    for b in range(B - 1):
+        cuts = [0, tile, tile + 7] + sorted(
+            rng.choice(np.arange(tile + 40, L - 24), S - 3, replace=False))
+        cuts.append(L - int(rng.integers(0, 24)))
+        for i in range(S):
+            seg[b, cuts[i]:cuts[i + 1]] = i + 1
+    seg = jnp.asarray(seg)
+
+    def xla(p, xx, bb, ss):
+        return K.local_track_segment_reference(
+            p, xx, K.gather_segment_broadcast(bb, ss), ss, 1, 5)
+
+    def f32(p, xx, bb, ss):
+        with jax.default_matmul_precision("highest"):
+            return xla(p, xx.astype(jnp.float32), bb.astype(jnp.float32),
+                       ss)
+
+    before = dict(K.PATH_TOTAL)
+    kernel = jax.jit(lambda p, xx, bb, ss: K.fused_local_track_segments(
+        p, xx, bb, ss, 1, 5, interp))
+    got_k = kernel(params, x, bc, seg)
+    moved = sorted(f"{p}/{r}" for (p, r), c in K.PATH_TOTAL.items()
+                   if c != before.get((p, r), 0))
+    xla_j = jax.jit(xla)
+    got_x = xla_j(params, x, bc, seg)
+    want = jax.jit(f32)(rounded, x, bc, seg)
+    real = np.asarray(seg) > 0
+
+    def gap(a, b):
+        a = np.asarray(a, np.float32)[real]
+        b = np.asarray(b, np.float32)[real]
+        assert np.isfinite(a).all() and np.isfinite(b).all()
+        return {"rel": round(float(np.linalg.norm(a - b)
+                                   / np.linalg.norm(b)), 6),
+                "max": round(float(np.max(np.abs(a - b))
+                                   / max(1.0, float(np.max(np.abs(b))))), 5)}
+
+    out = {"shape": shape, "tile": tile,
+           "decision": moved[0] if len(moved) == 1 else moved,
+           "kernel_vs_xla": gap(got_k, got_x),
+           "kernel_vs_f32": gap(got_k, want),
+           "xla_vs_f32": gap(got_x, want)}
+    if platform == "tpu":
+        for name, fn in (("kernel_ms", kernel), ("xla_ms", xla_j)):
+            fn(params, x, bc, seg).block_until_ready()
+            t0 = time.perf_counter()
+            for _ in range(20):
+                y = fn(params, x, bc, seg)
+            y.block_until_ready()
+            out[name] = round((time.perf_counter() - t0) / 20 * 1e3, 3)
+    return out
+
+
+# ------------------------------------------------------- phase: served_mesh
+
+def phase_served_mesh(opts, size) -> None:
+    t0 = time.monotonic()
+    out = last_json("served_mesh", run_child(
+        "served_mesh", "served_mesh", fn_cmd("served_mesh", {
+            **size["served_mesh"],
+            "cpu_devices": 4 if opts.platform == "cpu" else 0}), opts,
+        cap=600))
+    check(out["worst_gap"] <= SERVE_MODE_TOL, "served_mesh",
+          f"a chip's quarter of the batch differs from the same rows on "
+          f"one chip by {out['worst_gap']}: {out}")
+    check(out["devices_holding_outputs"] == 4 and not out["collectives"],
+          "served_mesh", f"not four replicas on their own rows: {out}")
+    if out["platform"] == "tpu":
+        check(out["fused_path"] == {"pallas/packed": 2}
+              and out["tpu_custom_calls"] == 1, "served_mesh",
+              f"the mesh's executable does not run the kernel: {out}")
+    emit("served_mesh", t0, compile_stats("served_mesh"), out)
+
+
+def _child_served_mesh(payload: str) -> None:
+    """`--serve-mode ragged --mesh` (ISSUE 42): a ragged dispatcher over
+    data=2 x fsdp=2 runs one packed batch of `rows` through its `embed`
+    executable (`parallel/sharding.on_each_replica`: every chip the
+    one-chip program on its quarter), against the first quarter of the
+    same batch through `inference._packed_encode_batch` on one chip. The
+    compiled text has no collective; on the chip it has the segment
+    kernel, and both calls are timed: four chips take as long over `rows`
+    as one over a quarter."""
+    args = json.loads(payload)
+    import jax
+    import numpy as np
+
+    from proteinbert_tpu.utils.compat import (
+        configure_compile_cache, request_cpu_devices,
+    )
+
+    if args["cpu_devices"]:
+        request_cpu_devices(args["cpu_devices"])
+    configure_compile_cache()
+    import dataclasses
+
+    from proteinbert_tpu import inference, kernels as K
+    from proteinbert_tpu.configs import MeshConfig, get_preset
+    from proteinbert_tpu.models import proteinbert
+    from proteinbert_tpu.parallel import make_mesh
+    from proteinbert_tpu.serve import RaggedDispatcher
+
+    devices = jax.devices()
+    assert len(devices) == 4, devices
+    rows, L, S = args["rows"], args["seq_len"], args["segments"]
+    cfg = get_preset(args["preset"])
+    cfg = cfg.replace(data=dataclasses.replace(cfg.data, seq_len=L),
+                      mesh=MeshConfig(data=2, fsdp=2))
+    params = proteinbert.init(jax.random.PRNGKey(0), cfg.model)
+    rng = np.random.default_rng(42)
+    tokens = rng.integers(4, 24, size=(rows, L)).astype(np.int32)
+    seg = np.zeros((rows, L), np.int32)
+    for r in range(rows):  # S spans a row, a pad tail behind the last
+        cuts = [0] + sorted(rng.choice(np.arange(8, L - 8), S - 1,
+                                       replace=False)) + [L - 5]
+        for i in range(S):
+            seg[r, cuts[i]:cuts[i + 1]] = i + 1
+    tokens[seg == 0] = 0
+    ann = (rng.random((rows, S, cfg.model.num_annotations)) < 0.005
+           ).astype(np.float32)
+
+    before = dict(K.PATH_TOTAL)
+    d = RaggedDispatcher(params, cfg, rows_per_batch=rows, max_segments=S,
+                         mesh=make_mesh(cfg.mesh, devices))
+    fn = d._packed_fn("embed")
+    placed = d._place_packed(tokens, seg, ann)
+    # Compiled once, ahead of time: the same executable gives the text
+    # and takes every call.
+    mesh_exe = fn.lower(d.params, *placed, cfg.model).compile()
+    text = mesh_exe.as_text()
+    got = mesh_exe(d.params, *placed)
+    q = rows // 4
+    one = jax.device_put((params, tokens[:q], seg[:q], ann[:q]), devices[0])
+    want = inference._packed_encode_batch(*one, cfg.model)
+    out = {
+        "platform": devices[0].platform, "kind": devices[0].device_kind,
+        "count": len(devices), "rows": rows, "seq_len": L,
+        "devices_holding_outputs": min(
+            len(a.sharding.device_set) for a in jax.tree.leaves(got)),
+        "worst_gap": round(max(float(np.max(np.abs(
+            np.asarray(a, np.float32)[:q] - np.asarray(b, np.float32))))
+            for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want))),
+            6),
+        "collectives": sorted(c for c in (
+            "all-reduce", "all-gather", "reduce-scatter",
+            "collective-permute", "all-to-all") if c + "(" in text
+            or c + "-start(" in text),
+        "tpu_custom_calls": text.count("tpu_custom_call"),
+        "fused_path": {f"{p}/{r}": c - before.get((p, r), 0)
+                       for (p, r), c in sorted(K.PATH_TOTAL.items())
+                       if c != before.get((p, r), 0)},
+    }
+    if out["platform"] == "tpu":
+        for name, call in (
+                ("mesh_ms", lambda: mesh_exe(d.params, *placed)),
+                ("one_chip_quarter_ms",
+                 lambda: inference._packed_encode_batch(*one, cfg.model))):
+            jax.block_until_ready(call())
+            t0 = time.perf_counter()
+            for _ in range(10):
+                y = call()
+            jax.block_until_ready(y)
+            out[name] = round((time.perf_counter() - t0) / 10 * 1e3, 3)
+    print(json.dumps(out))
 
 
 # --------------------------------------------------------- phase: multichip
@@ -949,6 +1166,7 @@ def _child_multichip(payload: str) -> None:
 
 
 CHILD_PHASES = {"device": _child_device, "kernels": _child_kernels,
+                "served_mesh": _child_served_mesh,
                 "multichip": _child_multichip}
 
 
@@ -957,8 +1175,9 @@ CHILD_PHASES = {"device": _child_device, "kernels": _child_kernels,
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="4: run ONLY the sharded-trainer path and the "
-                         "one-device run it is compared with")
+                    help="4: run ONLY the served mesh executable and the "
+                         "sharded-trainer path, each with the one-device "
+                         "run it is compared with")
     ap.add_argument("--platform", choices=("cpu",),
                     help="sandbox rehearsal at tiny width; the last line "
                          "then names the cpu")
@@ -968,6 +1187,7 @@ def main(argv=None) -> int:
     with tempfile.TemporaryDirectory(prefix="chip_smoke_") as work:
         device = phase_device(opts)
         if opts.chips == 4:
+            phase_served_mesh(opts, size)
             phase_multichip(opts, size)
         else:
             run_dir = phase_pretrain(opts, size, work, device)

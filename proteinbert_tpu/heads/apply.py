@@ -62,15 +62,17 @@ def packed_trunk_batch(params, tokens, segment_ids, annotations,
     `seg_mask` is True only at a segment's REAL token positions (a
     bucket-quantized span's <pad> tail is excluded), so the head tails
     pool exactly the positions the bucketed path's pad_mask keeps.
-    Under cfg.use_pallas the trunk's local track runs the segment-
-    aware fused Pallas kernel on supported shapes (ISSUE 10) — the
-    shared packed trunk executable is a fast-path executable."""
+    A forward-only entry: on a TPU and at C <= 512 the trunk's
+    local track runs the segment-aware fused Pallas kernel on supported
+    shapes (kernels/fused_block.packed_local_track_forward, ISSUE 42) —
+    the shared packed trunk executable is a fast-path executable."""
     from proteinbert_tpu import inference
     from proteinbert_tpu.data.vocab import PAD_ID
 
     local, global_ = proteinbert.encode(params, tokens, annotations, cfg,
                                         pad_mask=(tokens != PAD_ID),
-                                        segment_ids=segment_ids)
+                                        segment_ids=segment_ids,
+                                        forward_only=True)
     return {"local": local, "global": global_,
             "seg_mask": inference._segment_real_mask(
                 tokens, segment_ids, annotations.shape[1])}

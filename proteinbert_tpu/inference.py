@@ -124,16 +124,20 @@ def _packed_encode_batch(params, tokens, segment_ids, annotations,
     representations. Per-segment math mirrors the bucketed entry
     row-for-row (mask-weighted mean over real positions), so a span's
     outputs match the bucketed dispatcher's within jitted tolerance
-    (docs/serving.md, ragged batching). Under cfg.use_pallas the local
-    track runs the segment-aware fused Pallas kernel on supported
-    shapes (kernels/fused_block.fused_local_track_segments, ISSUE 10)
-    — the packed executables this builds are fast-path executables,
-    counted in fused_kernel_path_total{path=pallas,reason=packed}."""
+    (docs/serving.md, ragged batching). A forward-only entry (as its
+    `predict_go` / `predict_residues` siblings below): on a TPU and
+    at C <= 512 the local track runs the segment-aware fused Pallas
+    kernel on every shape its guard has a plan for
+    (kernels/fused_block.packed_local_track_forward, ISSUE 42), with or
+    without cfg.use_pallas — the packed executables this builds are
+    fast-path executables there, counted in
+    fused_kernel_path_total{path=pallas,reason=packed}."""
     pad_mask = tokens != PAD_ID
     with jax.named_scope("encode"):
         local, global_ = proteinbert.encode(params, tokens, annotations, cfg,
                                             pad_mask=pad_mask,
-                                            segment_ids=segment_ids)
+                                            segment_ids=segment_ids,
+                                            forward_only=True)
     with jax.named_scope("pool"):
         m = _segment_real_mask(tokens, segment_ids,
                                annotations.shape[1]).astype(jnp.float32)
@@ -165,7 +169,7 @@ def _packed_go_probs_batch(params, tokens, segment_ids, annotations,
     """(B, S, A) sigmoid GO probabilities per packed segment."""
     _, global_logits = proteinbert.apply(
         params, tokens, annotations, cfg, pad_mask=(tokens != PAD_ID),
-        segment_ids=segment_ids)
+        segment_ids=segment_ids, forward_only=True)
     return jax.nn.sigmoid(global_logits)
 
 
@@ -177,7 +181,7 @@ def _packed_residue_probs_batch(params, tokens, segment_ids, annotations,
     the bucketed entry's (bucket_len, V) output)."""
     local_logits, _ = proteinbert.apply(
         params, tokens, annotations, cfg, pad_mask=(tokens != PAD_ID),
-        segment_ids=segment_ids)
+        segment_ids=segment_ids, forward_only=True)
     return jax.nn.softmax(local_logits, -1)
 
 

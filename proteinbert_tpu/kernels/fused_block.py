@@ -57,20 +57,42 @@ whole L sweep (weight HBM traffic O(weights), not O(B·L/TL·weights));
 otherwise the per-row order runs with phase fastest. Shapes the tiled
 plan cannot fit either way fall back to the XLA path automatically.
 
-OFFICIAL SCOPE (rounds 2-3, measured on v5e — BASELINE.md "Kernel
-same-batch verdict"): a tiled plan exists only at C <= 512 (the full
-weight set VMEM-resident), but even there the full train step LOSES to
-the remat_policy="convs" XLA path at every measured batch (0.478 vs
-0.547 MFU at B=256/L=512, round 3) — its only full-step win was over
-NON-remat XLA, a configuration no preset uses. At C = 1024 every
-schedule is weight-bandwidth-bound (38 MB of conv weights vs 16 MB
-VMEM) and the measured kernel is 0.88-1.03x XLA. Every preset
-therefore trains on the XLA path with remat_policy="convs"; the kernel
-remains an opt-in (`model.use_pallas`) validated for correctness —
-including the Mosaic-only resident-order semantics — by
-chip_smoke.py (phase `kernels`) on real hardware, and is the reference
-implementation for fused-local-track schedules at sharded
-(seq-parallel) shapes.
+OFFICIAL SCOPE. Two verdicts, one a kind of program, and the ENTRY
+POINT tells the kinds apart (no shape does):
+
+- A program that will be DIFFERENTIATED (rounds 2-3, measured on v5e —
+  BASELINE.md "Kernel same-batch verdict"): a tiled plan exists only at
+  C <= 512 (the full weight set VMEM-resident), but even there the full
+  train step LOSES to the remat_policy="convs" XLA path at every
+  measured batch (0.478 vs 0.547 MFU at B=256/L=512, round 3) — its
+  only full-step win was over NON-remat XLA, a configuration no preset
+  uses: the custom VJP recomputes the whole track where XLA keeps its
+  saved `conv_out`. At C = 1024 every schedule is weight-bandwidth-
+  bound (38 MB of conv weights vs 16 MB VMEM) and the measured kernel
+  is 0.88-1.03x XLA. Every preset therefore TRAINS (and evaluates) on
+  the XLA path with remat_policy="convs"; there the kernel remains an
+  opt-in (`model.use_pallas`) validated for correctness — including
+  the Mosaic-only resident-order semantics — by chip_smoke.py (phase
+  `kernels`) on real hardware, and is the reference implementation for
+  fused-local-track schedules at sharded (seq-parallel) shapes. That
+  verdict stands, for training only.
+- A FORWARD-ONLY PACKED program (ISSUE 42: the serving and mapping
+  entries, `inference._packed_encode_batch`, `_packed_go_probs_batch`,
+  `_packed_residue_probs_batch`, `heads/apply.packed_trunk_batch`, and
+  through them serve/dispatch.py, mapper/engine.py and the int8 arm of
+  parallel/quant.py) runs the SEGMENT kernel with or without
+  `model.use_pallas`, on a TPU (`pallas_compiles`) at
+  C <= MAX_PALLAS_DIM (`packed_local_track_forward`), wherever the
+  guard has a whole-weights-resident plan (`_segment_tile`: the L tile
+  comes from the budget, 256 rows at the serving shape 1024 x 512 x 8
+  in bfloat16); a shape without one runs XLA's composition and is
+  counted `reference/segments`. There XLA's lowering of `_segment_conv` is eighteen
+  mask-multiply-product taps a block with the running sum written to
+  and read from HBM between taps, bound by memory; the kernel keeps
+  the row in VMEM and is bound by the MXU (PERF.md sections 5 and 6
+  have the chip's numbers per row class). C = 1024 (`large` served)
+  stays on XLA until a cell serves it; everything dense, `train_step`'s
+  packed path and `eval_step` are untouched.
 """
 
 from __future__ import annotations
@@ -150,14 +172,27 @@ def force_reference_requested() -> bool:
 
 def pallas_interpret() -> bool:
     """Whether a Pallas request runs under the interpreter in this
-    process — the ONE place that reads the backend. True only on the
-    CPU backend, where the interpreter is the only way a TPU kernel can
-    run (the tests and the CPU rehearsals); on any other backend it is
-    False, so the request compiles for the device or raises. The
+    process — with `pallas_compiles` below, the ONE place that reads
+    the backend. True only on the CPU backend, where the interpreter is
+    the only way a TPU kernel can run (the tests and the CPU
+    rehearsals); on any other backend it is False, so the request
+    compiles for the device or raises. The
     kernels' callers (models/proteinbert.block_apply,
     parallel/seq_parallel.seq_parallel_apply) ask once per trace and
     pass the answer down; no kernel entry looks at the backend."""
     return jax.default_backend() == "cpu"
+
+
+def pallas_compiles() -> bool:
+    """Whether this process's backend compiles a Mosaic kernel: a TPU,
+    and nothing else (the kernels hold `pltpu.VMEM` blocks, which no
+    other backend lowers). The second and last place that reads the
+    backend, for the one caller that takes a kernel nobody asked for by
+    name (`packed_local_track_forward`): anything that is not a TPU
+    keeps XLA's composition there, where `pallas_interpret` would send
+    an explicit `use_pallas` request to the compiler and let it
+    raise."""
+    return jax.default_backend() == "tpu"
 
 
 def register_path_observer(cb: Callable[[str, str], None]) -> None:
@@ -443,6 +478,35 @@ def fused_local_track_segments(
         params, x, broadcast_pos, segment_ids, narrow_dilation,
         wide_dilation
     )
+
+
+def packed_local_track_forward(
+    params: Params, x: jax.Array, broadcast_seg: jax.Array,
+    segment_ids: jax.Array,
+    narrow_dilation: int = 1, wide_dilation: int = 5,
+) -> jax.Array:
+    """Local track of a packed row in a program that will NOT be
+    differentiated (the serving and mapping entries of inference.py and
+    heads/apply.py). On a TPU and at C <= MAX_PALLAS_DIM (the whole
+    weight set stays in VMEM; the channel-tiled variant is bound by its
+    weights' bandwidth and keeps waiting for a cell that serves
+    `large`) it is `fused_local_track_segments`, which counts what it
+    did: `pallas/packed` where its guard has a plan for the shape,
+    `reference/segments` (and a warning, once a shape) where it has
+    none. Everywhere else (the CPU, where a served batch never runs the
+    interpreter; a backend with no Mosaic compiler; C = 1024) it is
+    `local_track_segment_reference` exactly as a differentiated program
+    runs it, uncounted as before. A program that will be differentiated
+    never comes here: it wants XLA's saved `conv_out`, the kernel's VJP
+    recomputes the whole track (module docstring), and no shape tells
+    the two apart, so the entry point does."""
+    if pallas_compiles() and x.shape[-1] <= MAX_PALLAS_DIM:
+        return fused_local_track_segments(
+            params, x, broadcast_seg, segment_ids, narrow_dilation,
+            wide_dilation, interpret=False)
+    return local_track_segment_reference(
+        params, x, gather_segment_broadcast(broadcast_seg, segment_ids),
+        segment_ids, narrow_dilation, wide_dilation)
 
 
 def track_halo(params: Params, narrow_dilation: int = 1,
@@ -1033,6 +1097,40 @@ def _fused_segment_kernel(
                              s2_ref, b2_ref, dtype)
 
 
+def _segment_tile(
+    local_dim: int, seq_len: int, max_segments: int, dtype,
+    narrow_taps: int, wide_taps: int, halo: int,
+) -> int:
+    """L tile of the weights-resident SEGMENT plan, or 0 where none
+    fits: the largest of `_pick_tile(seq_len)`, 256, 128 that divides
+    the row and whose working set is inside the VMEM budget. The tile
+    comes from the budget, not from `_pick_tile` alone, because the
+    fixed residents grow with the row while the temporaries grow with
+    the tile: at the serving shape (L=1024, C=512, bfloat16, S <= 128)
+    the whole weight set (9.96 MB), the padded row and its lane-padded
+    one-hot leave no room for three float32 temporaries of 512 rows
+    (3.15 MB) and all the room for those of 256 (13.03 MB of 13.63).
+    Priced: the weights, the padded row, the one-hot row block
+    (lane-padded to 128 on TPU), the (S, C) per-segment broadcast
+    block, the tile's three float32 temporaries and its mask lanes."""
+    item = _vb.itemsize(dtype)
+    Lp = seq_len + 2 * halo
+    resident = (
+        _vb.track_weight_bytes(local_dim, narrow_taps, wide_taps, item)
+        + Lp * local_dim * item
+        + Lp * _vb.lanes(max_segments) * item
+        + max_segments * local_dim * item)
+    first = _pick_tile(seq_len)
+    for tile in (first, 256, 128):
+        if tile > first or seq_len % tile:
+            continue
+        temps = (_vb.track_temp_bytes(tile, local_dim)
+                 + tile * _vb.lanes(max_segments) * 4)
+        if _vb.fits(resident, temps):
+            return tile
+    return 0
+
+
 def pallas_segments_supported(
     local_dim: int, seq_len: int, max_segments: int,
     dtype: str = "bfloat16",
@@ -1045,9 +1143,11 @@ def pallas_segments_supported(
     `pallas_supported`: taps must be odd (the symmetric-halo tap
     layout), and the budget additionally prices the (Lp, S) one-hot
     row block (lane-padded to 128 on TPU) and the (S, C) per-segment
-    broadcast block. Beyond MAX_PALLAS_DIM the channel-tiled SEGMENT
-    plan (`_plan_tiled(max_segments=)`, ISSUE 13) must find a tile
-    width — ProteinBERT-Large C=1024 packed rows run the fast path."""
+    broadcast block; up to MAX_PALLAS_DIM the L tile is the largest
+    the budget takes (`_segment_tile`). Beyond MAX_PALLAS_DIM the
+    channel-tiled SEGMENT plan (`_plan_tiled(max_segments=)`, ISSUE 13)
+    must find a tile width — ProteinBERT-Large C=1024 packed rows run
+    the fast path."""
     if not _vb.shape_prechecks(local_dim, seq_len, max_segments):
         return False
     if narrow_taps % 2 == 0 or wide_taps % 2 == 0:
@@ -1056,20 +1156,10 @@ def pallas_segments_supported(
         return _plan_tiled(local_dim, seq_len, dtype, narrow_taps,
                            wide_taps, wide_dilation,
                            max_segments=max_segments)[0] > 0
-    item = _vb.itemsize(dtype)
-    C = local_dim
     halo = max((narrow_taps - 1) // 2 * narrow_dilation,
                (wide_taps - 1) // 2 * wide_dilation)
-    tile = _pick_tile(seq_len)
-    Lp = seq_len + 2 * halo
-    weights = _vb.track_weight_bytes(C, narrow_taps, wide_taps, item)
-    row = Lp * C * item
-    # Mosaic pads the one-hot's lane dim UP to the next multiple of 128.
-    oh_row = Lp * _vb.lanes(max_segments) * item
-    bcast = max_segments * C * item
-    temps = (_vb.track_temp_bytes(tile, C)
-             + tile * _vb.lanes(max_segments) * 4)
-    return _vb.fits(weights, row, oh_row, bcast, temps)
+    return _segment_tile(local_dim, seq_len, max_segments, dtype,
+                         narrow_taps, wide_taps, halo) > 0
 
 
 def _pallas_segments_forward(
@@ -1091,7 +1181,6 @@ def _pallas_segments_forward(
     oh_padded = jnp.pad(seg_oh.astype(dtype),
                         ((0, 0), (halo, halo), (0, 0)))
     Lp = L + 2 * halo
-    tile = _pick_tile(L)
 
     def vec(p):  # (C,) fp32 vector → (1, C) activation-dtype VMEM block
         return p.reshape(1, C)
@@ -1127,6 +1216,9 @@ def _pallas_segments_forward(
         transcendentals=3 * B * L * C,
     )
     if C <= MAX_PALLAS_DIM:
+        tile = _segment_tile(C, L, S, dtype, narrow_taps, wide_taps, halo)
+        if tile == 0:  # callers gate via pallas_segments_supported
+            raise ValueError(f"no segment VMEM plan for C={C}, L={L}, S={S}")
         grid = (B, L // tile)
         row_spec = pl.BlockSpec((1, Lp, C), lambda b, j: (b, 0, 0),
                                 memory_space=pltpu.VMEM)

@@ -114,6 +114,7 @@ def block_apply(
     pad_mask: Optional[jax.Array],
     cfg: ModelConfig,
     segment_ids: Optional[jax.Array] = None,
+    forward_only: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Apply one block. local (B,L,C), global (B,G), pad_mask (B,L) bool.
 
@@ -122,11 +123,18 @@ def block_apply(
     masked, the global→local broadcast is gathered per position from the
     position's own segment, and attention/annotation state run per
     segment — a packed row is numerically a batch of independent
-    proteins (tests/test_packing.py asserts bit-level isolation)."""
+    proteins (tests/test_packing.py asserts bit-level isolation).
+
+    `forward_only` is the caller's word that the program will not be
+    differentiated (the packed serving and mapping entries say it): a
+    packed row's local track then runs as one VMEM-resident kernel
+    where the backend and the shape allow
+    (kernels/fused_block.packed_local_track_forward). It changes
+    nothing dense, and nothing a training or evaluation step traces."""
     packed = segment_ids is not None
     from proteinbert_tpu.kernels import (
         gather_segment_broadcast, local_track_reference,
-        local_track_segment_reference,
+        local_track_segment_reference, packed_local_track_forward,
     )
 
     # Local track (reference modules.py:201-217). The scopes
@@ -182,11 +190,16 @@ def block_apply(
         # (B, S, C) → (B, L, C), zero at pad so nothing row-wide
         # leaks into the masked conv taps.
         with jax.named_scope("local_track"):
-            local = local_track_segment_reference(
-                track_params, local,
-                gather_segment_broadcast(broadcast, segment_ids),
-                segment_ids, 1, cfg.wide_dilation,
-            )
+            if forward_only:
+                local = packed_local_track_forward(
+                    track_params, local, broadcast, segment_ids,
+                    1, cfg.wide_dilation)
+            else:
+                local = local_track_segment_reference(
+                    track_params, local,
+                    gather_segment_broadcast(broadcast, segment_ids),
+                    segment_ids, 1, cfg.wide_dilation,
+                )
         with jax.named_scope("attention"):
             attn = packed_global_attention_apply(
                 params["attention"], local, global_, segment_ids,
@@ -266,6 +279,7 @@ def encode(
     cfg: ModelConfig,
     pad_mask: Optional[jax.Array] = None,
     segment_ids: Optional[jax.Array] = None,
+    forward_only: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Trunk forward: embeddings + N dual-track blocks, no output heads.
 
@@ -277,7 +291,7 @@ def encode(
     PACKED rows: pass `segment_ids` (B, L) with annotations shaped
     (B, S, A) per segment; the global representation comes back
     per-segment as (B, S, G) and every cross-position op is segment-
-    masked (see block_apply).
+    masked (see block_apply). `forward_only`: see block_apply.
     """
     from proteinbert_tpu.parallel.sharding import (
         gathered_over_fsdp, pin_to_batch_layout,
@@ -302,7 +316,8 @@ def encode(
         local, global_ = pinned(local, global_)
 
     body = remat_wrap(
-        partial(block_apply, cfg=cfg, segment_ids=segment_ids), cfg)
+        partial(block_apply, cfg=cfg, segment_ids=segment_ids,
+                forward_only=forward_only), cfg)
 
     if cfg.scan_blocks:
         def scan_body(carry, blk):
@@ -365,6 +380,7 @@ def apply(
     cfg: ModelConfig,
     pad_mask: Optional[jax.Array] = None,
     segment_ids: Optional[jax.Array] = None,
+    forward_only: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Forward pass.
 
@@ -377,12 +393,14 @@ def apply(
         (or segment_ids) if omitted.
       segment_ids: optional (B, L) int segment map for PACKED rows
         (data/packing.py); 0 = pad, 1..S = packed protein index.
+      forward_only: the program will not be differentiated (see
+        block_apply); a training or evaluation step never passes it.
     Returns:
       (local_logits (B, L, V), global_logits (B, A)) — LOGITS, in
       float32; global_logits is (B, S, A) when packed.
     """
     local, global_ = encode(params, tokens, annotations, cfg, pad_mask,
-                            segment_ids)
+                            segment_ids, forward_only)
     with jax.named_scope("heads"):
         local_logits = dense_apply(
             params["local_head"], local).astype(jnp.float32)

@@ -40,6 +40,7 @@ memory is allocated before shardings are known.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -129,6 +130,44 @@ def serve_batch_sharding(mesh: Mesh) -> Dict[str, NamedSharding]:
         "segment_ids": NamedSharding(mesh, P(("data", "fsdp"), None)),
         "annotations": NamedSharding(mesh, P(("data", "fsdp"), None)),
     }
+
+
+@functools.lru_cache(maxsize=None)
+def on_each_replica(entry, mesh: Mesh):
+    """A PACKED serving entry `entry(params, tokens, segment_ids,
+    annotations, cfg)` (inference.py, heads/apply.py, the int8 arm of
+    parallel/quant.py) as one program over `mesh` in which every replica
+    runs `entry` ITSELF on its own rows: a `shard_map` over the whole
+    mesh, the batch arguments and every output split over
+    `serve_batch_sharding`'s joint ('data','fsdp') axis, the weights
+    whole on every device.
+
+    A packed row is a batch of independent proteins and no entry mixes
+    rows, so this is the batch-dim data parallelism `serve_batch_sharding`
+    asks the partitioner for, stated instead of inferred. It is stated
+    because the forward-only entries put a Mosaic kernel in the program
+    on a TPU (kernels/fused_block.packed_local_track_forward), and the
+    partitioner refuses one ("Mosaic kernels cannot be automatically
+    partitioned"): inside the map every axis is manual, each chip
+    compiles the one-chip program at rows / replicas, and no collective
+    exists. 'model' and 'seq' extents replicate the compute, as they did
+    under the partitioner (the served weights are replicated). One
+    jitted program an (entry, mesh), whoever asks."""
+    from jax import shard_map
+
+    rows = P(("data", "fsdp"))
+
+    def call(params, tokens, segment_ids, annotations, cfg):
+        # check_vma=False as in parallel/quant.py's bodies: the kernel's
+        # `pallas_call` declares its output with no varying-axes type,
+        # which the checker refuses.
+        return shard_map(
+            lambda p, t, s, a: entry(p, t, s, a, cfg), mesh=mesh,
+            in_specs=(P(), rows, rows, rows), out_specs=rows,
+            check_vma=False)(params, tokens, segment_ids, annotations)
+
+    call.__name__ = entry.__name__  # tracing.note_program's key
+    return jax.jit(call, static_argnames="cfg")
 
 
 def _path_has(path, name: str) -> bool:

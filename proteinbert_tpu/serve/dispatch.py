@@ -1205,6 +1205,20 @@ class RaggedDispatcher(BucketDispatcher):
                 jax.device_put(segment_ids, self._shardings["segment_ids"]),
                 jax.device_put(annotations, self._shardings["annotations"]))
 
+    def _over_mesh(self, entry):
+        """`entry`, a packed executable of the encoder, as this
+        dispatcher calls it: itself on one device; over a mesh, every
+        replica running it on its own rows
+        (parallel/sharding.on_each_replica: the entries hold a Mosaic
+        kernel on a TPU, which the partitioner cannot split, and rows
+        never mix). The decoder's entry, which sums its routing counters
+        over the batch, never comes here and stays the partitioner's."""
+        if self.mesh is None:
+            return entry
+        from proteinbert_tpu.parallel.sharding import on_each_replica
+
+        return on_each_replica(entry, self.mesh)
+
     def _packed_fn(self, kind: str, quantized: Optional[bool] = None):
         if quantized is None:
             quantized = self.quant != "fp32"
@@ -1217,13 +1231,13 @@ class RaggedDispatcher(BucketDispatcher):
         if quantized:
             from proteinbert_tpu.parallel.quant import quant_packed_entry
 
-            return quant_packed_entry(kind)
+            return self._over_mesh(quant_packed_entry(kind))
         if kind == "embed":
-            return inference._packed_encode_batch
+            return self._over_mesh(inference._packed_encode_batch)
         if kind == "predict_go":
-            return inference._packed_go_probs_batch
+            return self._over_mesh(inference._packed_go_probs_batch)
         if kind == "predict_residues":
-            return inference._packed_residue_probs_batch
+            return self._over_mesh(inference._packed_residue_probs_batch)
         raise ValueError(f"unknown request kind {kind!r}; have {KINDS}")
 
     def _packed_trunk_fn(self, quantized: Optional[bool] = None):
@@ -1234,8 +1248,8 @@ class RaggedDispatcher(BucketDispatcher):
                 _q_packed_trunk_batch,
             )
 
-            return _q_packed_trunk_batch
-        return heads_apply.packed_trunk_batch
+            return self._over_mesh(_q_packed_trunk_batch)
+        return self._over_mesh(heads_apply.packed_trunk_batch)
 
     def run_timed(self, *args, **kwargs):
         raise NotImplementedError(
@@ -1339,7 +1353,7 @@ class RaggedDispatcher(BucketDispatcher):
 
             def reference():
                 return heads_apply.apply_heads_packed(
-                    heads_apply.packed_trunk_batch(
+                    self._packed_trunk_fn(quantized=False)(
                         ref_params, tb, sb, ab, self.cfg.model), tails)
         else:
             fn = self._packed_fn(kind)

@@ -41,25 +41,33 @@ WIDTHS = {  # name: (C, G, H, key_dim)
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """One described v5e chip to compile for. The persistent cache is off
-    around these compiles: an entry written for a described device cannot
-    be read back without the device, and the next compile would warn."""
+def topo():
+    """A described v5e host (2x2) to compile for. The persistent cache is
+    off around these compiles: an entry written for a described device
+    cannot be read back without the device, and the next compile would
+    warn."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        desc = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — any refusal means no compiler
         pytest.skip(f"cannot describe a v5e topology here: {e}")
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", was_on)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """One chip of it."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _on(chip, tree):
@@ -194,6 +202,90 @@ def test_base_train_step_fits_the_chip(chip):
     assert 0 < need < V5E_HBM_BYTES, (need, m)
     # Donation took: the new state lives in the old one's buffers.
     assert m.alias_size_in_bytes > 0.9 * m.output_size_in_bytes, m
+
+
+@pytest.mark.parametrize("rows", [64, 512])
+def test_served_packed_encode_runs_the_segment_kernel_on_v5e(
+        chip, rows, monkeypatch):
+    """The serving cell's executable (`_packed_encode_batch` of `base`:
+    rows x 1024, eight segments a row) compiles for one v5e with its local
+    track on the segment kernel (ISSUE 42): one Mosaic call in the scanned
+    block body, named inside the `local_track` scope so that the scope
+    metrics go on reading it, counted `pallas/packed` once, and the
+    512-row class's temporaries far under the 6.5 GB XLA's nine-tap
+    lowering took. The backend predicate is steered here, in the test: the
+    sandbox's backend is the CPU."""
+    from proteinbert_tpu import inference
+    from proteinbert_tpu.kernels import fused_block
+
+    monkeypatch.setattr(fused_block, "pallas_compiles", lambda: True)
+    cfg = get_preset("base").model
+    params = jax.eval_shape(lambda k: proteinbert.init(k, cfg),
+                            jax.random.PRNGKey(0))
+    before = dict(fused_block.PATH_TOTAL)
+    compiled = inference._packed_encode_batch.lower(
+        _on(chip, params), *_on(chip, (
+            _sds((rows, 1024), jnp.int32), _sds((rows, 1024), jnp.int32),
+            _sds((rows, S, cfg.num_annotations), jnp.float32))),
+        cfg).compile()
+    moved = {k: v - before.get(k, 0)
+             for k, v in fused_block.PATH_TOTAL.items()
+             if v != before.get(k, 0)}
+    assert moved == {("pallas", "packed"): 1}, moved
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1, text.count("tpu_custom_call")
+    assert "local_track/pbt_local_track_segments/pallas_call" in text
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 3 * 1024 ** 3, m
+
+
+def test_ragged_mesh_executable_runs_the_kernel_on_each_chip(
+        topo, monkeypatch):
+    """`--serve-mode ragged --mesh` on a v5e host: the dispatcher's packed
+    executable (`on_each_replica` of `_packed_encode_batch`, 256 rows over
+    data x fsdp = 2 x 2) compiles for the four chips. Handed to the
+    partitioner the entry does not lower at all ("Mosaic kernels cannot be
+    automatically partitioned"); stated as a map over the mesh, each chip
+    runs the kernel on ITS 64 rows and nothing crosses chips: no
+    all-gather of the activations, no collective of any kind."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from proteinbert_tpu import inference
+    from proteinbert_tpu.kernels import fused_block
+    from proteinbert_tpu.parallel.sharding import (
+        on_each_replica, serve_batch_sharding,
+    )
+
+    monkeypatch.setattr(fused_block, "pallas_compiles", lambda: True)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2, 1, 1),
+                ("data", "fsdp", "model", "seq"))
+    cfg = get_preset("base").model
+    rows = 256
+    params = jax.eval_shape(lambda k: proteinbert.init(k, cfg),
+                            jax.random.PRNGKey(0))
+    placed = serve_batch_sharding(mesh)
+    args = (
+        jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, P())), params),
+        jax.ShapeDtypeStruct((rows, 1024), jnp.int32,
+                             sharding=placed["tokens"]),
+        jax.ShapeDtypeStruct((rows, 1024), jnp.int32,
+                             sharding=placed["segment_ids"]),
+        jax.ShapeDtypeStruct((rows, S, cfg.num_annotations), jnp.float32,
+                             sharding=placed["annotations"]))
+    with pytest.raises(NotImplementedError, match="automatically partition"):
+        inference._packed_encode_batch.lower(*args, cfg)
+    text = on_each_replica(inference._packed_encode_batch, mesh).lower(
+        *args, cfg).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert re.search(  # the kernel's own output: a chip's quarter
+        rf"pbt_local_track_segments\S* = bf16\[{rows // 4},1024,512\]", text)
+    assert not re.search(
+        r"\b(all-gather|all-reduce|all-to-all|collective-permute|"
+        r"reduce-scatter)(-start)?\(", text)
 
 
 def test_decoder_expert_layer_compiles_for_v5e(chip):

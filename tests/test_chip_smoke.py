@@ -89,6 +89,12 @@ def test_rehearsal_checks_what_the_chip_run_checks(rehearsal):
     assert {r["decisions"].get("onepass") for r in rows} == {
         "pallas/dense", "pallas/packed", "reference/unsupported_shape",
         "reference/segments"}
+    # The served packed local track (ISSUE 42): the kernel at its
+    # budget-chosen tile against XLA's composition and the float32 track.
+    served = by["kernels"]["served"]
+    assert served["decision"] == "pallas/packed" and served["tile"] > 0
+    assert served["kernel_vs_f32"]["rel"] <= served["xla_vs_f32"]["rel"]
+    assert "kernel_ms" not in served  # a time is the chip's to give
 
 
 def test_rehearsal_shares_one_cache_and_a_second_process_hits_it(rehearsal):

@@ -110,30 +110,44 @@ def test_pallas_supported_gating():
     assert pallas_supported(512, 2048 // 4)
 
 
-def test_pallas_segments_supported_gating():
-    from proteinbert_tpu.kernels import pallas_segments_supported
-
-    assert pallas_segments_supported(128, 256, 8, "float32")
-    assert pallas_segments_supported(512, 512, 8)       # base config, bf16
-    assert not pallas_segments_supported(96, 256, 8)    # non-lane-aligned C
+# (local_dim, seq_len, max_segments[, dtype], {taps}) -> supported. One
+# case a decision, so each counts; the last three are ISSUE 42's: the
+# serving row (1024 x 512, bfloat16) has a plan at a tile of 256.
+SEGMENT_GATING = [
+    ((128, 256, 8, "float32"), {}, True),
+    ((512, 512, 8), {}, True),            # base config, bf16
+    ((96, 256, 8), {}, False),            # non-lane-aligned C
     # Channel-tiled SEGMENT variant (ISSUE 13): Large C=1024 packed
-    # rows now run the fast path instead of falling back with
+    # rows run the fast path instead of falling back with
     # reason="segments"…
-    assert pallas_segments_supported(1024, 512, 8)
+    ((1024, 512, 8), {}, True),
     # …but the fp32 tiled plan still has no room, like the dense one,
     # and nothing exceeds MAX_TILED_DIM.
-    assert not pallas_segments_supported(1024, 512, 8, "float32")
-    assert not pallas_segments_supported(4096, 512, 8)
-    assert not pallas_segments_supported(512, 512, 8, "float32")  # VMEM
-    assert not pallas_segments_supported(128, 4, 2)     # seq too short
-    assert not pallas_segments_supported(128, 256, 0)   # no segments
+    ((1024, 512, 8, "float32"), {}, False),
+    ((4096, 512, 8), {}, False),
+    ((512, 512, 8, "float32"), {}, False),  # VMEM
+    ((128, 4, 2), {}, False),             # seq too short
+    ((128, 256, 0), {}, False),           # no segments
     # Even tap counts break the symmetric-halo tap layout.
-    assert not pallas_segments_supported(128, 256, 8, "float32",
-                                         narrow_taps=8)
+    ((128, 256, 8, "float32"), {"narrow_taps": 8}, False),
     # The one-hot row block is priced in: the dense kernel fits this
     # long-row bf16 shape, the segment kernel must still fit too (the
     # oh block is lane-padded but small next to the weights).
-    assert pallas_segments_supported(256, 1024, 16)
+    ((256, 1024, 16), {}, True),
+    ((512, 1024, 1), {}, True),
+    ((512, 1024, 8), {}, True),
+    ((512, 1024, 16), {}, True),
+]
+
+
+@pytest.mark.parametrize(
+    "shape,taps,want", SEGMENT_GATING,
+    ids=["-".join(map(str, sh)) + ("-even_taps" if kw else "")
+         for sh, kw, _ in SEGMENT_GATING])
+def test_pallas_segments_supported_gating(shape, taps, want):
+    from proteinbert_tpu.kernels import pallas_segments_supported
+
+    assert pallas_segments_supported(*shape, **taps) is want
 
 
 def test_train_step_with_pallas(key):

@@ -45,6 +45,14 @@ SEGMENT_GRID = [
     ((128, 128, 0, "float32"), False),  # no segments
     ((192, 128, 4, "bfloat16"), False),
     ((128, 4, 4, "float32"), False),
+    # ISSUE 42: the L tile comes from the budget (`_segment_tile`), so the
+    # serving row has a plan (256 rows a tile) and a longer one too (128);
+    # a row that passes the budget by itself still has none.
+    ((512, 1024, 1, "bfloat16"), True),
+    ((512, 1024, 8, "bfloat16"), True),
+    ((512, 1024, 16, "bfloat16"), True),
+    ((512, 2048, 8, "bfloat16"), True),
+    ((512, 4096, 8, "bfloat16"), False),
 ]
 
 # (local_dim, global_dim, seq_len, max_segments, key_dim, num_heads,

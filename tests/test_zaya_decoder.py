@@ -20,6 +20,7 @@ import pytest
 
 from benchmark.drivers import cca_serve
 from benchmark.reference import zaya_f32 as ref
+from proteinbert_tpu import inference
 from proteinbert_tpu.configs import get_preset
 from proteinbert_tpu.kernels.segment_flash import CCA_CORE_PATH_TOTAL
 from proteinbert_tpu.models import glm_moe
@@ -109,6 +110,10 @@ def test_the_whole_model_through_submit_equals_the_reference(tiny, served):
     rng = np.random.default_rng(3)
     docs = [rng.integers(0, cfg.model.vocab_size, n)
             for n in (20, 30, 7, 41, 15, 64, 3, 33, 8, 1)]
+    # A file that ran earlier on this worker may have served these widths
+    # (tests/benchmark/test_zaya_cell.py's rehearsals): its traces would
+    # be this server's, and the counter below would not move.
+    inference._packed_decoder_embed_batch.clear_cache()
     cores_before = {f"{p}/{r}": n for (p, r), n in CCA_CORE_PATH_TOTAL.items()}
     with Server(served, cfg, serve_mode="ragged", max_batch=2,
                 pack_max_segments=4, cache_size=0) as server:

@@ -16,7 +16,9 @@ Every line of a run goes, stamped with the seconds since the process
 started, to chiprun_out/wall_<tag>_<cell>.log; one JSON row a run to
 standard output and chiprun_out/wall_<tag>_<cell>.json: wall_s, backend_up_s,
 setup_s, window_s, stop_and_reduce_s, reference_s, program_scopes (program, what it
-was, seconds), the result line's metrics.
+was, seconds), fused_path (the process's
+`fused_kernel_path_total` as the window closes: the executables traced by
+then, by path and reason), the result line's metrics.
 """
 
 import argparse
@@ -60,6 +62,9 @@ def phases(wall, lines):
         if m:
             out["reference_s"] = float(m.group(1))
             out["reference_end_at_s"] = at
+        m = re.search(r"wall: fused_kernel_path_total (\{.*\})", line)
+        if m:
+            out["fused_path"] = json.loads(m.group(1))
         m = re.search(r"program_scopes\(([^)]*)\): (.*?) in ([\d.]+) s", line)
         if m:
             out.setdefault("program_scopes", []).append(
@@ -107,6 +112,12 @@ def child(argv) -> int:
                 t1 = time.perf_counter()
         print(f"wall: window closed, window_s {self.window_s:.2f}, stop_and_reduce "
               f"{time.perf_counter() - t1:.2f} s", flush=True)
+        from proteinbert_tpu.kernels.fused_block import PATH_TOTAL
+
+        # Before a traced run lowers each row class again for its scope map.
+        print("wall: fused_kernel_path_total " + json.dumps(
+            {f"{p}/{r}": n for (p, r), n in sorted(PATH_TOTAL.items())}),
+            flush=True)
 
     devices = run._devices
 

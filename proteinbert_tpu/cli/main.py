@@ -645,7 +645,8 @@ def _load_inference_trunk(args):
 def _load_serving_model(args):
     """(params, cfg) for `pbt serve`. The model is picked by the type of
     the preset's `model`: ProteinBERT loads its trunk from --pretrained;
-    the causal decoder (`--preset ling3flash_ep4`, `zaya1_8b_pp2`) has
+    the causal decoder (`--preset ling3flash_ep4`, `zaya1_8b_pp2`,
+    `nemotron3super_ep4`) has
     no checkpoint format on the serving path yet, so its weights are made on the
     device from the run's seed (`models/glm_moe.init_served`), and it is
     served the only way it is built: ragged, `embed`, no result cache,
@@ -2338,7 +2339,8 @@ def build_parser() -> argparse.ArgumentParser:
     sv.add_argument("--preset", default="tiny",
                     choices=["tiny", "base", "long", "large",
                              "ling3flash_ep4", "ling_tiny", "zaya1_8b_pp2",
-                             "zaya_tiny"])
+                             "zaya_tiny", "nemotron3super_ep4",
+                             "nemotron_tiny"])
     sv.add_argument("--pretrained-set", action="append",
                     metavar="PATH=VALUE",
                     help="config override the pretrain run was made with")

@@ -99,6 +99,22 @@ class DecoderConfig:
     kind and the residual scaling come with the mixer and cannot be asked
     for apart from it (`router`, `residual_scaling` below).
 
+    With `hybrid_override_pattern` the same decoder is Nemotron-H / Nemotron
+    3 (`model_type: nemotron_h`), built on the serving path: the pattern is
+    the published STRING, one character a layer, and a layer is ONE
+    sublayer (one norm, one mixer or one feed-forward part, one add):
+    `M` a Mamba-2 mixer (`ops/ssd.py`: `mamba_num_heads` heads of
+    `mamba_head_dim` on a state of `ssm_state_size`, B and C shared by the
+    heads of each of `n_groups` groups, a causal convolution of
+    `conv_kernel` taps in front, chunks of `chunk_size`), `*` attention
+    (`num_attention_heads` query heads on `num_key_value_heads` key heads
+    of `cca_head_dim`, no position term), `E` LatentMoE: the routed
+    experts `moe_latent_size` wide between one product down and one up,
+    two matrices an expert and squared ReLU (`mlp_hidden_act` "relu2"),
+    beside a shared expert of its own width
+    (`moe_shared_expert_intermediate_size`) on the stream. The layers held
+    are characters `first_layer_index` .. + `num_hidden_layers` of it.
+
     Three fields describe THIS CHIP'S SHARE of an expert-parallel group
     rather than the model: `experts_held` of the `n_routed_experts` the
     router scores (ids `expert_offset` ..), and `vocab_size` rows of the
@@ -145,6 +161,20 @@ class DecoderConfig:
     short_conv_kernel_size: int = 4
     kda_lower_bound: float = -5.0       # the log decay lies in (this, 0)
     kda_chunk: int = 64                 # tokens per chunk of the KDA scan
+    hybrid_override_pattern: str = ""   # one character a published layer: M | * | E
+    mamba_num_heads: int = 128
+    mamba_head_dim: int = 64
+    ssm_state_size: int = 128
+    n_groups: int = 8                   # groups of Mamba heads sharing one B and C
+    conv_kernel: int = 4                # taps of the Mamba mixer's convolution
+    chunk_size: int = 128               # tokens per chunk of the state-space scan
+    time_step_min: float = 0.001        # dt_bias starts as the inverse softplus of
+    time_step_max: float = 0.1          # a log-uniform draw between these,
+    time_step_floor: float = 1e-4       # floored (`glm_moe.init_served`)
+    moe_latent_size: Optional[int] = None   # the routed experts' own width (None: the stream's)
+    moe_shared_expert_intermediate_size: Optional[int] = None   # None: n_shared_experts
+                                        # x moe_intermediate_size
+    mlp_hidden_act: str = "silu"        # "silu": SwiGLU experts | "relu2": two matrices
     mtp_loss_weight: float = 0.3        # lambda (assumed; not in config.json)
     bias_update_speed: float = 1e-3     # gamma (assumed)
     init_std: float = 0.02              # (assumed)
@@ -171,6 +201,27 @@ class DecoderConfig:
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def pattern_held(self) -> str:
+        """The pattern's characters of the layers held here ("" where
+        the stack has no pattern string)."""
+        return self.hybrid_override_pattern[
+            self.first_layer_index:self.first_layer_index + self.num_hidden_layers]
+
+    @property
+    def expert_kind(self) -> str:
+        # `ops/moe.expert_ffn`'s kind: by the published activation's name
+        return {"silu": "swiglu", "relu2": "relu2"}[self.mlp_hidden_act]
+
+    @property
+    def shared_expert_width(self) -> int:
+        own = self.moe_shared_expert_intermediate_size
+        return self.n_shared_experts * self.moe_intermediate_size if own is None else own
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
 
     @property
     def router(self) -> str:
@@ -697,6 +748,71 @@ def _zaya_tiny() -> PretrainConfig:
     )
 
 
+NEMOTRON_PATTERN = ("MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+                    "EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
+
+
+def _nemotron(**sizes) -> DecoderConfig:
+    # What names Nemotron 3 whatever its widths: the published pattern of
+    # single-sublayer layers, squared-ReLU experts in a latent behind a
+    # sigmoid router with no groups, no leading dense layer, no rotary,
+    # float32 stream; `init_served`'s recipe at the published depth of 88.
+    return DecoderConfig(**{**dict(
+        hybrid_override_pattern=NEMOTRON_PATTERN, first_k_dense_replace=0,
+        first_layer_index=0, mlp_hidden_act="relu2", n_shared_experts=1,
+        norm_topk_prob=True, routed_scaling_factor=5.0, n_group=1, topk_group=1,
+        rms_norm_eps=1e-5, num_nextn_predict_layers=0, q_lora_rank=None,
+        embed_init_std=1.0, out_init_std=0.02 / (2 * 88) ** 0.5), **sizes})
+
+
+def _nemotron3super_ep4() -> PretrainConfig:
+    # Nemotron-3-Super-120B-A12B (huggingface.co/nvidia/NVIDIA-Nemotron-3-
+    # Super-120B-A12B-BF16 config.json, `nemotron_h`) on the SERVING path as
+    # ONE chip of the four that share the first of seven pipeline stages
+    # holds it: every width as published; published layers 0-12
+    # (`MEMEMEM*EMEME`: 6 Mamba-2, 6 LatentMoE, 1 attention layer), 128 of
+    # the 512 routed experts a layer (ids 0-127) and 32,768 of the 131,072
+    # embedding rows. 5,382.9 M parameters, bfloat16 in HBM; the output
+    # head and the prediction module are not on this path. `expert_block`
+    # 384: a held expert's ~700 tokens of a 16,384-token batch in two blocks.
+    return PretrainConfig(
+        model=_nemotron(
+            vocab_size=32_768, hidden_size=4096, num_hidden_layers=13,
+            mamba_num_heads=128, mamba_head_dim=64, ssm_state_size=128,
+            n_groups=8, conv_kernel=4, chunk_size=128,
+            num_attention_heads=32, num_key_value_heads=2, cca_head_dim=128,
+            n_routed_experts=512, experts_held=128, num_experts_per_tok=22,
+            moe_latent_size=1024, moe_intermediate_size=2688,
+            moe_shared_expert_intermediate_size=5376,
+            param_dtype="bfloat16", expert_block=384),
+        data=DataConfig(seq_len=8192, batch_size=2, packing=True,
+                        pack_max_segments=16,
+                        buckets=_span_ladder(8192, 128)),
+    )
+
+
+def _nemotron_tiny() -> PretrainConfig:
+    # Nemotron 3 at CPU-test size: the published pattern's first 13
+    # layers, 8 Mamba heads of 8 in 2 groups on a state of 16, 4 query
+    # heads on 2 key heads, 16 experts top 4 (all held) in a latent of
+    # 32, float32 throughout; weights large enough at this width for a
+    # sublayer's result to be a third of the stream it is added to.
+    return PretrainConfig(
+        model=_nemotron(
+            vocab_size=512, hidden_size=64, num_hidden_layers=13,
+            mamba_num_heads=8, mamba_head_dim=8, ssm_state_size=16,
+            n_groups=2, conv_kernel=4, chunk_size=16,
+            num_attention_heads=4, num_key_value_heads=2, cca_head_dim=16,
+            n_routed_experts=16, experts_held=16, num_experts_per_tok=4,
+            moe_latent_size=32, moe_intermediate_size=48,
+            moe_shared_expert_intermediate_size=96,
+            init_std=0.1, out_init_std=0.05, dtype="float32",
+            attention_block=16, expert_block=8, loss_chunk=32),
+        data=DataConfig(seq_len=64, batch_size=2, packing=True,
+                        pack_max_segments=4, buckets=_span_ladder(64, 8)),
+    )
+
+
 PRESETS = {
     "tiny": _tiny,
     "base": _base,
@@ -708,6 +824,8 @@ PRESETS = {
     "ling_tiny": _ling_tiny,
     "zaya1_8b_pp2": _zaya1_8b_pp2,
     "zaya_tiny": _zaya_tiny,
+    "nemotron3super_ep4": _nemotron3super_ep4,
+    "nemotron_tiny": _nemotron_tiny,
 }
 
 

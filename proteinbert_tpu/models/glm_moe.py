@@ -36,13 +36,9 @@ the layer with the published index i has the latent mixer where
 delta-rule linear attention of `ops/kda.py` behind a short causal
 convolution) elsewhere; both end in a head-wise sigmoid gate; the latent
 mixer has no low-rank query path and turns its rotary pairs interleaved;
-the router is group-limited. The period is carried by the stack: the
-expert layers held are whole periods (`hybrid_schedule`), a scan over
-periods whose body is a scan of `pre` KDA layers, one latent layer and a
-scan of `post` KDA layers, `pre` set by the first held layer's index.
-`served_embed` is what the server runs: the final-norm hidden state at
-each document's last token and its mean over the document, with the
-step's routing counters.
+the router is group-limited. `served_embed` is what the server runs: the
+final-norm hidden state at each document's last token and its mean over
+the document, with the step's routing counters.
 
 And a third stack (ZAYA1, `zaya`; `cfg.mixer` "cca"), on the serving
 path too: every layer is compressed convolutional attention (`cca_mixer`:
@@ -53,6 +49,21 @@ top 1 of a softmax by an MLP router (`ops/moe.route_mlp`) whose narrow
 state the scan over layers carries beside the stream, `(x, r)`; both
 sublayers write `(a x + c) + (a' f(N(x)) + c')` with learned vectors
 (`cfg.residual_scaling`). Its period is one layer: one stack, one scan.
+
+And a fourth (Nemotron-H / Nemotron 3, `nemotron_h`;
+`cfg.hybrid_override_pattern`), served too: a layer is ONE sublayer,
+`x + Mixer(RMSNorm(x))`, of the kind the published pattern STRING gives
+its index: `M` a Mamba-2 mixer (`mamba_mixer`: the selective state-space
+recurrence of `ops/ssd.py` behind a causal convolution, a gated group
+norm after it), `*` attention over grouped keys with no position term
+(`gqa_mixer`), `E` LatentMoE (routed squared-ReLU experts of two
+matrices in a latent narrower than the stream, `ops/moe.py`, beside a
+shared expert on the stream).
+
+ONE table carries the three served stacks (`layer_table`: published
+index and kind of every layer held): the layers of a kind lie stacked
+under the kind's name, and `served_trunk` walks the table, a repeating
+unit of kinds as a `lax.scan` over its repeats (`_compress`).
 
 The router's balance bias is in the parameter tree
 (`params["balance_bias"]`) so that it is sharded, saved and restored
@@ -79,9 +90,10 @@ from proteinbert_tpu.ops.attention import (
 from proteinbert_tpu.ops.cca import cca_mix
 from proteinbert_tpu.ops.kda import kda_chunked, segment_conv
 from proteinbert_tpu.ops.layers import (
-    rms_norm_apply, rotary_apply, segment_positions, swiglu_apply,
+    ffn_apply, rms_norm_apply, rotary_apply, segment_positions, swiglu_apply,
 )
 from proteinbert_tpu.ops.moe import moe_apply, router_probs
+from proteinbert_tpu.ops.ssd import gated_group_norm, ssd_chunked
 
 Params = Dict[str, Any]
 
@@ -96,6 +108,12 @@ def param_shapes(cfg: DecoderConfig) -> Dict[str, Any]:
             "the hybrid stack (layer_group_size > 0) is built on the serving "
             "path only (`init_served`, `served_embed`): its output head, its "
             "prediction module and the KDA kernel's backward pass are not")
+    if cfg.hybrid_override_pattern:
+        raise NotImplementedError(
+            "the pattern stack (hybrid_override_pattern) is built on the "
+            "serving path only (`init_served`, `served_embed`): its output "
+            "head and its prediction module are not built, and the flash "
+            "kernel's backward pass takes no grouped keys")
     if cfg.mixer == "cca":
         raise NotImplementedError(
             "the CCA mixer (mixer='cca') is built on the serving path only "
@@ -295,21 +313,76 @@ def cca_mixer(p: Params, x, segment_ids, positions, cfg: DecoderConfig):
             q, k, v = cca_mix(
                 p, *projected, segment_ids, positions, H, G,
                 int(d * cfg.partial_rotary_factor), cfg.rope_theta, dt)
-        # As `latent_attention`: the flash kernel where the program is
-        # lowered for a TPU, plain jax (keys repeated) elsewhere; sizes
-        # the tiles do not take are an error on a TPU.
-        sizes = dict(scale=float(d) ** -0.5, block=cfg.attention_block)
-        plain = partial(causal_segment_attention, **sizes)
-        fits = flash_tiles_fit(L, cfg.attention_block, d, d)
-        _note_cca_core(fits, (B, L, H, G, d))
+        _note_cca_core(flash_tiles_fit(L, cfg.attention_block, d, d),
+                       (B, L, H, G, d))
         with jax.named_scope("cca_core"):
-            if fits or jax.default_backend() == "tpu":
-                out = lax.platform_dependent(
-                    q, k, v, segment_ids,
-                    tpu=partial(flash_segment_attention, **sizes), default=plain)
-            else:
-                out = plain(q, k, v, segment_ids)
+            out = _grouped_key_core(q, k, v, segment_ids, cfg)
         return out.reshape(B, L, H * d) @ p["o"].astype(dt)
+
+
+def _grouped_key_core(q, k, v, segment_ids, cfg: DecoderConfig):
+    """softmax(q k^T / sqrt d) v, causal inside a document, over grouped
+    keys (q: (B, L, H, d); k, v: (B, L, G, d)). As `latent_attention`:
+    the flash kernel where the program is lowered for a TPU, plain jax
+    (keys repeated) elsewhere; sizes the tiles do not take are an error
+    on a TPU."""
+    L, d = q.shape[1], q.shape[-1]
+    sizes = dict(scale=float(d) ** -0.5, block=cfg.attention_block)
+    plain = partial(causal_segment_attention, **sizes)
+    if (flash_tiles_fit(L, cfg.attention_block, d, d)
+            or jax.default_backend() == "tpu"):
+        return lax.platform_dependent(
+            q, k, v, segment_ids,
+            tpu=partial(flash_segment_attention, **sizes), default=plain)
+    return plain(q, k, v, segment_ids)
+
+
+def gqa_mixer(p: Params, x, segment_ids, cfg: DecoderConfig):
+    """Nemotron's attention layer: `num_attention_heads` query heads on
+    `num_key_value_heads` key and value heads of `cca_head_dim`, no bias,
+    NO rotary and no other position term; the core as CCA's."""
+    with jax.named_scope("gqa"):
+        B, L, _ = x.shape
+        H, G, d, dt = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                       cfg.cca_head_dim, x.dtype)
+        q = (x @ p["q"].astype(dt)).reshape(B, L, H, d)
+        k = (x @ p["k"].astype(dt)).reshape(B, L, G, d)
+        v = (x @ p["v"].astype(dt)).reshape(B, L, G, d)
+        with jax.named_scope("gqa_core"):
+            out = _grouped_key_core(q, k, v, segment_ids, cfg)
+        return out.reshape(B, L, H * d) @ p["o"].astype(dt)
+
+
+def mamba_mixer(p: Params, x, segment_ids, cfg: DecoderConfig):
+    """The Mamba-2 mixer (`ops/ssd.py` has the recurrence): one product
+    in, [z | xBC | dt]; a causal depthwise convolution with bias and SiLU
+    over xBC that reads zero across a document's boundary; the selective
+    state-space recurrence over x with B, C (a group's heads share them)
+    and dt = softplus(dt + dt_bias), a = -exp(A_log); the skip D x; the
+    gate THEN the norm over each group's channels apart; one product out.
+    Products in the activation dtype accumulated in float32; convolution,
+    dt, decays, the state, gate and norm in float32."""
+    with jax.named_scope("mamba"):
+        B, L, _ = x.shape
+        H, P, G, N = (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups,
+                      cfg.ssm_state_size)
+        inner, dt, f32 = H * P, x.dtype, jnp.float32
+        z, xbc, step = jnp.split(x @ p["in_proj"].astype(dt),
+                                 [inner, 2 * inner + 2 * G * N], axis=-1)
+        xbc = jax.nn.silu(
+            segment_conv(xbc.astype(f32), p["conv"].astype(f32), segment_ids)
+            + p["conv_bias"].astype(f32)).astype(dt)
+        u, b, c = jnp.split(xbc, [inner, inner + G * N], axis=-1)
+        u = u.reshape(B, L, H, P)
+        step = jax.nn.softplus(step.astype(f32) + p["ssm_dt_bias"].astype(f32))
+        with jax.named_scope("ssd_core"):
+            y = ssd_chunked(u, step, -jnp.exp(p["ssm_A_log"].astype(f32)),
+                            b.reshape(B, L, G, N), c.reshape(B, L, G, N),
+                            segment_ids, cfg.chunk_size, dt)
+        y = y + p["ssm_D"].astype(f32)[:, None] * u.astype(f32)
+        y = gated_group_norm(p["norm"], y.reshape(B, L, inner), z, G,
+                             cfg.rms_norm_eps).astype(dt)
+        return y @ p["o"].astype(dt)
 
 
 def dense_layer(p: Params, x, segment_ids, positions, cfg: DecoderConfig):
@@ -355,16 +428,29 @@ def trunk(params: Params, tokens, segment_ids, positions, cfg: DecoderConfig):
                     (params["layers"], params["balance_bias"]["layers"]))
 
 
-# ---------------------------------------------- hybrid stack, serving path
+# ------------------------------------------- the served stacks, by one table
 
 TOP_INDEX = 2 ** 20     # the "layer index" of the embedding and the final norm
+
+# kind of a layer -> (its mixer or None, its feed-forward part or None).
+# A kind with both is a two-sublayer layer (`_hybrid_layer`, `_cca_layer`);
+# a kind with one is Nemotron's single-sublayer layer (`_single_layer`).
+# The kind is also the name of its stack in the served tree.
+LAYER_KINDS = {
+    "kda_dense": ("kda", "dense"), "kda_moe": ("kda", "moe"),
+    "mla_moe": ("mla", "moe"), "cca": ("cca", "routed"),
+    "mamba": ("mamba", None), "gqa": ("gqa", None), "latent_moe": (None, "moe"),
+}
+PATTERN_KINDS = {"M": "mamba", "*": "gqa", "E": "latent_moe"}
 
 
 def hybrid_schedule(cfg: DecoderConfig):
     """(periods, pre, post): the expert layers held are `periods` whole
     periods of `layer_group_size`, each `pre` KDA layers, the latent
     layer, `post` KDA layers; `pre` follows from the published index of
-    the first of them. Depths the stack does not carry are refused."""
+    the first of them. The table carries any depth; a cut that is not
+    whole periods is refused all the same, so that every kind of layer
+    is held in its published ratio."""
     G, n_dense = cfg.layer_group_size, cfg.first_k_dense_replace
     first = cfg.first_layer_index + n_dense
     if any((cfg.first_layer_index + j + 1) % G == 0 for j in range(n_dense)):
@@ -379,9 +465,57 @@ def hybrid_schedule(cfg: DecoderConfig):
     return periods, pre, G - 1 - pre
 
 
-def hybrid_layer_shapes(cfg: DecoderConfig, mixer: str, ffn: str) -> Dict[str, Any]:
+def layer_table(cfg: DecoderConfig) -> list:
+    """[(published index, kind)] of the layers held, in order: what the
+    served tree's stacks, the weights' recipe and the trunk all follow."""
+    held = range(cfg.first_layer_index, cfg.first_layer_index + cfg.num_hidden_layers)
+    if cfg.hybrid_override_pattern:
+        if (cfg.mixer == "cca" or cfg.hybrid or cfg.first_k_dense_replace
+                or len(cfg.pattern_held) != cfg.num_hidden_layers
+                or set(cfg.pattern_held) - set(PATTERN_KINDS)):
+            raise ValueError(
+                f"the pattern's layers held ({cfg.pattern_held!r}, "
+                f"{cfg.num_hidden_layers} from the published index "
+                f"{cfg.first_layer_index}) have to be characters of "
+                f"{sorted(PATTERN_KINDS)}, with no other stack asked for beside them")
+        return [(i, PATTERN_KINDS[ch]) for i, ch in zip(held, cfg.pattern_held)]
+    if cfg.mixer == "cca":
+        if cfg.first_k_dense_replace or cfg.n_shared_experts or cfg.hybrid:
+            raise ValueError(
+                "the CCA stack carries ZAYA1's layer alone: routed experts "
+                "chosen by the MLP router, residual scaling, no leading "
+                "dense layer, no shared expert, no layer period")
+        return [(i, "cca") for i in held]
+    if not cfg.hybrid:
+        raise ValueError("the plain latent-attention stack is built on the "
+                         "training path (`init`, `loss_and_stats`), not served")
+    hybrid_schedule(cfg)
+    G = cfg.layer_group_size
+    return [(i, ("mla" if (i + 1) % G == 0 else "kda")
+             + ("_dense" if j < cfg.first_k_dense_replace else "_moe"))
+            for j, i in enumerate(held)]
+
+
+def _kind_indices(cfg: DecoderConfig) -> Dict[str, list]:
+    """kind -> the published indices of its layers, in the stack's order."""
+    stacks: Dict[str, list] = {}
+    for index, kind in layer_table(cfg):
+        stacks.setdefault(kind, []).append(index)
+    return stacks
+
+
+def _ffn_shapes(cfg: DecoderConfig, width: int) -> Dict[str, Any]:
+    D = cfg.hidden_size
+    tree = {"up": (D, width), "down": (width, D)}
+    if cfg.expert_kind == "swiglu":
+        tree["gate"] = (D, width)
+    return tree
+
+
+def layer_shapes(cfg: DecoderConfig, kind: str) -> Dict[str, Any]:
     """One layer's tree as shapes; the names and their sorted order are
     part of the weights' recipe (`init_served`)."""
+    mixer, ffn = LAYER_KINDS[kind]
     D, H = cfg.hidden_size, cfg.num_attention_heads
     if mixer == "cca":
         G, d = cfg.num_key_value_heads, cfg.cca_head_dim
@@ -397,23 +531,36 @@ def hybrid_layer_shapes(cfg: DecoderConfig, mixer: str, ffn: str) -> Dict[str, A
                "beta": (D, H), "g": (D, H), "conv_q": (K, W), "conv_k": (K, W),
                "conv_v": (K, W), "A_log": (H,), "dt_bias": (W,),
                "o_norm": (cfg.kda_head_dim,)}
-    else:
+    elif mixer == "mla":
         mix = {"q": (D, H * cfg.qk_head_dim),
                "kv_a": (D, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
                "kv_norm": (cfg.kv_lora_rank,),
                "kv_b": (cfg.kv_lora_rank, H * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
                "o": (H * cfg.v_head_dim, D), "g": (D, H)}
-    swiglu = lambda width: {"gate": (D, width), "up": (D, width),  # noqa: E731
-                            "down": (width, D)}
-    tree = {"mixer": mix, "norm1": (D,), "norm2": (D,)}
+    elif mixer == "mamba":
+        Hm, inner = cfg.mamba_num_heads, cfg.mamba_inner
+        C = inner + 2 * cfg.n_groups * cfg.ssm_state_size
+        mix = {"in_proj": (D, inner + C + Hm), "conv": (cfg.conv_kernel, C),
+               "conv_bias": (C,), "ssm_A_log": (Hm,), "ssm_dt_bias": (Hm,),
+               "ssm_D": (Hm,), "norm": (inner,), "o": (inner, D)}
+    elif mixer == "gqa":
+        G, d = cfg.num_key_value_heads, cfg.cca_head_dim
+        mix = {"q": (D, H * d), "k": (D, G * d), "v": (D, G * d), "o": (H * d, D)}
+    if ffn is None or mixer is None:
+        tree = {"norm": (D,)}
+    else:
+        tree = {"norm1": (D,), "norm2": (D,)}
+    if mixer is not None:
+        tree["mixer"] = mix
     if cfg.residual_scaling:
         vectors = {"res_scale": (D,), "res_bias": (D,), "out_scale": (D,),
                    "out_bias": (D,)}
         tree.update(res1=vectors, res2=dict(vectors))
+    E, F = cfg.experts_held, cfg.moe_intermediate_size
     if ffn == "dense":
-        tree["mlp"] = swiglu(cfg.intermediate_size)
+        tree["mlp"] = _ffn_shapes(cfg, cfg.intermediate_size)
     elif ffn == "routed":
-        E, F, R = cfg.experts_held, cfg.moe_intermediate_size, cfg.router_hidden_size
+        R = cfg.router_hidden_size
         tree["moe"] = {"router": {"proj": (D, R), "proj_bias": (R,), "carry": (R,),
                                   "norm": (R,), "w1": (R, R), "b1": (R,),
                                   "w2": (R, R), "b2": (R,),
@@ -421,41 +568,17 @@ def hybrid_layer_shapes(cfg: DecoderConfig, mixer: str, ffn: str) -> Dict[str, A
                        "router_bias": (cfg.n_routed_experts,),
                        "experts": {"gate": (E, D, F), "up": (E, D, F),
                                    "down": (E, F, D)}}
-    else:
-        E, F = cfg.experts_held, cfg.moe_intermediate_size
+    elif ffn == "moe":
+        U = cfg.moe_latent_size or D
+        experts = {"up": (E, U, F), "down": (E, F, U)}
+        if cfg.expert_kind == "swiglu":
+            experts["gate"] = (E, U, F)
         tree["moe"] = {"router": (D, cfg.n_routed_experts),
-                       "router_bias": (cfg.n_routed_experts,),
-                       "experts": {"gate": (E, D, F), "up": (E, D, F),
-                                   "down": (E, F, D)}}
-        tree["shared"] = swiglu(cfg.n_shared_experts * F)
+                       "router_bias": (cfg.n_routed_experts,), "experts": experts}
+        if cfg.moe_latent_size is not None:
+            tree["moe"].update(to_latent=(D, U), from_latent=(U, D))
+        tree["shared"] = _ffn_shapes(cfg, cfg.shared_expert_width)
     return tree
-
-
-def _hybrid_stacks(cfg: DecoderConfig):
-    """[(name in the tree, leading shape, mixer, ffn, published index of
-    each layer in the order of the leading shape)]."""
-    if cfg.mixer == "cca":
-        if cfg.first_k_dense_replace or cfg.n_shared_experts or cfg.hybrid:
-            raise ValueError(
-                "the CCA stack carries ZAYA1's layer alone: routed experts "
-                "chosen by the MLP router, residual scaling, no leading "
-                "dense layer, no shared expert, no layer period")
-        return [("cca", (cfg.num_hidden_layers,), "cca", "routed",
-                 [cfg.first_layer_index + j for j in range(cfg.num_hidden_layers)])]
-    periods, pre, post = hybrid_schedule(cfg)
-    G, n_dense = cfg.layer_group_size, cfg.first_k_dense_replace
-    first = cfg.first_layer_index + n_dense
-    at = lambda p, j: first + p * G + j  # noqa: E731
-    stacks = [("dense", (n_dense,), "kda", "dense",
-               [cfg.first_layer_index + j for j in range(n_dense)]),
-              ("mla", (periods,), "mla", "moe", [at(p, pre) for p in range(periods)])]
-    if pre:
-        stacks.append(("pre", (periods, pre), "kda", "moe",
-                       [at(p, j) for p in range(periods) for j in range(pre)]))
-    if post:
-        stacks.append(("post", (periods, post), "kda", "moe",
-                       [at(p, pre + 1 + j) for p in range(periods) for j in range(post)]))
-    return stacks
 
 
 def served_param_count(cfg: DecoderConfig) -> int:
@@ -467,8 +590,8 @@ def served_param_count(cfg: DecoderConfig) -> int:
                    if path[-1].key != "router_bias")
 
     return (cfg.vocab_size * cfg.hidden_size + cfg.hidden_size
-            + sum(len(indices) * count(hybrid_layer_shapes(cfg, mixer, ffn))
-                  for _, _, mixer, ffn, indices in _hybrid_stacks(cfg)))
+            + sum(len(indices) * count(layer_shapes(cfg, kind))
+                  for kind, indices in _kind_indices(cfg).items()))
 
 
 def served_abstract(cfg: DecoderConfig) -> Params:
@@ -479,16 +602,26 @@ def served_abstract(cfg: DecoderConfig) -> Params:
     leaf = lambda s: jax.ShapeDtypeStruct(s, dt)  # noqa: E731
     tree = {"embed": leaf((cfg.vocab_size, cfg.hidden_size)),
             "final_norm": leaf((cfg.hidden_size,))}
-    for name, lead, mixer, ffn, _ in _hybrid_stacks(cfg):
-        tree[name] = jax.tree.map(lambda s: leaf(lead + s),
-                                  hybrid_layer_shapes(cfg, mixer, ffn),
-                                  is_leaf=is_shape)
+    for kind, indices in _kind_indices(cfg).items():
+        tree[kind] = jax.tree.map(lambda s: leaf((len(indices),) + s),
+                                  layer_shapes(cfg, kind), is_leaf=is_shape)
     return tree
 
 
-@partial(jax.jit, static_argnames=("name", "shape", "heads", "std", "dtype"))
-def _draw(key, index, j, name, shape, heads, std, dtype):
-    if "norm" in name:
+@partial(jax.jit, static_argnames=("name", "shape", "heads", "std", "dtype",
+                                   "dt_limits", "centred"))
+def _draw(key, index, j, name, shape, heads, std, dtype, dt_limits=None,
+          centred=False):
+    own = jax.random.fold_in(jax.random.fold_in(key, index), j)
+    if name == "ssm_A_log":
+        leaf = jnp.log(jax.random.uniform(own, shape, jnp.float32, 1.0, 16.0))
+    elif name == "ssm_dt_bias":
+        low, high, floor = dt_limits
+        dt = jnp.maximum(floor, jnp.exp(
+            jax.random.uniform(own, shape, jnp.float32)
+            * (np.log(high) - np.log(low)) + np.log(low)))
+        leaf = dt + jnp.log(-jnp.expm1(-dt))       # softplus(leaf) = dt
+    elif "norm" in name or name == "ssm_D":
         leaf = jnp.ones(shape, jnp.float32)
     elif name == "A_log":
         leaf = jnp.log(1.0 + 3.0 * jnp.arange(heads, dtype=jnp.float32)
@@ -500,30 +633,33 @@ def _draw(key, index, j, name, shape, heads, std, dtype):
     elif name.endswith("_bias") or name in ("b1", "b2", "tau"):
         leaf = jnp.zeros(shape, jnp.float32)
     else:
-        leaf = std * jax.random.normal(
-            jax.random.fold_in(jax.random.fold_in(key, index), j), shape,
-            jnp.float32)
+        leaf = std * jax.random.normal(own, shape, jnp.float32)
+        if centred:     # every column sums to zero over its input rows
+            leaf = leaf - leaf.mean(axis=-2, keepdims=True)
     return lax.reduce_precision(leaf, exponent_bits=8, mantissa_bits=7).astype(dtype)
 
 
 @partial(jax.jit, donate_argnums=0)
 def _put(stack, leaf, at):
     return lax.dynamic_update_slice(
-        stack, leaf[(None,) * at.shape[0]],
-        tuple(at) + (jnp.zeros((), at.dtype),) * leaf.ndim)
+        stack, leaf[None], (at,) + (jnp.zeros((), at.dtype),) * leaf.ndim)
 
 
-def _leaf_std(name: str, cfg: DecoderConfig) -> float:
+def _leaf_std(path: tuple, cfg: DecoderConfig) -> float:
     """The embedding's rows and the products that write into the
-    residual stream (`o`, `down`) have a deviation of their own."""
+    residual stream (`o`, `down`, the latent's way up) have a deviation
+    of their own; `path` is the leaf's names from its tree's root."""
+    name = path[-1]
     own = {"embed": cfg.embed_init_std, "o": cfg.out_init_std,
-           "down": cfg.out_init_std}.get(name)
-    if name in ("conv0", "conv1", "w1", "w2", "w3"):
-        # CCA's convolutions and the MLP router's layers by their fan-in:
-        # at `init_std` the convolved part of q and k would be a
-        # hundredth of the mean part and the router's logits all but
+           "down": cfg.out_init_std, "from_latent": cfg.out_init_std}.get(name)
+    if name == "down" and "experts" in path and cfg.moe_latent_size is not None:
+        own = None      # a latent expert writes the latent, not the stream
+    if name in ("conv0", "conv1", "w1", "w2", "w3", "conv"):
+        # CCA's and Mamba's convolutions and the MLP router's layers by
+        # their fan-in: at `init_std` the convolved part of q and k would
+        # be a hundredth of the mean part and the router's logits all but
         # equal, and neither mechanism would move an answer.
-        own = {"conv0": cfg.cca_time0,
+        own = {"conv0": cfg.cca_time0, "conv": cfg.conv_kernel,
                "conv1": cfg.cca_time1 * cfg.cca_head_dim}.get(
                    name, cfg.router_hidden_size) ** -0.5
     return cfg.init_std if own is None else own
@@ -552,9 +688,12 @@ def _balanced_bias(key, router: Params, eps: float, dtype):
 def _tree_of(key, index: int, shapes, cfg: DecoderConfig):
     flat, treedef = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=lambda s: isinstance(s, tuple))
+    names = lambda path: tuple(str(k.key) for k in path)  # noqa: E731
     tree = jax.tree.unflatten(treedef, [
-        _draw(key, index, j, str(path[-1].key), shape, cfg.num_attention_heads,
-              _leaf_std(str(path[-1].key), cfg), cfg.param_dtype)
+        _draw(key, index, j, names(path)[-1], shape, cfg.num_attention_heads,
+              _leaf_std(names(path), cfg), cfg.param_dtype,
+              (cfg.time_step_min, cfg.time_step_max, cfg.time_step_floor),
+              centred=cfg.expert_kind == "relu2" and names(path)[-1] == "down")
         for j, (path, shape) in enumerate(flat)])
     if cfg.router == "mlp" and "moe" in tree:
         tree["moe"]["router_bias"] = _balanced_bias(
@@ -564,7 +703,7 @@ def _tree_of(key, index: int, shapes, cfg: DecoderConfig):
 
 
 def init_served(key: jax.Array, cfg: DecoderConfig) -> Params:
-    """The served hybrid tree, made on the device LEAF BY LEAF in
+    """The served tree, made on the device LEAF BY LEAF in
     `cfg.param_dtype`: the layer with the published index i draws leaf
     number j of its own tree (keys sorted) as init_std * normal(
     fold_in(fold_in(key, i), j)) in float32, rounded to bfloat16 as it
@@ -574,40 +713,53 @@ def init_served(key: jax.Array, cfg: DecoderConfig) -> Params:
     dt_bias -4, every other leaf (the conv taps too) init_std * normal,
     but the embedding's rows (`embed_init_std`) and the products that
     write into the residual stream (`out_init_std`: a mixer's `o`, an
-    FFN's `down`); in the CCA stack besides: the router's `carry` and
-    the residual scales 1, every bias and `tau` 0, the convolutions and
-    the MLP router's three layers normal at fan_in^-1/2 (`_leaf_std`),
-    the balance bias from the layer's own router over seeded probes
-    (`_balanced_bias`): a recipe a reference can follow without this
-    module.
+    FFN's `down`, the latent's `from_latent`; a LATENT expert's `down`
+    writes the latent and stays at `init_std`); in the CCA stack besides:
+    the router's `carry` and the residual scales 1, every bias and `tau`
+    0, the convolutions and the MLP router's three layers normal at
+    fan_in^-1/2 (`_leaf_std`), the balance bias from the layer's own
+    router over seeded probes (`_balanced_bias`); in a Mamba layer (the
+    Mamba-2 reference initialisation): `ssm_A_log` = log(uniform(1, 16)),
+    `ssm_dt_bias` the inverse softplus of a log-uniform draw in
+    [`time_step_min`, `time_step_max`] floored at `time_step_floor`,
+    `ssm_D` 1, the convolution normal at fan_in^-1/2 with a zero bias;
+    the second matrix of a squared-ReLU feed-forward part (`down`, the
+    shared expert's and every routed expert's) CENTRED, each column's mean
+    over its input rows subtracted before the rounding (relu^2 has a mean
+    of half its input's variance in EVERY hidden channel; through an
+    uncentred W2 that mean is one vector no token moves, 41 % of the
+    shared expert's output at the published widths and 22 % of the stream
+    after six layers, the router's scores carry its part, and the chip
+    read the fullest held expert at 2.59 x the mean load: PERF.md section
+    6, PR 43): a recipe a reference can follow without this module.
     (With every leaf at 0.02 a layer's result is as large as the stream
     it is added to, and the seeded network passed a rounding on with a
     gain of ~20 over seven layers: no comparison could tell bfloat16
     products from int8. Rows of unit size and results a tenth of the
     stream, as a depth-scaled init gives a model of the published 42
     layers, keep the gain near 1. Taps of the size
-    of K^-1/2 were tried first: SiLU of a unit-variance input has a mean
-    of a quarter of its rms, linear attention sums that mean coherently
-    over a document, every token's hidden state collapses onto one
-    vector and every token picks the same experts. With taps of 0.02 the
-    SiLU is all but linear at its input's size and the mean is gone; q,
-    k and v are rescaled after it by their norms.) Tree: `embed`,
-    `final_norm`, and the stacks `dense` (n,), `mla` (periods,), `pre`
-    (periods, pre), `post` (periods, post) of layer trees; the CCA
-    stack's one stack is `cca` (layers,)."""
+    of K^-1/2 were tried first for KDA: SiLU of a unit-variance input has
+    a mean of a quarter of its rms, linear attention sums that mean
+    coherently over a document, every token's hidden state collapses onto
+    one vector and every token picks the same experts. With taps of 0.02
+    the SiLU is all but linear at its input's size and the mean is gone;
+    q, k and v are rescaled after it by their norms.) Tree: `embed`,
+    `final_norm`, and one stack a KIND of layer (`layer_table`), named by
+    the kind, (layers of that kind,) + the layer's tree, in the order of
+    their published indices."""
     top = {"embed": (cfg.vocab_size, cfg.hidden_size),
            "final_norm": (cfg.hidden_size,)}
     params = _tree_of(key, TOP_INDEX, top, cfg)
     dt = jnp.dtype(cfg.param_dtype)
-    for name, lead, mixer, ffn, indices in _hybrid_stacks(cfg):
-        shapes = hybrid_layer_shapes(cfg, mixer, ffn)
-        stack = jax.tree.map(lambda s: jnp.zeros(lead + s, dt), shapes,
+    for kind, indices in _kind_indices(cfg).items():
+        shapes = layer_shapes(cfg, kind)
+        stack = jax.tree.map(lambda s: jnp.zeros((len(indices),) + s, dt), shapes,
                              is_leaf=lambda s: isinstance(s, tuple))
         for n, index in enumerate(indices):
-            at = jnp.asarray(np.unravel_index(n, lead), jnp.int32)
+            at = jnp.asarray(n, jnp.int32)
             stack = jax.tree.map(lambda big, leaf: _put(big, leaf, at), stack,
                                  _tree_of(key, index, shapes, cfg))
-        params[name] = stack
+        params[kind] = stack
     return params
 
 
@@ -619,6 +771,16 @@ def _layer_at(stack: Params, at):
     return jax.tree.map(lambda a: a[at], {
         k: ({m: w for m, w in v.items() if m != "experts"} if k == "moe" else v)
         for k, v in stack.items()}), experts
+
+
+def _counters(stats, cfg: DecoderConfig):
+    """(held_counts (expert layers: 0 or 1, experts_held), dropped (),
+    block_rows ()) of one layer: what every kind hands the trunk."""
+    if stats is None:
+        zero = jnp.zeros((), jnp.int32)
+        return jnp.zeros((0, cfg.experts_held), jnp.int32), zero, zero
+    return (stats["held_counts"][None], stats["dropped"].astype(jnp.int32),
+            stats["block_rows"].astype(jnp.int32))
 
 
 def _hybrid_layer(stack: Params, at, x, segment_ids, positions, real,
@@ -641,51 +803,31 @@ def _hybrid_layer(stack: Params, at, x, segment_ids, positions, real,
         h.reshape(B * L, D), real.reshape(B * L), cfg, at=at)
     with jax.named_scope("shared_expert"):
         shared = swiglu_apply(p["shared"], h)
-    return x + routed.reshape(B, L, D) + shared, (
-        stats["held_counts"], stats["dropped"], stats["block_rows"])
+    return x + routed.reshape(B, L, D) + shared, stats
 
 
-def hybrid_trunk(params: Params, tokens, segment_ids, real, cfg: DecoderConfig):
-    """-> (h (B, L, D) before the final norm, held_counts (expert layers,
-    experts_held), dropped (), block_rows ()). `real` (B, L) marks the
-    positions that hold a token: a span's tail past its document is
-    routed nowhere."""
-    periods, pre, post = hybrid_schedule(cfg)
-    positions = segment_positions(segment_ids)
-    # The residual stream is float32 (a layer's result, in the activation
-    # dtype, is added to it): rounding it to bfloat16 at every add would
-    # cost as much accuracy as int8 products do.
-    with jax.named_scope("embed"):
-        x = jnp.take(params["embed"], jnp.maximum(tokens, 0),
-                     axis=0).astype(jnp.float32)
-
-    def run(x, name, mixer, count, lead=()):
-        def body(x, l):
-            return _hybrid_layer(params[name], lead + (l,), x, segment_ids,
-                                 positions, real, cfg, mixer)
-        return lax.scan(body, x, jnp.arange(count))
-
-    x, _ = run(x, "dense", "kda", cfg.first_k_dense_replace)
-
-    def period(x, p):
-        counts, sums = [], []
-        for name, mixer, count in (("pre", "kda", pre), ("mla", "mla", 1),
-                                   ("post", "kda", post)):
-            if not count:
-                continue
-            if name == "mla":
-                x, (c, d, b) = _hybrid_layer(params["mla"], (p,), x, segment_ids,
-                                             positions, real, cfg, "mla")
-                c = c[None]
-            else:
-                x, (c, d, b) = run(x, name, mixer, count, lead=(p,))
-            counts.append(c)
-            sums.append(jnp.stack([d.sum(), b.sum()]))
-        return x, (jnp.concatenate(counts), sum(sums))
-
-    x, (counts, sums) = lax.scan(period, x, jnp.arange(periods))
-    dropped, block_rows = sums.sum(0)
-    return x, counts.reshape(-1, cfg.experts_held), dropped, block_rows
+def _single_layer(stack: Params, at, x, segment_ids, real, cfg: DecoderConfig,
+                  kind: str):
+    """Layer `at` of a stack of single-sublayer layers (Nemotron's): one
+    norm, one mixer OR one feed-forward part, one add. The router reads
+    the normed stream in float32 (with 22 of 512 chosen the 22nd and 23rd
+    scores lie close: it is not rounded to the activation dtype first)."""
+    p, experts = _layer_at(stack, at)
+    dt = jnp.dtype(cfg.dtype)
+    h = rms_norm_apply(p["norm"], x, cfg.rms_norm_eps)
+    if kind == "mamba":
+        return x + mamba_mixer(p["mixer"], h.astype(dt), segment_ids, cfg), None
+    if kind == "gqa":
+        return x + gqa_mixer(p["mixer"], h.astype(dt), segment_ids, cfg), None
+    B, L, D = h.shape
+    rounded = h.astype(dt)
+    routed, stats = moe_apply(
+        dict(p["moe"], experts=experts), p["moe"]["router_bias"].astype(jnp.float32),
+        rounded.reshape(B * L, D), real.reshape(B * L), cfg, at=at,
+        router_x=h.reshape(B * L, D))
+    with jax.named_scope("shared_expert"):
+        shared = ffn_apply(p["shared"], rounded, cfg.expert_kind)
+    return x + routed.reshape(B, L, D) + shared, stats
 
 
 def _scaled_residual(p: Params, x, y):
@@ -699,7 +841,7 @@ def _cca_layer(stack: Params, at, x, r, segment_ids, positions, real,
                cfg: DecoderConfig):
     """Layer `at` of the CCA stack over the stream x (B, L, D) float32
     and the router's carried state r (B L, R) float32 -> (x, r, the
-    layer's counters). The router reads the normed stream in float32
+    layer's stats). The router reads the normed stream in float32
     (a top-1 choice moves a token's whole expert: it is not rounded to
     the activation dtype first)."""
     p, experts = _layer_at(stack, at)
@@ -719,29 +861,98 @@ def _cca_layer(stack: Params, at, x, r, segment_ids, positions, real,
         router_x=h.reshape(B * L, D), router_state=r)
     with jax.named_scope("residual"):
         x = _scaled_residual(p["res2"], x, routed.reshape(B, L, D))
-    return x, stats["router_state"], (
-        stats["held_counts"], stats["dropped"], stats["block_rows"])
+    return x, stats["router_state"], stats
 
 
-def cca_trunk(params: Params, tokens, segment_ids, real, cfg: DecoderConfig):
-    """`hybrid_trunk` of the CCA stack: one scan over the layers that
-    carries TWO streams, x and the router's state (zeros before the
-    first layer held: the stack starts at the published layer 0, or at a
-    pipeline stage's first layer with the state it would be handed)."""
+def _compress(kinds: tuple) -> list:
+    """[(unit, repeats)] covering `kinds` in order: at each place the
+    unit (a run of kinds) whose immediate repeats cover the most layers,
+    the shortest such unit first; a unit of several kinds stands only
+    where it repeats. `MEMEMEM*EMEME` -> (ME) x 3, M, *, (EM) x 2, E."""
+    out, i, n = [], 0, len(kinds)
+    while i < n:
+        best = ((kinds[i],), 1)
+        for u in range(1, (n - i) // 2 + 1):
+            unit, r = kinds[i:i + u], 1
+            while kinds[i + r * u:i + (r + 1) * u] == unit:
+                r += 1
+            if r > 1 and u * r > len(best[0]) * best[1]:
+                best = (unit, r)
+        out.append(best)
+        i += len(best[0]) * best[1]
+    return out
+
+
+def served_trunk(params: Params, tokens, segment_ids, real, cfg: DecoderConfig):
+    """-> (h (B, L, D) before the final norm, held_counts (expert layers,
+    experts_held), dropped (), block_rows ()). `real` (B, L) marks the
+    positions that hold a token: a span's tail past its document is
+    routed nowhere.
+
+    ONE walk of `layer_table` carries every served stack: the layers of a
+    kind lie stacked on a leading axis (`init_served`), a unit of kinds
+    that repeats (`_compress`) is one `lax.scan` whose body is the unit
+    (walked the same way, so a run of one kind inside it is a scan too)
+    and whose counter gives each kind's place in its stack; what does not
+    repeat is traced where it stands. The carry is the stream and, for
+    the MLP router alone, its narrow state (zeros before the first layer
+    held: the stack starts at the published layer 0, or at a pipeline
+    stage's first layer with the state it would be handed)."""
     positions = segment_positions(segment_ids)
+    # The residual stream is float32 (a layer's result, in the activation
+    # dtype, is added to it): rounding it to bfloat16 at every add would
+    # cost as much accuracy as int8 products do.
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], jnp.maximum(tokens, 0),
                      axis=0).astype(jnp.float32)
-    r = jnp.zeros((x.shape[0] * x.shape[1], cfg.router_hidden_size), jnp.float32)
+    r = jnp.zeros((x.shape[0] * x.shape[1],
+                   cfg.router_hidden_size if cfg.router == "mlp" else 0), jnp.float32)
 
-    def body(carry, l):
-        x, r, counters = _cca_layer(params["cca"], (l,), *carry, segment_ids,
-                                    positions, real, cfg)
-        return (x, r), counters
+    def layer(kind, at, carry):
+        x, r = carry
+        mixer, ffn = LAYER_KINDS[kind]
+        if kind == "cca":
+            x, r, stats = _cca_layer(params[kind], at, x, r, segment_ids,
+                                     positions, real, cfg)
+        elif mixer is None or ffn is None:
+            x, stats = _single_layer(params[kind], at, x, segment_ids, real,
+                                     cfg, kind)
+        else:
+            x, stats = _hybrid_layer(params[kind], at, x, segment_ids,
+                                     positions, real, cfg, mixer)
+        return (x, r), _counters(stats, cfg)
 
-    (x, _), (counts, dropped, block_rows) = lax.scan(
-        body, (x, r), jnp.arange(cfg.num_hidden_layers))
-    return x, counts, dropped.sum(), block_rows.sum()
+    def walk(carry, kinds, base):
+        """The layers `kinds` in order, each kind's first at `base[kind]`
+        of its stack."""
+        base, counters = dict(base), []
+        for unit, repeats in _compress(kinds):
+            per = {k: unit.count(k) for k in unit}
+            if repeats == 1:
+                carry, c = layer(unit[0], (base[unit[0]],), carry)
+            else:
+                def body(carry, i, unit=unit, per=per, base=dict(base)):
+                    at = {k: base[k] + i * per[k] for k in per}
+                    if len(unit) == 1:
+                        return layer(unit[0], (at[unit[0]],), carry)
+                    return walk(carry, unit, at)
+                carry, (counts, dropped, rows) = lax.scan(
+                    body, carry, jnp.arange(repeats))
+                c = (counts.reshape(-1, cfg.experts_held), dropped.sum(), rows.sum())
+            counters.append(c)
+            for k in per:
+                base[k] = base[k] + repeats * per[k]
+        counts, dropped, rows = zip(*counters)
+        return carry, (jnp.concatenate(counts), sum(dropped), sum(rows))
+
+    kinds = tuple(kind for _, kind in layer_table(cfg))
+    (x, _), (counts, dropped, block_rows) = walk(
+        (x, r), kinds, {k: 0 for k in set(kinds)})
+    return x, counts, dropped, block_rows
+
+
+# `benchmark/read_cca_flips.py` calls the CCA stack's trunk by this name.
+cca_trunk = served_trunk
 
 
 def served_embed(params: Params, tokens, segment_ids, num_segments: int,
@@ -755,8 +966,7 @@ def served_embed(params: Params, tokens, segment_ids, num_segments: int,
     document's tokens, "routing": the batch's counters}, float32."""
     real = (segment_ids > 0) & (tokens >= 0)
     with jax.named_scope("encode"):
-        trunk_of = cca_trunk if cfg.mixer == "cca" else hybrid_trunk
-        h, counts, dropped, block_rows = trunk_of(
+        h, counts, dropped, block_rows = served_trunk(
             params, tokens, segment_ids, real, cfg)
         h = rms_norm_apply(params["final_norm"], h, cfg.rms_norm_eps)
     with jax.named_scope("pool"):

@@ -163,3 +163,12 @@ def swiglu_apply(params: Params, x: jax.Array) -> jax.Array:
     gate = x @ params["gate"].astype(dt)
     up = x @ params["up"].astype(dt)
     return (jax.nn.silu(gate) * up) @ params["down"].astype(dt)
+
+
+def ffn_apply(params: Params, x: jax.Array, kind: str = "swiglu") -> jax.Array:
+    """A feed-forward part by its kind: "swiglu" (`swiglu_apply`) or
+    "relu2", W_down(relu(W_up x)^2): two matrices, no gate, no bias."""
+    if kind == "swiglu":
+        return swiglu_apply(params, x)
+    dt = x.dtype
+    return jnp.square(jax.nn.relu(x @ params["up"].astype(dt))) @ params["down"].astype(dt)

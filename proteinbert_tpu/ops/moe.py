@@ -41,11 +41,19 @@ Stages, each under its `jax.named_scope`:
   `block` spare rows of zeros. `kernels/moe_rows.MOE_ROWS_PATH_TOTAL`
   counts which of the two a traced loop got.
 - `moe_experts`: the grouped products: a loop over the blocks that hold
-  anything, each block three products against its expert's matrices.
+  anything, each block three products against its expert's matrices
+  (`kind` "swiglu": down(silu(gate x) * up x)) or two ("relu2":
+  down(relu(up x)^2), no gate matrix).
   The trip count is the number of blocks the step's routing filled, so
   the work follows the assignments that really fell here, and every one
   of them is taken whatever the imbalance: the block table is sized for
   every token choosing held experts in all its slots.
+
+Where the experts are NARROWER than the stream (`cfg.moe_latent_size`:
+LatentMoE) the layer's tokens go down to the latent once before the loop
+(`to_latent`) and the loop's sum comes up once after it (`from_latent`),
+both under `moe_latent`; the router still reads the stream, and the
+loop's rows, slabs and movers are the latent's width.
 
 A loop with a data-dependent trip count has no automatic transpose, so
 `expert_ffn` carries its own backward pass: the same loop, each block
@@ -251,21 +259,31 @@ def _hidden(xb, gate_e, up_e):
     return hg, hu
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def expert_ffn(x, weights, gate, up, down, plan: Plan, top_k: int, block: int):
+KINDS = ("swiglu", "relu2")
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def expert_ffn(x, weights, gate, up, down, plan: Plan, top_k: int, block: int,
+               kind: str = "swiglu"):
     """y (T, D) float32 = for every assignment (t, slot) to a held expert
     e, weights[t, slot] * Expert_e(x[t]) added at row t. x: (T, D) in the
-    compute dtype; gate, up: (E, D, F); down: (E, F, D)."""
-    return _expert_fwd(x, weights, gate, up, down, plan, top_k, block)[0]
+    compute dtype, D the experts' own input width (the stream's, or the
+    latent's); up: (E, D, F); down: (E, F, D); gate: (E, D, F) for the
+    kind "swiglu", None for "relu2" (down(relu(up x)^2))."""
+    return _expert_fwd(x, weights, gate, up, down, plan, top_k, block, kind)[0]
 
 
-def _expert_fwd(x, weights, gate, up, down, plan, top_k, block, at=()):
+def _expert_fwd(x, weights, gate, up, down, plan, top_k, block, kind="swiglu",
+                at=()):
     """`at`: leading indices of the held experts' matrices inside stacks
     of several layers' (the served hybrid decoder hands the whole stack
     and the layer's place in it, so that no layer's experts are copied
     out of the stack before the loop)."""
     T, D = x.shape
     dt = x.dtype
+    if kind not in KINDS or (gate is None) != (kind == "relu2"):
+        raise ValueError(f"expert kind {kind!r} (one of {KINDS}) with "
+                         f"{'no' if gate is None else 'a'} gate matrix")
     _note_path(T, D, block)
     with jax.named_scope("moe_dispatch"):
         xp = _loop_form(x, block)
@@ -281,8 +299,13 @@ def _expert_fwd(x, weights, gate, up, down, plan, top_k, block, at=()):
             # the expert's matrices are rounded here, a block at a time:
             # a copy of every held expert in the compute dtype would
             # stand in HBM for the whole layer
-            hg, hu = _hidden(xb, gate[(*at, e)].astype(dt), up[(*at, e)].astype(dt))
-            h = (jax.nn.silu(hg) * hu).astype(dt)
+            if kind == "swiglu":
+                hg, hu = _hidden(xb, gate[(*at, e)].astype(dt), up[(*at, e)].astype(dt))
+                h = (jax.nn.silu(hg) * hu).astype(dt)
+            else:
+                hu = jnp.dot(xb, up[(*at, e)].astype(dt),
+                             preferred_element_type=jnp.float32)
+                h = jnp.square(jax.nn.relu(hu)).astype(dt)
             ob = jnp.dot(h, down[(*at, e)].astype(dt),
                          preferred_element_type=jnp.float32)
         with jax.named_scope("moe_dispatch"):
@@ -295,11 +318,12 @@ def _expert_fwd(x, weights, gate, up, down, plan, top_k, block, at=()):
     return y, (x, weights, gate, up, down, plan)
 
 
-def _expert_bwd(top_k, block, saved, dy):
+def _expert_bwd(top_k, block, kind, saved, dy):
     x, weights, gate, up, down, plan = saved
     T, D = x.shape
     dt = x.dtype
     A = weights.size
+    swiglu = kind == "swiglu"
     _note_path(T, D, block)
     with jax.named_scope("moe_dispatch"):
         xp = _loop_form(x, block)
@@ -316,25 +340,35 @@ def _expert_bwd(top_k, block, saved, dy):
             xb, dyb = _gather_rows(xp, tok, n, D), _gather_rows(dyp, tok, n, D)
             wb = jnp.where(valid, w_flat[a], 0.0)
         with jax.named_scope("moe_experts"):
-            g16, u16, d16 = (gate[e].astype(dt), up[e].astype(dt),
-                             down[e].astype(dt))
-            hg, hu = _hidden(xb, g16, u16)
-            sg = jax.nn.sigmoid(hg)
-            act = hg * sg
-            h = (act * hu).astype(dt)
+            u16, d16 = up[e].astype(dt), down[e].astype(dt)
+            if swiglu:
+                g16 = gate[e].astype(dt)
+                hg, hu = _hidden(xb, g16, u16)
+                sg = jax.nn.sigmoid(hg)
+                act = hg * sg
+                h = (act * hu).astype(dt)
+            else:
+                hu = jnp.dot(xb, u16, preferred_element_type=jnp.float32)
+                act = jax.nn.relu(hu)
+                h = jnp.square(act).astype(dt)
             ob = jnp.dot(h, d16, preferred_element_type=jnp.float32)
             dwb = jnp.sum(dyb * ob, axis=-1)
             dob = (wb[:, None] * dyb).astype(dt)
             ddown_e = jnp.dot(h.T, dob, preferred_element_type=jnp.float32)
             dh = jnp.dot(dob, d16.T, preferred_element_type=jnp.float32)
-            dhu = (dh * act).astype(dt)
-            dhg = (dh * hu * sg * (1.0 + hg * (1.0 - sg))).astype(dt)
-            dgate_e = jnp.dot(xb.T, dhg, preferred_element_type=jnp.float32)
-            dup_e = jnp.dot(xb.T, dhu, preferred_element_type=jnp.float32)
-            dxb = (jnp.dot(dhg, g16.T, preferred_element_type=jnp.float32)
-                   + jnp.dot(dhu, u16.T, preferred_element_type=jnp.float32))
-            dgate, dup, ddown = (dgate.at[e].add(dgate_e), dup.at[e].add(dup_e),
-                                 ddown.at[e].add(ddown_e))
+            if swiglu:
+                dhu = (dh * act).astype(dt)
+                dhg = (dh * hu * sg * (1.0 + hg * (1.0 - sg))).astype(dt)
+                dgate_e = jnp.dot(xb.T, dhg, preferred_element_type=jnp.float32)
+                dup_e = jnp.dot(xb.T, dhu, preferred_element_type=jnp.float32)
+                dxb = (jnp.dot(dhg, g16.T, preferred_element_type=jnp.float32)
+                       + jnp.dot(dhu, u16.T, preferred_element_type=jnp.float32))
+                dgate = dgate.at[e].add(dgate_e)
+            else:
+                dhu = (dh * 2.0 * act).astype(dt)
+                dup_e = jnp.dot(xb.T, dhu, preferred_element_type=jnp.float32)
+                dxb = jnp.dot(dhu, u16.T, preferred_element_type=jnp.float32)
+            dup, ddown = dup.at[e].add(dup_e), ddown.at[e].add(ddown_e)
         with jax.named_scope("moe_dispatch"):
             dx = _scatter_add_rows(dx, dxb, tok, n)
             dw = dw.at[jnp.where(valid, a, A + spare)].set(
@@ -342,13 +376,13 @@ def _expert_bwd(top_k, block, saved, dy):
         return dx, dw, dgate, dup, ddown
 
     init = (dx0, jnp.zeros((A + block,), jnp.float32),
-            jnp.zeros(gate.shape, jnp.float32), jnp.zeros(up.shape, jnp.float32),
-            jnp.zeros(down.shape, jnp.float32))
+            jnp.zeros(gate.shape, jnp.float32) if swiglu else None,
+            jnp.zeros(up.shape, jnp.float32), jnp.zeros(down.shape, jnp.float32))
     dx, dw, dgate, dup, ddown = lax.fori_loop(0, plan.n_blocks, body, init)
     with jax.named_scope("moe_dispatch"):
         dx = _tokens_form(dx, T, D)
     return (dx.astype(dt), dw[:A].reshape(weights.shape).astype(weights.dtype),
-            dgate.astype(gate.dtype), dup.astype(up.dtype),
+            dgate.astype(gate.dtype) if swiglu else None, dup.astype(up.dtype),
             ddown.astype(down.dtype), None)
 
 
@@ -367,28 +401,39 @@ def moe_apply(params: Dict, bias, x, real, cfg, at=(), router_x=None,
     assignments over it: the share of a block's rows that are real and
     moved), `ids` the experts chosen (`n_routed_experts` at a pad).
     With `at` the experts' matrices are stacks of several layers' and
-    this layer's lie at those leading indices: forward only. The MLP
-    router (`cfg.router` "mlp") reads `router_x` (x where None) and the
-    previous layer's `router_state`, and its own is `stats["router_state"]`."""
+    this layer's lie at those leading indices: forward only. The router
+    reads `router_x` (x where None); the MLP router (`cfg.router` "mlp")
+    also the previous layer's `router_state`, and its own is `stats["router_state"]`.
+    With `cfg.moe_latent_size` the experts read x `to_latent` and their
+    sum comes back `from_latent`; the router reads x itself."""
     stats = {}
     if cfg.router == "mlp":
         ids, weights, stats["router_state"] = route_mlp(
             x if router_x is None else router_x, params["router"],
             router_state, bias, cfg.num_experts_per_tok, cfg.rms_norm_eps)
     else:
-        ids, weights = route(x, params["router"], bias, cfg.num_experts_per_tok,
+        ids, weights = route(x if router_x is None else router_x,
+                             params["router"], bias, cfg.num_experts_per_tok,
                              cfg.routed_scaling_factor, cfg.norm_topk_prob,
                              cfg.n_group, cfg.topk_group)
     ids = jnp.where(real[:, None], ids, cfg.n_routed_experts)
     plan = plan_dispatch(ids, cfg.experts_held, cfg.expert_offset,
                          cfg.expert_block)
-    experts = params["experts"]
-    operands = (x, weights, experts["gate"], experts["up"], experts["down"],
-                plan, cfg.num_experts_per_tok, cfg.expert_block)
+    experts, dt = params["experts"], x.dtype
+    latent = cfg.moe_latent_size is not None
+    if latent:
+        with jax.named_scope("moe_latent"):
+            x = x @ params["to_latent"].astype(dt)
+    operands = (x, weights, experts.get("gate"), experts["up"], experts["down"],
+                plan, cfg.num_experts_per_tok, cfg.expert_block, cfg.expert_kind)
     y = _expert_fwd(*operands, at=at)[0] if at else expert_ffn(*operands)
+    if latent:
+        with jax.named_scope("moe_latent"):
+            y = jnp.dot(y.astype(dt), params["from_latent"].astype(dt),
+                        preferred_element_type=jnp.float32)
     with jax.named_scope("moe_router"):
         load = jnp.zeros((cfg.n_routed_experts + 1,), jnp.int32).at[
             ids.reshape(-1)].add(1)[:-1]
     stats.update(load=load, held_counts=plan.held_counts, dropped=plan.dropped,
                  ids=ids, block_rows=plan.n_blocks * cfg.expert_block)
-    return y.astype(x.dtype), stats
+    return y.astype(dt), stats

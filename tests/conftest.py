@@ -130,12 +130,48 @@ PINNED_SINCE_PR_39 = {
         "asserts that a traced rehearsal prints no metric but the cell's "
         "twenty-six; PR 39's six are on the line too",
 }
+# ISSUE 43 appends an eighth cell, a fourth serving one. Nine assertions
+# under `tests/benchmark/` hold a list of `BENCHMARK.json` WHOLE (the
+# cells of the start-up metrics and of `scope_map_s.tput`; the one cell of
+# `moe_experts_roofline.tput`, which the new cell joins: one share of one
+# scope is not doubled under a second name) or the manifest's ORDER
+# (`scope_map_s.tput` listed after every reader that asks for a scope map:
+# a PR may only append, so the five new readers stand after it; in every
+# cell that lists one of them an earlier reader has asked by then, and the
+# map is made once a class). Marked from here as the two dictionaries above
+# are, in a dictionary of their own (`test_zaya_cell.py` reads those two
+# and requires them to match no test). What each held,
+# `tests/benchmark/test_nemotron_cell.py` asserts by name and as
+# "contains", a case for each; a `benchmark` PR has these nine lines to
+# put right (PERF.md section 7).
+_LISTS_WHOLE = ("holds the metric's list of cells whole "
+                "(`sorted(workloads) == sorted(SERVE)`); PR 43 appended its cell")
+PINNED_SINCE_PR_43 = {
+    **{"benchmark/test_startup_metrics.py::"
+       f"test_the_manifest_lists_the_metric_by_name_with_a_reader[{name}]":
+           _LISTS_WHOLE
+       for name in ("startup_compile_s", "startup_compiles", "startup_cache_load_s",
+                    "startup_trace_lower_s", "startup_warmup_s", "scope_map_s.tput")},
+    "benchmark/test_startup_metrics.py::"
+    "test_the_second_served_decoders_own_metric_stands_as_written"
+    "[moe_experts_roofline.tput]":
+        "holds `moe_experts_roofline.tput`'s list as ZAYA1's cell alone; PR "
+        "43's cell reports the same share of the same scope and joined it",
+    "benchmark/test_zaya_cell.py::"
+    "test_the_manifest_lists_zayas_cell_configuration_and_metrics_by_name":
+        "holds `moe_experts_roofline.tput`'s list as its cell alone; PR 43's "
+        "cell joined it",
+    "benchmark/test_startup_metrics.py::"
+    "test_the_scope_maps_seconds_are_read_after_every_reader_that_asks":
+        "holds `scope_map_s.tput` after EVERY reader that asks for a scope "
+        "map; a PR may only append, so PR 43's five readers stand after it",
+}
 
 
 def pytest_collection_modifyitems(config, items):
     for item in items:
-        for tail, why in {**PINNED_TO_AN_OLDER_MANIFEST,
-                          **PINNED_SINCE_PR_39}.items():
+        for tail, why in {**PINNED_TO_AN_OLDER_MANIFEST, **PINNED_SINCE_PR_39,
+                          **PINNED_SINCE_PR_43}.items():
             if item.nodeid.endswith(tail):
                 item.add_marker(pytest.mark.xfail(reason=why, strict=True))
     unmarked = [

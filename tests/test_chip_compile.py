@@ -413,3 +413,34 @@ def test_served_cca_decoder_fits_the_chip(chip):
     # no copy of K or V at the eight query heads: the kernel's key
     # operand is (rows, 2 key heads, positions, 128)
     assert "bf16[2,2,8192,128]" in text and "bf16[2,8,8192,128]" in text
+
+
+def test_served_pattern_decoder_fits_the_chip(chip):
+    """The packed executable `serve-nemotron3super-sat` times (one chip's
+    share of Nemotron-3-Super's first pipeline stage: 5.38 B bfloat16
+    parameters, 2 rows x 8,192 x 16 documents) compiles for one v5e
+    beside Ling's and ZAYA1's and fits it: the flash forward kernel at a
+    group of 16 (32 query heads on 2 key heads of 128, K and V never
+    repeated) and the experts' two row movers over slabs of the
+    1,024-wide latent are there, the state-space recurrence is plain
+    XLA (no kernel of its own), and arguments + temporaries stay under
+    the 15.75 GiB the compiler holds a program to."""
+    from proteinbert_tpu import inference
+    from proteinbert_tpu.models import glm_moe
+
+    cfg = get_preset("nemotron3super_ep4").model
+    params = glm_moe.served_abstract(cfg)
+    assert glm_moe.served_param_count(cfg) == 5_382_756_608
+    grid = _sds((2, 8192), jnp.int32)
+    compiled = inference._packed_decoder_embed_batch.lower(
+        *_on(chip, (params, grid, grid, _sds((2, 16, 0), jnp.float32))),
+        cfg=cfg).compile()
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes > 10.0 * 2 ** 30
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * 2 ** 30, m
+    text = compiled.as_text()
+    # `MEMEMEM*EMEME`: the flash core once; the movers in (ME) x 3, (EM) x 2
+    # and the last E, each traced once
+    assert text.count("segment_flash_fwd") >= 1
+    assert text.count("moe_gather_rows") >= 3 and text.count("moe_scatter_add_rows") >= 3
+    assert "bf16[2,2,8192,128]" in text and "bf16[2,32,8192,128]" in text

@@ -94,6 +94,7 @@ from proteinbert_tpu.ops.layers import (
 )
 from proteinbert_tpu.ops.moe import moe_apply, router_probs
 from proteinbert_tpu.ops.ssd import gated_group_norm, ssd_chunked
+from proteinbert_tpu.ops.ssd import kernel_takes as ssd_kernel_takes
 
 Params = Dict[str, Any]
 
@@ -353,15 +354,29 @@ def gqa_mixer(p: Params, x, segment_ids, cfg: DecoderConfig):
         return out.reshape(B, L, H * d) @ p["o"].astype(dt)
 
 
+def _note_ssd_core(fits: bool, shape) -> None:
+    """Which recurrence this traced mixer gets (trace time, once a mixer)."""
+    from proteinbert_tpu.kernels.ssd import note_ssd_core_path
+
+    if fits and jax.default_backend() == "tpu":
+        note_ssd_core_path("pallas", "chunked")
+    else:
+        note_ssd_core_path(
+            "reference", "not_tpu" if fits else "tiles_do_not_fit", shape)
+
+
 def mamba_mixer(p: Params, x, segment_ids, cfg: DecoderConfig):
     """The Mamba-2 mixer (`ops/ssd.py` has the recurrence): one product
     in, [z | xBC | dt]; a causal depthwise convolution with bias and SiLU
     over xBC that reads zero across a document's boundary; the selective
     state-space recurrence over x with B, C (a group's heads share them)
-    and dt = softplus(dt + dt_bias), a = -exp(A_log); the skip D x; the
-    gate THEN the norm over each group's channels apart; one product out.
-    Products in the activation dtype accumulated in float32; convolution,
-    dt, decays, the state, gate and norm in float32."""
+    and dt = softplus(dt + dt_bias), a = -exp(A_log): on a TPU the Pallas
+    kernel `kernels/ssd.ssd_chunks` (a group's state in VMEM, x, B, C and y
+    where they lie) at sizes its tiles take, the plain scan over chunks
+    otherwise, counted either way; the skip D x; the gate THEN the norm
+    over each group's channels apart; one product out. Products in the
+    activation dtype accumulated in float32; convolution, dt, decays, the
+    state, gate and norm in float32."""
     with jax.named_scope("mamba"):
         B, L, _ = x.shape
         H, P, G, N = (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups,
@@ -375,10 +390,11 @@ def mamba_mixer(p: Params, x, segment_ids, cfg: DecoderConfig):
         u, b, c = jnp.split(xbc, [inner, inner + G * N], axis=-1)
         u = u.reshape(B, L, H, P)
         step = jax.nn.softplus(step.astype(f32) + p["ssm_dt_bias"].astype(f32))
+        b, c = b.reshape(B, L, G, N), c.reshape(B, L, G, N)
+        _note_ssd_core(ssd_kernel_takes(u, b, cfg.chunk_size, dt), (B, L, H, P, G, N))
         with jax.named_scope("ssd_core"):
             y = ssd_chunked(u, step, -jnp.exp(p["ssm_A_log"].astype(f32)),
-                            b.reshape(B, L, G, N), c.reshape(B, L, G, N),
-                            segment_ids, cfg.chunk_size, dt)
+                            b, c, segment_ids, cfg.chunk_size, dt)
         y = y + p["ssm_D"].astype(f32)[:, None] * u.astype(f32)
         y = gated_group_norm(p["norm"], y.reshape(B, L, inner), z, G,
                              cfg.rms_norm_eps).astype(dt)

@@ -10,12 +10,14 @@ Per head h (its B and C those of group h // (H / G)), with a state S
 
 `ssd_recurrent` is that, token by token (`lax.scan` over tokens): what
 the chunked form is tested and differentiated against. `ssd_chunked` is
-what the model runs, on the CPU and on the chip: a `lax.scan` over chunks
-of Q tokens that carries the state (no Pallas kernel: on the v5e the scan
-is 12 % of a served batch, under a quarter of what the mixer's products
-and elementwise passes around it take; PERF.md section 5). With l_t =
-dt_t a, Lam the running sum of l inside the chunk and S_0 the state the
-chunk starts from:
+what the model runs, in chunks of Q tokens with the state carried from
+chunk to chunk: on a TPU, at sizes its tiles take, one Pallas kernel with
+the state in VMEM (`kernels/ssd.py`); elsewhere, and at other sizes on a
+TPU too, a `lax.scan` over the chunks in plain `jax.numpy` (`_ssd_plain`;
+on the v5e that scan was 18.7 % of a served batch at 2.7 % of its
+roofline: PERF.md sections 5 and 6, PR 44). Both compute the same numbers
+at the same places. With l_t = dt_t a, Lam the running sum of l inside
+the chunk and S_0 the state the chunk starts from:
 
     y_t  = sum over s <= t of one document of
                exp(Lam_t - Lam_s) dt_s (C_t . B_s) x_s          (inside)
@@ -34,12 +36,16 @@ contiguous run (`data/packing.py`), as `ops/kda.py` does.
 The four products of a chunk (C B^T, its masked and decayed form against
 x, C against the state, x against B) take their operands in `dtype` and
 accumulate in float32; dt, the decays and the carried state are float32.
-Plain `jax.numpy`, differentiable as it stands; nothing of the size
-tokens x heads x Q ever stands in HBM whole (a chunk's decay mask is
-rows x heads x Q x Q, 16 MiB at two rows).
+The plain form is differentiable as it stands and the dispatching one
+through it (the kernel's backward is the plain form's, recomputed);
+nothing of the size tokens x heads x Q ever stands in HBM whole (the
+plain form's decay mask is rows x heads x Q x Q a chunk, 16 MiB at two
+rows; the kernel's is one head's, in VMEM).
 """
 
 from __future__ import annotations
+
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -74,20 +80,14 @@ def ssd_recurrent(x, dt, a, b, c, segment_ids):
     return jnp.moveaxis(y, 0, 1)
 
 
-def ssd_chunked(x, dt, a, b, c, segment_ids, chunk: int, dtype=jnp.float32):
-    """`ssd_recurrent` in chunks of `chunk` tokens (L a multiple of it);
-    the products' operands in `dtype`. Inside the scan the heads are ONE
-    leading batch axis beside the rows, B and C repeated to their group's
-    heads a chunk at a time. (Timed ALONE on the v5e at 2 x 8,192 this
-    form took 5.9 ms a layer where the group and its heads as two batch
-    axes took 10.4; inside the served program both read ~11: PERF.md
-    section 6, PR 43.)"""
+def _ssd_plain(x, dt, a, b, c, segment_ids, chunk: int, dtype=jnp.float32):
+    """The chunked recurrence as a `lax.scan` over chunks. Inside the scan
+    the heads are ONE leading batch axis beside the rows, B and C repeated
+    to their group's heads a chunk at a time."""
     f32 = jnp.float32
     B, L, H, P = x.shape
     G, N = b.shape[2:]
     R, Q = H // G, chunk
-    if L % Q:
-        raise ValueError(f"a row of {L} positions is no multiple of the chunk {Q}")
     precision = _HI if jnp.dtype(dtype) == f32 else None
     dot = lambda spec, m, n: jnp.einsum(  # noqa: E731
         spec, m.astype(dtype), n.astype(dtype), precision=precision,
@@ -125,6 +125,66 @@ def ssd_chunked(x, dt, a, b, c, segment_ids, chunk: int, dtype=jnp.float32):
         chunks(x), chunks(dt.astype(f32)[..., None])[..., 0], chunks(b), chunks(c),
         segment_ids.reshape(B, L // Q, Q).transpose(1, 0, 2)))
     return y.transpose(1, 0, 3, 2, 4).reshape(B, L, H, P)
+
+
+def _ssd_tpu(x, dt, a, b, c, segment_ids, chunk: int, dtype=jnp.float32,
+             interpret: bool = False):
+    """The same through the Pallas kernel: x, B and C are read where they
+    lie and y is written where the gated norm reads it; XLA makes only
+    what is (B, L, H) float32 (`kernels/ssd.operands`)."""
+    from proteinbert_tpu.kernels import ssd as kernels
+
+    B, L, H, P = x.shape
+    G = b.shape[2]
+    y = kernels.ssd_chunks(
+        x.reshape(B, L, H * P), b.reshape(B, L, -1), c.reshape(B, L, -1),
+        *kernels.operands(dt, a, segment_ids, G, P, chunk), H, dtype,
+        interpret=interpret)
+    return y.reshape(B, L, H, P)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _ssd_by_platform(x, dt, a, b, c, segment_ids, chunk, dtype):
+    return lax.platform_dependent(
+        x, dt, a, b, c, segment_ids,
+        tpu=partial(_ssd_tpu, chunk=chunk, dtype=dtype),
+        default=partial(_ssd_plain, chunk=chunk, dtype=dtype))
+
+
+def _ssd_fwd(x, dt, a, b, c, segment_ids, chunk, dtype):
+    return (_ssd_by_platform(x, dt, a, b, c, segment_ids, chunk, dtype),
+            (x, dt, a, b, c, segment_ids))
+
+
+def _ssd_bwd(chunk, dtype, saved, g):
+    *floats, segment_ids = saved
+    _, vjp = jax.vjp(lambda *o: _ssd_plain(*o, segment_ids, chunk, dtype), *floats)
+    return (*vjp(g), None)
+
+
+_ssd_by_platform.defvjp(_ssd_fwd, _ssd_bwd)
+
+
+def kernel_takes(x, b, chunk: int, dtype=jnp.float32) -> bool:
+    """Whether `ssd_chunked` on a TPU runs these operands through the
+    kernel (x: (B, L, H, P); b: (B, L, G, N))."""
+    from proteinbert_tpu.kernels.ssd import tiles_fit
+
+    return tiles_fit(x.shape[1], x.shape[2], x.shape[3], *b.shape[2:], chunk, dtype)
+
+
+def ssd_chunked(x, dt, a, b, c, segment_ids, chunk: int, dtype=jnp.float32):
+    """`ssd_recurrent` in chunks of `chunk` tokens (L a multiple of it);
+    the products' operands in `dtype`. Where the kernel's tiles take the
+    sizes (`kernel_takes`) a program lowered for a TPU runs the kernel and
+    any other the plain scan; at other sizes the plain scan everywhere.
+    One backward, the plain scan's."""
+    if x.shape[1] % chunk:
+        raise ValueError(
+            f"a row of {x.shape[1]} positions is no multiple of the chunk {chunk}")
+    if kernel_takes(x, b, chunk, dtype):
+        return _ssd_by_platform(x, dt, a, b, c, segment_ids, chunk, dtype)
+    return _ssd_plain(x, dt, a, b, c, segment_ids, chunk, dtype)
 
 
 def gated_group_norm(scale, y, z, groups: int, eps: float):

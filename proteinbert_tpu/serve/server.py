@@ -392,6 +392,9 @@ class Server:
         from proteinbert_tpu.kernels.segment_flash import (
             register_cca_core_path_observer,
         )
+        from proteinbert_tpu.kernels.ssd import (
+            register_ssd_core_path_observer,
+        )
 
         self._path_c: Dict[Any, Any] = {}
 
@@ -422,8 +425,13 @@ class Server:
         def _mirror_cca_core_path(path: str, reason: str) -> None:
             _mirror("cca_core_kernel_path_total", path, reason)
 
+        def _mirror_ssd_core_path(path: str, reason: str) -> None:
+            _mirror("ssd_core_kernel_path_total", path, reason)
+
         self._cca_core_path_cb = _mirror_cca_core_path
         register_cca_core_path_observer(self._cca_core_path_cb)
+        self._ssd_core_path_cb = _mirror_ssd_core_path
+        register_ssd_core_path_observer(self._ssd_core_path_cb)
         self._path_cb = _mirror_path
         self._attn_path_cb = _mirror_attn_path
         self._onepass_path_cb = _mirror_onepass_path
@@ -741,12 +749,16 @@ class Server:
         from proteinbert_tpu.kernels.segment_flash import (
             unregister_cca_core_path_observer,
         )
+        from proteinbert_tpu.kernels.ssd import (
+            unregister_ssd_core_path_observer,
+        )
 
         unregister_path_observer(self._path_cb)
         unregister_attention_path_observer(self._attn_path_cb)
         unregister_onepass_path_observer(self._onepass_path_cb)
         unregister_moe_rows_path_observer(self._moe_rows_path_cb)
         unregister_cca_core_path_observer(self._cca_core_path_cb)
+        unregister_ssd_core_path_observer(self._ssd_core_path_cb)
 
     def abort(self) -> None:
         """Hard shutdown: fail all queued + pending work with
@@ -1173,6 +1185,7 @@ class Server:
         from proteinbert_tpu.kernels.moe_rows import MOE_ROWS_PATH_TOTAL
         from proteinbert_tpu.kernels.one_pass import ONEPASS_PATH_TOTAL
         from proteinbert_tpu.kernels.segment_flash import CCA_CORE_PATH_TOTAL
+        from proteinbert_tpu.kernels.ssd import SSD_CORE_PATH_TOTAL
 
         qw = self.scheduler.queue_wait
         # One coherent locked read of the dispatch counters: the
@@ -1222,6 +1235,12 @@ class Server:
             "cca_core_path": {f"{p}/{r}": n
                               for (p, r), n
                               in sorted(CCA_CORE_PATH_TOTAL.items())},
+            # The Mamba-2 mixer's recurrence (ISSUE 44): "pallas/chunked"
+            # where it is the kernel with a group's state in VMEM,
+            # "reference/*" where the plain scan over chunks.
+            "ssd_core_path": {f"{p}/{r}": n
+                              for (p, r), n
+                              in sorted(SSD_CORE_PATH_TOTAL.items())},
             # Quantized executable arm (ISSUE 12): which arm serves,
             # the measured weight-HBM footprint, and the worst sampled
             # parity deviation vs the fp32 shadow (None = fp32 arm).

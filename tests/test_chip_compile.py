@@ -339,6 +339,29 @@ def test_kda_core_compiles_for_v5e(chip):
     assert text.count("tpu_custom_call") == 2, "A and B; the state walk"
 
 
+def test_ssd_core_compiles_for_v5e(chip):
+    """The Mamba-2 recurrence at Nemotron-3-Super's published sizes (one
+    row of 8,192, 128 heads of 64 in 8 groups on a state of 128, chunks
+    of 128, bfloat16 operands): ONE Pallas kernel (kernels/ssd.py: a
+    group's state in VMEM for the whole row) compiles for one v5e, and no
+    operand or result of x's size is copied or transposed around it."""
+    from proteinbert_tpu.ops import ssd
+
+    B, L, H, P, G, N = 1, 8192, 128, 64, 8, 128
+    assert ssd.kernel_takes(_sds((B, L, H, P)), _sds((B, L, G, N)), 128, jnp.bfloat16)
+
+    def flat(x, dt, a, b, c, seg):      # as `mamba_mixer` holds them
+        return ssd._ssd_tpu(x.reshape(B, L, H, P), dt, a, b.reshape(B, L, G, N),
+                            c.reshape(B, L, G, N), seg, 128,
+                            jnp.bfloat16).reshape(B, L, H * P)
+
+    args = (_sds((B, L, H * P)), _sds((B, L, H), jnp.float32), _sds((H,), jnp.float32),
+            _sds((B, L, G * N)), _sds((B, L, G * N)), _sds((B, L), jnp.int32))
+    text = jax.jit(flat).lower(*_on(chip, args)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "bf16[1,8192,128,64]" not in text and "f32[1,8192,128,64]" not in text
+
+
 @pytest.mark.parametrize("width,block", [(2560, 384), (2048, 512)])
 def test_the_experts_row_movers_compile_for_v5e(chip, width, block):
     """The experts' loop's two movers (kernels/moe_rows.py) at the served
@@ -422,9 +445,10 @@ def test_served_pattern_decoder_fits_the_chip(chip):
     beside Ling's and ZAYA1's and fits it: the flash forward kernel at a
     group of 16 (32 query heads on 2 key heads of 128, K and V never
     repeated) and the experts' two row movers over slabs of the
-    1,024-wide latent are there, the state-space recurrence is plain
-    XLA (no kernel of its own), and arguments + temporaries stay under
-    the 15.75 GiB the compiler holds a program to."""
+    1,024-wide latent are there, the state-space recurrence is its own
+    kernel (`ssd_chunks`, once a traced mixer) with x handed to it as the
+    split leaves it, and arguments + temporaries stay under the 15.75 GiB
+    the compiler holds a program to."""
     from proteinbert_tpu import inference
     from proteinbert_tpu.models import glm_moe
 
@@ -443,4 +467,7 @@ def test_served_pattern_decoder_fits_the_chip(chip):
     # and the last E, each traced once
     assert text.count("segment_flash_fwd") >= 1
     assert text.count("moe_gather_rows") >= 3 and text.count("moe_scatter_add_rows") >= 3
+    # (ME) x 3, M, (EM) x 2: three traced mixers, no scan over chunks left
+    assert text.count("ssd_chunks") >= 3
+    assert "bf16[2,8192,128,64]" not in text and "f32[2,8192,128,64]" not in text
     assert "bf16[2,2,8192,128]" in text and "bf16[2,32,8192,128]" in text

@@ -550,15 +550,19 @@ def test_the_tpu_branches_of_the_whole_model_equal_the_reference(tiny, monkeypat
     `lax.platform_dependent` on its TPU branch and the Pallas kernels in
     the interpreter: the flash kernel at a group of 2 with heads of the
     published 128, the experts' row movers over slabs of a 128-wide
-    latent; packed rows with a pad tail, against the reference on each
-    document ALONE."""
+    latent, the recurrence's kernel over 2 groups of 2 heads of the
+    published 64 on the published state of 128 in chunks of 128 (two
+    heads share a lane tile; boundaries fall inside chunks); packed rows
+    with a pad tail, against the reference on each document ALONE."""
     from jax import lax
     from jax.experimental import pallas as pl
 
     cfg, c = tiny
+    mamba = dict(mamba_num_heads=4, mamba_head_dim=64, ssm_state_size=128,
+                 chunk_size=128)
     m = dataclasses.replace(cfg.model, cca_head_dim=128, attention_block=128,
-                            moe_latent_size=128, num_hidden_layers=9)
-    c = dict(c, head_dim=128, moe_latent_size=128, num_hidden_layers=9)
+                            moe_latent_size=128, num_hidden_layers=9, **mamba)
+    c = dict(c, head_dim=128, moe_latent_size=128, num_hidden_layers=9, **mamba)
     params = glm_moe.init_served(ref.seed_key(SEED), m)
     tokens, seg, docs = _packed([[100, 37, 90], [200, 56]], 256, m.vocab_size)
     called, pallas_call = [], pl.pallas_call
@@ -573,8 +577,9 @@ def test_the_tpu_branches_of_the_whole_model_equal_the_reference(tiny, monkeypat
     got = jax.jit(lambda p: glm_moe.served_embed(p, tokens, seg, 4, m))(params)
     # MEMEMEM*E: the unit (M, E) traced once, then M, *, E
     assert sorted(set(called)) == ["moe_gather_rows", "moe_scatter_add_rows",
-                                   "segment_flash_fwd"]
+                                   "segment_flash_fwd", "ssd_chunks"]
     assert called.count("segment_flash_fwd") == 1
+    assert called.count("ssd_chunks") == 2
     assert called.count("moe_gather_rows") == 2
     assert int(got["routing"]["dropped"]) == 0
     want = ref.embed_documents(SEED, [d for _, _, d in docs], c)
